@@ -27,6 +27,10 @@ import tempfile
 from pathlib import Path
 from urllib import request as urlreq
 
+# A CPU smoke: this process and every child it starts are held to the
+# CPU (jax reads the variable when it is imported; children inherit it).
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -46,7 +50,6 @@ def check_finite(record, path):
 def main():
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, REPO)
     # Pin the roofline denominators: a host CPU has no device-kind
     # entry, and the classification path must still be exercised.

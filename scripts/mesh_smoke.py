@@ -237,7 +237,6 @@ def check_population_sharded():
 def main():
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     if jax.device_count() != N_DEV:
         fail(
             f"expected {N_DEV} forced CPU devices, got {jax.device_count()} "

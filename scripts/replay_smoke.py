@@ -28,6 +28,10 @@ import sys
 import tempfile
 from pathlib import Path
 
+# A CPU smoke: this process and every child it starts are held to the
+# CPU (jax reads the variable when it is imported; children inherit it).
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # The loss columns the bitwise A/B comparison pins.
@@ -69,7 +73,6 @@ def run(train_main, root, extra):
 def main():
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, REPO)
     from torch_actor_critic_tpu.train import main as train_main
 

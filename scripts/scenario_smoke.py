@@ -25,6 +25,10 @@ import sys
 import tempfile
 from pathlib import Path
 
+# A CPU smoke: this process and every child it starts are held to the
+# CPU (jax reads the variable when it is imported; children inherit it).
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -62,7 +66,6 @@ def run_dir_of(root: Path):
 def main():
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, REPO)
     from torch_actor_critic_tpu.train import main as train_main
 

@@ -6,7 +6,7 @@ the contract docs/OBSERVABILITY.md promises:
 
 - ``<run_dir>/telemetry.jsonl`` exists, every line is strict JSON, and
   there is one ``epoch`` event per epoch with the full 8-phase
-  taxonomy whose per-phase sums cover ~the epoch wall time;
+  classification whose per-phase sums cover ~the epoch wall time;
 - ``<run_dir>/trace`` holds a TensorBoard/xprof-loadable XLA trace
   (``plugins/profile/<ts>/*``) captured over exactly the window;
 - ``<run_dir>/metrics.jsonl`` rows carry the save/sentinel accounting
@@ -20,6 +20,10 @@ import os
 import sys
 import tempfile
 from pathlib import Path
+
+# A CPU smoke: this process and every child it starts are held to the
+# CPU (jax reads the variable when it is imported; children inherit it).
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PHASES = (
@@ -36,7 +40,6 @@ def fail(msg):
 def main():
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, REPO)
     from torch_actor_critic_tpu.train import main as train_main
 

@@ -100,18 +100,20 @@ def parse_arguments(argv=None) -> argparse.Namespace:
                      help="Warm-start bundle dir (aot/bundle.py), or "
                           "'auto' for the checkpoint-adjacent default "
                           "(<ckpt parent>/warm_start). Warmup loads "
-                          "pre-compiled executables from the bundle's "
-                          "persistent cache so the first /act pays "
+                          "the executables the bundle's build left in "
+                          "the compile cache, so the first /act pays "
                           "ZERO live compiles; a fingerprint-"
                           "mismatched bundle is rejected loudly "
                           "(watchdog bundle_rejected) and serving "
                           "falls back to live compile")
-    aot.add_argument("--compile-cache", metavar="DIR", default=None,
-                     help="Persistent XLA compilation cache dir "
-                          "(aot/cache.py) shared across processes — "
-                          "fleet workers and restarts compile once "
-                          "fleet-wide. Overrides the bundle's own "
-                          "cache when both are given")
+    aot.add_argument("--compile-cache", action="store_true",
+                     help="Turn on the persistent XLA compilation "
+                          "cache (aot/cache.py) shared across "
+                          "processes — fleet workers and restarts "
+                          "compile once fleet-wide. It lives where "
+                          "JAX_COMPILATION_CACHE_DIR says, else in "
+                          "<checkout>/.jax_cache. Implied by "
+                          "--warm-start")
     flt = p.add_argument_group("fleet (multi-process)")
     flt.add_argument("--fleet", type=int, default=0,
                      help="Spawn N serve.py worker processes and front "
@@ -381,20 +383,103 @@ def _await_worker_ready(proc, idx: int, timeout_s: float = 300.0):
     return address
 
 
-def _spawn_worker(argv, idx: int):
+def _spawn_worker(argv, idx: int, chip: int | None = None):
     """Launch one serve.py worker subprocess (ephemeral port) — the
     spawn half of warm-pool/replacement spawns; readiness is awaited
-    separately (or by the caller via _await_worker_ready)."""
+    separately (or by the caller via _await_worker_ready). ``chip``
+    is the local chip the worker is shown, and the only one: a chip
+    belongs to one process at a time, so workers that inherited one
+    environment would all reach for the same (every) chip. ``None``
+    (a CPU host) inherits the environment unchanged."""
     import os
     import subprocess
     import sys
+
+    from torch_actor_critic_tpu.utils.procenv import chip_env
 
     here = os.path.dirname(os.path.abspath(__file__))
     return subprocess.Popen(
         [sys.executable, os.path.join(here, "serve.py")]
         + _worker_argv(argv, worker=idx),
         stdout=subprocess.PIPE, stderr=None, text=True, cwd=here,
+        env=None if chip is None else chip_env(chip),
     )
+
+
+def _local_chips() -> int | None:
+    """How many accelerator chips this host has for workers, or None on
+    a CPU host. Asked of a short-lived child that exits before any
+    worker starts, so the router process itself never holds a chip."""
+    import os
+    import subprocess
+    import sys
+
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return None
+    probe = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; d = jax.local_devices(); "
+         "print(d[0].platform, len(d))"],
+        capture_output=True, text=True, timeout=300,
+    )
+    if probe.returncode != 0:
+        raise SystemExit(
+            "--fleet could not ask JAX for this host's devices: "
+            + probe.stderr[-500:]
+        )
+    platform, count = probe.stdout.split()[-2:]
+    return None if platform == "cpu" else int(count)
+
+
+class _ChipLeases:
+    """One chip per live worker. A worker is started on the lowest
+    local chip whose last tenant has exited; on a CPU host
+    (``chips=None``) workers simply inherit the environment."""
+
+    def __init__(self, chips: int | None):
+        import threading
+
+        self.chips = chips
+        self._tenant = {}  # chip -> Popen; guarded-by: _lock
+        self._lock = threading.Lock()
+
+    def check(self, args) -> None:
+        """Refuse, before anything is spawned, a fleet that would ever
+        need more workers alive than there are chips."""
+        if self.chips is None:
+            return
+        replicas = max(
+            args.fleet, args.elastic_max if args.elastic == "on" else 0
+        )
+        need = replicas + args.warm_pool
+        if need > self.chips:
+            raise SystemExit(
+                f"--fleet needs {need} chips ({replicas} serving worker(s)"
+                f" + {args.warm_pool} warm spare(s)), one for each worker "
+                f"process, but this host has {self.chips}: lower --fleet"
+                " / --warm-pool / --elastic-max, or serve several "
+                "devices from one process with --devices"
+            )
+
+    def spawn(self, argv, idx: int):
+        if self.chips is None:
+            return _spawn_worker(argv, idx)
+        with self._lock:
+            chip = next(
+                (
+                    c for c in range(self.chips)
+                    if c not in self._tenant
+                    or self._tenant[c].poll() is not None
+                ),
+                None,
+            )
+            if chip is None:
+                raise RuntimeError(
+                    f"all {self.chips} chips have a live worker; worker "
+                    f"{idx} cannot start"
+                )
+            self._tenant[chip] = proc = _spawn_worker(argv, idx, chip)
+            return proc
 
 
 def run_fleet(args, argv):
@@ -431,9 +516,11 @@ def run_fleet(args, argv):
                 "serving path)"
             )
 
+    leases = _ChipLeases(_local_chips())
+    leases.check(args)
     workers, worker_lock = [], threading.Lock()
     for i in range(args.fleet):
-        workers.append(_spawn_worker(argv, i))
+        workers.append(leases.spawn(argv, i))
     addresses = [
         _await_worker_ready(proc, i) for i, proc in enumerate(workers)
     ]
@@ -490,7 +577,7 @@ def run_fleet(args, argv):
 
         def _spawn_spare():
             idx = next(spare_idx)
-            proc = _spawn_worker(argv, idx)
+            proc = leases.spawn(argv, idx)
             return proc, _await_worker_ready(proc, idx)
 
         def _kill_worker(proc):
@@ -696,10 +783,6 @@ def main(argv=None):
     if args.fleet and args.fleet > 0:
         run_fleet(args, argv)
         return
-    from torch_actor_critic_tpu.utils.platform import honor_platform_env
-
-    honor_platform_env()
-
     from torch_actor_critic_tpu.serve import (
         CircuitBreaker,
         ModelRegistry,
@@ -740,17 +823,13 @@ def main(argv=None):
         except BundleMismatchError as e:
             get_watchdog().note_bundle_rejected(str(bundle_dir) + ": " + e.reason)
             bundle = None
-    if args.compile_cache:
+    if args.compile_cache or bundle is not None:
         from torch_actor_critic_tpu.aot import enable_persistent_cache
 
-        enable_persistent_cache(args.compile_cache)
-    elif bundle is not None:
-        from torch_actor_critic_tpu.aot import enable_persistent_cache
-
-        # The bundle's own pre-populated cache: reads make warmup
-        # compile-free; writes (boot-time host programs) accrete for
-        # the next worker consuming the same bundle.
-        enable_persistent_cache(bundle.cache_dir, export_env=False)
+        # A bundle is the index of programs its build left in this
+        # cache: reads make warmup compile-free; writes (boot-time
+        # host programs) accrete for the next worker.
+        enable_persistent_cache()
 
     try:
         tp, fsdp = (int(x) for x in args.submesh.lower().split("x"))
@@ -858,8 +937,17 @@ def main(argv=None):
     # Rolling-restart contract: SIGTERM stops admissions, answers every
     # accepted request, then serve_forever returns and we exit 0.
     install_drain_handler(server, flush_timeout_s=args.drain_timeout)
+    import jax
+
+    devs = jax.devices()
     print(json.dumps({
         "serving": server.address, "slots": registry.slots(),
+        # What this worker's forwards run on, as JAX reports it.
+        "device": {
+            "platform": devs[0].platform,
+            "kind": devs[0].device_kind,
+            "count": len(devs),
+        },
     }), flush=True)
     try:
         server.serve_forever()

@@ -107,15 +107,17 @@ def test_serve_programs_cover_the_warmup_ladder():
 
 
 @pytest.fixture(scope="module")
-def built(tmp_path_factory):
+def built(tmp_path_factory, compile_cache_at):
     """One bundle shared by the read-only bundle tests: a real
-    build_bundle() run (engine warmup -> xla_cache + jax.export)."""
-    root = tmp_path_factory.mktemp("aot") / "warm_start"
+    build_bundle() run (engine warmup -> compile cache + jax.export),
+    with the cache in a directory of this module's own."""
+    tmp = tmp_path_factory.mktemp("aot")
     actor, params = make_actor_and_params()
-    bundle = build_bundle(
-        root, actor, flat_spec(), params, max_batch=4,
-    )
-    return bundle, actor, params
+    with compile_cache_at(tmp / "jax_cache"):
+        bundle = build_bundle(
+            tmp / "warm_start", actor, flat_spec(), params, max_batch=4,
+        )
+        yield bundle, actor, params
 
 
 def test_bundle_layout_and_manifest(built):
@@ -321,15 +323,15 @@ def test_warm_pool_counts_spawn_failures():
 # ------------------------------------------- learner restart on the cache
 
 
-def test_learner_restart_rides_cache_bitwise(tmp_path):
-    """A restarted learner pointed at the run's persistent compilation
-    cache re-jits from disk hits and produces a loss stream BITWISE
-    identical to the cold-cache run; --emit-bundle drops the
-    checkpoint-adjacent warm_start bundle at the first update epoch."""
-    from torch_actor_critic_tpu.aot.cache import (
-        disable_persistent_cache,
-        enable_persistent_cache,
-    )
+def test_learner_restart_rides_cache_bitwise(tmp_path, compile_cache_at):
+    """A restarted learner on the same persistent compilation cache
+    re-jits from disk hits — the donated burst and push programs
+    included: the jaxlib 0.4.36 defect that once kept them out of the
+    cache does not reproduce on the installed jaxlib — and produces a
+    loss stream BITWISE identical to the cold-cache run; --emit-bundle
+    drops the checkpoint-adjacent warm_start bundle at the first
+    update epoch."""
+    from torch_actor_critic_tpu.aot.cache import enable_persistent_cache
     from torch_actor_critic_tpu.parallel import make_mesh
     from torch_actor_critic_tpu.sac.trainer import Trainer
     from torch_actor_critic_tpu.utils.checkpoint import Checkpointer
@@ -340,8 +342,6 @@ def test_learner_restart_rides_cache_bitwise(tmp_path):
         steps_per_epoch=40, start_steps=10, update_after=10,
         update_every=10, buffer_size=500, max_ep_len=100, save_every=1,
     )
-    cache_dir = str(tmp_path / "xla_cache")
-
     def run(sub, emit):
         losses = []
         cfg = SACConfig(**tiny, emit_bundle=emit)
@@ -365,9 +365,11 @@ def test_learner_restart_rides_cache_bitwise(tmp_path):
         return losses, ckpt_dir
 
     wd = get_watchdog().install()
-    enable_persistent_cache(cache_dir)
-    try:
+    with compile_cache_at(tmp_path / "jax_cache"):
+        enable_persistent_cache()
+        wd.reset()
         losses_a, ckpt_a = run("a", emit=True)
+        cold = wd.snapshot()
         # --emit-bundle: the bundle landed next to the checkpoint at
         # the first update epoch, cache populated by its own warmup.
         bundle = load_bundle(default_bundle_dir(ckpt_a))
@@ -377,10 +379,12 @@ def test_learner_restart_rides_cache_bitwise(tmp_path):
         wd.reset()
         losses_b, _ = run("b", emit=False)
         snap = wd.snapshot()
-    finally:
-        disable_persistent_cache()
 
     assert losses_a and losses_a == losses_b  # bitwise on the stream
     # The restarted learner really did ride the cache, not re-derive
-    # it: its jit dispatches resolved to persistent-cache disk hits.
+    # it: every program the cold run had to compile — the train
+    # plane's donated burst and push among them — came back as a
+    # persistent-cache disk hit.
+    assert cold["cache_misses_total"] > 0
     assert snap["cache_hits_total"] > 0
+    assert snap["cache_misses_total"] == 0

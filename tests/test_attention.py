@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from torch_actor_critic_tpu.models import SequenceActor, SequenceDoubleCritic
-from torch_actor_critic_tpu.parallel.context import manual_shard_map as shard_map
+from jax import shard_map
 from torch_actor_critic_tpu.ops.attention import (
     attention,
     blockwise_attention,

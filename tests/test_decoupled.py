@@ -9,7 +9,7 @@ or call counts, clocks/sleeps are injected, nothing is timing-flaky:
   drops + bounds the lag histogram, conservation invariant, pause/
   resume, checkpoint array round-trip.
 - PolicyClient: the in-process retry/backoff is bounded, deadline-aware
-  and taxonomy-preserving (transport parity with PR-9's HTTP mode).
+  and classification-preserving (transport parity with PR-9's HTTP mode).
 - ActorWorker: degrade-to-snapshot on serving loss (no stalled envs),
   probe-and-re-home, idle-spin against a paused staging buffer.
 - DecoupledTrainer: acting through the real serving stack, per-epoch
@@ -353,7 +353,7 @@ def test_inprocess_client_retries_sheds_with_backoff_and_hint():
     assert all(t_ is not None and t_ <= 60.0 for t_ in batcher.timeouts)
 
 
-def test_inprocess_client_retry_is_bounded_and_taxonomy_preserved():
+def test_inprocess_client_retry_is_bounded_and_classification_preserved():
     batcher = _ScriptedBatcher([
         ShedError("queue_full", "full", retry_after_s=0.0)
         for _ in range(10)

@@ -275,7 +275,7 @@ def test_reduction_suffix_rules():
 
 def test_replica_skew_under_shard_map():
     from torch_actor_critic_tpu.parallel import make_mesh
-    from torch_actor_critic_tpu.parallel.context import manual_shard_map as shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = make_mesh(dp=4)

@@ -67,14 +67,15 @@ def _dp_inputs(dp, seed_buf=128, n_updates_chunk=10):
 
 def test_gspmd_burst_matches_legacy_shard_map_burst():
     """THE substrate-parity pin: one update burst through the retired
-    ``compat.shard_map`` path and through the new jit-with-sharding
+    manual ``jax.shard_map`` body and through the jit-with-sharding
     path, same 2-device mesh, same inputs — params, opt state and
     metrics must agree. Proves the rebuild is a pure substrate swap:
     identical per-device key streams and math, only the mapping
     machinery changed (on CPU the two even agree bitwise; the pin is
     allclose so TPU reduction-order freedom can't break it)."""
+    from jax import shard_map
+
     from torch_actor_critic_tpu.parallel import dp as dp_mod
-    from torch_actor_critic_tpu.parallel.compat import shard_map
 
     sac = make_sac()
     mesh = make_mesh(dp=2, devices=jax.devices()[:2])
@@ -154,10 +155,9 @@ def test_gspmd_burst_matches_legacy_shard_map_burst():
 def test_dp_burst_no_shard_map_on_hot_path():
     """The acceptance pin, promoted from a source-regex check to the
     tac-lint ``shard-map-hot-path`` rule (docs/ANALYSIS.md): any
-    ``shard_map`` reference outside ``parallel/context.py`` +
-    ``parallel/compat.py`` must sit in the rule's checked allowlist
-    (the ``parallel/__init__`` re-export and the manual-by-nature sp
-    ring burst), and every allowlist entry must still match real code
+    ``shard_map`` reference outside ``parallel/context.py`` must sit
+    in the rule's checked allowlist (the manual-by-nature sp ring
+    burst), and every allowlist entry must still match real code
     (``stale-allowlist``). Zero findings over the whole package means
     the allowlist is the single source of truth for where manual
     mapping is allowed to live."""
@@ -181,10 +181,8 @@ def test_dp_burst_no_shard_map_on_hot_path():
 def test_dp_fsdp_hybrid_runs_without_version_gate():
     """(dp=2, fsdp=2) with the size threshold forced to 0: parameters
     really shard over fsdp, the burst compiles and runs under plain
-    auto partitioning on the installed jax (no ``hasattr(jax,
-    'shard_map')`` gate anywhere), and the update equals the
-    all-replicated (fsdp=1) burst — fsdp changes layout, not math."""
-    assert not hasattr(jax, "shard_map")  # the gated jax: still works
+    auto partitioning, and the update equals the all-replicated
+    (fsdp=1) burst — fsdp changes layout, not math."""
 
     def run(fsdp):
         sac = make_sac()

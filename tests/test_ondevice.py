@@ -326,7 +326,7 @@ class TestHistoryEnv:
 def test_on_device_run_evaluates_through_host_eval_cli(tmp_path):
     """A run trained with the fused on-device loop must load through the
     product eval CLI and roll out on the real host env — the crossover
-    ``scripts/tpu_train_proof.py`` relies on (checkpoint layout shared
+    the runs/train_proof/ artifacts rest on (checkpoint layout shared
     between OnDeviceLoop and the host Trainer, buffer excluded)."""
     from torch_actor_critic_tpu.run_agent import main as eval_main
     from torch_actor_critic_tpu.train import main as train_main

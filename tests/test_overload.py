@@ -424,7 +424,13 @@ def test_reload_rejects_nan_checkpoint_keeps_last_good(tmp_path):
     )
     with MicroBatcher(reg, max_batch=4, max_wait_ms=1.0) as mb:
         before = mb.act(obs, timeout=30.0)
-        np.testing.assert_array_equal(before.action, np.asarray(expected0))
+        # Against the unbatched apply: float32 round-off (a padded
+        # bucket forward and a matvec are differently batched
+        # computations; XLA promises no bitwise equality there). The
+        # bitwise pin is below, at equal shapes: before == after.
+        np.testing.assert_allclose(
+            before.action, np.asarray(expected0), rtol=2e-6, atol=2e-7
+        )
 
         _save_checkpoint(ckpt_dir, 1, seed=99)
         corrupt_checkpoint(ckpt_dir, 1, mode="nan-params")

@@ -19,7 +19,7 @@ from torch_actor_critic_tpu.parallel import (
     make_mesh,
     shard_chunk,
 )
-from torch_actor_critic_tpu.parallel.context import manual_shard_map as shard_map
+from jax import shard_map
 from torch_actor_critic_tpu.sac import SAC
 from torch_actor_critic_tpu.utils.config import SACConfig
 

@@ -103,9 +103,13 @@ def test_warmup_epoch_bitwise_equals_stacked_single_runs():
 
 
 def test_update_epoch_matches_stacked_single_runs():
-    """PBT off, with gradient bursts: loss streams stay bitwise; the
-    parameter trajectories agree to the documented float-reassociation
-    tolerance (vmap batches the backward matmuls)."""
+    """PBT off, with gradient bursts: loss streams and parameter
+    trajectories agree to the documented float-reassociation tolerance.
+    The vmapped population and a single run are differently batched
+    computations of the same numbers (vmap batches the forward and
+    backward matmuls), and XLA promises no bitwise equality between
+    those — the bitwise pin at equal shapes is
+    ``test_member_independence_is_bitwise`` below."""
     sac = _sac()
     pop = PopulationOnDeviceLoop(sac, PendulumJax, 2, n_envs=N_ENVS)
     root = jax.random.key(1)
@@ -126,12 +130,11 @@ def test_update_epoch_matches_stacked_single_runs():
         sts, sbuf, ses, skey, sm = single.epoch(
             sts, sbuf, ses, skey, steps=20, update_every=10
         )
-        np.testing.assert_array_equal(
-            np.asarray(m["loss_q"])[i], np.asarray(sm["loss_q"])
-        )
-        np.testing.assert_array_equal(
-            np.asarray(m["loss_pi"])[i], np.asarray(sm["loss_pi"])
-        )
+        for loss in ("loss_q", "loss_pi"):
+            np.testing.assert_allclose(
+                np.asarray(m[loss])[i], np.asarray(sm[loss]),
+                rtol=2e-5, atol=2e-6,
+            )
         got = jax.tree_util.tree_leaves(
             jax.tree_util.tree_map(lambda x: x[i], ts.actor_params)
         )

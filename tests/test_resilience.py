@@ -24,7 +24,7 @@ import numpy as np
 import pytest
 
 from torch_actor_critic_tpu.envs.vec_env import ParallelEnvPool
-from torch_actor_critic_tpu.native import load_runtime
+from torch_actor_critic_tpu.native import NativeRuntimeError, load_runtime
 from torch_actor_critic_tpu.parallel import make_mesh
 from torch_actor_critic_tpu.resilience import (
     REQUEUE_EXIT_CODE,
@@ -45,8 +45,16 @@ from torch_actor_critic_tpu.sac.trainer import Trainer
 from torch_actor_critic_tpu.utils.checkpoint import Checkpointer
 from torch_actor_critic_tpu.utils.config import SACConfig
 
+def _native_missing() -> bool:
+    try:
+        load_runtime()
+    except NativeRuntimeError:
+        return True
+    return False
+
+
 needs_native = pytest.mark.skipif(
-    load_runtime() is None, reason="native runtime unavailable"
+    _native_missing(), reason="native runtime unavailable"
 )
 
 TINY = dict(

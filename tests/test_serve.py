@@ -43,6 +43,18 @@ def flat_spec():
     return jax.ShapeDtypeStruct((OBS_DIM,), jnp.float32)
 
 
+def assert_matches_unbatched(got, want):
+    """A padded bucket forward (a gemm) against the unbatched model
+    apply (a matvec): two differently batched computations of the same
+    numbers reduce in different orders, and XLA promises no bitwise
+    equality between them (the drift on jaxlib 0.9.0 is ~1e-7
+    relative). They agree to float32 round-off; what IS pinned bitwise
+    in this file is computed at equal shapes."""
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=2e-6, atol=2e-7
+    )
+
+
 def make_registry(max_batch=8, warmup=False, **kw):
     actor, params = make_actor_and_params(**kw)
     reg = ModelRegistry()
@@ -69,8 +81,10 @@ def test_default_buckets_power_of_two():
 
 def test_engine_bucket_padding_bitwise_matches_unbatched_forward():
     """The acceptance bar: a padded bucket forward returns, row for
-    row, the SAME bits as the unbatched model apply (row-wise ops only
-    — padding rows cannot leak into real rows)."""
+    row, what the unbatched model apply returns (to float32 round-off:
+    the shapes differ), and — at equal shapes, bitwise — the same rows
+    whatever else shares their bucket: the ops are row-wise, so padding
+    rows cannot leak into real rows."""
     actor, params = make_actor_and_params()
     eng = PolicyEngine(actor, flat_spec(), max_batch=16)
     obs = np.random.default_rng(0).standard_normal((5, OBS_DIM)).astype(
@@ -83,7 +97,15 @@ def test_engine_bucket_padding_bitwise_matches_unbatched_forward():
             params, jnp.asarray(obs[i]), None,
             deterministic=True, with_logprob=False,
         )
-        np.testing.assert_array_equal(batched[i], np.asarray(single))
+        assert_matches_unbatched(batched[i], single)
+    # Same bucket (8), other company: 2 more real rows where the
+    # padding was. Same program, same shape — the first 5 rows must not
+    # move by a bit.
+    crowded = np.concatenate([obs, 7.0 * np.ones((2, OBS_DIM), np.float32)])
+    assert eng.bucket_for(7) == 8
+    np.testing.assert_array_equal(
+        eng.act(params, crowded, deterministic=True)[:5], batched
+    )
 
 
 def test_engine_visual_pytree_obs():
@@ -166,7 +188,7 @@ def test_deadline_flush_single_request():
 def test_oversized_request_splits_and_reassembles():
     """A single request with rows > max_batch is split across engine
     calls and reassembled in order, bitwise-equal to the unbatched
-    forwards."""
+    forwards (to float32 round-off: see assert_matches_unbatched)."""
     reg, actor, params = make_registry(max_batch=4)
     n = 4 * 3 + 1  # 13 rows -> chunks of 4,4,4,1
     obs = np.random.default_rng(2).standard_normal((n, OBS_DIM)).astype(
@@ -182,7 +204,7 @@ def test_oversized_request_splits_and_reassembles():
             params, jnp.asarray(obs[i]), None,
             deterministic=True, with_logprob=False,
         )
-        np.testing.assert_array_equal(res.action[i], np.asarray(single))
+        assert_matches_unbatched(res.action[i], single)
 
 
 def test_concurrent_requests_coalesce_and_multiple_buckets():
@@ -191,6 +213,17 @@ def test_concurrent_requests_coalesce_and_multiple_buckets():
     and every response matches its own unbatched forward."""
     reg, actor, params = make_registry(max_batch=8)
     engine, _, _ = reg.acquire("default")
+    # Continuous batching admits whatever queued up DURING the previous
+    # forward, so on a fast host a herd of tiny forwards may never
+    # coalesce. Give each forward a floor so the herd does queue behind
+    # it — the coalescing is then by construction, not by luck.
+    fast_act = engine.act
+
+    def act(*args, **kwargs):
+        time.sleep(0.02)
+        return fast_act(*args, **kwargs)
+
+    engine.act = act
     rng = np.random.default_rng(3)
     all_obs = rng.standard_normal((24, OBS_DIM)).astype(np.float32)
     results = {}
@@ -220,7 +253,7 @@ def test_concurrent_requests_coalesce_and_multiple_buckets():
             params, jnp.asarray(all_obs[i]), None,
             deterministic=True, with_logprob=False,
         )
-        np.testing.assert_array_equal(res.action, np.asarray(single))
+        assert_matches_unbatched(res.action, single)
 
 
 def test_sampled_actions_need_key_and_vary():
@@ -261,7 +294,7 @@ def test_batcher_chunks_at_engine_max_batch():
             params, jnp.asarray(obs[i]), None,
             deterministic=True, with_logprob=False,
         )
-        np.testing.assert_array_equal(res.action[i], np.asarray(single))
+        assert_matches_unbatched(res.action[i], single)
 
 
 def test_duplicate_slot_registration_raises_unless_replace():
@@ -388,7 +421,7 @@ def test_hot_reload_swaps_generation_with_inflight_requests(tmp_path):
     # no torn reads, no half-swapped weights.
     assert not np.array_equal(expected[0], expected[1])
     for r in results:
-        np.testing.assert_array_equal(r.action, expected[r.generation])
+        assert_matches_unbatched(r.action, expected[r.generation])
     # a second reload with no new checkpoint is a no-op
     again = reg.reload()
     assert again["default"]["reloaded"] is False

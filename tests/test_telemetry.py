@@ -231,7 +231,7 @@ def test_epoch_accounting_metrics_present(off_and_on):
 
 def test_jsonl_schema_roundtrip_and_phase_coverage(off_and_on):
     """Every line parses as strict JSON; epoch events carry the full
-    8-phase taxonomy with consistent aggregates, and the phase sums
+    8-phase classification with consistent aggregates, and the phase sums
     cover ~the epoch wall time (the breakdown partitions the loop)."""
     tracker_on, _, _ = off_and_on["on"]
     lines = (tracker_on.run_dir / "telemetry.jsonl").read_text().splitlines()

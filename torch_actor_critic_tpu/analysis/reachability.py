@@ -56,7 +56,7 @@ JIT_WRAPPERS: t.FrozenSet[str] = frozenset({
     "jax.lax.associative_scan", "lax.associative_scan",
     "jax.checkpoint", "jax.remat", "jax.grad", "jax.value_and_grad",
     "jax.custom_vjp", "jax.custom_jvp",
-    "shard_map", "manual_shard_map", "jax.shard_map",
+    "shard_map", "jax.shard_map",
     "pl.pallas_call", "pallas_call", "pltpu.pallas_call",
 })
 

@@ -19,8 +19,8 @@ hazards before a single test runs (docs/OBSERVABILITY.md
   (donation is a no-op) and the bug ships.
 * ``shard-map-hot-path`` — the PR-8 invariant, promoted from the
   retired source-regex pin in tests/test_mesh_gspmd.py: ``shard_map``
-  belongs only in ``parallel/context.py`` (the manual-mapping home)
-  and ``parallel/compat.py`` (the deprecation stub). Every other
+  belongs only in ``parallel/context.py`` (the ring-attention home).
+  Every other
   reference must sit in :data:`SHARD_MAP_ALLOWLIST`, and every
   allowlist entry must still match a real reference
   (``stale-allowlist``) — the allowlist is checked, never trusted.
@@ -45,22 +45,19 @@ FAMILY = "recompile-risk"
 _JIT_MAKERS = frozenset({"jax.jit", "jit", "pjit", "jax.pmap", "pmap"})
 
 # Files where shard_map lives by definition (the rule text itself).
-SHARD_MAP_HOME = ("parallel/context.py", "parallel/compat.py")
+SHARD_MAP_HOME = ("parallel/context.py",)
 
 # (path suffix, scope qualname) pairs allowed to reference shard_map
 # outside its home. Scope "<module>" means module level. Every entry
 # must match at least one live reference or the run fails with
 # stale-allowlist. Justifications live in docs/ANALYSIS.md.
 SHARD_MAP_ALLOWLIST: t.FrozenSet[t.Tuple[str, str]] = frozenset({
-    # Public re-export of the manual-mapping helper.
-    ("parallel/__init__.py", "<module>"),
     # The sp ring-attention burst is manual by nature (a real named
-    # axis for the K/V rotation); it routes through
-    # context.manual_shard_map — the one sanctioned hot-path use.
+    # axis for the K/V rotation) — the one sanctioned hot-path use.
     ("parallel/dp.py", "DataParallelSAC._build_ring_burst"),
 })
 
-_SHARD_NAMES = frozenset({"shard_map", "manual_shard_map"})
+_SHARD_NAMES = frozenset({"shard_map"})
 
 
 def _is_jit_maker(node: ast.AST) -> bool:
@@ -346,11 +343,11 @@ def _check_shard_map(
             continue
         findings.append(Finding(
             "shard-map-hot-path", ctx.path, node.lineno, node.col_offset,
-            f"{name!r} referenced outside parallel/context.py + "
-            "parallel/compat.py (PR-8 invariant: hot paths are plain "
-            "GSPMD jit-with-sharding)",
-            "route manual mapping through context.manual_shard_map from "
-            "an allowlisted scope, or add a justified entry to "
+            f"{name!r} referenced outside parallel/context.py "
+            "(PR-8 invariant: hot paths are plain GSPMD "
+            "jit-with-sharding)",
+            "keep manual mapping in an allowlisted scope, or add a "
+            "justified entry to "
             "SHARD_MAP_ALLOWLIST (analysis/recompile.py) and "
             "docs/ANALYSIS.md",
         ))

@@ -12,13 +12,14 @@ wall-clock). This subsystem makes compilation a **build artifact**:
   new entry point cannot ship without declaring its bundleability
   (`stale-bundle-manifest` lint).
 - :mod:`~torch_actor_critic_tpu.aot.bundle` — a ``warm_start`` bundle
-  next to the Orbax checkpoint: ``jax.export``-serialized programs +
-  a pre-populated persistent compilation cache, stamped with a
-  compatibility fingerprint. A mismatched bundle is rejected loudly
+  next to the Orbax checkpoint: ``jax.export``-serialized programs,
+  built while populating the persistent compilation cache and stamped
+  with a compatibility fingerprint. A mismatched bundle is rejected loudly
   and counted; serving falls back to live compile.
 - :mod:`~torch_actor_critic_tpu.aot.cache` — the persistent
-  compilation cache shared by fleet workers and restarted learners,
-  hit/miss counters surfaced through the watchdog onto ``/metrics``
+  compilation cache shared by fleet workers and restarted learners
+  (one directory per checkout, placed from outside by
+  ``JAX_COMPILATION_CACHE_DIR``), hit/miss counters surfaced through the watchdog onto ``/metrics``
   and metrics.jsonl.
 - :mod:`~torch_actor_critic_tpu.aot.prefork` — a pre-forked warm
   worker pool for the fleet router (``serve.py --warm-pool N``):
@@ -40,8 +41,7 @@ from torch_actor_critic_tpu.aot.bundle import (
     load_bundle,
 )
 from torch_actor_critic_tpu.aot.cache import (
-    CACHE_ENV_VAR,
-    enable_cache_from_env,
+    cache_dir,
     enable_persistent_cache,
 )
 from torch_actor_critic_tpu.aot.manifest import (
@@ -59,8 +59,7 @@ __all__ = [
     "default_bundle_dir",
     "emit_bundle",
     "load_bundle",
-    "CACHE_ENV_VAR",
-    "enable_cache_from_env",
+    "cache_dir",
     "enable_persistent_cache",
     "ManifestError",
     "bundled_entry_points",

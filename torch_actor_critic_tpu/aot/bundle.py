@@ -15,13 +15,15 @@ fresh worker needs to answer its first request without a live compile:
     break the bitwise pin between warmup and live dispatch); they
     deserialize to check avals against their own jit programs, and the
     round-trip test proves bitwise agreement with the live compile.
-``xla_cache/``
-    A persistent compilation cache pre-populated by running the REAL
-    ``PolicyEngine`` warmup at build time. Because cache keys cover the
-    HLO + compile options + backend, a consumer pointing its cache here
-    and dispatching the same jit programs gets disk hits instead of XLA
-    runs — this is the mechanism that actually delivers
-    ``live_compiles == 0``.
+
+The compiled executables themselves are not in the bundle: building one
+runs the REAL ``PolicyEngine`` warmup with the persistent compilation
+cache on (:mod:`~torch_actor_critic_tpu.aot.cache` — one directory per
+checkout, placed by ``JAX_COMPILATION_CACHE_DIR``), so every consumer on
+the same cache that dispatches the same jit programs gets disk hits
+instead of XLA runs. That is the mechanism that delivers
+``live_compiles == 0``; the bundle is the verified index of what was
+put there.
 
 A bundle whose fingerprint or avals disagree with the consuming
 process is **rejected loudly** (:class:`BundleMismatchError`), counted
@@ -55,7 +57,6 @@ BUNDLE_FORMAT = 1
 
 _MANIFEST = "MANIFEST.json"
 _PROGRAMS = "programs"
-_XLA_CACHE = "xla_cache"
 
 
 class BundleMismatchError(RuntimeError):
@@ -135,13 +136,6 @@ class WarmStartBundle:
         self.manifest = manifest
 
     # ----------------------------------------------------------- layout
-
-    @property
-    def cache_dir(self) -> str:
-        """The pre-populated persistent compilation cache — consumers
-        point :func:`~torch_actor_critic_tpu.aot.cache
-        .enable_persistent_cache` here."""
-        return str(self.root / _XLA_CACHE)
 
     @property
     def fingerprint(self) -> t.Dict[str, t.Any]:
@@ -259,12 +253,11 @@ def build_bundle(
     """Build a warm-start bundle at ``bundle_dir``.
 
     Instantiates a real :class:`~torch_actor_critic_tpu.serve.engine
-    .PolicyEngine`, points the persistent compilation cache at the
-    bundle's ``xla_cache/`` and runs the engine's own warmup — so the
-    cache entries are keyed by the *exact* jit programs every consumer
-    dispatches — then ``jax.export``-serializes each manifest program
-    for fingerprinting and bitwise verification. The builder's previous
-    cache configuration is restored on exit.
+    .PolicyEngine`, turns the persistent compilation cache on (at the
+    checkout's one directory, where it stays on) and runs the engine's
+    own warmup — so the cache entries are keyed by the *exact* jit
+    programs every consumer dispatches — then ``jax.export``-serializes
+    each manifest program for fingerprinting and bitwise verification.
     """
     import jax
     import numpy as np
@@ -280,75 +273,65 @@ def build_bundle(
 
     root = pathlib.Path(bundle_dir)
     (root / _PROGRAMS).mkdir(parents=True, exist_ok=True)
-    (root / _XLA_CACHE).mkdir(parents=True, exist_ok=True)
 
     engine = PolicyEngine(
         actor_def, obs_spec, max_batch=max_batch, buckets=buckets,
     )
 
-    prev_cache = aot_cache.current_cache_dir()
-    aot_cache.enable_persistent_cache(str(root / _XLA_CACHE), export_env=False)
-    try:
-        # The warmup below IS the cache-population pass: every
-        # (bucket, deterministic) jit program compiles once and is
-        # persisted unthresholded (aot/cache.py).
-        engine.warmup(params, deterministic_only=deterministic_only)
+    aot_cache.enable_persistent_cache()
+    # The warmup below IS the cache-population pass: every
+    # (bucket, deterministic) jit program compiles once and is
+    # persisted unthresholded (aot/cache.py).
+    engine.warmup(params, deterministic_only=deterministic_only)
 
-        programs: t.Dict[str, t.Dict[str, t.Any]] = {}
-        # jax.export cannot serialize typed-PRNG-key avals (no
-        # flatbuffer dtype kind for key<fry>), so the sampled programs
-        # are exported through a raw-uint32 wrapper: the serialized
-        # program takes jax.random.key_data(key) and re-wraps inside.
-        # Bitwise identical to the engine's typed-key program — only
-        # the calling convention of the ARTIFACT differs (the engine's
-        # own jit path, which the xla_cache serves, is untouched).
-        key_data = jax.random.key_data(jax.random.key(0))
+    programs: t.Dict[str, t.Dict[str, t.Any]] = {}
+    # jax.export cannot serialize typed-PRNG-key avals (no
+    # flatbuffer dtype kind for key<fry>), so the sampled programs
+    # are exported through a raw-uint32 wrapper: the serialized
+    # program takes jax.random.key_data(key) and re-wraps inside.
+    # Bitwise identical to the engine's typed-key program — only
+    # the calling convention of the ARTIFACT differs (the engine's
+    # own jit path, which the compile cache serves, is untouched).
+    key_data = jax.random.key_data(jax.random.key(0))
 
-        def sampled_raw(params_, obs_, key_data_):
-            return engine._fwd[False](
-                params_, obs_, jax.random.wrap_key_data(key_data_)
-            )
+    def sampled_raw(params_, obs_, key_data_):
+        return engine._fwd[False](
+            params_, obs_, jax.random.wrap_key_data(key_data_)
+        )
 
-        sampled_raw_jit = jax.jit(sampled_raw)
+    sampled_raw_jit = jax.jit(sampled_raw)
 
-        for spec in serve_programs(engine.buckets, deterministic_only):
-            zero_obs = jax.tree_util.tree_map(
-                lambda s: np.zeros(
-                    (spec.bucket,) + tuple(s.shape), s.dtype
-                ),
-                obs_spec,
-            )
-            if spec.deterministic:
-                call_args: t.Tuple[t.Any, ...] = (params, zero_obs)
-                fn = engine._fwd[True]
-            else:
-                call_args = (params, zero_obs, key_data)
-                fn = sampled_raw_jit
-            exported = jax_export.export(fn)(*call_args)
-            fname = program_filename(spec.name)
-            (root / _PROGRAMS / fname).write_bytes(exported.serialize())
-            programs[spec.name] = {
-                "file": fname,
-                "identity": spec.identity,
-                "bucket": spec.bucket,
-                "deterministic": spec.deterministic,
-                "in_avals": _flat_avals(*call_args),
-            }
-    finally:
-        # Restore without touching CACHE_ENV_VAR: the builder may run
-        # inside a learner that already published a run-wide cache.
-        if prev_cache:
-            aot_cache.enable_persistent_cache(prev_cache, export_env=False)
+    for spec in serve_programs(engine.buckets, deterministic_only):
+        zero_obs = jax.tree_util.tree_map(
+            lambda s: np.zeros(
+                (spec.bucket,) + tuple(s.shape), s.dtype
+            ),
+            obs_spec,
+        )
+        if spec.deterministic:
+            call_args: t.Tuple[t.Any, ...] = (params, zero_obs)
+            fn = engine._fwd[True]
         else:
-            jax.config.update("jax_compilation_cache_dir", None)
-            aot_cache._reset_backend_cache()
+            call_args = (params, zero_obs, key_data)
+            fn = sampled_raw_jit
+        exported = jax_export.export(fn)(*call_args)
+        fname = program_filename(spec.name)
+        (root / _PROGRAMS / fname).write_bytes(exported.serialize())
+        programs[spec.name] = {
+            "file": fname,
+            "identity": spec.identity,
+            "bucket": spec.bucket,
+            "deterministic": spec.deterministic,
+            "in_avals": _flat_avals(*call_args),
+        }
 
-    entries = aot_cache.cache_entries(str(root / _XLA_CACHE))
+    entries = aot_cache.cache_entries()
     if entries == 0:
         logger.warning(
-            "warm-start bundle %s: xla_cache is EMPTY after warmup — "
-            "persistent-cache writes are being skipped on this "
-            "backend; consumers will fall back to live compiles", root,
+            "warm-start bundle %s: the compile cache at %s is EMPTY "
+            "after warmup — persistent-cache writes are being skipped "
+            "on this backend; consumers will fall back to live "
+            "compiles", root, aot_cache.cache_dir(),
         )
     manifest = {
         "format": BUNDLE_FORMAT,

@@ -1,30 +1,29 @@
-"""Persistent XLA compilation cache shared across processes.
+"""The persistent XLA compilation cache: one directory, placed from outside.
 
 JAX's persistent compilation cache keys a compiled executable on the
 program HLO + compile options + backend identity, so two *processes*
-compiling the same jit program share one cache entry: a fleet worker
-spawned after the first one — or a learner restarted after preemption
-— retrieves the executable from disk instead of re-running XLA. This
-module is the one place that cache gets configured, for three reasons:
+compiling the same jit program share one entry: a fleet worker spawned
+after the first one, a learner restarted after preemption, or the next
+run of the same command retrieves the executable from disk instead of
+re-running XLA. The directory is part of how an entry is found, so it
+never moves:
 
-- **Key stability.** Any cache-affecting config knob that differs
-  between the process that wrote an entry and the process reading it
-  silently changes the cache key (measured: toggling
-  ``jax_persistent_cache_enable_xla_caches`` alone forks the keyspace).
-  Funneling every enable through :func:`enable_persistent_cache` keeps
-  the builder (``--emit-bundle``, bundle build) and every consumer
-  (serve workers, restarted learners, respawned actors) on identical
-  settings.
-- **Unthresholded writes.** The jax defaults only persist compiles
-  slower than ~1s / larger than a floor — on the CPU tier-1 shim most
-  serve-bucket programs compile faster than that and would never be
-  written, making the cold-start win unprovable. We persist
-  everything; the cache is per-run-scoped, not a global grow-forever
-  directory.
-- **Inheritance.** The chosen directory is exported as
-  :data:`CACHE_ENV_VAR` so *spawned children* (fleet actor processes,
-  ``serve.py --fleet`` workers) join the same cache via
-  :func:`enable_cache_from_env` without any extra plumbing.
+- where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX itself read it at
+  import and the program uses that directory — nothing here or anywhere
+  else writes ``jax_compilation_cache_dir``;
+- where it is not, the directory is ``<checkout>/.jax_cache``
+  (git-ignored), never a temp name, pid or timestamp.
+
+:func:`cache_dir` is the one place that decides; ``train.py``,
+``serve.py``, ``chip_smoke.py`` and every process they spawn go through
+:func:`enable_persistent_cache`, so parent and children agree without
+handing a path down.
+
+One setting rides along, identical in every process because a
+cache-affecting knob that differs between writer and reader silently
+forks the key space: compiles are persisted **unthresholded** (the jax
+defaults skip programs that compile in under a second, which is most
+serve-bucket programs).
 
 Hit/miss counters ride the watchdog
 (:mod:`~torch_actor_critic_tpu.diagnostics.watchdog` listens for the
@@ -34,179 +33,64 @@ Hit/miss counters ride the watchdog
 
 from __future__ import annotations
 
-import contextlib
-import functools
 import logging
 import os
-import threading
+
+from torch_actor_critic_tpu.utils.procenv import PACKAGE_ROOT
 
 logger = logging.getLogger(__name__)
 
 __all__ = [
-    "CACHE_ENV_VAR",
-    "enable_persistent_cache",
-    "enable_cache_from_env",
-    "disable_persistent_cache",
-    "current_cache_dir",
+    "CACHE_DIR_ENV",
+    "cache_dir",
     "cache_entries",
-    "cache_excluded",
-    "exclude_from_cache",
+    "enable_persistent_cache",
 ]
 
-# Spawned children (multiprocessing actors, fleet worker subprocesses)
-# inherit the cache through this env var (enable_cache_from_env).
-CACHE_ENV_VAR = "TAC_COMPILE_CACHE"
-
-# cache_excluded() nesting state — shared across threads on purpose:
-# the flag it toggles is process-global, so the exclusion must be too.
-_exclusion_lock = threading.Lock()
-_exclusion_depth = [0]
-_exclusion_prev = True
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
-def _reset_backend_cache() -> None:
-    """Make a cache-dir change take effect in an already-initialized
-    process: jax memoizes the cache object on first use, so switching
-    directories (the bundle builder does, mid-run) needs a reset."""
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:  # noqa: BLE001 — best-effort: on jax versions
-        # without reset_cache the dir is simply fixed at first use
-        logger.debug("compilation-cache reset unavailable", exc_info=True)
+def cache_dir() -> str:
+    """Where this checkout's compiled programs are kept: the directory
+    ``JAX_COMPILATION_CACHE_DIR`` names, else ``<checkout>/.jax_cache``.
+    Touches no backend — the parent of chip-using children may ask."""
+    return os.environ.get(CACHE_DIR_ENV) or os.path.join(
+        PACKAGE_ROOT, ".jax_cache"
+    )
 
 
-def enable_persistent_cache(
-    cache_dir: str, export_env: bool = True
-) -> str:
-    """Point this process's persistent compilation cache at
-    ``cache_dir`` (created if absent) and arm the watchdog's hit/miss
-    counters. Returns the absolute directory. With ``export_env``
-    (default) the directory is published to :data:`CACHE_ENV_VAR` so
-    spawned children join the same cache."""
+def enable_persistent_cache() -> str:
+    """Turn the persistent compilation cache on at :func:`cache_dir`
+    and arm the watchdog's hit/miss counters. Returns the directory."""
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
     from torch_actor_critic_tpu.diagnostics.watchdog import get_watchdog
 
-    cache_dir = os.path.abspath(cache_dir)
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # Persist EVERY compile: the defaults skip fast/small programs,
-    # which on the CPU shim is most of them (see module docstring).
+    path = cache_dir()
+    if not os.environ.get(CACHE_DIR_ENV):
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    _reset_backend_cache()
-    if export_env:
-        os.environ[CACHE_ENV_VAR] = cache_dir
+    # jax decides once per process whether the cache is in use; a
+    # process that already compiled something must be asked again.
+    compilation_cache.reset_cache()
     # Counters must be live before the first compile probes the cache.
     get_watchdog().install()
-    logger.info("persistent compilation cache: %s", cache_dir)
-    return cache_dir
+    logger.info("persistent compilation cache: %s", path)
+    return path
 
 
-def enable_cache_from_env() -> str | None:
-    """Join the cache a parent process published via
-    :data:`CACHE_ENV_VAR` (the respawned-actor / spawned-worker path).
-    No-op returning None when the variable is unset or empty."""
-    cache_dir = os.environ.get(CACHE_ENV_VAR, "")
-    if not cache_dir:
-        return None
-    return enable_persistent_cache(cache_dir, export_env=False)
-
-
-def disable_persistent_cache() -> None:
-    """Turn the persistent cache back off (test isolation: a test that
-    enabled a tmpdir cache must not leak it into later tests)."""
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir", None)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    _reset_backend_cache()
-    os.environ.pop(CACHE_ENV_VAR, None)
-
-
-@contextlib.contextmanager
-def cache_excluded():
-    """Bypass the persistent cache (read AND write) for the compiles
-    dispatched inside this context.
-
-    Exists because of a measured jaxlib 0.4.36 XLA:CPU defect: when
-    BOTH of the train plane's big donated+sharded executables (the
-    buffer ``push`` and the ``burst``, whose donated replay-buffer
-    pytree flows from one into the other) are *deserialized* from the
-    persistent cache instead of freshly compiled, executing them
-    corrupts memory — non-finite training state on a good day, a
-    segfault on a bad one. Bisected to exactly that entry pair:
-    evicting either one makes the restarted learner bitwise-clean, so
-    the train plane's donated programs opt out of the cache wholesale
-    (:func:`exclude_from_cache`) and always compile live. The serve
-    plane — where the cold-start win lives — keeps riding the cache;
-    its bundle-armed zero-live-compile pin is verified bitwise by
-    tests/test_aot.py and the coldstart smoke.
-
-    Toggling ``jax_enable_compilation_cache`` does not retrace and is
-    microseconds per call — noise against a burst dispatch. The
-    ``reset_cache()`` on each side is load-bearing: jax memoizes the
-    cache-used decision ONCE globally (``_cache_checked``), so a bare
-    flag flip after the first compile in the process is silently
-    ignored; the reset forces re-evaluation under the flipped flag
-    (and again under the restored one).
-    """
-    import jax
-
-    global _exclusion_prev
-    # Depth-counted so overlapping exclusions from different threads
-    # (the prefetch thread's push racing the main thread's burst) keep
-    # the flag off until the LAST one exits — an early restore would
-    # let the other thread's compile probe the cache mid-exclusion.
-    with _exclusion_lock:
-        _exclusion_depth[0] += 1
-        if _exclusion_depth[0] == 1:
-            _exclusion_prev = jax.config.jax_enable_compilation_cache
-            jax.config.update("jax_enable_compilation_cache", False)
-            _reset_backend_cache()
-    try:
-        yield
-    finally:
-        with _exclusion_lock:
-            _exclusion_depth[0] -= 1
-            if _exclusion_depth[0] == 0:
-                jax.config.update(
-                    "jax_enable_compilation_cache", _exclusion_prev
-                )
-                _reset_backend_cache()
-
-
-def exclude_from_cache(fn):
-    """Wrap a (jitted) callable so every compile it triggers bypasses
-    the persistent cache — see :func:`cache_excluded` for why the
-    donated train-plane programs need this."""
-
-    @functools.wraps(fn)
-    def wrapper(*args, **kwargs):
-        with cache_excluded():
-            return fn(*args, **kwargs)
-
-    return wrapper
-
-
-def current_cache_dir() -> str | None:
-    """The directory this process's persistent cache points at (None
-    when disabled)."""
-    import jax
-
-    return jax.config.jax_compilation_cache_dir
-
-
-def cache_entries(cache_dir: str) -> int:
-    """Number of persisted executables under ``cache_dir`` (0 for a
-    missing directory) — the bundle builder's sanity check and the
-    coldstart bench's evidence that the cache actually populated."""
-    if not os.path.isdir(cache_dir):
+def cache_entries() -> int:
+    """Number of persisted executables under :func:`cache_dir` (0 for a
+    missing directory) — the bundle builder's check that its warmup
+    really was written."""
+    path = cache_dir()
+    if not os.path.isdir(path):
         return 0
     return sum(
-        1 for name in os.listdir(cache_dir)
-        if os.path.isfile(os.path.join(cache_dir, name))
+        1 for name in os.listdir(path)
+        if os.path.isfile(os.path.join(path, name))
+        and not name.endswith("-atime")
     )

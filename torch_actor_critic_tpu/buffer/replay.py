@@ -112,8 +112,9 @@ def warn_if_buffer_exceeds_hbm(
     The HBM-resident buffer is the design's core trade (zero
     host<->device replay traffic); an oversized capacity otherwise fails
     as an opaque allocator OOM mid-run. Shared by the host Trainer and
-    the fused on-device loop so the device lookup / ``memory_stats``
-    fallback / threshold logic cannot drift between them. ``sp`` > 1
+    the fused on-device loop so the device lookup / threshold logic
+    cannot drift between them. A device that reports no ``bytes_limit``
+    is not assumed to have one: the check is skipped and says so. ``sp`` > 1
     discounts sequence-history leaves whose T axis is sharded over the
     ring (``init_sharded_buffer``). No-op on CPU backends (host RAM,
     like the reference's buffer, ref ``buffer/replay_buffer.py``).
@@ -128,8 +129,12 @@ def warn_if_buffer_exceeds_hbm(
     dev = jax.local_devices()[0]
     if dev.platform == "cpu":
         return
-    stats = getattr(dev, "memory_stats", lambda: None)() or {}
-    hbm = stats.get("bytes_limit", 16 * 1024**3)
+    hbm = (dev.memory_stats() or {}).get("bytes_limit")
+    if hbm is None:
+        logging.getLogger(__name__).info(
+            "%s reports no bytes_limit; replay-size check skipped", dev
+        )
+        return
     need = estimate_buffer_bytes(capacity, obs_spec, act_dim) // max(sp, 1)
     if need > 0.5 * hbm:
         logging.getLogger(__name__).warning(

@@ -46,7 +46,7 @@ logger = logging.getLogger(__name__)
 __all__ = ["ActorWorker"]
 
 # Serving-unavailability classes the degradation path absorbs: sheds
-# (breaker/drain/queue/deadline taxonomy), connection-level failures
+# (breaker/drain/queue/deadline classification), connection-level failures
 # (OSError covers urllib's URLError and injected lossy links), backend
 # timeouts, and engine faults surfaced as RuntimeError (the HTTP 5xx
 # analogue). Request-shape errors (ValueError/TypeError) propagate —

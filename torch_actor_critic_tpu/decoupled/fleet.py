@@ -57,6 +57,7 @@ from torch_actor_critic_tpu.decoupled.transport import (
     StagingTransportServer,
     canonical_transition,
 )
+from torch_actor_critic_tpu.utils.procenv import spawning_on_cpu
 
 logger = logging.getLogger(__name__)
 
@@ -207,14 +208,15 @@ def actor_main(
         level=logging.INFO,
         format=f"[actor {actor_id}.{incarnation}] %(message)s",
     )
-    # Cold-start machinery (aot/cache.py): a learner running with
-    # --compile-cache publishes the dir via TAC_COMPILE_CACHE, which
-    # this spawn-child inherited — so a RESPAWNED actor (incarnation
-    # > 0) finds its acting programs already compiled on disk instead
-    # of re-paying the compile inside its restart window.
-    from torch_actor_critic_tpu.aot.cache import enable_cache_from_env
+    if (options or {}).get("compile_cache"):
+        # The learner runs with --compile-cache: join the same cache
+        # (aot/cache.py resolves the one directory), so a RESPAWNED
+        # actor (incarnation > 0) finds its acting programs already
+        # compiled on disk instead of re-paying the compile inside its
+        # restart window.
+        from torch_actor_critic_tpu.aot.cache import enable_persistent_cache
 
-    enable_cache_from_env()
+        enable_persistent_cache()
     stop = threading.Event()
 
     def _stop_handler(signum, frame):  # pragma: no cover — signal path
@@ -659,10 +661,15 @@ class FleetTrainer(DecoupledTrainer):
                 "act_timeout_s": self.config.actor_timeout_s,
                 "push_retry_s": self.config.actor_push_retry_s,
                 "trace_dir": self._trace_dir,
+                "compile_cache": bool(self.config.compile_cache),
             }},
             daemon=True,
         )
-        proc.start()
+        # Actors step envs and act through the learner's serving plane;
+        # they start held to the CPU so none can reach for the chip the
+        # learner holds.
+        with spawning_on_cpu():
+            proc.start()
         return proc
 
     def train(self, render: bool = False) -> dict:

@@ -1,12 +1,15 @@
 """ctypes loader for the native runtime (``libtacrt.so``).
 
-The library is tiny (one translation unit, no dependencies) so if a
-prebuilt ``.so`` is absent we attempt a direct ``g++`` build into the
-package directory — one-time, ~1s. Set ``TAC_NATIVE_LIB`` to use a
-specific build (e.g. the ASan variant from ``make asan``).
+The library is one translation unit with no dependencies and is never
+committed: it is built from ``tac_runtime.cpp`` with ``g++`` into the
+package directory (~1 s) whenever it is missing or older than its
+source, so what runs is always what git holds. Set ``TAC_NATIVE_LIB``
+to load a specific build instead (the ASan variant from
+``make native-asan``).
 
-``load_runtime`` returns ``None`` when the library is unavailable
-(no compiler, non-Linux); callers fall back to pure-Python paths.
+A library that cannot be built or loaded is a
+:class:`NativeRuntimeError` on the path that asked for it
+(``parallel_envs``) — there is no silent Python path behind it.
 """
 
 from __future__ import annotations
@@ -47,59 +50,58 @@ def _declare(lib: ctypes.CDLL) -> ctypes.CDLL:
     return lib
 
 
-def _build(out: Path) -> bool:
+class NativeRuntimeError(RuntimeError):
+    """The native runtime could not be built or loaded."""
+
+
+def _stale(lib: Path) -> bool:
+    return not lib.exists() or any(
+        src.stat().st_mtime > lib.stat().st_mtime for src in SOURCES
+    )
+
+
+def _build(out: Path) -> None:
+    # Build to a temp file then rename: concurrent builders (spawned
+    # env workers racing the parent) each land a complete .so.
+    with tempfile.NamedTemporaryFile(
+        dir=out.parent, suffix=".so.tmp", delete=False
+    ) as tmp:
+        tmp_path = Path(tmp.name)
     cmd = [
-        os.environ.get("CXX", "g++"),
-        "-O2",
-        "-Wall",
-        "-fPIC",
-        "-std=c++17",
-        "-shared",
-        "-o",
-        str(out),
+        os.environ.get("CXX", "g++"), "-O2", "-Wall", "-fPIC",
+        "-std=c++17", "-shared", "-o", str(tmp_path),
         *[str(s) for s in SOURCES],
     ]
     try:
-        # Build to a temp file then rename: concurrent builders (e.g.
-        # spawned env workers racing the parent) each land a complete .so.
-        with tempfile.NamedTemporaryFile(
-            dir=out.parent, suffix=".so.tmp", delete=False
-        ) as tmp:
-            tmp_path = Path(tmp.name)
-        cmd[cmd.index(str(out))] = str(tmp_path)
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp_path, out)
-        return True
     except (OSError, subprocess.SubprocessError) as e:
-        logger.debug("native build failed: %s", e)
-        if "tmp_path" in locals():
-            tmp_path.unlink(missing_ok=True)
-        return False
+        tmp_path.unlink(missing_ok=True)
+        detail = getattr(e, "stderr", b"") or b""
+        raise NativeRuntimeError(
+            f"building {out.name} failed ({e}): "
+            f"{detail.decode(errors='replace')[-500:]}"
+        ) from e
 
 
-def load_runtime(build_if_missing: bool = True) -> ctypes.CDLL | None:
-    """Load (building if needed) the native runtime, or ``None``."""
-    if not sys.platform.startswith("linux"):
-        return None
+def load_runtime() -> ctypes.CDLL:
+    """Load the native runtime, building it first when the ``.so`` is
+    missing or older than its source. Raises
+    :class:`NativeRuntimeError` when that is impossible."""
     with _LOCK:
         if "lib" in _CACHE:
             return _CACHE["lib"]
-        path = os.environ.get("TAC_NATIVE_LIB")
-        candidates = [Path(path)] if path else [_NATIVE_DIR / "libtacrt.so"]
-        for cand in candidates:
-            if cand.exists():
-                try:
-                    _CACHE["lib"] = _declare(ctypes.CDLL(str(cand)))
-                    return _CACHE["lib"]
-                except OSError as e:
-                    logger.warning("failed to load %s: %s", cand, e)
-        if build_if_missing and path is None:
-            out = _NATIVE_DIR / "libtacrt.so"
-            if _build(out):
-                try:
-                    _CACHE["lib"] = _declare(ctypes.CDLL(str(out)))
-                    return _CACHE["lib"]
-                except OSError as e:  # pragma: no cover
-                    logger.warning("failed to load built %s: %s", out, e)
-        _CACHE["lib"] = None
-        return None
+        if not sys.platform.startswith("linux"):
+            raise NativeRuntimeError(
+                f"the native runtime is futex-based (Linux only); this "
+                f"is {sys.platform}"
+            )
+        override = os.environ.get("TAC_NATIVE_LIB")
+        path = Path(override) if override else _NATIVE_DIR / "libtacrt.so"
+        if not override and _stale(path):
+            _build(path)
+        try:
+            _CACHE["lib"] = _declare(ctypes.CDLL(str(path)))
+        except OSError as e:
+            raise NativeRuntimeError(f"loading {path} failed: {e}") from e
+        return _CACHE["lib"]

@@ -183,12 +183,6 @@ def blockwise_attention(
 _LANE = 128  # TPU lane width: last tile dim, and scratch column count
 
 
-def _compiler_params(pltpu):
-    """``pltpu.CompilerParams`` across the 0.4->0.5 rename (older jax
-    spells it ``TPUCompilerParams``; same constructor surface)."""
-    return getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-
 def _acc_dot(a: jax.Array, b: jax.Array, dims) -> jax.Array:
     """``dot_general`` with f32 accumulation on MXU-native operands.
 
@@ -314,11 +308,10 @@ def _pad_head_dim(
     SCALING.md's attention roofline), but the q/k/v/o tiles carry half
     the HBM traffic and VMEM footprint of the zero-padded layout.
 
-    ADOPTION GATE: ``lanes=64`` is validated in Pallas interpret mode
-    only; Mosaic may reject or de-optimize sub-128-lane tiles on real
-    hardware. 128 stays the default (and the only recommended value)
-    until an on-chip sweep artifact in ``runs/tpu/`` shows 64 both
-    lowering and winning.
+    ``lanes=64`` compiles for the v5e (tests/test_chip_compile.py) and
+    matches the dense reference on the chip forward and backward
+    (chip_smoke.py, PR 21). Whether it is FASTER has not been measured;
+    128 stays the default until a chip timing says otherwise.
     """
     d = arrays[0].shape[-1]
     if d % lanes == 0:
@@ -449,7 +442,7 @@ def _flash_forward(
         ],
         # bh and q-block programs are independent; the k sweep carries
         # the online-softmax scratch and must stay sequential.
-        compiler_params=_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -618,7 +611,7 @@ def _flash_backward(
         in_specs=[qspec, kspec_dq, kspec_dq, qspec, rowspec, rowspec],
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32)],
-        compiler_params=_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -648,7 +641,7 @@ def _flash_backward(
             pltpu.VMEM((block_k, dp), jnp.float32),
             pltpu.VMEM((block_k, dp), jnp.float32),
         ],
-        compiler_params=_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

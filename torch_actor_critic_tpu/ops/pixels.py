@@ -24,18 +24,21 @@ Three implementations of the same math, one contract — exactly the
 - :func:`gather_frames_reference` — pure jnp (gather + clipped-index
   shift + cast). Ground truth for tests; the training-path default on
   non-TPU backends.
-- :func:`_gather_frames_pallas` — a Pallas TPU kernel: grid
-  ``(batch, stack)``, the replay row selected per program via
-  scalar-prefetch index maps (the ring never streams — one frame block
-  of VMEM per program), the DrQ shift expressed as two one-hot
-  **matmul-gathers** (MXU-friendly selection; exact for uint8 values,
-  which are integers <= 255 and therefore exactly representable in
-  f32 *and* bf16), decode/normalize fused into the epilogue, output
-  written directly in the compute dtype. ADOPTION GATE: validated in
-  interpret mode (CPU CI); Mosaic may reject the uint8 VMEM blocks or
-  the in-kernel transpose on some generations — the ``impl`` dispatch
-  keeps the XLA path one flag away until a chip artifact in
-  ``runs/tpu/`` shows the kernel lowering and winning.
+- :func:`_gather_frames_pallas` — a Pallas TPU kernel: one program
+  per example, each frame seen as a 2-D ``(H, W*C)`` slab (the view of
+  a 3-channel frame that TPU tiling accepts), the replay rows selected
+  via scalar-prefetch index maps (the ring never streams — ``S`` frame
+  blocks of VMEM per program), the DrQ shift and the channel
+  interleave of a stack expressed as one-hot **matmul-gathers**
+  (MXU-friendly selection; exact for uint8 values, which are integers
+  <= 255 and therefore exactly representable in f32 *and* bf16), the
+  decode fused into the epilogue, output written directly in the
+  compute dtype; the ``/ 255`` of a normalized decode follows in XLA
+  (Mosaic's division rounds differently in the last bit). It compiles
+  for the v5e at the wall-runner geometry (tests/test_chip_compile.py)
+  and agrees bit for bit with the reference on the chip
+  (chip_smoke.py, PR 21). It has NOT been timed: whether it wins is
+  ROADMAP S3's question.
 - :func:`fused_frame_gather` — the dispatch: ``'pallas'`` on a
   TPU-default backend, ``'xla'`` otherwise; ``interpret=True`` runs
   the kernel in the Pallas interpreter for CPU tests. Tracing the
@@ -159,60 +162,59 @@ def gather_frames_reference(
 # --------------------------------------------------------------------------
 
 
-def _pixel_kernel(
-    rows_ref, offs_ref, ring_ref, o_ref, *,
-    pad: int, normalize: bool, augment: bool, out_dtype,
-):
-    """One ``(example, stack-slot)`` program.
+def _pixel_kernel(rows_ref, *refs, augment: bool, out_dtype):
+    """One example: ``S`` uint8 frames in, one decoded frame out.
 
-    The replay row was already selected by the scalar-prefetch index
-    map (``rows_ref[i, s]`` steers the ring BlockSpec), so the body
-    only sees one ``(H, W, C)`` uint8 frame in VMEM. The DrQ shift is
-    two one-hot matmul-gathers — selection expressed as MXU work, the
-    layout TPUs execute well — computed in f32 where every uint8 value
-    is exact, then decoded straight into the output dtype.
+    Everything is a 2-D ``(H, W*C)`` slab — the frame with its two minor
+    axes merged, the only view of a 3-channel frame whose blocks the
+    TPU's ``(sublane, 128-lane)`` tiling accepts. The replay rows were
+    already selected by the scalar-prefetch index maps (``rows_ref``
+    steers each ring BlockSpec), so the body sees ``S`` frames in VMEM.
+
+    The DrQ shift and the channel interleave of a stack are one-hot
+    **matmul-gathers** — selection expressed as MXU work: ``sy`` names
+    the source row of every output row, ``src[s]`` the source lane in
+    stack slot ``s`` of every output lane (-1: another slot's lane).
+    One unit term per output element, so the result holds the original
+    integers exactly, in the output dtype. The ``/ 255`` of a normalized
+    decode is left to XLA, right behind the kernel: Mosaic's division
+    and XLA's round differently in the last bit (seen on the v5e), and
+    the bit contract is with the XLA reference.
     """
-    from jax.experimental import pallas as pl  # deferred: TPU-only path
+    del rows_ref  # consumed by the index maps
+    if augment:
+        sy_ref, src_ref, *frame_refs, o_ref = refs
+    else:
+        src_ref, *frame_refs, o_ref = refs
+    n_stack = len(frame_refs)
+    h, lanes_in = frame_refs[0].shape[1:]
+    lanes_out = o_ref.shape[2]
 
-    i = pl.program_id(0)
-    frame = ring_ref[0]  # (H, W, C) uint8
-    h, w, c = frame.shape
-    if not augment:
-        o_ref[0] = _decode(frame, normalize, out_dtype)
-        return
-    oy = offs_ref[i, 0]
-    ox = offs_ref[i, 1]
-    f = frame.astype(jnp.float32)
-    sy = jnp.clip(
-        jax.lax.broadcasted_iota(jnp.int32, (h,), 0) + oy - pad, 0, h - 1
-    )
-    onehot_y = (
-        jax.lax.broadcasted_iota(jnp.int32, (h, h), 1) == sy[:, None]
-    ).astype(jnp.float32)
-    g = jax.lax.dot_general(
-        onehot_y, f.reshape(h, w * c), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(h, w, c)
-    sx = jnp.clip(
-        jax.lax.broadcasted_iota(jnp.int32, (w,), 0) + ox - pad, 0, w - 1
-    )
-    # onehot_x[w, x] = (w == sx[x]); contracting g's W axis against it
-    # yields out[y, c, x] — one transpose back to (y, x, c).
-    onehot_x = (
-        jax.lax.broadcasted_iota(jnp.int32, (w, w), 0) == sx[None, :]
-    ).astype(jnp.float32)
-    out = jax.lax.dot_general(
-        g, onehot_x, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).transpose(0, 2, 1)
-    # The matmul-gather is exact selection (one unit term per output),
-    # so `out` holds the original integer values: the f32->out_dtype
-    # cast is exact and the decode contract matches the reference path
-    # bit for bit.
-    out = out.astype(out_dtype)
-    if normalize:
-        out = out / jnp.asarray(255.0, out_dtype)
-    o_ref[0] = out
+    def load(ref):
+        # Mosaic has no uint8 -> float cast; widen through int32.
+        return ref[0].astype(jnp.int32).astype(jnp.float32)
+
+    if not augment and n_stack == 1:
+        out = load(frame_refs[0])
+    else:
+        if augment:
+            onehot_y = jnp.where(
+                jax.lax.broadcasted_iota(jnp.int32, (h, h), 1) == sy_ref[0],
+                1.0, 0.0,
+            )
+        src_lane = jax.lax.broadcasted_iota(
+            jnp.int32, (lanes_in, lanes_out), 0
+        )
+        out = jnp.zeros((h, lanes_out), jnp.float32)
+        for s, ref in enumerate(frame_refs):
+            f = load(ref)
+            if augment:
+                f = jnp.dot(onehot_y, f, preferred_element_type=jnp.float32)
+            onehot_x = jnp.where(src_lane == src_ref[0, s:s + 1, :], 1.0, 0.0)
+            out = out + jnp.dot(
+                f, onehot_x, preferred_element_type=jnp.float32
+            )
+    o_ref[0] = out.astype(out_dtype)
 
 
 def _gather_frames_pallas(
@@ -240,33 +242,58 @@ def _gather_frames_pallas(
         )
     b = idx.shape[0]
     capacity, h, w, c = ring.shape
-    rows = stack_rows(idx.astype(jnp.int32), frame_stack, capacity)
+    n_stack = frame_stack
+    rows = stack_rows(idx.astype(jnp.int32), n_stack, capacity)
     augment = offsets is not None
-    if offsets is None:
-        # Scalar-prefetch operands are positional; feed a zero block
-        # the no-augment kernel never reads.
-        offsets = jnp.zeros((b, 2), jnp.int32)
+    # Source lane, in its own frame's merged (W*C) axis, of every lane
+    # of the merged (W*S*C) output axis: lane x*(S*C) + s*C + ch reads
+    # lane sx(x)*C + ch of stack slot s. Tiny int32 index arrays, built
+    # outside the kernel so the body needs no integer division.
+    lane = jnp.arange(w * n_stack * c, dtype=jnp.int32)
+    x, slot, ch = lane // (n_stack * c), (lane // c) % n_stack, lane % c
+    if augment:
+        offsets = offsets.astype(jnp.int32)
+        sx = _clipped_axis_indices(offsets[:, 1], w, pad).astype(jnp.int32)
+        sx = sx[:, x]  # (B, W*S*C)
+        sy = _clipped_axis_indices(offsets[:, 0], h, pad).astype(jnp.int32)
+    else:
+        sx = jnp.broadcast_to(x, (b, lane.shape[0]))
+    src = jnp.where(
+        slot[None, None, :] == jnp.arange(n_stack, dtype=jnp.int32)[None, :, None],
+        (sx * c + ch)[:, None, :],
+        -1,
+    )  # (B, S, W*S*C)
+
+    def per_example(*block):
+        return pl.BlockSpec(block, lambda i, rows: (i,) + (0,) * (len(block) - 1))
+
+    index_args, index_specs = [src], [per_example(1, n_stack, lane.shape[0])]
+    if augment:
+        index_args.insert(0, sy[:, :, None])
+        index_specs.insert(0, per_example(1, h, 1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, frame_stack),
-        in_specs=[
+        num_scalar_prefetch=1,
+        grid=(b,),
+        in_specs=index_specs + [
             pl.BlockSpec(
-                (1, h, w, c), lambda i, s, rows, offs: (rows[i, s], 0, 0, 0)
-            ),
+                (1, h, w * c), lambda i, rows, s=s: (rows[i, s], 0, 0)
+            )
+            for s in range(n_stack)
         ],
-        out_specs=pl.BlockSpec(
-            (1, h, w, c), lambda i, s, rows, offs: (i, 0, 0, s)
-        ),
+        out_specs=per_example(1, h, w * n_stack * c),
     )
-    return pl.pallas_call(
+    ring2d = ring.reshape(capacity, h, w * c)
+    out = pl.pallas_call(
         functools.partial(
-            _pixel_kernel, pad=pad, normalize=normalize, augment=augment,
-            out_dtype=out_dtype,
+            _pixel_kernel, augment=augment, out_dtype=out_dtype,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, w, frame_stack * c), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, w * n_stack * c), out_dtype),
         interpret=interpret,
-    )(rows, offsets.astype(jnp.int32), ring)
+    )(rows, *index_args, *([ring2d] * n_stack))
+    if normalize:
+        out = out / jnp.asarray(255.0, out_dtype)
+    return out.reshape(b, h, w, n_stack * c)
 
 
 def fused_frame_gather(

@@ -17,7 +17,6 @@ from torch_actor_critic_tpu.parallel.distributed import (  # noqa: F401
 from torch_actor_critic_tpu.parallel.context import (  # noqa: F401
     context_parallel_actor_step,
     make_ring_attention_fn,
-    manual_shard_map,
     ring_attention,
 )
 from torch_actor_critic_tpu.parallel.population import (  # noqa: F401

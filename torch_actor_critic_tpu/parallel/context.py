@@ -23,7 +23,6 @@ run their own sequence ring.
 from __future__ import annotations
 
 import functools
-import typing as t
 
 import jax
 import jax.numpy as jnp
@@ -35,58 +34,6 @@ from torch_actor_critic_tpu.ops.attention import (
 )
 
 NEG_INF = float("-inf")
-
-
-def manual_shard_map(
-    f: t.Callable,
-    *,
-    mesh: Mesh,
-    in_specs,
-    out_specs,
-    axis_names: t.Optional[t.AbstractSet[str]] = None,
-    check_vma: t.Optional[bool] = None,
-):
-    """``shard_map`` for the few programs that are manual by nature.
-
-    The GSPMD rebuild (parallel/dp.py, sac/ondevice.py) retired
-    ``shard_map`` from every data-parallel hot path — those are plain
-    ``jit`` with ``in_shardings``/``out_shardings`` now. Ring attention
-    cannot follow: its per-device K/V rotation (``ppermute``) IS the
-    algorithm, so the sp-sharded acting and gradient paths keep a
-    manual mapping. This helper accepts the modern ``jax.shard_map``
-    signature and forwards to it when present, else to the legacy
-    ``jax.experimental.shard_map`` (``axis_names`` complemented into
-    ``auto``, ``check_vma`` renamed ``check_rep``). Non-manual axes
-    must be size 1 on the legacy API — its partial-auto mode
-    miscompiles — which every caller here satisfies (the ring runs on
-    fully-manual ``(dp, sp)`` sub-layouts).
-    """
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        kwargs: dict = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return native(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-
-    from jax.experimental.shard_map import shard_map as legacy
-
-    kwargs = {}
-    if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kwargs["auto"] = auto
-    return legacy(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
-
-
-shard_map = manual_shard_map
 
 
 def ring_attention(
@@ -171,7 +118,7 @@ def _build_context_actor_step(
         )
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(), P(None, "sp", None), P()),

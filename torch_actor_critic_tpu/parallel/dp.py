@@ -36,12 +36,10 @@ parameter layout (:func:`~torch_actor_critic_tpu.parallel.sharding.
 param_specs` — tp roles + size-thresholded fsdp), and the per-device
 view expressed as ``jax.vmap(..., axis_name='dp')`` over the leading
 device axis so ``lax.pmean``/``pmax``/``pmin`` keep their named-axis
-spelling while XLA inserts the actual collectives. No ``shard_map``,
-no version shims, and the dp+tp/fsdp hybrid needs no partial-auto
-mode — it is ordinary auto partitioning, so the legacy version gate is
-gone. Ring-attention sequence parallelism (``sp``) is the one manual
-algorithm left; that burst routes through
-:func:`~torch_actor_critic_tpu.parallel.context.manual_shard_map`.
+spelling while XLA inserts the actual collectives. The dp+tp/fsdp
+hybrid is ordinary auto partitioning. Ring-attention sequence
+parallelism (``sp``) is the one manual algorithm left; that burst is a
+``jax.shard_map`` with ``(dp, sp)`` manual and tp/fsdp auto.
 """
 
 from __future__ import annotations
@@ -414,14 +412,10 @@ class DataParallelSAC:
         self, num_updates: int, buffer: BufferState, chunk: Batch
     ):
         """The sp (ring-attention) burst: manual by nature — the K/V
-        rotation needs a real named manual axis — so it keeps a
-        ``shard_map`` via :func:`~torch_actor_critic_tpu.parallel.
-        context.manual_shard_map`. On the legacy jax API every
-        non-manual axis must be size 1 (the partial-auto mode
-        miscompiles); tp/fsdp therefore cannot combine with sp there.
+        rotation needs a real named manual axis — so it is a
+        ``jax.shard_map`` over ``(dp, sp)``; tp/fsdp stay GSPMD auto
+        axes inside the body.
         """
-        from torch_actor_critic_tpu.parallel.context import manual_shard_map
-
         sac = self.sac_sp
         mesh = self.mesh
         sp = self.effective_sp
@@ -432,15 +426,6 @@ class DataParallelSAC:
         # __init__ note).
         axes = ("dp", "sp")
         manual = {"dp", "sp"}
-        if not hasattr(jax, "shard_map") and any(
-            mesh.shape[a] > 1 for a in mesh.axis_names if a not in manual
-        ):
-            raise NotImplementedError(
-                f"sp ring attention with tp/fsdp needs jax.shard_map "
-                f"with partial-auto axis support (jax >= 0.5); this jax "
-                f"{jax.__version__} only runs the ring on fully-manual "
-                "meshes — set tp=1 and fsdp=1, or upgrade jax."
-            )
         min_bytes = self.fsdp_min_bytes
         buf_specs = _buffer_specs(buffer, sp)
         chunk_specs = _batch_specs(chunk, sp)
@@ -457,9 +442,8 @@ class DataParallelSAC:
             # the batch is not).
             dev = jax.lax.axis_index(DataParallelSAC.AXIS)
             local = state.replace(rng=jax.random.fold_in(state.rng, dev))
-            # tp/fsdp are GSPMD *auto* axes inside this manual body
-            # (size 1 on the legacy API): re-assert the parameter
-            # layout for the partitioner.
+            # tp/fsdp are GSPMD *auto* axes inside this manual body:
+            # re-assert the parameter layout for the partitioner.
             local = tp_sharding.constrain(local, mesh, min_bytes)
 
             local, buffer, metrics = sac.update_burst(
@@ -481,7 +465,7 @@ class DataParallelSAC:
             buffer = jax.tree_util.tree_map(lambda x: x[None], buffer)
             return state_out, buffer, metrics
 
-        mapped = manual_shard_map(
+        mapped = jax.shard_map(
             burst_body,
             mesh=mesh,
             in_specs=(rep_spec, buf_specs, chunk_specs),
@@ -506,19 +490,12 @@ class DataParallelSAC:
         """Push per-device chunks and run ``num_updates`` DP gradient
         steps as one device dispatch. ``chunk`` leaves have leading axes
         ``(n_dev, per_dev, ...)`` (see :func:`shard_chunk`)."""
-        from torch_actor_critic_tpu.aot.cache import cache_excluded
-
         if self._burst is None or self._burst[0] != num_updates:
             self._burst = (
                 num_updates,
                 self._build_burst(num_updates, state, buffer, chunk),
             )
-        # cache_excluded: the donated burst/push executable pair is
-        # unsafe to DESERIALIZE from the persistent compilation cache
-        # (jaxlib 0.4.36 XLA:CPU memory corruption — see aot/cache.py);
-        # these programs always compile live.
-        with cache_excluded():
-            return self._burst[1](state, buffer, chunk)
+        return self._burst[1](state, buffer, chunk)
 
     def burst_jit(self, num_updates: int):
         """The cached jitted burst for ``num_updates`` (None before its
@@ -550,11 +527,7 @@ class DataParallelSAC:
                 out_shardings=buf_sh,
                 donate_argnums=(0,),
             )
-        from torch_actor_critic_tpu.aot.cache import cache_excluded
-
-        # Same persistent-cache exclusion as update_burst (aot/cache.py).
-        with cache_excluded():
-            return self._push(buffer, chunk)
+        return self._push(buffer, chunk)
 
     # ------------------------------------------------------------- acting
 

@@ -194,14 +194,9 @@ class PopulationLearner:
             fn = self._bursts[num_updates] = jax.jit(
                 jax.vmap(one_member), donate_argnums=(0, 1)
             )
-        from torch_actor_critic_tpu.aot.cache import cache_excluded
         from torch_actor_critic_tpu.diagnostics.watchdog import get_watchdog
 
-        # cache_excluded: donated train-plane executables are unsafe to
-        # deserialize from the persistent compilation cache (see
-        # aot/cache.py) — always compile live.
-        with get_watchdog().source("train/population_burst"), \
-                cache_excluded():
+        with get_watchdog().source("train/population_burst"):
             return fn(state, buffer, chunk)
 
     # Cost-registry key: matches the watchdog source scope above.
@@ -217,11 +212,7 @@ class PopulationLearner:
         """Warmup-path store (no gradient steps), vmapped per member."""
         if self._push is None:
             self._push = jax.jit(jax.vmap(push), donate_argnums=(0,))
-        from torch_actor_critic_tpu.aot.cache import cache_excluded
-
-        # Same persistent-cache exclusion as the burst (aot/cache.py).
-        with cache_excluded():
-            return self._push(buffer, chunk)
+        return self._push(buffer, chunk)
 
     # ------------------------------------------------------------- acting
 

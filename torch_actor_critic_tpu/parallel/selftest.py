@@ -35,14 +35,7 @@ import sys
 def run_selftest(
     coordinator: str, num_processes: int, process_id: int, ckpt_dir: str
 ) -> None:
-    import os
-
-    # Order matters: platform choice must be pinned before any backend
-    # init; the test harness sets JAX_PLATFORMS=cpu in our env.
     import jax
-
-    if "cpu" in os.environ.get("JAX_PLATFORMS", ""):
-        jax.config.update("jax_platforms", "cpu")
 
     from torch_actor_critic_tpu.parallel.distributed import (
         global_statistics,
@@ -212,12 +205,7 @@ def run_elastic_phase(
     :func:`~torch_actor_critic_tpu.parallel.elastic.reshard_buffer`,
     and keep training.
     """
-    import os
-
     import jax
-
-    if "cpu" in os.environ.get("JAX_PLATFORMS", ""):
-        jax.config.update("jax_platforms", "cpu")
 
     from torch_actor_critic_tpu.parallel.distributed import (
         initialize_multihost,

@@ -277,15 +277,10 @@ class OfflineLearner:
         from torch_actor_critic_tpu.diagnostics.watchdog import get_watchdog
 
         num_updates = int(batches.rewards.shape[0])
-        from torch_actor_critic_tpu.aot.cache import cache_excluded
-
         if self._burst is None or self._burst_len != num_updates:
             self._burst = self._build_burst(num_updates)
             self._burst_len = num_updates
-        # cache_excluded: donated train-plane executables are unsafe to
-        # deserialize from the persistent compilation cache (see
-        # aot/cache.py) — always compile live.
-        with get_watchdog().source(self.burst_cost_name), cache_excluded():
+        with get_watchdog().source(self.burst_cost_name):
             return self._burst(state, batches)
 
     def maybe_register_cost(self, state_abstract, batches_abstract) -> None:

@@ -172,14 +172,9 @@ class RefillPrefetcher:
         ``replay/prefetch_push``; post-steady ones are anomalies)."""
         from torch_actor_critic_tpu.diagnostics.watchdog import get_watchdog
 
-        from torch_actor_critic_tpu.aot.cache import cache_excluded
-
         if self._push is None:
             self._push = self._build_push(buf_shardings, chunk_shardings)
-        # cache_excluded: donated train-plane executables are unsafe to
-        # deserialize from the persistent compilation cache (see
-        # aot/cache.py) — always compile live.
-        with get_watchdog().source(self.push_cost_name), cache_excluded():
+        with get_watchdog().source(self.push_cost_name):
             out = self._push(buffer, chunk)
         self.refills_served += 1
         return out

@@ -41,7 +41,7 @@ def call_with_retries(
     checkpoint raises ``FileNotFoundError`` — an ``OSError`` subclass —
     on every read; retrying it only delays the fallback to the previous
     epoch). The final failure re-raises the original exception so
-    callers keep their error taxonomy.
+    callers keep their error classification.
     """
     if attempts < 1:
         raise ValueError(f"attempts must be >= 1, got {attempts}")

@@ -52,10 +52,6 @@ def parse_arguments(argv=None) -> argparse.Namespace:
 
 def main(argv=None):
     args = parse_arguments(argv)
-    from torch_actor_critic_tpu.utils.platform import honor_platform_env
-
-    honor_platform_env()
-
     from torch_actor_critic_tpu.parallel import make_mesh
     from torch_actor_critic_tpu.sac.trainer import Trainer
 

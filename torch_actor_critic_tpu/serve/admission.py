@@ -1,4 +1,4 @@
-"""Admission-control error taxonomy for the serving plane.
+"""Admission-control error classification for the serving plane.
 
 Under overload a service has exactly three honest answers: do the work,
 reject it *now* with a signal the client can act on, or (worst) accept

@@ -85,7 +85,7 @@ class PolicyClient:
     **deadline-aware**: the ``timeout`` passed to ``act`` is the
     caller's total budget, so a retry that could not complete before
     the deadline is never started and the last rejection (its
-    ``ShedError`` taxonomy preserved) is raised instead. 4xx client
+    ``ShedError`` classification preserved) is raised instead. 4xx client
     errors and 5xx server faults — ``ValueError``/engine faults
     in-process — are never retried (retrying a malformed request or a
     broken engine is not backoff's job). Pass ``retries=0`` for the
@@ -156,7 +156,7 @@ class PolicyClient:
         (``ShedError`` — queue full, breaker open, draining, expired)
         is retried up to ``retries`` times with jittered backoff off
         the shed's own ``retry_after_s`` hint, never past the caller's
-        ``timeout``; the last rejection is re-raised with its taxonomy
+        ``timeout``; the last rejection is re-raised with its classification
         intact. Engine faults and request-shape errors propagate
         unretried (the 5xx/4xx analogue)."""
         deadline = (
@@ -190,7 +190,7 @@ class PolicyClient:
                     time.perf_counter() + delay >= deadline
                 ):
                     # Never retry past the caller's deadline: raise
-                    # the rejection we have (taxonomy intact) instead
+                    # the rejection we have (classification intact) instead
                     # of one we'd manufacture by timing out mid-retry.
                     raise
                 self.retries_total += 1

@@ -110,14 +110,22 @@ class Peaks(t.NamedTuple):
     @classmethod
     def detect(cls) -> "Peaks":
         """Peaks for the default backend's first device (env overrides
-        honored) — None entries on unknown hardware (host CPUs)."""
-        try:
-            import jax
+        honored). A host CPU has no entry and gets None peaks (its
+        dependent metrics are omitted); an accelerator that is not in
+        the tables is an error, not a default."""
+        import jax
 
-            kind = jax.devices()[0].device_kind
-        except Exception:  # noqa: BLE001 — no backend, no peaks
-            kind = None
-        return cls(peak_flops_for(kind), peak_hbm_bw_for(kind), kind)
+        dev = jax.devices()[0]
+        kind = dev.device_kind
+        peaks = cls(peak_flops_for(kind), peak_hbm_bw_for(kind), kind)
+        if dev.platform != "cpu" and None in (peaks.flops, peaks.hbm_bw):
+            raise ValueError(
+                f"no peak FLOP/s / HBM bandwidth known for device kind "
+                f"{kind!r}: add it to PEAK_FLOPS_BY_KIND / "
+                "PEAK_HBM_BW_BY_KIND (telemetry/costmodel.py) with its "
+                "source, or set TAC_PEAK_FLOPS and TAC_PEAK_BW"
+            )
+        return peaks
 
 
 def _extract_costs(analysis: t.Any) -> dict | None:

@@ -46,7 +46,7 @@ logger = logging.getLogger(__name__)
 
 __all__ = ["PHASES", "PhaseTimer", "SpanRing", "TelemetryRecorder"]
 
-# The Trainer step taxonomy (ISSUE 3 / docs/OBSERVABILITY.md): indices
+# The Trainer step classification (ISSUE 3 / docs/OBSERVABILITY.md): indices
 # are the lap() argument — integer phase ids keep the hot path free of
 # dict lookups.
 PHASES: t.Tuple[str, ...] = (
@@ -293,7 +293,7 @@ class TelemetryRecorder:
             },
         }
         # Host/device/input attribution rides the epoch event whenever
-        # the phase taxonomy is the Trainer's (custom phase sets skip
+        # the phase classification is the Trainer's (custom phase sets skip
         # it rather than misclassify).
         if wall_s > 0 and any(p in PHASE_PLANES for p in phases):
             attr = classify_epoch(phases, wall_s)

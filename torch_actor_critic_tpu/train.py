@@ -166,9 +166,6 @@ def config_from_args(args: argparse.Namespace) -> SACConfig:
 
 def main(argv=None):
     args = parse_arguments(argv)
-    from torch_actor_critic_tpu.utils.platform import honor_platform_env
-
-    honor_platform_env()
     initialize_multihost()
 
     from torch_actor_critic_tpu.sac.trainer import Trainer  # jax-heavy import
@@ -201,11 +198,11 @@ def main(argv=None):
         # Persistent compilation cache (aot/cache.py, docs/SERVING.md
         # "Cold start"): epoch programs persist to disk, so a
         # preempted learner's `--run <id>` restart — and every spawned
-        # actor process, which joins via the exported TAC_COMPILE_CACHE
-        # env var — resumes compile-free.
+        # actor process, which resolves the same directory — resumes
+        # compile-free.
         from torch_actor_critic_tpu.aot import enable_persistent_cache
 
-        enable_persistent_cache(config.compile_cache)
+        enable_persistent_cache()
 
     mesh = make_mesh(dp=args.devices, fsdp=args.fsdp)
     checkpointer = Checkpointer(

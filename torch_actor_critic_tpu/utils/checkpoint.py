@@ -411,12 +411,8 @@ class Checkpointer:
         prev_level = absl_logger.level
         absl_logger.setLevel(_logging.ERROR)
         try:
-            restore_args = (
-                ocp.args.StandardRestore()
-                if shardings is None
-                else ocp.args.StandardRestore(
-                    self._sharded_abstract_state(epoch, shardings)
-                )
+            restore_args = ocp.args.StandardRestore(
+                self._sharded_abstract_state(epoch, shardings)
             )
 
             def _restore():
@@ -457,11 +453,13 @@ class Checkpointer:
         """Abstract ``train_state`` tree for a direct-to-sharded actor
         restore, built from the checkpoint's OWN array metadata (so
         serving still needs no caller-side abstract tree): the
-        ``actor_params`` subtree carries the requested shardings,
-        every other subtree restores unconstrained. Orbax cannot
-        partially restore a ``StandardSave`` item, so the full tree is
-        described — but only the actor arrays get layouts; the rest
-        land exactly as the plain shape-from-disk path lands them."""
+        ``actor_params`` subtree carries the requested shardings
+        (``None``: one device), every other subtree lands on this
+        process's first device. Orbax cannot partially restore a
+        ``StandardSave`` item, so the full tree is described. Every
+        leaf names its target: left to itself Orbax restores onto the
+        devices the checkpoint was WRITTEN from, which a worker shown
+        one chip of the four that trained does not have."""
         ts_meta = self._retry(
             lambda: self._mgr.item_metadata(epoch),
             what=f"checkpoint array-metadata read (epoch {epoch})",
@@ -485,7 +483,9 @@ class Checkpointer:
                 "actor_params item — not a TrainState checkpoint?"
             )
 
-        def sds(m, sharding=None):
+        here = jax.sharding.SingleDeviceSharding(jax.local_devices()[0])
+
+        def sds(m, sharding=here):
             return jax.ShapeDtypeStruct(
                 tuple(m.shape), m.dtype, sharding=sharding
             )
@@ -495,9 +495,10 @@ class Checkpointer:
         }
         if callable(shardings):
             shardings = shardings(abstract["actor_params"])
-        abstract["actor_params"] = jax.tree_util.tree_map(
-            sds, ts_meta["actor_params"], shardings
-        )
+        if shardings is not None:
+            abstract["actor_params"] = jax.tree_util.tree_map(
+                sds, ts_meta["actor_params"], shardings
+            )
         return abstract
 
     def refresh(self) -> None:

@@ -382,14 +382,13 @@ class SACConfig:
     # tests/test_sanitize.py).
     sanitize: str = "off"
     # Cold-start machinery (aot/, docs/SERVING.md "Cold start &
-    # warm-start bundles"): `compile_cache` points the persistent XLA
-    # compilation cache at a directory shared by fleet workers,
-    # spawned actors, and learner RESTARTS — a preempted learner
-    # resumes compile-free because its epoch programs are already on
-    # disk. The dir is published to child processes via
-    # TAC_COMPILE_CACHE. Empty (default) leaves jax's cache config
-    # untouched.
-    compile_cache: str = ""
+    # warm-start bundles"): `compile_cache` turns on the persistent XLA
+    # compilation cache shared by fleet workers, spawned actors, and
+    # learner RESTARTS — a preempted learner resumes compile-free
+    # because its epoch programs are already on disk. WHERE the cache
+    # lives is not an option: JAX_COMPILATION_CACHE_DIR if set, else
+    # <checkout>/.jax_cache (aot/cache.py), the same in every process.
+    compile_cache: bool = False
     # `--emit-bundle` writes a warm-start bundle next to the Orbax
     # checkpoint at the FIRST update epoch (the earliest moment real
     # actor params exist): serve.py --warm-start auto then answers its
