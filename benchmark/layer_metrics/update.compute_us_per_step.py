@@ -1,0 +1,9 @@
+"""Device microseconds per gradient step in the compute group: critic, actor,
+alpha, optimizer and polyak scopes together (`harness/scopes.py`)."""
+
+from benchmark.harness import scopes
+
+
+def read(ctx):
+    steps = ctx.n_windows * ctx.per_window["grad_steps"]
+    return scopes.group_us(ctx, "compute", steps)

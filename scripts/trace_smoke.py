@@ -28,7 +28,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PHASES = (
     "act", "env_step", "stage", "place_chunk", "burst_dispatch",
-    "drain", "sentinel", "checkpoint",
+    "drain", "sentinel", "checkpoint", "param_sync",
 )
 
 

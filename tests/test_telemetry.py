@@ -231,7 +231,7 @@ def test_epoch_accounting_metrics_present(off_and_on):
 
 def test_jsonl_schema_roundtrip_and_phase_coverage(off_and_on):
     """Every line parses as strict JSON; epoch events carry the full
-    8-phase classification with consistent aggregates, and the phase sums
+    9-phase classification with consistent aggregates, and the phase sums
     cover ~the epoch wall time (the breakdown partitions the loop)."""
     tracker_on, _, _ = off_and_on["on"]
     lines = (tracker_on.run_dir / "telemetry.jsonl").read_text().splitlines()
@@ -247,12 +247,15 @@ def test_jsonl_schema_roundtrip_and_phase_coverage(off_and_on):
             assert 0.0 <= p["max_s"] <= p["total_s"] + 1e-12
         covered = sum(p["total_s"] for p in ev["phases"].values())
         assert 0.8 * ev["wall_s"] <= covered <= 1.1 * ev["wall_s"]
-        # act/env_step run every step; the window phases once per window
-        assert ev["phases"]["act"]["count"] == TINY["steps_per_epoch"]
-        assert (
-            ev["phases"]["burst_dispatch"]["count"]
-            == TINY["steps_per_epoch"] // TINY["update_every"]
-        )
+        # act/env_step run every step; the window phases once per window.
+        # The host actor's mirror refresh (param_sync, once after every
+        # burst) interrupts an act, which is then charged in two spans.
+        windows = TINY["steps_per_epoch"] // TINY["update_every"]
+        syncs = ev["phases"]["param_sync"]["count"]
+        assert windows - 1 <= syncs <= windows
+        assert ev["phases"]["env_step"]["count"] == TINY["steps_per_epoch"]
+        assert ev["phases"]["act"]["count"] == TINY["steps_per_epoch"] + syncs
+        assert ev["phases"]["burst_dispatch"]["count"] == windows
         assert ev["env_steps"] == TINY["steps_per_epoch"]
         assert ev["phases"]["checkpoint"]["count"] == 1
 
@@ -264,9 +267,14 @@ def test_recorder_snapshot_matches_run(off_and_on):
     assert snap["counters"]["env_steps"] == (
         TINY["epochs"] * TINY["steps_per_epoch"]
     )
-    # 2 full epochs of act spans accumulated at run level
+    # 2 full epochs of spans accumulated at run level (an act that a
+    # param_sync interrupts is two spans)
+    assert snap["phases"]["env_step"]["count"] == (
+        TINY["epochs"] * TINY["steps_per_epoch"]
+    )
     assert snap["phases"]["act"]["count"] == (
         TINY["epochs"] * TINY["steps_per_epoch"]
+        + snap["phases"]["param_sync"]["count"]
     )
 
 

@@ -80,11 +80,12 @@ _RANDOM_HEADS = frozenset({"jax", "random", "jrandom", "jr"})
 
 # Callees that read key METADATA or raw bytes without consuming the
 # stream: `key_data`/`key_impl` (serialization, utils/checkpoint.py),
-# the repo's `_is_prng_key` predicate, and `_abstract_args` (the
-# ShapeDtypeStruct capture the cost registry lowers with — shapes
-# only, docs/ANALYSIS.md). Passing a key to these is not a sink.
+# the repo's `_is_prng_key` predicate, and `abstract_of` (the
+# ShapeDtypeStruct capture the cost registry and the scope table lower
+# with — shapes only, docs/ANALYSIS.md). Passing a key to these is not
+# a sink.
 _METADATA_SINKS = frozenset({
-    "key_data", "key_impl", "_is_prng_key", "_abstract_args",
+    "key_data", "key_impl", "_is_prng_key", "abstract_of",
 })
 
 

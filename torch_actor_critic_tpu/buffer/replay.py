@@ -50,6 +50,7 @@ from torch_actor_critic_tpu.buffer.striped import (
     sample_striped,
 )
 from torch_actor_critic_tpu.core.types import Batch, BufferState, MultiObservation
+from torch_actor_critic_tpu.telemetry import scopes
 
 
 def _zeros_like_spec(capacity: int, spec: t.Any) -> t.Any:
@@ -186,6 +187,7 @@ def init_visual_replay_buffer(
     return init_replay_buffer(capacity, obs_spec, act_dim)
 
 
+@jax.named_scope(scopes.PUSH)
 def push(state: BufferState, chunk: Batch) -> BufferState:
     """Append a chunk of ``n`` transitions, overwriting oldest on wrap.
 
@@ -222,6 +224,7 @@ def push(state: BufferState, chunk: Batch) -> BufferState:
     )
 
 
+@jax.named_scope(scopes.SAMPLE)
 def sample(state: BufferState, key: jax.Array, batch_size: int) -> Batch:
     """Draw a uniform batch over the valid region ``[0, size)``.
 
@@ -247,6 +250,7 @@ def sample(state: BufferState, key: jax.Array, batch_size: int) -> Batch:
     return jax.tree_util.tree_map(lambda ring: jnp.take(ring, idx, axis=0), state.data)
 
 
+@jax.named_scope(scopes.SAMPLE)
 def sample_fused_visual(
     state: BufferState,
     key: jax.Array,
@@ -296,7 +300,9 @@ def sample_fused_visual(
         k_idx, (batch_size,), 0, jnp.maximum(state.size, 1)
     )
     take = lambda ring: jnp.take(ring, idx, axis=0)  # noqa: E731
-    gather = lambda ring, offs: fused_frame_gather(  # noqa: E731
+    gather = lambda ring, offs: jax.named_scope(scopes.DECODE)(  # noqa: E731
+        fused_frame_gather
+    )(
         ring, idx, offsets=offs, pad=pad, normalize=normalize,
         out_dtype=out_dtype, impl=impl, interpret=interpret,
     )

@@ -57,6 +57,7 @@ from torch_actor_critic_tpu.core.types import Batch, BufferState, TrainState
 from torch_actor_critic_tpu.diagnostics import ingraph as diag
 from torch_actor_critic_tpu.parallel import sharding as tp_sharding
 from torch_actor_critic_tpu.parallel.mesh import global_device_put
+from torch_actor_critic_tpu.telemetry import scopes
 
 # Per-device metrics whose cross-replica spread (pmax - pmin) is the
 # replica-desync leading indicator (docs/OBSERVABILITY.md): param-norm
@@ -259,6 +260,10 @@ class DataParallelSAC:
         else:
             self.sac_sp = None
         self._burst = None
+        # What the burst was built for (shape, dtype, sharding of state,
+        # ring and chunk): the burst donates state and ring, so whoever
+        # lowers it again (cost registry, scope table) asks here.
+        self.burst_abstract: tuple = ()
         self._push = None
         self._select_action = None
 
@@ -495,6 +500,7 @@ class DataParallelSAC:
                 num_updates,
                 self._build_burst(num_updates, state, buffer, chunk),
             )
+            self.burst_abstract = scopes.abstract_of(state, buffer, chunk)
         return self._burst[1](state, buffer, chunk)
 
     def burst_jit(self, num_updates: int):
@@ -504,6 +510,11 @@ class DataParallelSAC:
         if self._burst is not None and self._burst[0] == num_updates:
             return self._burst[1]
         return None
+
+    def burst_scope_table(self) -> dict:
+        """Which ``tac/`` scope each instruction of the compiled burst
+        belongs to (telemetry/scopes.py); one compile, after a burst ran."""
+        return scopes.scope_table_for(self._burst[1], *self.burst_abstract)
 
     def push_chunk(self, buffer: BufferState, chunk: Batch) -> BufferState:
         """Store per-device chunks without gradient steps — the warmup

@@ -46,6 +46,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from torch_actor_critic_tpu.buffer.replay import init_replay_buffer, push
 from torch_actor_critic_tpu.core.types import Batch, BufferState, TrainState
 from torch_actor_critic_tpu.sac.algorithm import Metrics
+from torch_actor_critic_tpu.telemetry import scopes
 
 
 class PopulationLearner:
@@ -100,6 +101,8 @@ class PopulationLearner:
         # tails, tests) must hit a cache per size — a single-slot cache
         # silently re-jitted EVERY call when two sizes alternate.
         self._bursts: t.Dict[int, t.Callable] = {}
+        # What the bursts were built for (see DataParallelSAC).
+        self.burst_abstract: tuple = ()
         self._push = None
         self._select = None
 
@@ -194,6 +197,7 @@ class PopulationLearner:
             fn = self._bursts[num_updates] = jax.jit(
                 jax.vmap(one_member), donate_argnums=(0, 1)
             )
+            self.burst_abstract = scopes.abstract_of(state, buffer, chunk)
         from torch_actor_critic_tpu.diagnostics.watchdog import get_watchdog
 
         with get_watchdog().source("train/population_burst"):
@@ -207,6 +211,13 @@ class PopulationLearner:
         dispatch) — same cost-registry lowering hook as
         :meth:`DataParallelSAC.burst_jit`."""
         return self._bursts.get(num_updates)
+
+    def burst_scope_table(self) -> dict:
+        """Which ``tac/`` scope each instruction of the compiled burst
+        (the one built last) belongs to (telemetry/scopes.py); one
+        compile, after a burst ran."""
+        fn = list(self._bursts.values())[-1]
+        return scopes.scope_table_for(fn, *self.burst_abstract)
 
     def push_chunk(self, buffer: BufferState, chunk: Batch) -> BufferState:
         """Warmup-path store (no gradient steps), vmapped per member."""

@@ -45,6 +45,7 @@ from torch_actor_critic_tpu.diagnostics import ingraph as diag
 from torch_actor_critic_tpu.ops.polyak import polyak_update
 from torch_actor_critic_tpu.ops.augment import augment_batch
 from torch_actor_critic_tpu.sac import losses
+from torch_actor_critic_tpu.telemetry import scopes
 from torch_actor_critic_tpu.utils.config import SACConfig
 
 Metrics = t.Dict[str, jax.Array]
@@ -75,6 +76,12 @@ def dynamic_lr_step(
     updates, inner = core.update(grads, inner, params)
     updates = jax.tree_util.tree_map(lambda u: -lr * u, updates)
     return updates, (inner, *rest)
+
+
+@jax.named_scope(scopes.ALLREDUCE)
+def _pmean(grads: t.Any, axis_name) -> t.Any:
+    """Gradient averaging over the data-parallel axis, under its scope."""
+    return jax.lax.pmean(grads, axis_name)
 
 
 class SAC:
@@ -208,9 +215,10 @@ class SAC:
         tier = cfg.diagnostics
         if cfg.frame_augment != "none" and cfg.pixel_pipeline != "fused":
             rng, key_q, key_pi, key_aug = jax.random.split(state.rng, 4)
-            batch = augment_batch(
-                batch, key_aug, cfg.frame_augment, cfg.augment_pad
-            )
+            with jax.named_scope(scopes.DECODE):
+                batch = augment_batch(
+                    batch, key_aug, cfg.frame_augment, cfg.augment_pad
+                )
         else:
             # Parity path keeps the historical 3-way split: 'none' must
             # reproduce pre-augmentation streams bit-for-bit (resumed
@@ -230,8 +238,8 @@ class SAC:
             alpha = hp.get("alpha", jnp.float32(cfg.alpha))
 
         # --- critic step ---
-        (loss_q, q_aux), q_grads = jax.value_and_grad(
-            losses.critic_loss, has_aux=True
+        (loss_q, q_aux), q_grads = jax.named_scope(scopes.CRITIC)(
+            jax.value_and_grad(losses.critic_loss, has_aux=True)
         )(
             state.critic_params,
             actor_apply=self._actor_apply,
@@ -252,12 +260,15 @@ class SAC:
             # Pre-pmean: per-device norm, so replica skew is visible.
             diag_metrics["diag/grad_norm_q"] = diag.global_norm(q_grads)
         if axis_name is not None:
-            q_grads = jax.lax.pmean(q_grads, axis_name)
-        q_updates, q_opt_state = dynamic_lr_step(
-            self._adam_core, self.q_tx, q_grads, state.q_opt_state,
-            state.critic_params, hp.get("critic_lr"),
-        )
-        critic_params = optax.apply_updates(state.critic_params, q_updates)
+            q_grads = _pmean(q_grads, axis_name)
+        with jax.named_scope(scopes.OPTIMIZER):
+            q_updates, q_opt_state = dynamic_lr_step(
+                self._adam_core, self.q_tx, q_grads, state.q_opt_state,
+                state.critic_params, hp.get("critic_lr"),
+            )
+            critic_params = optax.apply_updates(
+                state.critic_params, q_updates
+            )
         if tier != "off":
             diag_metrics["diag/update_ratio_q"] = diag.norm_ratio(
                 q_updates, state.critic_params
@@ -265,8 +276,8 @@ class SAC:
 
         # --- actor step (critic frozen by construction: grad w.r.t.
         # actor params only) ---
-        (loss_pi, pi_aux), pi_grads = jax.value_and_grad(
-            losses.actor_loss, has_aux=True
+        (loss_pi, pi_aux), pi_grads = jax.named_scope(scopes.ACTOR)(
+            jax.value_and_grad(losses.actor_loss, has_aux=True)
         )(
             state.actor_params,
             actor_apply=self._actor_apply,
@@ -282,12 +293,15 @@ class SAC:
         if tier != "off":
             diag_metrics["diag/grad_norm_pi"] = diag.global_norm(pi_grads)
         if axis_name is not None:
-            pi_grads = jax.lax.pmean(pi_grads, axis_name)
-        pi_updates, pi_opt_state = dynamic_lr_step(
-            self._adam_core, self.pi_tx, pi_grads, state.pi_opt_state,
-            state.actor_params, hp.get("actor_lr"),
-        )
-        actor_params = optax.apply_updates(state.actor_params, pi_updates)
+            pi_grads = _pmean(pi_grads, axis_name)
+        with jax.named_scope(scopes.OPTIMIZER):
+            pi_updates, pi_opt_state = dynamic_lr_step(
+                self._adam_core, self.pi_tx, pi_grads, state.pi_opt_state,
+                state.actor_params, hp.get("actor_lr"),
+            )
+            actor_params = optax.apply_updates(
+                state.actor_params, pi_updates
+            )
         if tier != "off":
             diag_metrics["diag/update_ratio_pi"] = diag.norm_ratio(
                 pi_updates, state.actor_params
@@ -297,28 +311,30 @@ class SAC:
         log_alpha = state.log_alpha
         alpha_opt_state = state.alpha_opt_state
         if cfg.learn_alpha:
-            a_grad = jax.grad(
+            a_grad = jax.named_scope(scopes.ALPHA)(jax.grad(
                 lambda la: losses.alpha_loss(
                     la, pi_aux["logp_pi"], target_entropy
                 )
-            )(state.log_alpha)
+            ))(state.log_alpha)
             if tier != "off":
                 diag_metrics["diag/grad_norm_alpha"] = jnp.abs(a_grad)
             if axis_name is not None:
-                a_grad = jax.lax.pmean(a_grad, axis_name)
-            a_updates, alpha_opt_state = self.alpha_tx.update(
-                a_grad, state.alpha_opt_state, state.log_alpha
-            )
-            log_alpha = optax.apply_updates(state.log_alpha, a_updates)
+                a_grad = _pmean(a_grad, axis_name)
+            with jax.named_scope(scopes.OPTIMIZER):
+                a_updates, alpha_opt_state = self.alpha_tx.update(
+                    a_grad, state.alpha_opt_state, state.log_alpha
+                )
+                log_alpha = optax.apply_updates(state.log_alpha, a_updates)
             if tier != "off":
                 diag_metrics["diag/update_ratio_alpha"] = jnp.abs(
                     a_updates
                 ) / (jnp.abs(state.log_alpha) + 1e-12)
 
         # --- polyak target update (ref sac/algorithm.py:77-81) ---
-        target_critic_params = polyak_update(
-            critic_params, state.target_critic_params, cfg.polyak
-        )
+        with jax.named_scope(scopes.POLYAK):
+            target_critic_params = polyak_update(
+                critic_params, state.target_critic_params, cfg.polyak
+            )
 
         new_state = TrainState(
             step=state.step + 1,

@@ -32,6 +32,7 @@ from torch_actor_critic_tpu.core.types import Batch, BufferState, TrainState
 from torch_actor_critic_tpu.envs.ondevice import EnvState
 from torch_actor_critic_tpu.utils.sync import drain
 from torch_actor_critic_tpu.sac.algorithm import SAC
+from torch_actor_critic_tpu.telemetry import scopes
 
 Metrics = t.Dict[str, jax.Array]
 
@@ -50,7 +51,39 @@ PIXEL_CONV = dict(
 PIXEL_RECIPE = dict(PIXEL_CONV, frame_augment="shift", learn_alpha=True)
 
 
-class OnDeviceLoop:
+class _EpochPrograms:
+    """A fused loop's jitted epoch programs by signature ``(steps,
+    update_every, warmup)``, each with what it was built for: the
+    program donates its state and ring, so whoever lowers it again
+    (the cost registry, the scope table) needs the shapes kept."""
+
+    def _epoch_program(self, sig: tuple, args: tuple):
+        if sig not in self._epoch_fns:
+            self._epoch_fns[sig] = self._build_epoch(*sig)
+            self._epoch_abstract[sig] = scopes.abstract_of(*args)
+        return self._epoch_fns[sig]
+
+    def epoch_jit(self, steps: int, update_every: int, warmup: bool = False):
+        """The cached jitted epoch program for a signature (None before
+        its first dispatch) — the cost registry lowers this with
+        :meth:`epoch_abstract` (telemetry/costmodel.py)."""
+        return self._epoch_fns.get((steps, update_every, warmup))
+
+    def epoch_abstract(self, steps: int, update_every: int, warmup: bool = False):
+        """Shape, dtype and sharding of the program's four arguments
+        (``()`` before its first dispatch)."""
+        return self._epoch_abstract.get((steps, update_every, warmup), ())
+
+    def epoch_scope_table(self, steps: int, update_every: int, warmup: bool = False):
+        """Which ``tac/`` scope each instruction of the compiled epoch
+        program belongs to (telemetry/scopes.py); one compile."""
+        sig = (steps, update_every, warmup)
+        return scopes.scope_table_for(
+            self._epoch_fns[sig], *self._epoch_abstract[sig]
+        )
+
+
+class OnDeviceLoop(_EpochPrograms):
     """Collect+update loop compiled end-to-end — one device or a mesh.
 
     ``n_envs`` pure-JAX envs step in a vmapped batch; every
@@ -79,6 +112,7 @@ class OnDeviceLoop:
         self.mesh = mesh
         self.n_dp = mesh.shape["dp"] if mesh is not None else 1
         self._epoch_fns: dict = {}
+        self._epoch_abstract: dict = {}
 
     # ------------------------------------------------------------------ init
 
@@ -142,29 +176,31 @@ class OnDeviceLoop:
 
         def step_fn(carry, _):
             es, key = carry
-            key, k_act = jax.random.split(key)
             obs = es.obs
-            if warmup:
-                actions = jax.random.uniform(
-                    k_act,
-                    (self.n_envs, env.act_dim),
-                    minval=-env.act_limit,
-                    maxval=env.act_limit,
+            with jax.named_scope(scopes.COLLECT_ACT):
+                key, k_act = jax.random.split(key)
+                if warmup:
+                    actions = jax.random.uniform(
+                        k_act,
+                        (self.n_envs, env.act_dim),
+                        minval=-env.act_limit,
+                        maxval=env.act_limit,
+                    )
+                else:
+                    actions, _ = self.sac.actor_def.apply(
+                        params, obs, k_act, with_logprob=False
+                    )
+            with jax.named_scope(scopes.COLLECT_ENV):
+                es, out = jax.vmap(env.step)(es, actions)
+                transition = Batch(
+                    states=obs,
+                    actions=actions,
+                    rewards=out.reward,
+                    next_states=out.next_obs,
+                    done=out.terminated,
                 )
-            else:
-                actions, _ = self.sac.actor_def.apply(
-                    params, obs, k_act, with_logprob=False
-                )
-            es, out = jax.vmap(env.step)(es, actions)
-            transition = Batch(
-                states=obs,
-                actions=actions,
-                rewards=out.reward,
-                next_states=out.next_obs,
-                done=out.terminated,
-            )
-            ended = out.ended.astype(jnp.float32)
-            stats = (jnp.sum(ended), jnp.sum(ended * out.final_return))
+                ended = out.ended.astype(jnp.float32)
+                stats = (jnp.sum(ended), jnp.sum(ended * out.final_return))
             return (es, key), (transition, stats)
 
         (env_states, act_key), (transitions, stats) = jax.lax.scan(
@@ -358,19 +394,10 @@ class OnDeviceLoop:
         ``sac/algorithm.py:227-228,273``)."""
         from torch_actor_critic_tpu.diagnostics.watchdog import get_watchdog
 
-        sig = (steps, update_every, warmup)
-        if sig not in self._epoch_fns:
-            self._epoch_fns[sig] = self._build_epoch(*sig)
+        args = (train_state, buffer, env_states, act_key)
+        fn = self._epoch_program((steps, update_every, warmup), args)
         with get_watchdog().source(self.epoch_cost_name):
-            return self._epoch_fns[sig](
-                train_state, buffer, env_states, act_key
-            )
-
-    def epoch_jit(self, steps: int, update_every: int, warmup: bool = False):
-        """The cached jitted epoch program for a signature (None before
-        its first dispatch) — the cost registry lowers this with
-        abstract args (telemetry/costmodel.py)."""
-        return self._epoch_fns.get((steps, update_every, warmup))
+            return fn(*args)
 
 
 def loop_class_for(env_cls) -> type:
@@ -409,7 +436,7 @@ class PBTState:
     rng: jax.Array         # PRNG key
 
 
-class PopulationOnDeviceLoop:
+class PopulationOnDeviceLoop(_EpochPrograms):
     """N complete fused training runs advanced by ONE device dispatch.
 
     The member axis is ``jax.vmap`` over the ENTIRE
@@ -495,6 +522,7 @@ class PopulationOnDeviceLoop:
         # keep the bitwise-pinned base body.
         self.inner = loop_class_for(env_cls)(sac, env_cls, n_envs=n_envs)
         self._epoch_fns: dict = {}
+        self._epoch_abstract: dict = {}
         self._pbt_fn = None
         self._ema_fn = None
 
@@ -646,16 +674,10 @@ class PopulationOnDeviceLoop:
         device dispatch for everything."""
         from torch_actor_critic_tpu.diagnostics.watchdog import get_watchdog
 
-        sig = (steps, update_every, warmup)
-        if sig not in self._epoch_fns:
-            self._epoch_fns[sig] = self._build_epoch(*sig)
+        args = (state, buffer, env_states, act_keys)
+        fn = self._epoch_program((steps, update_every, warmup), args)
         with get_watchdog().source(self.epoch_cost_name):
-            return self._epoch_fns[sig](state, buffer, env_states, act_keys)
-
-    def epoch_jit(self, steps: int, update_every: int, warmup: bool = False):
-        """The cached jitted population-epoch program (None before its
-        first dispatch) — the cost-registry lowering hook."""
-        return self._epoch_fns.get((steps, update_every, warmup))
+            return fn(*args)
 
     # ------------------------------------------------------------------- pbt
 
@@ -846,21 +868,8 @@ def _wrap_and_build(env_cls, config) -> t.Tuple[t.Any, SAC]:
     return env_cls, make_learner(config, actor, critic, env_cls.act_dim)
 
 
-def _abstract_args(*trees):
-    """Shape/dtype specs of the epoch-program arguments, captured
-    BEFORE dispatch (the program donates state+buffer) so the cost
-    registry can lower the compiled program without live buffers."""
-    try:
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), trees
-        )
-    except Exception:  # noqa: BLE001 — cost accounting must never
-        # break training
-        return ()
-
-
 def _note_epoch_cost(
-    loop, sig, abstract, cost_state, metrics, dt, telemetry, e,
+    loop, sig, cost_state, metrics, dt, telemetry, e,
     devices: int = 1, compute_dtype: str | None = None,
 ):
     """Fused-loop per-epoch cost attribution (telemetry on only):
@@ -882,6 +891,7 @@ def _note_epoch_cost(
     if not cost_state["registered"]:
         cost_state["registered"] = True
         fn = loop.epoch_jit(*sig)
+        abstract = loop.epoch_abstract(*sig)
         if fn is not None and abstract:
             registry.register_jit(
                 loop.epoch_cost_name, fn, *abstract, devices=devices
@@ -986,12 +996,7 @@ def train_on_device(
     metrics: dict = {}
     sig = (config.steps_per_epoch, config.update_every, False)
     cost_state = {"registered": False, "peaks": None}
-    cost_abstract = None
     for e in range(start_epoch, start_epoch + config.epochs):
-        if telemetry is not None and cost_abstract is None:
-            cost_abstract = _abstract_args(
-                state, buffer, env_states, act_key
-            )
         t0 = time.time()
         state, buffer, env_states, act_key, m = loop.epoch(
             state,
@@ -1020,7 +1025,7 @@ def train_on_device(
         )
         if telemetry is not None:
             _note_epoch_cost(
-                loop, sig, cost_abstract, cost_state, metrics, dt,
+                loop, sig, cost_state, metrics, dt,
                 telemetry, e, devices=loop.n_dp,
                 compute_dtype=config.compute_dtype,
             )
@@ -1179,12 +1184,7 @@ def train_population_on_device(
     metrics: dict = {}
     sig = (config.steps_per_epoch, config.update_every, False)
     cost_state = {"registered": False, "peaks": None}
-    cost_abstract = None
     for e in range(start_epoch, start_epoch + config.epochs):
-        if telemetry is not None and cost_abstract is None:
-            cost_abstract = _abstract_args(
-                state, buffer, env_states, act_keys
-            )
         t0 = time.time()
         state, buffer, env_states, act_keys, m = loop.epoch(
             state, buffer, env_states, act_keys,
@@ -1216,7 +1216,7 @@ def train_population_on_device(
             # sharded, the per-device divide keeps MFU the aggregate
             # utilization of ONE chip's slice of the population.
             _note_epoch_cost(
-                loop, sig, cost_abstract, cost_state, metrics, dt,
+                loop, sig, cost_state, metrics, dt,
                 telemetry, e,
                 devices=(
                     pop_mesh.shape["dp"] if pop_mesh is not None else 1

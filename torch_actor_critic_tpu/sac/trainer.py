@@ -71,7 +71,7 @@ from torch_actor_critic_tpu.utils.tracking import Tracker
 logger = logging.getLogger(__name__)
 
 # Integer indices into telemetry.PHASES, hoisted to module constants so
-# the hot loop's instrumentation is `rec.lap(_PH_ACT)` — no dict or
+# the hot loop's instrumentation is `rec.begin(_PH_ENV)` — no dict or
 # attribute lookups per phase mark (docs/OBSERVABILITY.md).
 (
     _PH_ACT,
@@ -82,7 +82,8 @@ logger = logging.getLogger(__name__)
     _PH_DRAIN,
     _PH_SENTINEL,
     _PH_CKPT,
-) = range(8)
+    _PH_SYNC,
+) = range(9)
 
 
 def build_models(config: SACConfig, env) -> t.Tuple[t.Any, t.Any]:
@@ -438,9 +439,9 @@ class Trainer:
         # telemetry on, the first update epoch registers the burst's
         # XLA cost analysis (one extra lowering+compile, off the step
         # path) and every later epoch reports achieved-FLOPs / roofline
-        # metrics against the burst+drain span time. telemetry=None
-        # leaves all of this untouched — no lowering, no extra keys.
-        self._burst_abstract = None
+        # metrics against the burst's span time (dispatch, the wait for
+        # it in param_sync, drain). telemetry=None leaves all of this
+        # untouched — no lowering, no extra keys.
         self._cost_registered = False
         self._peaks = None  # costmodel.Peaks, detected lazily
         # Learning-health diagnostics (diagnostics/, docs/OBSERVABILITY
@@ -771,7 +772,7 @@ class Trainer:
         self._act_key, sub = jax.random.split(self._act_key)
         if self.config.host_actor:
             if self._host_params is None:
-                self._host_params = self._fetch_params_single_transfer()
+                self._host_params = self._sync_host_params()
             actions = self._host_select(
                 self._host_params, obs_batch, sub, deterministic=deterministic
             )
@@ -780,6 +781,19 @@ class Trainer:
                 self.state.actor_params, obs_batch, sub, deterministic=deterministic
             )
         return np.asarray(actions)
+
+    def _sync_host_params(self):
+        """The mirror refresh as a phase of its own (``param_sync``): it
+        waits for the burst that wrote the parameters, so inside an
+        epoch it is charged apart from the phase it interrupts (``act``,
+        or ``burst_dispatch`` under ``actor_param_lag``)."""
+        rec = self.telemetry
+        if rec is None or rec.open_phase < 0:  # off, or outside an epoch
+            return self._fetch_params_single_transfer()
+        prev = rec.begin(_PH_SYNC)
+        params = self._fetch_params_single_transfer()
+        rec.begin(prev)
+        return params
 
     def _fetch_params_single_transfer(self):
         """Mirror actor params to the host with one device->host copy."""
@@ -931,13 +945,13 @@ class Trainer:
             # already-built burst; failures degrade to "no cost keys".
             self._cost_registered = True
             fn = self.dp.burst_jit(self.config.updates_per_window)
-            if fn is not None and self._burst_abstract:
+            if fn is not None and self.dp.burst_abstract:
                 # Whole-mesh program -> per-device cost: the lowered
                 # analysis spans every dp/fsdp/tp participant, so the
                 # registered FLOPs divide by the mesh size and MFU
                 # stays honest against one chip's peak.
                 registry.register_jit(
-                    name, fn, *self._burst_abstract,
+                    name, fn, *self.dp.burst_abstract,
                     devices=int(self.mesh.devices.size),
                 )
         cost = registry.get(name)
@@ -946,7 +960,8 @@ class Trainer:
         if self._peaks is None:
             self._peaks = Peaks.detect()
         burst_s = (
-            rec.timer.sums[_PH_BURST] + rec.timer.sums[_PH_DRAIN]
+            rec.timer.sums[_PH_BURST] + rec.timer.sums[_PH_SYNC]
+            + rec.timer.sums[_PH_DRAIN]
         )
         rl = roofline(
             cost, burst_s, calls=n_bursts, peaks=self._peaks,
@@ -1229,6 +1244,7 @@ class Trainer:
             self._epoch = e
             if rec is not None:
                 rec.epoch_begin(e)
+                rec.begin(_PH_ACT)
             losses_q, losses_pi = [], []
             env_steps_this_epoch = 0
 
@@ -1239,7 +1255,7 @@ class Trainer:
                 else:
                     actions = self._policy_actions(obs)
                 if rec is not None:
-                    rec.lap(_PH_ACT)
+                    rec.begin(_PH_ENV)
 
                 # --- env step (one lockstep pool dispatch) + bookkeeping
                 # (ref :238-260), batch numpy ops across envs — no
@@ -1309,15 +1325,19 @@ class Trainer:
                     ep_len[ended] = 0
                 obs = next_obs
                 env_steps_this_epoch += n
-                if rec is not None:
-                    rec.lap(_PH_ENV)
 
                 # --- device window: push or push+update (ref :273-283) ---
                 window_full = (step + 1) % cfg.update_every == 0
+                if rec is not None:
+                    # What follows this step and its device window.
+                    after = _PH_DRAIN if epoch_ended else _PH_ACT
+                    rec.begin(_PH_STAGE if window_full else after)
                 if window_full:
                     local_chunk = self._drain_window(staging)
                     if rec is not None:
-                        rec.lap(_PH_STAGE)
+                        rec.begin(
+                            _PH_PLACE if local_chunk is not None else after
+                        )
                 # A None chunk (decoupled only: the admission gate
                 # dropped staged transitions below one fixed-size
                 # window) skips this boundary's device work entirely —
@@ -1338,26 +1358,8 @@ class Trainer:
                             local_chunk, self.mesh, sp=self.dp.effective_sp,
                         )
                     if rec is not None:
-                        rec.lap(_PH_PLACE)
+                        rec.begin(_PH_BURST)
                     if step > cfg.update_after:
-                        if rec is not None and self._burst_abstract is None:
-                            # Shape/dtype specs of the burst arguments,
-                            # captured BEFORE dispatch (the burst
-                            # donates state+buffer) — the cost registry
-                            # lowers the compiled program with these at
-                            # epoch end (telemetry/costmodel.py).
-                            try:
-                                self._burst_abstract = (
-                                    jax.tree_util.tree_map(
-                                        lambda x: jax.ShapeDtypeStruct(
-                                            x.shape, x.dtype
-                                        ),
-                                        (self.state, self.buffer, chunk),
-                                    )
-                                )
-                            except Exception:  # noqa: BLE001 — cost
-                                # accounting must never break training
-                                self._burst_abstract = ()
                         # (config validation guarantees host_actor here)
                         if cfg.actor_param_lag and step + 1 >= cfg.start_steps:
                             # Mirror the PRE-burst params now (their
@@ -1368,35 +1370,23 @@ class Trainer:
                             # staleness (opt-in; see SACConfig). While
                             # acting is still random (< start_steps)
                             # nothing reads the mirror — skip the sync.
-                            self._host_params = (
-                                self._fetch_params_single_transfer()
-                            )
-                        if (
-                            rec is None and self.watchdog is None
-                            and not self._sanitize
-                        ):
+                            self._host_params = self._sync_host_params()
+                        if self.watchdog is None and not self._sanitize:
                             self.state, self.buffer, m = self.dp.update_burst(
                                 self.state, self.buffer, chunk,
                                 cfg.updates_per_window,
                             )
                         else:
-                            # Named XLA-trace span (the burst dispatch
-                            # shows up labeled in a --profile-epochs
-                            # capture; queued device execution surfaces
-                            # under `drain`) and/or watchdog source
-                            # attribution (any compile in this dispatch
-                            # belongs to the burst — post-steady ones
-                            # are hot-path recompile anomalies).
+                            # Watchdog source attribution (any
+                            # compile in this dispatch belongs to the
+                            # burst — post-steady ones are hot-path
+                            # recompile anomalies).
                             with contextlib.ExitStack() as stack:
                                 if self.watchdog is not None:
                                     stack.enter_context(
                                         self.watchdog.source(
                                             "train/update_burst"
                                         )
-                                    )
-                                if rec is not None:
-                                    stack.enter_context(
-                                        rec.annotate("train/update_burst")
                                     )
                                 if self._sanitize:
                                     # Sanitize tier: the burst dispatch
@@ -1442,7 +1432,8 @@ class Trainer:
                         # the NEXT window's sampling.
                         self._maybe_refill()
                     if rec is not None:
-                        rec.lap(_PH_BURST)
+                        rec.window += 1
+                        rec.begin(after)
 
                 step += 1
 
@@ -1601,9 +1592,9 @@ class Trainer:
                     for a in new_anoms:
                         rec.event("recompile_anomaly", epoch=e, **a)
             if rec is not None:
-                rec.lap(_PH_DRAIN)
+                rec.begin(_PH_SENTINEL)
                 # Per-program roofline for the epoch: burst FLOPs from
-                # the cost registry over the burst+drain span time just
+                # the cost registry over the burst's span time just
                 # recorded (dispatch is async — queued device execution
                 # surfaces under drain). Adds cost/ columns to
                 # metrics.jsonl and a `cost` telemetry event; absent
@@ -1654,7 +1645,7 @@ class Trainer:
                 time.perf_counter() - t_sentinel, 4
             )
             if rec is not None:
-                rec.lap(_PH_SENTINEL)
+                rec.begin(_PH_CKPT)
 
             # Orbax saves of sharded arrays are collective: EVERY process
             # must call save (each host owns shards of the dp-sharded
@@ -1677,7 +1668,7 @@ class Trainer:
             # dispatch; Orbax finishes the IO in the background).
             last_metrics["save_s"] = round(time.perf_counter() - t_save, 4)
             if rec is not None:
-                rec.lap(_PH_CKPT)
+                rec.end()
 
             # Decoupled-plane boundary work (no-op in the base class):
             # publish this epoch's params to the serving registry and

@@ -39,6 +39,7 @@ from torch_actor_critic_tpu.buffer.replay import init_replay_buffer, push
 from torch_actor_critic_tpu.buffer.striped import init_striped_replay_buffer
 from torch_actor_critic_tpu.core.types import Batch
 from torch_actor_critic_tpu.sac.ondevice import Metrics, OnDeviceLoop
+from torch_actor_critic_tpu.telemetry import scopes
 
 _BASE_RAW_KEYS = ("loss_q", "loss_pi", "episodes", "return_sum")
 
@@ -72,20 +73,22 @@ class ScenarioOnDeviceLoop(OnDeviceLoop):
 
         def step_fn(carry, _):
             es, key = carry
-            key, k_act = jax.random.split(key)
             obs = es.obs
-            if warmup:
-                actions = jax.random.uniform(
-                    k_act,
-                    (self.n_envs, env.act_dim),
-                    minval=-env.act_limit,
-                    maxval=env.act_limit,
-                )
-            else:
-                actions, _ = self.sac.actor_def.apply(
-                    params, obs, k_act, with_logprob=False
-                )
-            es, out = jax.vmap(env.step)(es, actions)
+            with jax.named_scope(scopes.COLLECT_ACT):
+                key, k_act = jax.random.split(key)
+                if warmup:
+                    actions = jax.random.uniform(
+                        k_act,
+                        (self.n_envs, env.act_dim),
+                        minval=-env.act_limit,
+                        maxval=env.act_limit,
+                    )
+                else:
+                    actions, _ = self.sac.actor_def.apply(
+                        params, obs, k_act, with_logprob=False
+                    )
+            with jax.named_scope(scopes.COLLECT_ENV):
+                es, out = jax.vmap(env.step)(es, actions)
             transition = Batch(
                 states=obs,
                 actions=actions,

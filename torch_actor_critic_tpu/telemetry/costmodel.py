@@ -366,6 +366,9 @@ PHASE_PLANES: t.Mapping[str, str] = {
     "drain": "device",
     "sentinel": "host",
     "checkpoint": "host",
+    # The host waits here while the device runs the burst whose
+    # parameters the actor mirror needs.
+    "param_sync": "device",
 }
 
 

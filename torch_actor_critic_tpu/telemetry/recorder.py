@@ -3,7 +3,7 @@
 Design constraints (the tentpole contract, docs/OBSERVABILITY.md):
 
 - **Zero code when disabled.** The Trainer stores ``telemetry=None``
-  and every instrumentation point is ``if rec is not None: rec.lap(i)``
+  and every instrumentation point is ``if rec is not None: rec.begin(i)``
   over a loop-local — one always-false predicted branch per phase mark,
   no calls, no allocation, no events. Disabled-mode metrics are
   byte-identical to an uninstrumented build (pinned by
@@ -24,6 +24,13 @@ charges everything since the previous lap (or :meth:`mark`) to phase
 ``i``, so the per-epoch phase sums add up to ~the epoch wall time and
 the breakdown answers "where did the time go" without leaving gaps
 (the acceptance check ``make trace-smoke`` asserts the coverage).
+
+The Trainer names a phase where it starts: ``begin(i)`` laps the phase
+that was open and opens ``i``, also as a ``jax.profiler.TraceAnnotation``
+named ``tac/host/<phase>`` with ``window=`` and ``epoch=``: the same
+partition on the profiler's clock, beside the device operations, in
+whatever trace is being taken (``--profile-epochs``, the benchmark's).
+With no trace active an annotation is a TraceMe no-op.
 """
 
 from __future__ import annotations
@@ -32,6 +39,7 @@ import logging
 import time
 import typing as t
 
+import jax
 import numpy as np
 
 from torch_actor_critic_tpu.telemetry.costmodel import (
@@ -40,6 +48,7 @@ from torch_actor_critic_tpu.telemetry.costmodel import (
 )
 from torch_actor_critic_tpu.telemetry.memory import device_memory_watermarks
 from torch_actor_critic_tpu.telemetry.profiler import ProfilerWindow
+from torch_actor_critic_tpu.telemetry.scopes import HOST_PREFIX
 from torch_actor_critic_tpu.telemetry.sinks import JsonlSink, format_summary
 
 logger = logging.getLogger(__name__)
@@ -58,6 +67,7 @@ PHASES: t.Tuple[str, ...] = (
     "drain",          # epoch-end device-queue drain (true burst cost)
     "sentinel",       # divergence check (+ rollback when it fires)
     "checkpoint",     # Orbax save dispatch
+    "param_sync",     # device->host actor mirror: waits for the burst
 )
 SCHEMA_VERSION = 1
 
@@ -185,6 +195,15 @@ class TelemetryRecorder:
         self._run_counts = [0] * len(self.phases)
         self._run_maxs = [0.0] * len(self.phases)
         self._t_epoch: float | None = None
+        # begin()/end(): the open phase (-1: none), its annotation, and
+        # the identifiers every annotation carries. The Trainer bumps
+        # `window` after each burst dispatch, so a window's host spans
+        # and the dispatch of the burst they fed share one number.
+        self.open_phase = -1
+        self._annotation = None
+        self._annotation_names = tuple(HOST_PREFIX + p for p in self.phases)
+        self.window = 0
+        self._epoch = 0
         self.last_memory: dict | None = None
         # Host/device/input epoch attribution (costmodel.classify_epoch)
         # — rolling counts per class plus frac sums, surfaced by
@@ -250,18 +269,37 @@ class TelemetryRecorder:
         path — counters allocate on first use)."""
         self.counters[name] = self.counters.get(name, 0.0) + value
 
-    def annotate(self, name: str):
-        """Named ``jax.profiler`` trace annotation context — shows up as
-        a labeled span in the captured XLA trace; near-free (a TraceMe
-        no-op) when no trace is active."""
-        import jax
+    def begin(self, phase: int) -> int:
+        """Close the open phase (charged as :meth:`lap` charges it) and
+        open ``phase``; ``-1`` opens none. Returns the phase that was
+        open, so that a phase nested in another can hand back to it."""
+        prev = self.open_phase
+        if prev >= 0:
+            self.lap(prev)
+            self._annotation.__exit__(None, None, None)
+        else:
+            self.timer.mark()
+        self.open_phase = phase
+        if phase >= 0:
+            self._annotation = jax.profiler.TraceAnnotation(
+                self._annotation_names[phase],
+                window=self.window, epoch=self._epoch,
+            )
+            self._annotation.__enter__()
+        return prev
 
-        return jax.profiler.TraceAnnotation(name)
+    def end(self) -> None:
+        """Close the open phase; what follows is charged to nothing."""
+        self.begin(-1)
 
     # ------------------------------------------------------ epoch boundary
 
     def epoch_begin(self, epoch: int) -> None:
         self.profiler.epoch_begin(epoch)
+        if self.open_phase >= 0:  # an epoch that raised left it open
+            self._annotation.__exit__(None, None, None)
+            self.open_phase = -1
+        self._epoch = int(epoch)
         self._t_epoch = self.timer.mark()
 
     def epoch_end(self, epoch: int, extra: t.Mapping[str, t.Any] | None = None) -> dict:
