@@ -9,6 +9,7 @@ oldest-overwrite, and sampling restricted to the valid region.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from torch_actor_critic_tpu.buffer import (
     init_replay_buffer,
@@ -130,3 +131,163 @@ def test_estimate_buffer_bytes():
     assert estimate_buffer_bytes(10, vis, 56) == 10 * per_row
     # The motivating case: 1e6 visual transitions ~ 26 GB > any v5e.
     assert estimate_buffer_bytes(1_000_000, vis, 56) > 16 * 1024**3
+
+
+# --------------------------------------------------------------------------
+# push against a NumPy model of ``(ptr + arange(n)) % capacity``, bit for bit.
+
+
+def _model_push(data, ptr, size, chunk, capacity):
+    """The reference's ``store`` n times (ref ``replay_buffer.py:29-43``)."""
+    n = len(jax.tree_util.tree_leaves(chunk)[0])
+    idx = (ptr + np.arange(n)) % capacity
+    data = jax.tree_util.tree_map(lambda r: np.array(r), data)
+    for ring, new in zip(
+        jax.tree_util.tree_leaves(data), jax.tree_util.tree_leaves(chunk)
+    ):
+        ring[idx] = new
+    return data, (ptr + n) % capacity, min(size + n, capacity)
+
+
+def _random_ring(rng, capacity, visual, ptr):
+    """A ring full of random bits with its write pointer at ``ptr``."""
+    if visual:
+        buf = init_visual_replay_buffer(
+            capacity, feature_dim=3, frame_shape=(4, 4, 3), act_dim=ACT_DIM
+        )
+    else:
+        buf = init_replay_buffer(
+            capacity, jax.ShapeDtypeStruct((OBS_DIM,), jnp.float32), ACT_DIM
+        )
+    data = jax.tree_util.tree_map(lambda x: _random_like(rng, x), buf.data)
+    return buf.replace(data=data, ptr=jnp.int32(ptr), size=jnp.int32(ptr))
+
+
+def _random_like(rng, x, lead=()):
+    shape = tuple(lead) + x.shape
+    if x.dtype == jnp.uint8:
+        return jnp.asarray(rng.integers(0, 256, shape, dtype=np.uint8))
+    # Every bit pattern of a finite float32, not only "nice" values.
+    return jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+
+
+def _random_chunk(rng, buf, n, lead=()):
+    return jax.tree_util.tree_map(
+        lambda ring: _random_like(
+            rng, jax.ShapeDtypeStruct((n,) + ring.shape[1:], ring.dtype), lead
+        ),
+        buf.data,
+    )
+
+
+def _assert_same(buf, model):
+    data, ptr, size = model
+    for got, want in zip(
+        jax.tree_util.tree_leaves(buf.data), jax.tree_util.tree_leaves(data)
+    ):
+        got = np.asarray(got)
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(
+            got.view(np.uint8), want.view(np.uint8)  # bitwise
+        )
+    assert int(buf.ptr) == ptr and int(buf.size) == size
+
+
+def _case_single(rng, capacity, ptr, n, visual=False):
+    buf = _random_ring(rng, capacity, visual, ptr)
+    chunk = _random_chunk(rng, buf, n)
+    model = _model_push(buf.data, ptr, ptr, jax.device_get(chunk), capacity)
+    _assert_same(push(buf, chunk), model)
+
+
+def _stack(trees):
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
+
+
+def _case_vmapped(rng, capacity, ptrs, n):
+    """``jax.vmap(push)`` with a pointer of its own in every member."""
+    members = [_random_ring(rng, capacity, False, p) for p in ptrs]
+    chunks = [_random_chunk(rng, members[0], n) for _ in ptrs]
+    out = jax.jit(jax.vmap(push), donate_argnums=(0,))(
+        _stack(members), _stack(chunks)
+    )
+    for i, (buf, chunk, p) in enumerate(zip(members, chunks, ptrs)):
+        model = _model_push(buf.data, p, p, jax.device_get(chunk), capacity)
+        _assert_same(jax.tree_util.tree_map(lambda x: x[i], out), model)
+
+
+def _case_nested(rng, capacity, ptrs, n):
+    """``vmap(vmap(push))``: the member rule under a second batch axis."""
+    members = [[_random_ring(rng, capacity, False, p) for p in row] for row in ptrs]
+    chunks = [[_random_chunk(rng, members[0][0], n) for _ in row] for row in ptrs]
+    out = jax.jit(jax.vmap(jax.vmap(push)))(
+        _stack([_stack(row) for row in members]),
+        _stack([_stack(row) for row in chunks]),
+    )
+    for i, row in enumerate(ptrs):
+        for j, p in enumerate(row):
+            model = _model_push(
+                members[i][j].data, p, p, jax.device_get(chunks[i][j]), capacity
+            )
+            _assert_same(jax.tree_util.tree_map(lambda x: x[i, j], out), model)
+
+
+def _case_two_donated(rng, capacity, ptr, n):
+    """Two pushes in a row under ``jit`` with the ring donated: the
+    second wraps."""
+    buf = _random_ring(rng, capacity, False, ptr)
+    model = (buf.data, ptr, ptr)
+    push_jit = jax.jit(push, donate_argnums=(0,))
+    for _ in range(2):
+        chunk = _random_chunk(rng, buf, n)
+        model = _model_push(*model, jax.device_get(chunk), capacity)
+        buf = push_jit(buf, chunk)
+    _assert_same(buf, model)
+
+
+PUSH_CASES = [
+    pytest.param(_case_single, dict(capacity=10, ptr=2, n=4), id="no-wrap"),
+    pytest.param(_case_single, dict(capacity=10, ptr=6, n=4), id="ends-at-the-end"),
+    pytest.param(_case_single, dict(capacity=10, ptr=8, n=5), id="wrap-mid-chunk"),
+    pytest.param(_case_single, dict(capacity=10, ptr=9, n=4), id="ptr-at-last-row"),
+    pytest.param(_case_single, dict(capacity=10, ptr=9, n=1), id="one-row-last"),
+    pytest.param(_case_single, dict(capacity=10, ptr=3, n=1), id="one-row"),
+    pytest.param(_case_single, dict(capacity=10, ptr=3, n=10), id="whole-ring-at-3"),
+    pytest.param(_case_single, dict(capacity=10, ptr=0, n=10), id="whole-ring-at-0"),
+    pytest.param(_case_single, dict(capacity=10, ptr=7, n=8), id="windows-overlap"),
+    pytest.param(_case_single, dict(capacity=10, ptr=1, n=8), id="overlap-no-wrap"),
+    pytest.param(
+        _case_single, dict(capacity=10, ptr=8, n=5, visual=True),
+        id="uint8-frames-wrap",
+    ),
+    pytest.param(
+        _case_single, dict(capacity=10, ptr=2, n=4, visual=True),
+        id="uint8-frames",
+    ),
+    pytest.param(
+        _case_vmapped, dict(capacity=10, ptrs=(0, 3, 8, 9), n=4),
+        id="vmap-own-ptr-one-wraps",
+    ),
+    pytest.param(
+        _case_vmapped, dict(capacity=6, ptrs=(5, 0, 2), n=6),
+        id="vmap-whole-ring",
+    ),
+    pytest.param(
+        _case_nested, dict(capacity=10, ptrs=((1, 8), (9, 5)), n=4),
+        id="vmap-of-vmap",
+    ),
+    pytest.param(
+        _case_two_donated, dict(capacity=10, ptr=3, n=4), id="two-donated"
+    ),
+]
+
+
+@pytest.mark.parametrize("case, kwargs", PUSH_CASES)
+def test_push_matches_numpy_model(case, kwargs):
+    case(np.random.default_rng(25), **kwargs)
+
+
+def test_push_rejects_chunk_larger_than_ring():
+    buf = init_replay_buffer(4, jax.ShapeDtypeStruct((OBS_DIM,), jnp.float32), ACT_DIM)
+    with pytest.raises(ValueError, match="exceeds buffer capacity"):
+        push(buf, _chunk(0, 5))

@@ -14,6 +14,7 @@ be written to the cache but not read back without a chip.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,12 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from torch_actor_critic_tpu.buffer.replay import (
+    init_replay_buffer,
+    init_visual_replay_buffer,
+    nbytes,
+    push,
+)
 from torch_actor_critic_tpu.core.types import Batch, BufferState
 from torch_actor_critic_tpu.models import Actor, DoubleCritic
 from torch_actor_critic_tpu.ops import pixels
@@ -102,6 +109,83 @@ def _pixel(devices, batch, dtype, shift, frame_stack=1):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _on(device, tree):
+    return jax.tree_util.tree_map(
+        lambda x: _shape(x.shape, x.dtype, device), tree
+    )
+
+
+def _chunk_of(ring, rows):
+    """``rows`` rows a member of the shapes a member-stacked ring holds."""
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(
+            (x.shape[0], rows) + x.shape[2:], x.dtype
+        ),
+        ring.data,
+    )
+
+
+def _ring_scatters(hlo_text, rows):
+    """The ``scatter`` instructions, fused ones too, whose result has a
+    dimension of at least the ring's row count."""
+    found = []
+    for shape in re.findall(r"= (\S+?\[[\d,]*\])\S* scatter\(", hlo_text):
+        dims = [int(d) for d in re.findall(r"\d+", shape.split("[", 1)[1])]
+        if any(d >= rows for d in dims):
+            found.append(shape)
+    return found
+
+
+def _push_in_place(devices, make_ring, members, rows):
+    """``jit(vmap(push), donate_argnums=0)`` alone passes over no ring
+    leaf: a relayout of one reads and writes 200% of its bytes (the
+    scatter this replaced: 500-700% accessed, 94-143% temp, PERF.md PR
+    25), the contiguous write touches the two windows' tiles, whatever
+    the ring's size."""
+    ring = jax.eval_shape(
+        lambda: jax.vmap(lambda _: make_ring())(jnp.arange(members))
+    )
+    compiled = jax.jit(jax.vmap(push), donate_argnums=0).lower(
+        *_on(devices[0], (ring, _chunk_of(ring, rows)))
+    ).compile()
+    ring_bytes = nbytes(ring.data)
+    cost = compiled.cost_analysis()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= ring_bytes, "updated in place"
+    assert mem.temp_size_in_bytes < 0.01 * ring_bytes
+    assert cost["bytes accessed"] < 0.05 * ring_bytes
+    text = compiled.as_text()
+    assert " scatter(" not in text and " gather(" not in text
+
+
+def _population_programs(devices, members):
+    """The population burst and the fused population epoch at the
+    reference configuration: neither holds a scatter over a ring."""
+    from torch_actor_critic_tpu.envs.ondevice import get_on_device_env
+    from torch_actor_critic_tpu.parallel.population import PopulationLearner
+    from torch_actor_critic_tpu.sac.ondevice import (
+        PopulationOnDeviceLoop,
+        _wrap_and_build,
+    )
+
+    cfg = SACConfig()
+    env_cls, sac = _wrap_and_build(get_on_device_env("cheetah-run-jax"), cfg)
+    loop = PopulationOnDeviceLoop(sac, env_cls, n_members=members, n_envs=16)
+    state, ring, env_states, act_keys, _ = jax.eval_shape(
+        lambda: loop.init(jax.random.key(0), cfg.buffer_size)
+    )
+    chunk = _chunk_of(ring, cfg.update_every)
+    burst = PopulationLearner(sac, members)._build_burst(cfg.update_every)
+    epoch = loop._build_epoch(2 * cfg.update_every, cfg.update_every, False)
+    for program, args in (
+        (burst, (state, ring, chunk)),
+        (epoch, (state, ring, env_states, act_keys)),
+    ):
+        compiled = program.lower(*_on(devices[0], args)).compile()
+        assert compiled.memory_analysis().alias_size_in_bytes >= nbytes(ring)
+        assert _ring_scatters(compiled.as_text(), cfg.buffer_size) == []
+
+
 def _reference_burst(devices, dp):
     """The main path's program at the reference configuration: 50
     update steps, batch 64, (256, 256), a 1,000,000-slot ring, state
@@ -150,6 +234,7 @@ def _reference_burst(devices, dp):
     assert mem.alias_size_in_bytes >= ring_bytes // dp
     text = compiled.as_text()
     assert ("all-reduce" in text) == (dp > 1), "pmean over dp"
+    assert _ring_scatters(text, cfg.buffer_size // dp) == []
 
 
 CASES = [
@@ -172,6 +257,29 @@ CASES = [
     pytest.param(_pixel, (32, jnp.bfloat16, True, 3), id="pixel-stack3"),
     pytest.param(_reference_burst, (1,), id="update-burst"),
     pytest.param(_reference_burst, (4,), id="dp4-burst"),
+    pytest.param(
+        _push_in_place,
+        (
+            functools.partial(
+                init_replay_buffer, 1_000_000,
+                jax.ShapeDtypeStruct((OBS_DIM,), jnp.float32), ACT_DIM,
+            ),
+            32, 800,
+        ),
+        id="push-pop32-1M-rows",
+    ),
+    pytest.param(
+        _push_in_place,
+        (
+            functools.partial(
+                init_visual_replay_buffer, 200_000, 168,
+                WALL_RUNNER_RING[1:], 56,
+            ),
+            1, 50,
+        ),
+        id="push-frames-200k-rows",
+    ),
+    pytest.param(_population_programs, (8,), id="population-burst-and-epoch"),
 ]
 
 

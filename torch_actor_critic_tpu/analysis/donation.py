@@ -88,7 +88,7 @@ DONATING_ENTRY_POINTS: t.Dict[str, DonationRow] = {
         "update_burst", (0, 1),
     ),
     "train/population_burst": DonationRow(
-        "parallel/population.py", "PopulationLearner.update_burst",
+        "parallel/population.py", "PopulationLearner._build_burst",
         "update_burst", (0, 1),
     ),
     "train/ondevice_epoch": DonationRow(

@@ -84,7 +84,7 @@ ENTRY_POINTS: t.Dict[str, t.Tuple[str, str]] = {
     # The population burst builds its jit inline in the dispatch
     # method (no separate _build_*): the method IS the builder.
     "train/population_burst": (
-        "parallel/population.py", "PopulationLearner.update_burst",
+        "parallel/population.py", "PopulationLearner._build_burst",
     ),
     "replay/prefetch_push": (
         "replay/prefetch.py", "RefillPrefetcher._build_push",

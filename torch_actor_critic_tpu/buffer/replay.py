@@ -21,8 +21,14 @@ re-designed for TPU:
 - **Chunked stores**: the host env loop accumulates ``update_every``
   transitions and pushes them in one call (one dispatch per burst
   instead of the reference's per-step ``store``,
-  ref ``sac/algorithm.py:249``). Wraparound handled with modular
-  scatter indices — compiler-friendly, no data-dependent shapes.
+  ref ``sac/algorithm.py:249``). A chunk is written as contiguous,
+  in-place updates of the donated ring (``dynamic_update_slice``): one
+  window of ``n`` rows at the write pointer and one at row 0, so a
+  chunk that wraps needs no other path and no shape depends on data.
+  Not a scatter over ``(ptr + arange(n)) % capacity``: for one, XLA:TPU
+  relays every whole ring leaf into a layout of the scatter's own and
+  back, once a window, which was 80% of the visual burst's device time
+  and 95% of the population programs' (PERF.md, PR 24 and PR 25).
 - **Sampling is uniform with replacement** (``randint`` + ``take``).
   The reference samples *without* replacement via ``random.sample``
   (ref ``replay_buffer.py:46``); at 1e6-slot buffers and batch 64 the
@@ -43,6 +49,8 @@ import typing as t
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+from jax.custom_batching import custom_vmap
 
 from torch_actor_critic_tpu.buffer.striped import (
     StripedBufferState,
@@ -187,15 +195,44 @@ def init_visual_replay_buffer(
     return init_replay_buffer(capacity, obs_spec, act_dim)
 
 
+@custom_vmap
+def _write_rows(ring: jax.Array, rows: jax.Array, at: jax.Array) -> jax.Array:
+    """``ring`` with ``rows`` written at row ``at``: one contiguous,
+    in-place ``dynamic-update-slice`` of a donated ring."""
+    return lax.dynamic_update_slice_in_dim(ring, rows, at, 0)
+
+
+@_write_rows.def_vmap
+def _write_rows_of_members(axis_size, in_batched, ring, rows, at):
+    """Under ``vmap`` (members, ``dp``) every member writes at its own
+    ``at``. jax's own rule turns that into a scatter, and so would a
+    batched ``dynamic_slice`` into a gather; for either, XLA:TPU picks
+    a layout by the window's size and relays the whole ring into it
+    (PERF.md, PR 25). One update a member, at a constant member index,
+    stays in place in the layout the ring rests in."""
+    ring, rows, at = (
+        x if batched else jnp.broadcast_to(x, (axis_size,) + x.shape)
+        for x, batched in zip((ring, rows, at), in_batched)
+    )
+    zeros = (0,) * (ring.ndim - 2)
+    for member in range(axis_size):
+        ring = lax.dynamic_update_slice(
+            ring, rows[member:member + 1], (member, at[member]) + zeros
+        )
+    return ring, True
+
+
 @jax.named_scope(scopes.PUSH)
 def push(state: BufferState, chunk: Batch) -> BufferState:
     """Append a chunk of ``n`` transitions, overwriting oldest on wrap.
 
     Equivalent of ``n`` reference ``store`` calls
-    (ref ``replay_buffer.py:29-43``): writes at
-    ``(ptr + arange(n)) % capacity``, then advances ``ptr`` and
-    saturates ``size`` at capacity. ``n`` must be static (it is: the
-    trainer always pushes ``update_every``-sized chunks).
+    (ref ``replay_buffer.py:29-43``): row ``j`` lands at
+    ``(ptr + j) % capacity``, then ``ptr`` advances and ``size``
+    saturates at capacity. ``n`` must be static (it is: the trainer
+    always pushes ``update_every``-sized chunks). The rows are written
+    in place, contiguously, wrap-around included; no scatter, so the
+    ring keeps the layout it rests in (module docstring).
 
     A striped (per-task) ring dispatches to
     :func:`~torch_actor_critic_tpu.buffer.striped.push_striped` — the
@@ -207,16 +244,37 @@ def push(state: BufferState, chunk: Batch) -> BufferState:
     capacity = state.capacity
     n = jax.tree_util.tree_leaves(chunk)[0].shape[0]
     if n > capacity:
-        # Duplicate scatter indices would overwrite in unspecified order.
+        # Two rows of the chunk would land on one row of the ring.
         raise ValueError(
             f"push: chunk of {n} transitions exceeds buffer capacity "
             f"{capacity}; use a larger buffer or smaller chunks."
         )
-    idx = (state.ptr + jnp.arange(n)) % capacity
+    # Rows ``[ptr, capacity)`` take the chunk's head and rows
+    # ``[0, wrapped)`` its tail: two windows of ``n`` rows, one at
+    # ``start`` and one at 0, each merged with the rows it holds and
+    # written back, so a window that receives nothing rewrites what it
+    # read. The first keeps rows only when the chunk wraps, and then it
+    # is the ring's last ``n`` rows: those are read at a constant
+    # offset (a read at a traced one is a gather under ``vmap``). The
+    # second is read after the first is written: they may overlap.
+    start = jnp.minimum(state.ptr, capacity - n)
+    wrapped = state.ptr - start
+    head = jnp.arange(n) >= wrapped
 
-    data = jax.tree_util.tree_map(
-        lambda ring, new: ring.at[idx].set(new), state.data, chunk
-    )
+    def write(ring, new):
+        new = new.astype(ring.dtype)
+        # seam[wrapped + j] = new[j]: where ``head``, seam[:n] is the
+        # window at ``start``; elsewhere seam[n:] is the window at 0.
+        seam = _write_rows(jnp.concatenate([new, new]), new, wrapped)
+        mine = head.reshape((n,) + (1,) * (ring.ndim - 1))
+        kept = lax.slice_in_dim(ring, capacity - n, capacity)
+        ring = _write_rows(ring, jnp.where(mine, seam[:n], kept), start)
+        kept = lax.slice_in_dim(ring, 0, n)
+        return lax.dynamic_update_slice_in_dim(
+            ring, jnp.where(mine, kept, seam[n:]), 0, 0
+        )
+
+    data = jax.tree_util.tree_map(write, state.data, chunk)
     return BufferState(
         data=data,
         ptr=(state.ptr + n) % capacity,
@@ -229,9 +287,13 @@ def sample(state: BufferState, key: jax.Array, batch_size: int) -> Batch:
     """Draw a uniform batch over the valid region ``[0, size)``.
 
     With replacement (deliberate deviation from ref
-    ``replay_buffer.py:46``, see module docstring). Gathers are plain
-    ``jnp.take`` so XLA lowers them to efficient dynamic-gathers; a
-    Pallas gather path can slot in here if profiles demand it.
+    ``replay_buffer.py:46``, see module docstring). The gathers are
+    plain ``jnp.take``. On the v5e the gather itself is cheap (29 us
+    a step in the visual burst), but it does not read a ring leaf in
+    the layout the leaf rests in (rows minor-most): the compiler makes
+    one copy of each gathered leaf a window into the gather's layout,
+    30 ms for a ``u8[200000, 64, 64, 3]`` leaf, and that copy is what
+    is left of the ring's traffic after PR 25 (PERF.md sections 5, 7).
 
     An empty buffer raises eagerly; under ``jit`` the size is traced and
     cannot be checked, so the index range is clamped to ``[0, 1)`` —

@@ -170,6 +170,17 @@ class PopulationLearner:
 
     # ----------------------------------------------------------- the burst
 
+    def _build_burst(self, num_updates: int):
+        """The jitted population burst: one member's burst, vmapped
+        over the member axis, state and rings donated."""
+
+        def one_member(st, buf, ch):
+            return self.learner.update_burst(
+                st, buf, ch, num_updates, axis_name=None
+            )
+
+        return jax.jit(jax.vmap(one_member), donate_argnums=(0, 1))
+
     def update_burst(
         self,
         state: TrainState,
@@ -188,15 +199,7 @@ class PopulationLearner:
         anomaly (docs/OBSERVABILITY.md)."""
         fn = self._bursts.get(num_updates)
         if fn is None:
-
-            def one_member(st, buf, ch):
-                return self.learner.update_burst(
-                    st, buf, ch, num_updates, axis_name=None
-                )
-
-            fn = self._bursts[num_updates] = jax.jit(
-                jax.vmap(one_member), donate_argnums=(0, 1)
-            )
+            fn = self._bursts[num_updates] = self._build_burst(num_updates)
             self.burst_abstract = scopes.abstract_of(state, buffer, chunk)
         from torch_actor_critic_tpu.diagnostics.watchdog import get_watchdog
 
