@@ -731,10 +731,14 @@ def test_fleet_trainer_trains_through_actor_death():
         # bounds its accepts (sheds skip seqs, so seq+1 >= accepted;
         # a retire resets seq to -1, which is why retired slots are
         # excluded — their audit is the purge count).
-        per_actor = trainer.transport.snapshot()["actors"]
+        # (Both sides from ONE snapshot: the surviving and the respawned
+        # actors keep feeding while the loop above waits for the respawn,
+        # so a total read before it is stale by then on a loaded host.)
+        snap = trainer.transport.snapshot()
+        per_actor = snap["actors"]
         assert sum(
             a["accepted_total"] for a in per_actor.values()
-        ) == tsnap["accepted_total"]
+        ) == snap["accepted_total"]
         for aid, a in per_actor.items():
             if st["actors"][int(aid)]["restarts"] == 0:
                 assert a["accepted_total"] <= a["seq"] + 1

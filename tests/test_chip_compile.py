@@ -136,6 +136,77 @@ def _ring_scatters(hlo_text, rows):
     return found
 
 
+def _as_large_as(hlo_text, elements, op):
+    """``op`` instructions (fused ones too) whose result has at least
+    ``elements`` elements."""
+    found = []
+    for shape in re.findall(rf"= (\S+?\[[\d,]*\])\S* {op}\(", hlo_text):
+        dims = [int(d) for d in re.findall(r"\d+", shape.split("[", 1)[1])]
+        if int(np.prod(dims)) >= elements:
+            found.append(shape)
+    return found
+
+
+def _flash_grouped(devices, block_length):
+    """The three flash kernels at the SDAR trunk's shapes: 32 query heads
+    over 4 shared key/value heads of 128 (read through the index maps),
+    the block-causal mask, float32 tiles with bfloat16 products."""
+    q = _shape((8, 32, 1024, 128), jnp.float32, devices[0])
+    kv = _shape((8, 4, 1024, 128), jnp.float32, devices[0])
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, True, None, None, False, 128, block_length, True)
+
+    grads = jax.grad(lambda q, k, v: fwd(q, k, v).sum(), (0, 1, 2))
+    for fn, n_kernels in ((fwd, 1), (grads, 3)):
+        compiled = jax.jit(fn).lower(q, kv, kv).compile()
+        assert compiled.as_text().count("tpu_custom_call") == n_kernels
+    assert [x.shape for x in jax.eval_shape(grads, q, kv, kv)] == [
+        q.shape, kv.shape, kv.shape
+    ]
+
+
+def _trunk_burst(devices):
+    """The shared-trunk burst over the cell's ring of histories (8,192 rows
+    of 1024 x 17, the trunk itself at a cut width so that this compiles in
+    seconds): the grouped products and the kernels lower under the burst's
+    ``vmap`` over its device axis, and no gather or scatter of the ring's
+    size is among its instructions."""
+    from torch_actor_critic_tpu.sac.trainer import build_models, make_learner
+
+    rows, history = 8192, 1024
+    cfg = SACConfig(
+        trunk_block="sdar_moe", history_len=history, batch_size=8, update_every=10,
+        buffer_size=rows, burst_unroll=1, trunk_hidden=256, trunk_q_heads=8,
+        trunk_kv_heads=2, trunk_head_dim=128, trunk_layers=1, trunk_experts=16,
+        trunk_experts_held=(0, 4), trunk_experts_per_tok=4, trunk_expert_width=128,
+    )
+    spec = jax.ShapeDtypeStruct((history, OBS_DIM), jnp.float32)
+    env = type("Env", (), dict(act_dim=ACT_DIM, act_limit=1.0, obs_spec=spec))
+    sac = make_learner(cfg, *build_models(cfg, env), ACT_DIM)
+    learner = DataParallelSAC(sac, make_mesh(dp=1, devices=devices[:1]))
+    state = jax.eval_shape(sac.init_state, jax.random.key(0), jnp.zeros(spec.shape))
+
+    def ring_of(n):
+        one = jax.eval_shape(lambda: init_replay_buffer(n, spec, ACT_DIM).data)
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct((1,) + x.shape, x.dtype), one
+        )
+
+    index = jax.ShapeDtypeStruct((1,), jnp.int32)
+    ring = BufferState(data=ring_of(rows), ptr=index, size=index)
+    chunk = ring_of(cfg.update_every)
+    text = learner._build_burst(cfg.update_every, state, ring, chunk).lower(
+        state, ring, chunk
+    ).compile().as_text()
+    assert "ragged-dot" in text and text.count("tpu_custom_call") >= 3
+    # Neither a scatter nor a gather whose result is as large as a ring leaf:
+    # the sample's gather is batch-sized, the expert layer's dispatch gather
+    # and combine scatter-add are 2 * tokens rows of the trunk's width.
+    assert _as_large_as(text, rows * history * OBS_DIM, "scatter") == []
+    assert _as_large_as(text, rows * history * OBS_DIM, "gather") == []
+
+
 def _push_in_place(devices, make_ring, members, rows):
     """``jit(vmap(push), donate_argnums=0)`` alone passes over no ring
     leaf: a relayout of one reads and writes 200% of its bytes (the
@@ -280,6 +351,9 @@ CASES = [
         id="push-frames-200k-rows",
     ),
     pytest.param(_population_programs, (8,), id="population-burst-and-epoch"),
+    pytest.param(_flash_grouped, (1,), id="flash-grouped-causal"),
+    pytest.param(_flash_grouped, (4,), id="flash-grouped-block4"),
+    pytest.param(_trunk_burst, (), id="trunk-burst-ring"),
 ]
 
 

@@ -101,6 +101,8 @@ def reduction_for(key: str) -> str:
         return "min"
     if key.endswith("_sum") or key.endswith("_hist"):
         return "sum"
+    if key.endswith("_first"):
+        return "first"
     return "mean"
 
 
@@ -120,6 +122,8 @@ def reduce_burst_metrics(metrics: t.Dict[str, jax.Array]) -> t.Dict[str, jax.Arr
             out[k] = jnp.max(v, axis=0)
         elif r == "min":
             out[k] = jnp.min(v, axis=0)
+        elif r == "first":  # the burst's first update alone
+            out[k] = v[0]
         else:
             out[k] = jnp.mean(v, axis=0)
     return out

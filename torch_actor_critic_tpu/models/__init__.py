@@ -14,6 +14,10 @@ from torch_actor_critic_tpu.models.sequence import (  # noqa: F401
     SequenceCritic,
     SequenceDoubleCritic,
     SequenceTrunk,
+    SharedTrunkActor,
+    SharedTrunkCritic,
+    TrunkSpec,
+    policy_params,
 )
 from torch_actor_critic_tpu.models.multiagent import (  # noqa: F401
     MultiAgentActor,

@@ -19,15 +19,19 @@ module under ``shard_map`` with ring attention over an ``sp`` mesh axis.
 
 from __future__ import annotations
 
+import dataclasses
+import math
 import typing as t
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from torch_actor_critic_tpu.models.mlp import Dense
+from torch_actor_critic_tpu.models.mlp import Dense, torch_linear_kernel_init
+from torch_actor_critic_tpu.ops import moe
 from torch_actor_critic_tpu.ops.attention import attention as sdpa
 from torch_actor_critic_tpu.ops.distributions import squashed_gaussian_sample
+from torch_actor_critic_tpu.telemetry import scopes
 
 # attention_fn(q, k, v, causal) -> out, all (batch, heads, seq, head_dim)
 AttentionFn = t.Callable[..., jax.Array]
@@ -44,8 +48,10 @@ def _auto_batch(obs_seq: jax.Array, *rest: jax.Array):
     return (unbatched, obs_seq, *rest)
 
 
-def default_attention(q, k, v, causal=True):
-    return sdpa(q, k, v, causal=causal)
+def default_attention(q, k, v, causal=True, **mask):
+    """``mask``: ``block_length`` / ``bf16_dots`` of :func:`ops.attention.attention`
+    (the SDAR block passes them; the transformer block passes none)."""
+    return sdpa(q, k, v, causal=causal, **mask)
 
 
 def _sp_pos_offset(obs_seq: jax.Array, sp_axis: str | None):
@@ -72,11 +78,11 @@ def _sp_last_token(h: jax.Array, sp_axis: str | None, sp_size: int):
     return jax.lax.psum(masked, sp_axis)
 
 
-def xla_attention(q, k, v, causal=True):
+def xla_attention(q, k, v, causal=True, **mask):
     """Backend-portable attention (no Pallas): for modules that must
     compile on the host CPU while TPU is the default backend, e.g. the
     trainer's host actor mirror."""
-    return sdpa(q, k, v, causal=causal, impl="xla")
+    return sdpa(q, k, v, causal=causal, impl="xla", **mask)
 
 
 class MultiHeadAttention(nn.Module):
@@ -133,13 +139,181 @@ class TransformerBlock(nn.Module):
         return x + h
 
 
+@dataclasses.dataclass(frozen=True)
+class TrunkSpec:
+    """The decoder layer of SDAR-30B-A3B (``sdar_moe``) as a history trunk:
+    its published widths, the experts this chip holds and the block length
+    of its mask (``SACConfig.trunk_*``)."""
+
+    hidden: int = 2048
+    q_heads: int = 32
+    kv_heads: int = 4
+    head_dim: int = 128
+    layers: int = 4
+    experts: int = 128
+    experts_per_tok: int = 8
+    expert_width: int = 768
+    experts_held: t.Tuple[int, int] = (0, 16)
+    block_length: int = 4
+    rope_theta: float = 1e6
+    rms_eps: float = 1e-6
+    remat: int = 0  # the first n blocks are recomputed in the backward pass
+    # float32 operands of the kernels' products (flash attention, grouped
+    # expert products) rounded to bfloat16: the TPU's default precision.
+    bf16_dots: bool = True
+
+    @classmethod
+    def from_config(cls, config) -> "TrunkSpec":
+        names = [f.name for f in dataclasses.fields(cls)]
+        return cls(**{n: getattr(config, "trunk_" + n) for n in names})
+
+
+class RMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * weight`` over the last axis, the
+    statistics in float32."""
+
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        weight = self.param("weight", nn.initializers.ones, (x.shape[-1],))
+        xf = x.astype(jnp.float32)
+        var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        return (xf * jax.lax.rsqrt(var + self.eps)).astype(x.dtype) * weight
+
+
+def rotary(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
+    """Rotate-half rotary positions on ``x`` ``(B, T, heads, d)`` at the
+    global positions ``pos`` ``(T,)``."""
+    d = x.shape[-1]
+    inv_freq = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angles = pos.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    angles = jnp.concatenate([angles, angles], axis=-1)[None, :, None, :]
+    x1, x2 = x[..., : d // 2], x[..., d // 2:]
+    rotated = jnp.concatenate([-x2, x1], axis=-1)
+    return x * jnp.cos(angles) + rotated * jnp.sin(angles)
+
+
+def _linear(features: int, dtype, name: str) -> nn.Dense:
+    """A projection with no bias (the published layer has none anywhere)."""
+    return nn.Dense(
+        features, use_bias=False, kernel_init=torch_linear_kernel_init,
+        dtype=dtype, param_dtype=jnp.float32, name=name,
+    )
+
+
+def _expert_kernel_init(key, shape, dtype=jnp.float32):
+    """Uniform ``+-1/sqrt(fan_in)`` for ``(experts, fan_in, fan_out)``
+    kernels: the leading expert axis is no fan-in."""
+    bound = 1.0 / math.sqrt(shape[-2])
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+class GroupedQueryAttention(nn.Module):
+    """``W_o Attn(rope(norm(W_q u)), rope(norm(W_k u)), W_v u)`` with
+    ``q_heads`` query heads reading ``kv_heads`` shared key/value heads
+    under the block-causal mask."""
+
+    spec: TrunkSpec
+    attention_fn: AttentionFn = default_attention
+    dtype: t.Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, u: jax.Array, pos: jax.Array) -> jax.Array:
+        sp, dtype = self.spec, self.dtype
+        b, s, _ = u.shape
+        d = sp.head_dim
+        q = _linear(sp.q_heads * d, dtype, "q_proj")(u).reshape(b, s, sp.q_heads, d)
+        k = _linear(sp.kv_heads * d, dtype, "k_proj")(u).reshape(b, s, sp.kv_heads, d)
+        v = _linear(sp.kv_heads * d, dtype, "v_proj")(u).reshape(b, s, sp.kv_heads, d)
+        q = rotary(RMSNorm(sp.rms_eps, name="q_norm")(q), pos, sp.rope_theta)
+        k = rotary(RMSNorm(sp.rms_eps, name="k_norm")(k), pos, sp.rope_theta)
+        # (batch, heads, seq, d) for the kernels. Reading (batch, seq, heads, d)
+        # in place, a head as a column block of the kernels' index maps, was
+        # tried and measured slower on the v5e (PERF.md, PR 26).
+        heads_first = lambda y: y.transpose(0, 2, 1, 3)  # noqa: E731
+        out = self.attention_fn(
+            heads_first(q), heads_first(k), heads_first(v), causal=True,
+            block_length=sp.block_length,
+            # float32 tiles, one bfloat16 pass on the MXU: the TPU's default
+            # precision for a float32 product, inside the kernels too.
+            bf16_dots=sp.bf16_dots and dtype == jnp.float32,
+        )
+        out = out.transpose(0, 2, 1, 3).reshape(b, s, sp.q_heads * d)
+        return _linear(sp.hidden, dtype, "o_proj")(out)
+
+
+class SparseMoE(nn.Module):
+    """The sparse-expert feed-forward, this chip's share (:mod:`ops.moe`).
+
+    Sows ``sizes`` (tokens of every held expert) and ``choices`` (every
+    token's chosen experts, of all) into the ``moe_stats`` collection for
+    whoever applies the trunk with that collection mutable."""
+
+    spec: TrunkSpec
+
+    @nn.compact
+    def __call__(self, u: jax.Array) -> jax.Array:
+        sp = self.spec
+        hidden = u.shape[-1]
+        lo, hi = sp.experts_held
+        x = u.reshape(-1, hidden)
+        w_router = self.param(
+            "router", torch_linear_kernel_init, (hidden, sp.experts)
+        )
+        w_gate = self.param(
+            "w_gate", _expert_kernel_init, (hi - lo, hidden, sp.expert_width)
+        )
+        w_up = self.param(
+            "w_up", _expert_kernel_init, (hi - lo, hidden, sp.expert_width)
+        )
+        w_down = self.param(
+            "w_down", _expert_kernel_init, (hi - lo, sp.expert_width, hidden)
+        )
+        with jax.named_scope(scopes.TRUNK_MOE_ROUTE):
+            top_e, top_w = moe.route(x, w_router, sp.experts_per_tok)
+        with jax.named_scope(scopes.TRUNK_MOE_EXPERTS):
+            y, plan = moe.expert_ffn(
+                x, w_gate, w_up, w_down, top_e, top_w, (lo, hi),
+                num_experts=sp.experts, bf16_dots=sp.bf16_dots,
+            )
+        self.sow("moe_stats", "sizes", plan.sizes)
+        self.sow("moe_stats", "choices", top_e)
+        return y.reshape(u.shape).astype(u.dtype)
+
+
+class SDARBlock(nn.Module):
+    """``h = x + Attn(RMSNorm(x))``, ``y = h + MoE(RMSNorm(h))``."""
+
+    spec: TrunkSpec
+    attention_fn: AttentionFn = default_attention
+    dtype: t.Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array, pos: jax.Array) -> jax.Array:
+        sp = self.spec
+        with jax.named_scope(scopes.TRUNK_ATTENTION):
+            h = x + GroupedQueryAttention(
+                sp, self.attention_fn, self.dtype, name="attention"
+            )(RMSNorm(sp.rms_eps, name="input_norm")(x), pos)
+        with jax.named_scope(scopes.TRUNK_MOE_ROUTE):
+            u = RMSNorm(sp.rms_eps, name="post_attention_norm")(h)
+        return h + SparseMoE(sp, name="moe")(u)
+
+
 class SequenceTrunk(nn.Module):
-    """Embed + positional encode + N causal transformer blocks.
+    """Embed + N blocks over a history, the block taken from ``spec``.
+
+    ``spec=None``: the small pre-LN transformer (learned positions, N causal
+    :class:`TransformerBlock`, LayerNorm), sized by ``d_model`` /
+    ``num_heads`` / ``num_layers``. A :class:`TrunkSpec`: the SDAR decoder
+    stack (``Dense(obs_dim -> hidden)`` where the token embedding was,
+    rotary positions inside the blocks, one RMSNorm after the last).
 
     ``pos_offset`` is the global index of this chunk's first timestep —
     0 on a single device; ``axis_index('sp') * T_local`` under context
-    parallelism, so positional embeddings stay globally consistent when
-    the sequence is sharded.
+    parallelism, so positions stay globally consistent when the sequence
+    is sharded.
     """
 
     d_model: int = 128
@@ -148,9 +322,12 @@ class SequenceTrunk(nn.Module):
     max_len: int = 512
     attention_fn: AttentionFn = default_attention
     dtype: t.Any = jnp.float32
+    spec: TrunkSpec | None = None
 
     @nn.compact
     def __call__(self, obs_seq: jax.Array, pos_offset: jax.Array | int = 0):
+        if self.spec is not None:
+            return self._sdar(obs_seq, pos_offset)
         dtype = self.dtype
         b, s, _ = obs_seq.shape
         # jnp.take clamps out-of-bounds rows silently — aliased positions
@@ -175,6 +352,19 @@ class SequenceTrunk(nn.Module):
                 self.num_heads, attention_fn=self.attention_fn, dtype=dtype
             )(x)
         return nn.LayerNorm()(x)
+
+    def _sdar(self, obs_seq: jax.Array, pos_offset: jax.Array | int):
+        sp = self.spec
+        with jax.named_scope(scopes.TRUNK_EMBED):
+            x = _linear(sp.hidden, self.dtype, "embed")(obs_seq)
+        pos = pos_offset + jnp.arange(obs_seq.shape[1])
+        for i in range(sp.layers):
+            # Recomputing a block saves its residuals (about 1 GB at the
+            # published widths and 8,192 tokens) for a fifth more of its work.
+            block = nn.remat(SDARBlock) if i < sp.remat else SDARBlock
+            x = block(sp, self.attention_fn, self.dtype, name=f"layer_{i}")(x, pos)
+        with jax.named_scope(scopes.TRUNK_EMBED):
+            return RMSNorm(sp.rms_eps, name="final_norm")(x)
 
 
 class SequenceActor(nn.Module):
@@ -311,3 +501,124 @@ class SequenceDoubleCritic(nn.Module):
             dtype=self.dtype,
             name="ensemble",
         )(obs_seq, action)
+
+
+# --------------------------------------------------------------------------
+# One trunk shared by actor and critics (SACConfig.trunk_block="sdar_moe")
+# --------------------------------------------------------------------------
+
+TRUNK = "trunk"  # the trunk's subtree, under the same name in both modules
+
+
+def policy_params(actor_params: t.Any, critic_params: t.Any) -> t.Any:
+    """The parameters the policy acts with, for every site that acts (the
+    trainer's device and host actors, the serving registry, a checkpoint's
+    actor restore). Separate networks: ``actor_params`` as they are. A shared
+    trunk lives in the critic's tree (the critic loss trains it); the policy
+    is that trunk under the actor's heads."""
+    try:
+        trunk = critic_params["params"][TRUNK]
+    except (KeyError, TypeError, IndexError):
+        return actor_params
+    return {"params": {**actor_params["params"], TRUNK: trunk}}
+
+
+class QHead(nn.Module):
+    """``Q(h_T, a)``: the last step's features with the action, a 2-layer MLP."""
+
+    hidden: int = 256
+    dtype: t.Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h: jax.Array, action: jax.Array) -> jax.Array:
+        x = jnp.concatenate([h, action.astype(h.dtype)], axis=-1)
+        x = nn.relu(Dense(self.hidden, dtype=self.dtype)(x))
+        x = Dense(1, dtype=self.dtype)(x)
+        return jnp.squeeze(x.astype(jnp.float32), axis=-1)
+
+
+class SharedTrunkCritic(nn.Module):
+    """The shared history trunk and ``num_qs`` Q heads on its last step.
+
+    ``features`` and ``q`` are applied apart by the shared-trunk losses
+    (one trunk pass feeds the Q heads and the policy head); ``__call__`` is
+    the plain critic contract ``(obs_seq, action) -> (num_qs, batch)``."""
+
+    spec: TrunkSpec
+    hidden: int = 256
+    num_qs: int = 2
+    attention_fn: AttentionFn = default_attention
+    dtype: t.Any = jnp.float32
+    shared_trunk: t.ClassVar[bool] = True
+
+    def setup(self):
+        self.trunk = SequenceTrunk(
+            attention_fn=self.attention_fn, dtype=self.dtype, spec=self.spec
+        )
+        self.ensemble = nn.vmap(
+            QHead,
+            variable_axes={"params": 0},
+            split_rngs={"params": True},
+            in_axes=None,
+            out_axes=0,
+            axis_size=self.num_qs,
+        )(self.hidden, self.dtype)
+
+    def features(self, obs_seq: jax.Array) -> jax.Array:
+        return self.trunk(obs_seq)[:, -1]
+
+    def q(self, h: jax.Array, action: jax.Array) -> jax.Array:
+        return self.ensemble(h, action)
+
+    def __call__(self, obs_seq: jax.Array, action: jax.Array) -> jax.Array:
+        unbatched, obs_seq, action = _auto_batch(obs_seq, action)
+        q = self.q(self.features(obs_seq), action)
+        return jnp.squeeze(q, 1) if unbatched else q
+
+
+class SharedTrunkActor(nn.Module):
+    """The policy over the shared trunk: trunk, then the squashed-Gaussian
+    head on the last step. Its trained parameters are the heads alone
+    (``TrainState.actor_params``); acting takes :func:`policy_params`."""
+
+    act_dim: int
+    spec: TrunkSpec
+    act_limit: float = 1.0
+    attention_fn: AttentionFn = default_attention
+    dtype: t.Any = jnp.float32
+    shared_trunk: t.ClassVar[bool] = True
+
+    def setup(self):
+        self.trunk = SequenceTrunk(
+            attention_fn=self.attention_fn, dtype=self.dtype, spec=self.spec
+        )
+        self.mu = Dense(self.act_dim, dtype=self.dtype)
+        self.log_std = Dense(self.act_dim, dtype=self.dtype)
+
+    def head(
+        self,
+        h: jax.Array,
+        key: jax.Array | None = None,
+        deterministic: bool = False,
+        with_logprob: bool = True,
+    ):
+        mu = self.mu(h).astype(jnp.float32)
+        log_std = self.log_std(h).astype(jnp.float32)
+        return squashed_gaussian_sample(
+            key, mu, log_std, self.act_limit, deterministic, with_logprob
+        )
+
+    def __call__(
+        self,
+        obs_seq: jax.Array,
+        key: jax.Array | None = None,
+        deterministic: bool = False,
+        with_logprob: bool = True,
+    ):
+        unbatched, obs_seq = _auto_batch(obs_seq)
+        h = self.trunk(obs_seq)[:, -1]
+        action, logp = self.head(h, key, deterministic, with_logprob)
+        if unbatched:
+            action = jnp.squeeze(action, 0)
+            logp = jnp.squeeze(logp, 0) if logp is not None else None
+        return action, logp

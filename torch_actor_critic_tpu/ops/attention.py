@@ -29,6 +29,16 @@ All take ``(batch, heads, seq, head_dim)`` arrays. ``q_offset`` /
 ``k_offset`` are *global* position offsets of the local q/k chunks —
 the hook that lets ring attention apply a correct causal mask when the
 sequence axis is sharded across devices.
+
+Two generalisations, shared by all three: ``block_length`` ``b`` widens the
+causal mask to *block-causal* (position ``i`` sees ``j`` iff
+``j // b <= i // b``: causal across blocks of ``b``, full inside one;
+``b = 1`` is the causal mask, by the same code as before), and grouped
+heads: ``k``/``v`` may carry ``heads // group`` heads, query head ``i``
+then reads key/value head ``i // group``. The XLA paths repeat ``k`` and
+``v``; the kernels read the shared head through their index maps, so no
+repeated copy is ever written (the dK/dV kernel sweeps the group's query
+heads in its innermost grid axis and sums them in VMEM).
 """
 
 from __future__ import annotations
@@ -43,6 +53,22 @@ import jax.numpy as jnp
 NEG_INF = float("-inf")
 
 
+def _visible(q_pos, k_pos, block_length: int = 1):
+    """The (block-)causal mask: ``k_pos // b <= q_pos // b``."""
+    if block_length == 1:
+        return q_pos >= k_pos
+    return k_pos < (q_pos // block_length + 1) * block_length
+
+
+def _repeat_kv(q: jax.Array, k: jax.Array, v: jax.Array):
+    """``k``/``v`` with every shared head repeated for its query group."""
+    h, hkv = q.shape[1], k.shape[1]
+    if h == hkv:
+        return k, v
+    assert h % hkv == 0, (h, hkv)
+    return jnp.repeat(k, h // hkv, axis=1), jnp.repeat(v, h // hkv, axis=1)
+
+
 def reference_attention(
     q: jax.Array,
     k: jax.Array,
@@ -50,14 +76,16 @@ def reference_attention(
     causal: bool = False,
     q_offset: jax.Array | int = 0,
     k_offset: jax.Array | int = 0,
+    block_length: int = 1,
 ) -> jax.Array:
     """Plain softmax(QK^T/sqrt(d))V with the full score matrix."""
+    k, v = _repeat_kv(q, k, v)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(q.shape[-1])
     if causal:
         tq, tk = scores.shape[-2], scores.shape[-1]
         q_pos = q_offset + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
         k_pos = k_offset + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-        scores = jnp.where(q_pos >= k_pos, scores, NEG_INF)
+        scores = jnp.where(_visible(q_pos, k_pos, block_length), scores, NEG_INF)
     # Rows with no visible key (possible when k_offset > q position, as
     # happens for future chunks in ring attention) would softmax to NaN;
     # zero them instead to match the online-softmax convention.
@@ -79,6 +107,7 @@ def online_block_update(
     k_offset: jax.Array | int = 0,
     k_end: jax.Array | int | None = None,
     scale: float | None = None,
+    block_length: int = 1,
 ) -> t.Tuple[jax.Array, jax.Array, jax.Array]:
     """One online-softmax accumulation step against a K/V block.
 
@@ -103,7 +132,7 @@ def online_block_update(
             valid = k_pos < k_end
         if causal:
             q_pos = q_offset + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
-            valid = valid & (q_pos >= k_pos)
+            valid = valid & _visible(q_pos, k_pos, block_length)
         scores = jnp.where(valid, scores, NEG_INF)
     m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
     # exp(-inf - -inf) = NaN; a fully-masked row keeps m_new == -inf and
@@ -133,6 +162,7 @@ def blockwise_attention(
     q_offset: jax.Array | int = 0,
     k_offset: jax.Array | int = 0,
     block_k: int = 256,
+    block_length: int = 1,
 ) -> jax.Array:
     """Online-softmax attention scanning over K/V blocks.
 
@@ -140,6 +170,7 @@ def blockwise_attention(
     O(Tq · block_k) per (batch, head). Differentiable (plain jnp under
     ``lax.scan``), so it is the training-path implementation.
     """
+    k, v = _repeat_kv(q, k, v)
     b, h, tq, d = q.shape
     tk = k.shape[2]
     block_k = min(block_k, tk)
@@ -168,6 +199,7 @@ def blockwise_attention(
             q_offset=q_offset,
             k_offset=k_offset + j * block_k,
             k_end=k_offset + tk if padded else None,
+            block_length=block_length,
         )
         return (m, l, acc), None
 
@@ -183,7 +215,7 @@ def blockwise_attention(
 _LANE = 128  # TPU lane width: last tile dim, and scratch column count
 
 
-def _acc_dot(a: jax.Array, b: jax.Array, dims) -> jax.Array:
+def _acc_dot(a: jax.Array, b: jax.Array, dims, mxu_dtype=None) -> jax.Array:
     """``dot_general`` with f32 accumulation on MXU-native operands.
 
     Operands keep their storage dtype (bf16 stays bf16 — the MXU's fast
@@ -193,7 +225,14 @@ def _acc_dot(a: jax.Array, b: jax.Array, dims) -> jax.Array:
     the intermediate is cast DOWN to match — FlashAttention's standard
     TPU scheme; bf16 probabilities are inside the softmax's own error
     budget. f32-in/f32-out math is bit-identical to a plain f32 dot.
+
+    ``mxu_dtype`` (the kernels' ``bf16_dots``) rounds both operands to it
+    first: float32 tiles in HBM and VMEM, one bfloat16 pass on the MXU
+    with float32 accumulation, which is what XLA's default precision makes
+    of a float32 product outside the kernels.
     """
+    if mxu_dtype is not None:
+        a, b = a.astype(mxu_dtype), b.astype(mxu_dtype)
     if a.dtype != b.dtype:
         if a.dtype == jnp.float32:
             a = a.astype(b.dtype)
@@ -204,10 +243,21 @@ def _acc_dot(a: jax.Array, b: jax.Array, dims) -> jax.Array:
     )
 
 
+def _k_block_needed(iq, j, block_q: int, block_k: int, block_length: int):
+    """Whether k block ``j`` holds a key some row of q block ``iq`` sees:
+    the block's first key lies at or before the last key visible to the q
+    block's last row. Blocks past it are skipped whole; with
+    ``block_length`` 1 this is the causal diagonal test."""
+    if block_length == 1:
+        return j * block_k <= (iq + 1) * block_q - 1
+    last_row = (iq + 1) * block_q - 1
+    return j * block_k < (last_row // block_length + 1) * block_length
+
+
 def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, *rest,
     block_q: int, block_k: int, scale: float, causal: bool,
-    save_lse: bool = False,
+    save_lse: bool = False, block_length: int = 1, mxu_dtype=None,
 ):
     """One ``(batch·head, q-block, k-block)`` program.
 
@@ -237,14 +287,16 @@ def _flash_kernel(
 
     # Under causality, K blocks strictly past this q block's diagonal
     # contribute nothing; skip their compute entirely.
-    needed = True if not causal else j * block_k <= (iq + 1) * block_q - 1
+    needed = True if not causal else _k_block_needed(
+        iq, j, block_q, block_k, block_length
+    )
 
     @pl.when(needed)
     def _update():
         q = q_ref[0]
         k_blk = k_ref[0]
         v_blk = v_ref[0]
-        scores = _acc_dot(q, k_blk, ((1,), (1,))) * scale
+        scores = _acc_dot(q, k_blk, ((1,), (1,)), mxu_dtype) * scale
         if causal:
             q_pos = iq * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0
@@ -252,7 +304,9 @@ def _flash_kernel(
             k_pos = j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1
             )
-            scores = jnp.where(q_pos >= k_pos, scores, NEG_INF)
+            scores = jnp.where(
+                _visible(q_pos, k_pos, block_length), scores, NEG_INF
+            )
         m = m_ref[:, 0]
         m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
         # No isneginf guards in-kernel (unlike online_block_update,
@@ -267,7 +321,7 @@ def _flash_kernel(
         alpha = jnp.exp(m - m_new)
         l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=-1)
         acc_ref[:] = acc_ref[:] * alpha[:, None] + _acc_dot(
-            p, v_blk, ((1,), (0,))
+            p, v_blk, ((1,), (0,)), mxu_dtype
         )
         m_ref[:, 0] = m_new
 
@@ -295,6 +349,15 @@ def _flash_kernel(
                 l == 0.0, NEG_INF, m_ref[:, 0] + jnp.log(jnp.where(l == 0.0, 1.0, l))
             )
             lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref.shape[1:])
+
+
+def _head_group(h: int, hkv: int) -> int:
+    if h % hkv:
+        raise ValueError(
+            f"flash_attention: {h} query heads do not divide into {hkv} "
+            "key/value heads"
+        )
+    return h // hkv
 
 
 def _pad_head_dim(
@@ -369,6 +432,8 @@ def _flash_forward(
     interpret: bool,
     save_lse: bool = False,
     pad_lanes: int = _LANE,
+    block_length: int = 1,
+    bf16_dots: bool = False,
 ):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -387,7 +452,8 @@ def _flash_forward(
             "pass interpret=True for CPU testing."
         )
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    hkv, tk = k.shape[1], k.shape[2]
+    group = _head_group(h, hkv)
     block_q, block_k = _check_blocks(tq, tk, block_q, block_k)
     if not (q.dtype == k.dtype == v.dtype):
         # _acc_dot's downcast rule is only safe for the kernels' own f32
@@ -403,8 +469,8 @@ def _flash_forward(
     q, k, v = _pad_head_dim(q, k, v, lanes=pad_lanes)
     dp = q.shape[-1]
     qr = q.reshape(b * h, tq, dp)
-    kr = k.reshape(b * h, tk, dp)
-    vr = v.reshape(b * h, tk, dp)
+    kr = k.reshape(b * hkv, tk, dp)
+    vr = v.reshape(b * hkv, tk, dp)
     out_shape = [jax.ShapeDtypeStruct((b * h, tq, dp), q.dtype)]
     out_specs = [
         pl.BlockSpec((1, block_q, dp), lambda bh, iq, j: (bh, iq, 0),
@@ -422,16 +488,19 @@ def _flash_forward(
         functools.partial(
             _flash_kernel,
             block_q=block_q, block_k=block_k, scale=scale, causal=causal,
-            save_lse=save_lse,
+            save_lse=save_lse, block_length=block_length,
+            mxu_dtype=jnp.bfloat16 if bf16_dots else None,
         ),
         out_shape=out_shape,
         grid=(b * h, tq // block_q, tk // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, dp), lambda bh, iq, j: (bh, iq, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, dp), lambda bh, iq, j: (bh, j, 0),
+            # Row bh = batch * h + head of q reads row bh // group of k/v:
+            # batch * hkv + head // group, the shared head.
+            pl.BlockSpec((1, block_k, dp), lambda bh, iq, j: (bh // group, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, dp), lambda bh, iq, j: (bh, j, 0),
+            pl.BlockSpec((1, block_k, dp), lambda bh, iq, j: (bh // group, j, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=out_specs,
@@ -453,14 +522,17 @@ def _flash_forward(
     return out
 
 
-def _attn_probs(q, k, lse, scale, causal, iq, jk, block_q, block_k):
+def _attn_probs(
+    q, k, lse, scale, causal, iq, jk, block_q, block_k, block_length=1,
+    mxu_dtype=None,
+):
     """Recompute the (block_q, block_k) probability tile from saved lse.
 
     ``p[r, c] = exp(s[r, c] - lse[r])`` — exactly the forward's softmax
     weights, recovered without re-running the online max/normalizer scan.
     Shared by both backward kernels.
     """
-    s = _acc_dot(q, k, ((1,), (1,))) * scale
+    s = _acc_dot(q, k, ((1,), (1,)), mxu_dtype) * scale
     if causal:
         q_pos = iq * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0
@@ -468,7 +540,7 @@ def _attn_probs(q, k, lse, scale, causal, iq, jk, block_q, block_k):
         k_pos = jk * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1
         )
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        s = jnp.where(_visible(q_pos, k_pos, block_length), s, NEG_INF)
     # lse is finite for every row inside the kernel (each causal row
     # sees at least key 0 — see the forward's guard-removal note), and
     # masked scores are -inf -> exp(-inf - finite) = 0 with no NaN
@@ -479,6 +551,7 @@ def _attn_probs(q, k, lse, scale, causal, iq, jk, block_q, block_k):
 def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
     *, block_q: int, block_k: int, scale: float, causal: bool,
+    block_length: int = 1, mxu_dtype=None,
 ):
     """dQ: grid ``(batch·head, q-block, k-block)``, k innermost.
 
@@ -496,7 +569,9 @@ def _flash_bwd_dq_kernel(
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    needed = True if not causal else j * block_k <= (iq + 1) * block_q - 1
+    needed = True if not causal else _k_block_needed(
+        iq, j, block_q, block_k, block_length
+    )
 
     @pl.when(needed)
     def _update():
@@ -505,11 +580,12 @@ def _flash_bwd_dq_kernel(
         v_blk = v_ref[0]
         do = do_ref[0]
         p = _attn_probs(
-            q, k_blk, lse_ref[0][:, 0], scale, causal, iq, j, block_q, block_k
+            q, k_blk, lse_ref[0][:, 0], scale, causal, iq, j, block_q, block_k,
+            block_length, mxu_dtype,
         )
-        dpv = _acc_dot(do, v_blk, ((1,), (1,)))
+        dpv = _acc_dot(do, v_blk, ((1,), (1,)), mxu_dtype)
         ds = p * (dpv - delta_ref[0][:, 0][:, None])
-        dq_acc[:] += _acc_dot(ds, k_blk, ((1,), (0,))) * scale
+        dq_acc[:] += _acc_dot(ds, k_blk, ((1,), (0,)), mxu_dtype) * scale
 
     @pl.when(j == n_kb - 1)
     def _finalize():
@@ -520,26 +596,32 @@ def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_acc, dv_acc,
     *, block_q: int, block_k: int, scale: float, causal: bool,
+    block_length: int = 1, mxu_dtype=None, n_qb: int | None = None,
 ):
-    """dK/dV: grid ``(batch·head, k-block, q-block)``, q innermost.
+    """dK/dV: grid ``(batch·kv-head, k-block, group·q-block)``, q innermost.
 
     ``dv += pᵀ dO``; ``dk += dsᵀ Q · scale`` — both accumulated in VMEM
-    scratch over the q sweep for a fixed k block.
+    scratch over the q sweep for a fixed k block. With grouped heads the
+    sweep runs over the q blocks of every query head of the group in turn
+    (``n_qb`` q blocks a head), so the group's sum never leaves VMEM.
     """
     from jax.experimental import pallas as pl
 
     jk = pl.program_id(1)
-    i = pl.program_id(2)
-    n_qb = pl.num_programs(2)
+    sweep = pl.program_id(2)
+    n_sweep = pl.num_programs(2)
+    i = sweep if n_qb is None else jax.lax.rem(sweep, n_qb)
 
-    @pl.when(i == 0)
+    @pl.when(sweep == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     # Under causality, q blocks strictly before this k block's start see
     # none of it; skip them.
-    needed = True if not causal else (i + 1) * block_q - 1 >= jk * block_k
+    needed = True if not causal else _k_block_needed(
+        i, jk, block_q, block_k, block_length
+    )
 
     @pl.when(needed)
     def _update():
@@ -548,14 +630,15 @@ def _flash_bwd_dkv_kernel(
         v_blk = v_ref[0]
         do = do_ref[0]
         p = _attn_probs(
-            q, k_blk, lse_ref[0][:, 0], scale, causal, i, jk, block_q, block_k
+            q, k_blk, lse_ref[0][:, 0], scale, causal, i, jk, block_q, block_k,
+            block_length, mxu_dtype,
         )
-        dv_acc[:] += _acc_dot(p, do, ((0,), (0,)))
-        dpv = _acc_dot(do, v_blk, ((1,), (1,)))
+        dv_acc[:] += _acc_dot(p, do, ((0,), (0,)), mxu_dtype)
+        dpv = _acc_dot(do, v_blk, ((1,), (1,)), mxu_dtype)
         ds = p * (dpv - delta_ref[0][:, 0][:, None])
-        dk_acc[:] += _acc_dot(ds, q, ((0,), (0,))) * scale
+        dk_acc[:] += _acc_dot(ds, q, ((0,), (0,)), mxu_dtype) * scale
 
-    @pl.when(i == n_qb - 1)
+    @pl.when(sweep == n_sweep - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -563,13 +646,17 @@ def _flash_bwd_dkv_kernel(
 
 def _flash_backward(
     q, k, v, o, lse, g, causal, block_q, block_k, interpret,
-    pad_lanes: int = _LANE,
+    pad_lanes: int = _LANE, block_length: int = 1, bf16_dots: bool = False,
 ):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    hkv, tk = k.shape[1], k.shape[2]
+    group = _head_group(h, hkv)
+    mask = dict(
+        block_length=block_length, mxu_dtype=jnp.bfloat16 if bf16_dots else None
+    )
     block_q, block_k = _check_blocks(tq, tk, block_q, block_k)
     scale = 1.0 / math.sqrt(d)
     # The forward enforced a single q/k/v dtype; the cotangent can still
@@ -584,8 +671,8 @@ def _flash_backward(
     q, k, v, g = _pad_head_dim(q, k, v, g, lanes=pad_lanes)
     dp = q.shape[-1]
     qr = q.reshape(b * h, tq, dp)
-    kr = k.reshape(b * h, tk, dp)
-    vr = v.reshape(b * h, tk, dp)
+    kr = k.reshape(b * hkv, tk, dp)
+    vr = v.reshape(b * hkv, tk, dp)
     gr = g.reshape(b * h, tq, dp)
     # Row stats enter the kernels broadcast across a 128-lane axis —
     # (1, block_q) blocks are not (8, 128)-tileable on TPU (see the
@@ -597,7 +684,7 @@ def _flash_backward(
 
     qspec = pl.BlockSpec((1, block_q, dp), lambda bh, x, y: (bh, x, 0),
                          memory_space=pltpu.VMEM)
-    kspec_dq = pl.BlockSpec((1, block_k, dp), lambda bh, iq, j: (bh, j, 0),
+    kspec_dq = pl.BlockSpec((1, block_k, dp), lambda bh, iq, j: (bh // group, j, 0),
                             memory_space=pltpu.VMEM)
     rowspec = pl.BlockSpec((1, block_q, _LANE), lambda bh, x, y: (bh, x, 0),
                            memory_space=pltpu.VMEM)
@@ -605,6 +692,7 @@ def _flash_backward(
         functools.partial(
             _flash_bwd_dq_kernel,
             block_q=block_q, block_k=block_k, scale=scale, causal=causal,
+            **mask,
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, tq, dp), q.dtype),
         grid=(b * h, tq // block_q, tk // block_k),
@@ -617,23 +705,31 @@ def _flash_backward(
         interpret=interpret,
     )(qr, kr, vr, gr, lse_r, delta)
 
-    # dK/dV sweep: the grid's second axis is the k block, q innermost.
-    qspec_kv = pl.BlockSpec((1, block_q, dp), lambda bh, jk, i: (bh, i, 0),
+    # dK/dV sweep: the grid's second axis is the k block, q innermost. Row
+    # bkv of k/v is read by the q rows bkv * group .. + group - 1; step i of
+    # the sweep is q block i % n_qb of the group's query head i // n_qb.
+    n_qb = tq // block_q
+    if group == 1:
+        q_row = lambda bkv, i: (bkv, i)  # noqa: E731
+    else:
+        q_row = lambda bkv, i: (bkv * group + i // n_qb, i % n_qb)  # noqa: E731
+    qspec_kv = pl.BlockSpec((1, block_q, dp), lambda bh, jk, i: (*q_row(bh, i), 0),
                             memory_space=pltpu.VMEM)
     kspec_kv = pl.BlockSpec((1, block_k, dp), lambda bh, jk, i: (bh, jk, 0),
                             memory_space=pltpu.VMEM)
-    rowspec_kv = pl.BlockSpec((1, block_q, _LANE), lambda bh, jk, i: (bh, i, 0),
+    rowspec_kv = pl.BlockSpec((1, block_q, _LANE), lambda bh, jk, i: (*q_row(bh, i), 0),
                               memory_space=pltpu.VMEM)
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel,
             block_q=block_q, block_k=block_k, scale=scale, causal=causal,
+            n_qb=None if group == 1 else n_qb, **mask,
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, tk, dp), k.dtype),
-            jax.ShapeDtypeStruct((b * h, tk, dp), v.dtype),
+            jax.ShapeDtypeStruct((b * hkv, tk, dp), k.dtype),
+            jax.ShapeDtypeStruct((b * hkv, tk, dp), v.dtype),
         ],
-        grid=(b * h, tk // block_k, tq // block_q),
+        grid=(b * hkv, tk // block_k, group * n_qb),
         in_specs=[qspec_kv, kspec_kv, kspec_kv, qspec_kv, rowspec_kv,
                   rowspec_kv],
         out_specs=[kspec_kv, kspec_kv],
@@ -648,12 +744,12 @@ def _flash_backward(
     )(qr, kr, vr, gr, lse_r, delta)
 
     dq = dq.reshape(b * h, tq, dp)[..., :d].reshape(b, h, tq, d)
-    dk = dk.reshape(b * h, tk, dp)[..., :d].reshape(b, h, tk, d)
-    dv = dv.reshape(b * h, tk, dp)[..., :d].reshape(b, h, tk, d)
+    dk = dk.reshape(b * hkv, tk, dp)[..., :d].reshape(b, hkv, tk, d)
+    dv = dv.reshape(b * hkv, tk, dp)[..., :d].reshape(b, hkv, tk, d)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -663,6 +759,8 @@ def flash_attention(
     block_k: int | None = None,
     interpret: bool = False,
     pad_lanes: int = _LANE,
+    block_length: int = 1,
+    bf16_dots: bool = False,
 ):
     """Pallas TPU flash attention, forward *and* backward kernels.
 
@@ -681,25 +779,38 @@ def flash_attention(
     head dim works (zero-padded to the 128-lane width internally).
     ``interpret=True`` runs the kernels in the Pallas interpreter
     (CPU-testable; used by the test suite).
+
+    ``block_length`` (with ``causal``) makes the mask block-causal and
+    ``k``/``v`` may carry fewer, shared heads (module docstring).
+    ``bf16_dots`` rounds the operands of every product to bfloat16 inside
+    the kernels (see :func:`_acc_dot`); inputs, outputs and accumulators
+    keep their dtype.
     """
     return _flash_forward(
-        q, k, v, causal, block_q, block_k, interpret, pad_lanes=pad_lanes
+        q, k, v, causal, block_q, block_k, interpret, pad_lanes=pad_lanes,
+        block_length=block_length, bf16_dots=bf16_dots,
     )
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, pad_lanes):
+def _flash_fwd(
+    q, k, v, causal, block_q, block_k, interpret, pad_lanes, block_length,
+    bf16_dots,
+):
     out, lse = _flash_forward(
         q, k, v, causal, block_q, block_k, interpret, save_lse=True,
-        pad_lanes=pad_lanes,
+        pad_lanes=pad_lanes, block_length=block_length, bf16_dots=bf16_dots,
     )
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, pad_lanes, res, g):
+def _flash_bwd(
+    causal, block_q, block_k, interpret, pad_lanes, block_length, bf16_dots,
+    res, g,
+):
     q, k, v, o, lse = res
     return _flash_backward(
         q, k, v, o, lse, g, causal, block_q, block_k, interpret,
-        pad_lanes=pad_lanes,
+        pad_lanes=pad_lanes, block_length=block_length, bf16_dots=bf16_dots,
     )
 
 
@@ -714,6 +825,8 @@ def attention(
     impl: str = "auto",
     block_q: int | None = None,
     block_k: int | None = None,
+    block_length: int = 1,
+    bf16_dots: bool = False,
 ) -> jax.Array:
     """Dispatch: ``'pallas'`` kernel on TPU-compatible shapes,
     ``'xla'`` blockwise scan otherwise; ``'auto'`` picks by the process
@@ -751,7 +864,11 @@ def attention(
         )
         impl = "pallas" if (on_tpu and shapes_ok) else "xla"
     if impl == "pallas":
-        return flash_attention(q, k, v, causal, block_q, block_k)
+        return flash_attention(
+            q, k, v, causal, block_q, block_k, False, _LANE, block_length,
+            bf16_dots,
+        )
     return blockwise_attention(
-        q, k, v, causal, block_k=128 if block_k is None else block_k
+        q, k, v, causal, block_k=128 if block_k is None else block_k,
+        block_length=block_length,
     )

@@ -23,6 +23,7 @@ optax transforms) — it is hashable setup, never traced state.
 
 from __future__ import annotations
 
+import functools
 import typing as t
 
 import jax
@@ -115,6 +116,9 @@ class SAC:
         self.q_tx = optax.adam(config.lr)
         self.alpha_tx = optax.adam(config.lr)
         self._adam_core = optax.scale_by_adam()
+        # One history trunk shared by actor and critics (models/sequence.py
+        # SharedTrunk*): the critic's tree holds it, the losses differ.
+        self.shared_trunk = bool(getattr(critic_def, "shared_trunk", False))
         self.target_entropy = (
             config.target_entropy
             if config.target_entropy is not None
@@ -152,8 +156,18 @@ class SAC:
         """
         k_actor, k_critic, k_sample, k_state = jax.random.split(key, 4)
         example_act = jnp.zeros((self.act_dim,))
-        actor_params = self.actor_def.init(k_actor, example_obs, k_sample)
         critic_params = self.critic_def.init(k_critic, example_obs, example_act)
+        if self.shared_trunk:
+            # init also fills the collection the expert layers sow into.
+            critic_params = {"params": critic_params["params"]}
+            # The trained actor parameters are the policy head alone; the
+            # trunk it reads is the critic's (models.sequence.policy_params).
+            actor_params = self.actor_def.init(
+                k_actor, jnp.zeros((1, self.critic_def.spec.hidden)), k_sample,
+                method=self.actor_def.head,
+            )
+        else:
+            actor_params = self.actor_def.init(k_actor, example_obs, k_sample)
         log_alpha = jnp.log(jnp.float32(self.config.alpha))
         return TrainState(
             step=jnp.int32(0),
@@ -177,11 +191,33 @@ class SAC:
     def _critic_apply(self, params, obs, action):
         return self.critic_def.apply(params, obs, action)
 
+    def _features_apply(self, params, obs):
+        """One pass of the shared trunk: the last step's features, and the
+        expert layers' statistics ``{"sizes": (layers, held experts),
+        "choices": (layers, tokens, experts a token)}``."""
+        h, sown = self.critic_def.apply(
+            params, obs, method=self.critic_def.features, mutable=["moe_stats"]
+        )
+        layers = sown["moe_stats"]["trunk"]
+        names = sorted(layers, key=lambda n: int(n.rsplit("_", 1)[1]))
+        stats = {
+            k: jnp.stack([layers[n]["moe"][k][0] for n in names])
+            for k in ("sizes", "choices")
+        }
+        return h, stats
+
+    def _q_apply(self, params, h, action):
+        return self.critic_def.apply(params, h, action, method=self.critic_def.q)
+
+    def _head_apply(self, params, h, key):
+        return self.actor_def.apply(params, h, key, method=self.actor_def.head)
+
     def select_action(
         self, params, obs, key: jax.Array | None = None, deterministic: bool = False
     ):
         """Policy for env interaction (no log-prob, like the no-grad
-        action selection at ref ``sac/algorithm.py:231-236``)."""
+        action selection at ref ``sac/algorithm.py:231-236``). ``params``
+        are the policy's (``models.sequence.policy_params`` of a state)."""
         action, _ = self.actor_def.apply(
             params, obs, key, deterministic=deterministic, with_logprob=False
         )
@@ -238,12 +274,28 @@ class SAC:
             alpha = hp.get("alpha", jnp.float32(cfg.alpha))
 
         # --- critic step ---
+        # One history trunk shared by actor and critics changes the two
+        # losses and nothing after them: the critic step trains trunk and Q
+        # heads, the actor step trains the policy head on the critic step's
+        # features, the polyak target covers trunk and Q heads.
+        if self.shared_trunk:
+            critic_loss = functools.partial(
+                losses.shared_trunk_critic_loss,
+                features_apply=self._features_apply,
+                q_apply=self._q_apply,
+                head_apply=self._head_apply,
+            )
+        else:
+            critic_loss = functools.partial(
+                losses.critic_loss,
+                actor_apply=self._actor_apply,
+                critic_apply=self._critic_apply,
+                diagnostics=tier != "off",
+            )
         (loss_q, q_aux), q_grads = jax.named_scope(scopes.CRITIC)(
-            jax.value_and_grad(losses.critic_loss, has_aux=True)
+            jax.value_and_grad(critic_loss, has_aux=True)
         )(
             state.critic_params,
-            actor_apply=self._actor_apply,
-            critic_apply=self._critic_apply,
             actor_params=state.actor_params,
             target_critic_params=state.target_critic_params,
             batch=batch,
@@ -251,7 +303,6 @@ class SAC:
             alpha=alpha,
             gamma=cfg.gamma,
             reward_scale=cfg.reward_scale,
-            diagnostics=tier != "off",
         )
         diag_q = q_aux.pop("diag_q", None)
         diag_backup = q_aux.pop("diag_backup", None)
@@ -276,18 +327,30 @@ class SAC:
 
         # --- actor step (critic frozen by construction: grad w.r.t.
         # actor params only) ---
+        if self.shared_trunk:
+            trunk_stats = q_aux.pop("stats"), q_aux.pop("stats_target")
+            actor_loss = functools.partial(
+                losses.shared_trunk_actor_loss,
+                head_apply=self._head_apply,
+                q_apply=self._q_apply,
+                features=q_aux.pop("features"),
+            )
+        else:
+            actor_loss = functools.partial(
+                losses.actor_loss,
+                actor_apply=self._actor_apply,
+                critic_apply=self._critic_apply,
+                batch=batch,
+                parity_pi_obs=cfg.parity_pi_obs,
+                diagnostics=tier != "off",
+            )
         (loss_pi, pi_aux), pi_grads = jax.named_scope(scopes.ACTOR)(
-            jax.value_and_grad(losses.actor_loss, has_aux=True)
+            jax.value_and_grad(actor_loss, has_aux=True)
         )(
             state.actor_params,
-            actor_apply=self._actor_apply,
-            critic_apply=self._critic_apply,
             critic_params=critic_params,
-            batch=batch,
             key=key_pi,
             alpha=alpha,
-            parity_pi_obs=cfg.parity_pi_obs,
-            diagnostics=tier != "off",
         )
         diag_pi = pi_aux.pop("diag_pi", None)
         if tier != "off":
@@ -355,6 +418,8 @@ class SAC:
             **q_aux,
             **pi_aux,
         }
+        if self.shared_trunk:
+            metrics.update(self._trunk_counters(*trunk_stats))
         if tier != "off":
             metrics.update(diag_metrics)
             metrics.update(
@@ -364,6 +429,26 @@ class SAC:
                 )
             )
         return new_state, metrics
+
+    def _trunk_counters(self, stats, stats_target) -> Metrics:
+        """Counters of the expert layers, reduced here on the device: the
+        assignments that landed on held experts in the online pass (the one
+        the backward pass repeats) and in the target pass, summed over
+        layers, and the online pass's largest and mean tokens a held expert."""
+        sizes = stats["sizes"].astype(jnp.float32)
+        counters = {
+            "trunk/held_assignments": jnp.sum(sizes),
+            "trunk/held_assignments_target": jnp.sum(
+                stats_target["sizes"].astype(jnp.float32)
+            ),
+            "trunk/expert_load_max": jnp.max(sizes),
+            "trunk/expert_load_mean": jnp.mean(sizes),
+        }
+        if self.config.trunk_report_choices:
+            # float32 holds an expert's index exactly and survives the
+            # data-parallel burst's whole-tree pmean.
+            counters["trunk/choices_first"] = stats["choices"].astype(jnp.float32)
+        return counters
 
     # --------------------------------------------------------------- burst
 

@@ -119,3 +119,79 @@ def alpha_loss(
     alpha, ref ``main.py:148``): ``-log_alpha * (logp_pi + H_target)``.
     """
     return -log_alpha * (jax.lax.stop_gradient(logp_pi) + target_entropy)
+
+
+# --------------------------------------------------------------------------
+# One history trunk shared by actor and critics (models/sequence.py)
+# --------------------------------------------------------------------------
+#
+# The critic's tree holds the trunk and the Q heads; the actor's holds the
+# policy head alone. ``features_apply(critic_params, obs) -> (h, stats)`` is
+# one trunk pass (``h``: the last step's features), ``q_apply(critic_params,
+# h, action)`` the Q heads and ``head_apply(actor_params, h, key)`` the
+# policy head. A gradient step makes two trunk passes and one backward pass:
+#
+# - the target trunk on ``next_states``: its features feed the target Q heads
+#   and the policy head that draws ``a'`` (the target is the polyak average of
+#   the online trunk; a third pass, of the online trunk on ``next_states``
+#   for ``a'`` alone, would cost a quarter more of the step);
+# - the online trunk on ``states``, differentiated by the critic loss, which
+#   is what trains it. The policy loss and the entropy term read the same
+#   features through ``stop_gradient`` against the updated Q heads, the way
+#   DrQ-v2 encodes once, steps the critic and encoder, and hands the detached
+#   encoding to the actor step.
+
+
+def shared_trunk_critic_loss(
+    critic_params: t.Any,
+    *,
+    features_apply: t.Callable,
+    q_apply: t.Callable,
+    head_apply: t.Callable,
+    actor_params: t.Any,
+    target_critic_params: t.Any,
+    batch: Batch,
+    key: jax.Array,
+    alpha: jax.Array,
+    gamma: float,
+    reward_scale: float,
+) -> t.Tuple[jax.Array, t.Dict[str, t.Any]]:
+    """:func:`critic_loss` on a shared trunk. The aux carries the online
+    features (``features``, stop-gradient'd, for the actor step) and both
+    passes' trunk statistics."""
+    h_next, stats_target = features_apply(target_critic_params, batch.next_states)
+    next_action, next_logp = head_apply(actor_params, h_next, key)
+    q_target_min = jnp.min(q_apply(target_critic_params, h_next, next_action), axis=0)
+    backup = reward_scale * batch.rewards + gamma * (1.0 - batch.done) * (
+        q_target_min - alpha * next_logp
+    )
+    backup = jax.lax.stop_gradient(backup)
+
+    h, stats = features_apply(critic_params, batch.states)
+    q = q_apply(critic_params, h, batch.actions)  # (num_qs, B)
+    loss = jnp.sum(jnp.mean((q - backup[None, :]) ** 2, axis=-1))
+    aux = {
+        "q_mean": jnp.mean(q), "backup_mean": jnp.mean(backup),
+        "features": jax.lax.stop_gradient(h),
+        "stats": jax.lax.stop_gradient(stats),
+        "stats_target": jax.lax.stop_gradient(stats_target),
+    }
+    return loss, aux
+
+
+def shared_trunk_actor_loss(
+    actor_params: t.Any,
+    *,
+    head_apply: t.Callable,
+    q_apply: t.Callable,
+    critic_params: t.Any,
+    features: jax.Array,
+    key: jax.Array,
+    alpha: jax.Array,
+) -> t.Tuple[jax.Array, t.Dict[str, jax.Array]]:
+    """:func:`actor_loss` on the trunk's features, which carry no gradient:
+    ``actor_params`` are the policy head alone."""
+    pi, logp_pi = head_apply(actor_params, features, key)
+    q_pi_min = jnp.min(q_apply(critic_params, features, pi), axis=0)
+    loss = jnp.mean(alpha * logp_pi - q_pi_min)
+    return loss, {"logp_pi": jnp.mean(logp_pi), "entropy": -jnp.mean(logp_pi)}

@@ -270,6 +270,26 @@ def build_models(config: SACConfig, env) -> t.Tuple[t.Any, t.Any]:
             SequenceDoubleCritic,
         )
 
+        if config.trunk_block == "sdar_moe":
+            # One trunk for actor and critics, its block from the
+            # configuration (models/sequence.py, SACConfig.trunk_*).
+            from torch_actor_critic_tpu.models import (
+                SharedTrunkActor,
+                SharedTrunkCritic,
+                TrunkSpec,
+            )
+
+            spec = TrunkSpec.from_config(config)
+            return (
+                SharedTrunkActor(
+                    act_dim=env.act_dim, spec=spec, act_limit=env.act_limit,
+                    dtype=dtype,
+                ),
+                SharedTrunkCritic(
+                    spec=spec, hidden=config.trunk_q_hidden,
+                    num_qs=config.num_qs, dtype=dtype,
+                ),
+            )
         horizon = env.obs_spec.shape[0]
         actor = SequenceActor(
             act_dim=env.act_dim,
@@ -778,7 +798,8 @@ class Trainer:
             )
         else:
             actions = self.dp.select_action(
-                self.state.actor_params, obs_batch, sub, deterministic=deterministic
+                self.serve_actor_params(), obs_batch, sub,
+                deterministic=deterministic,
             )
         return np.asarray(actions)
 
@@ -797,7 +818,7 @@ class Trainer:
 
     def _fetch_params_single_transfer(self):
         """Mirror actor params to the host with one device->host copy."""
-        params = self.state.actor_params
+        params = self.serve_actor_params()
         if self._param_struct is None:
             leaves, treedef = jax.tree_util.tree_flatten(params)
             shapes = [x.shape for x in leaves]
@@ -1086,8 +1107,13 @@ class Trainer:
     def serve_actor_params(self):
         """The actor-param subtree a serve worker would restore from a
         checkpoint of the current state — what the warm-start bundle
-        must be built against for its avals to match at load time."""
-        return self.state.actor_params
+        must be built against for its avals to match at load time, and
+        what this trainer's own actors act with: ``policy_params`` of the
+        state (the actor's tree; with a shared history trunk, that trunk
+        from the critic's tree under the actor's heads)."""
+        from torch_actor_critic_tpu.models.sequence import policy_params
+
+        return policy_params(self.state.actor_params, self.state.critic_params)
 
     def _load_checkpoint(
         self, epoch: int | None = None, include_buffer: bool = True

@@ -39,9 +39,16 @@ POLYAK = "tac/polyak"
 ALLREDUCE = "tac/allreduce"
 COLLECT_ACT = "tac/collect/act"
 COLLECT_ENV = "tac/collect/env_step"
+# The parts of a history trunk (models/sequence.py), nested inside
+# tac/critic and tac/actor: the innermost name is the one a reader gets.
+TRUNK_EMBED = "tac/trunk/embed"  # observation projection, final norm
+TRUNK_ATTENTION = "tac/trunk/attention"
+TRUNK_MOE_ROUTE = "tac/trunk/moe/route"
+TRUNK_MOE_EXPERTS = "tac/trunk/moe/experts"
 SCOPES = (
     PUSH, SAMPLE, DECODE, CRITIC, ACTOR, ALPHA, OPTIMIZER, POLYAK,
-    ALLREDUCE, COLLECT_ACT, COLLECT_ENV,
+    ALLREDUCE, COLLECT_ACT, COLLECT_ENV, TRUNK_EMBED, TRUNK_ATTENTION,
+    TRUNK_MOE_ROUTE, TRUNK_MOE_EXPERTS,
 )
 HOST_PREFIX = "tac/host/"  # the recorder's phase annotations
 

@@ -447,7 +447,15 @@ class Checkpointer:
                 f"checkpoint at {self.directory} epoch {epoch} has no "
                 "actor_params item — not a TrainState checkpoint?"
             )
-        return train_state["actor_params"], dict(out["meta"], epoch=epoch)
+        # A shared history trunk lives in the critic's tree: the policy is
+        # that trunk under the actor's heads, by the one function every
+        # acting site uses (``shardings`` cover the actor's own subtree).
+        from torch_actor_critic_tpu.models.sequence import policy_params
+
+        params = policy_params(
+            train_state["actor_params"], train_state.get("critic_params")
+        )
+        return params, dict(out["meta"], epoch=epoch)
 
     def _sharded_abstract_state(self, epoch: int, shardings: t.Any):
         """Abstract ``train_state`` tree for a direct-to-sharded actor

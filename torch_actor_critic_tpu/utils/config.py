@@ -103,6 +103,39 @@ class SACConfig:
     seq_d_model: int = 64
     seq_num_heads: int = 4
     seq_num_layers: int = 2
+    # The history trunk's block (models/sequence.py). "transformer" is the
+    # small pre-LN block above, one trunk in the actor and one in every
+    # critic, sized by seq_*. "sdar_moe" is the decoder layer of
+    # SDAR-30B-A3B (RMSNorm, rotary positions, grouped-query block-causal
+    # attention with per-head q/k norm, a sparse-expert feed-forward of
+    # which this chip holds experts trunk_experts_held) as ONE trunk that
+    # the critic loss trains, the actor reads through stop_gradient and the
+    # polyak target covers; trunk_* are its published widths and the cut.
+    trunk_block: str = "transformer"
+    trunk_hidden: int = 2048
+    trunk_q_heads: int = 32
+    trunk_kv_heads: int = 4
+    trunk_head_dim: int = 128
+    trunk_layers: int = 4
+    trunk_experts: int = 128  # the router's outputs, all of them
+    trunk_experts_per_tok: int = 8
+    trunk_expert_width: int = 768
+    trunk_experts_held: t.Tuple[int, ...] = (0, 16)  # [lo, hi) held here
+    trunk_block_length: int = 4  # causal across blocks, full inside one
+    trunk_rope_theta: float = 1e6
+    trunk_rms_eps: float = 1e-6
+    trunk_q_hidden: int = 256  # width of the Q heads' hidden layer
+    trunk_remat: int = 0  # the first n blocks are recomputed in the backward pass
+    # compute_dtype float32 means the TPU's default precision for a float32
+    # product: operands rounded to bfloat16, float32 accumulation. XLA does
+    # that to its own products on the TPU; the trunk's kernels (flash
+    # attention, the grouped expert products) are told here, and round on
+    # every platform. False keeps their operands float32 (a CPU run held to
+    # a float32 reference).
+    trunk_bf16_dots: bool = True
+    # Return the first update's expert choices with the burst's metrics
+    # (an array; the benchmark's check reads it, the Trainer's logs cannot).
+    trunk_report_choices: bool = False
 
     # Fully-fused on-device training (sac/ondevice.py): env + replay +
     # learner compiled into one program per epoch. Only for envs with a
@@ -438,6 +471,34 @@ class SACConfig:
     elastic_readmit_epochs: int = 1
 
     def __post_init__(self):
+        self.trunk_experts_held = tuple(self.trunk_experts_held)
+        if self.trunk_block not in ("transformer", "sdar_moe"):
+            raise ValueError(
+                f"trunk_block must be 'transformer' or 'sdar_moe', got "
+                f"{self.trunk_block!r}"
+            )
+        if self.trunk_block == "sdar_moe":
+            lo, hi = self.trunk_experts_held
+            if not 0 <= lo < hi <= self.trunk_experts:
+                raise ValueError(
+                    f"trunk_experts_held={self.trunk_experts_held} must be a "
+                    f"range [lo, hi) inside the {self.trunk_experts} experts"
+                )
+            if self.trunk_q_heads % self.trunk_kv_heads:
+                raise ValueError(
+                    f"trunk_q_heads={self.trunk_q_heads} must be a multiple of "
+                    f"trunk_kv_heads={self.trunk_kv_heads}"
+                )
+            # The shared trunk rewires the SAC losses alone; fail at
+            # construction, like the augment/pixel gates in build_models.
+            if self.algorithm != "sac" or self.parity_pi_obs or (
+                self.diagnostics != "off"
+            ):
+                raise ValueError(
+                    "trunk_block='sdar_moe' trains one shared trunk by the SAC "
+                    "critic loss: it needs algorithm='sac', parity_pi_obs=False "
+                    "and diagnostics='off'"
+                )
         if not (len(self.filters) == len(self.kernel_sizes) == len(self.strides)):
             raise ValueError(
                 "filters/kernel_sizes/strides must have equal length, got "
@@ -771,7 +832,10 @@ class SACConfig:
         raw = json.loads(s)
         field_names = {f.name for f in dataclasses.fields(cls)}
         kwargs = {k: v for k, v in raw.items() if k in field_names}
-        for tup in ("hidden_sizes", "filters", "kernel_sizes", "strides"):
+        for tup in (
+            "hidden_sizes", "filters", "kernel_sizes", "strides",
+            "trunk_experts_held",
+        ):
             if tup in kwargs:
                 kwargs[tup] = tuple(kwargs[tup])
         return cls(**kwargs)
