@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark.harness import check, data
+from benchmark.harness import check, data, flops
 
 
 def sac_config(config: dict, cell: dict, overrides: dict | None = None):
@@ -130,6 +130,13 @@ class FollowedCall:
     losses: list
     finite = True
 
+    @staticmethod
+    def at_rest_bytes(cell: dict, config: dict) -> int:
+        """What the driver holds on a chip between steps: its rings, where the
+        learner beside them is small."""
+        rows = cell["traffic"]["ring_rows"] * config["sac"].get("population", 1)
+        return rows // cell["chips"] * flops.row_bytes(config["model"])
+
     def note_losses(self, metrics) -> None:
         """Keep a call's losses on the device; fold them to one flag now and
         then so that a long window holds few of them."""
@@ -177,18 +184,21 @@ class FollowedCall:
         )
 
 
-def gather_rows(ring, idx, in_axes=(0, 0), out_axis: int = 0):
+def gather_rows(ring, idx, rows, in_axes=(0, 0), out_axis: int = 0):
     """The rows ``idx`` selects from every shard (or member) of ``ring``, on
-    the host: leaves ``(streams, rows, ...)`` indexed by ``idx``'s stream."""
-    take = jax.jit(
-        lambda ring, idx: jax.tree_util.tree_map(
+    the host: stored leaves ``(streams, rows, ...)`` indexed along the rows
+    axis by ``idx``'s stream, each row handed back in its transition's shape
+    (``rows``: :func:`benchmark.harness.data.transition_rows`)."""
+    def take(ring, idx):
+        taken = jax.tree_util.tree_map(
             lambda leaf: jax.vmap(
                 lambda r, i: jnp.take(r, i, axis=0), in_axes=in_axes, out_axes=out_axis
             )(leaf, idx),
             ring,
         )
-    )
-    return jax.device_get(take(ring, idx))
+        return data.as_rows(taken, rows, idx.ndim)
+
+    return jax.device_get(jax.jit(take)(ring, idx))
 
 
 def with_stream_axis(tree):

@@ -29,42 +29,59 @@ class Driver(_common.FollowedCall):
         self.losses = []
 
     # ------------------------------------------------------------ set-up
-    def setup(self) -> None:
-        from jax.sharding import NamedSharding, PartitionSpec as P
+    def learner_config(self):
+        """The program's ``SACConfig`` and the env's spec ``build_models`` reads."""
+        return (
+            _common.sac_config(self.config, self.cell, self.overrides.get("sac")),
+            _common.EnvSpec(self.config["model"]),
+        )
 
-        from torch_actor_critic_tpu.buffer.replay import init_replay_buffer
-        from torch_actor_critic_tpu.core.types import BufferState
+    def seeded_params(self, env):
+        return _common.seeded_params(self.sac, env.example_obs(), self.seed)
+
+    def _build_learner(self):
         from torch_actor_critic_tpu.parallel.dp import DataParallelSAC
         from torch_actor_critic_tpu.parallel.mesh import make_mesh
         from torch_actor_critic_tpu.sac.trainer import build_models, make_learner
 
-        self.spans.lap("setup/import")
-        traffic, model = self.cell["traffic"], self.config["model"]
         self.n_dev = self.cell["chips"]
-        self.cfg = _common.sac_config(self.config, self.cell, self.overrides.get("sac"))
+        self.cfg, env = self.learner_config()
         self.n_updates = self.cfg.updates_per_window
         self.mesh = make_mesh(dp=self.n_dev, devices=jax.devices()[: self.n_dev])
-        env = _common.EnvSpec(model)
         actor_def, critic_def = build_models(self.cfg, env)
         self.sac = make_learner(self.cfg, actor_def, critic_def, env.act_dim)
         self.dp = DataParallelSAC(self.sac, self.mesh)
 
         self.rng0 = data.state_key(self.seed, 0)
-        self.actor0, self.critic0 = _common.seeded_params(
-            self.sac, env.example_obs(), self.seed
-        )
+        self.actor0, self.critic0 = self.seeded_params(env)
         state = self.dp.init_state(jax.random.key(0), env.example_obs())
         self.state = _common.with_params(state, self.actor0, self.critic0, self.rng0)
+        del state
         # Host copies: the burst donates the state these were placed into.
         self.actor0, self.critic0 = jax.device_get((self.actor0, self.critic0))
+        return env
+
+    def setup(self) -> None:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from torch_actor_critic_tpu.buffer.replay import init_replay_buffer
+        from torch_actor_critic_tpu.core.types import BufferState
+        from torch_actor_critic_tpu.sac import trainer  # noqa: F401 — timed as import
+
+        self.spans.lap("setup/import")
+        traffic = self.cell["traffic"]
+        env = self._build_learner()
 
         self.spans.lap("setup/build_learner")
         # The ring: per-device shards (n_dev, rows/n_dev, ...), sharded over
         # dp like init_sharded_buffer's, full, with the write pointer at 0.
+        # Its leaves are stored as the program's own ring stores them; the
+        # seeded rows are a transition's (harness/data.py).
         self.cap = traffic["ring_rows"] // self.n_dev
         one = jax.eval_shape(
             lambda: init_replay_buffer(self.cap, env.obs_spec, env.act_dim).data
         )
+        self.rows = data.transition_rows(one, env.obs_spec, env.act_dim)
         abstract = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct((self.n_dev,) + x.shape, x.dtype), one
         )
@@ -73,6 +90,7 @@ class Driver(_common.FollowedCall):
             data.data_key(self.seed, 2), abstract,
             slab=traffic.get("fill_slab_rows", 8192),
             shardings=jax.tree_util.tree_map(lambda _: dp_sharding, abstract),
+            rows=self.rows,
         )
         self.buffer = BufferState(
             data=ring,
@@ -84,11 +102,11 @@ class Driver(_common.FollowedCall):
         # The pool of staged windows: host numpy, made before the window.
         self.window_rows = self.cfg.update_every
         step_abs = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(
-                (self.n_dev, traffic["pool_windows"] * self.window_rows) + x.shape[2:],
-                x.dtype,
+            lambda row: jax.ShapeDtypeStruct(
+                (self.n_dev, traffic["pool_windows"] * self.window_rows) + row.shape,
+                row.dtype,
             ),
-            abstract,
+            self.rows,
         )
         pool = jax.device_get(
             data.fill_transitions(data.data_key(self.seed, 3), step_abs)
@@ -111,7 +129,7 @@ class Driver(_common.FollowedCall):
             draws.dp_burst_draws, static_argnums=(1, 2, 3, 4, 5)
         )(self.rng0, self.n_dev, self.n_updates, self.cfg.batch_size, env.act_dim, self.cap)
         self.idx = idx
-        self.pre_rows = _common.gather_rows(self.buffer.data, idx, (0, 1), 1)
+        self.pre_rows = _common.gather_rows(self.buffer.data, idx, self.rows, (0, 1), 1)
 
         self.spans.lap("setup/draws_and_rows")
         # First call: compiles, and is the call the reference follows.
@@ -163,14 +181,18 @@ class Driver(_common.FollowedCall):
             self.final["step"], self.final["ptr"], self.calls, self.n_updates,
             self.window_rows, self.cap,
         )
-        # Rows of the first call: the filled ring's, except where the first
-        # pushed chunk (at rows 0..49 of each shard) had already landed.
+        return out + self.compare_first_call(
+            mode, self.rows_of_first_call(), self.eps_q, self.eps_pi, False
+        )
+
+    def rows_of_first_call(self):
+        """The filled ring's rows at the first call's draws, except where the
+        first pushed chunk (at rows 0..n of each shard) had already landed."""
         from torch_actor_critic_tpu.sac.trainer import Trainer
 
         first_chunk = _common.batch_dict(Trainer._build_chunk(None, self.pool[0]))
         visible = jnp.full((self.n_updates,), self.window_rows)
-        rows = jax.jit(jax.vmap(
+        return jax.jit(jax.vmap(
             lambda p, ch, i: check.visible_rows(p, ch, i, 0, self.cap, visible),
             in_axes=(1, 0, 1), out_axes=1,
         ))(_common.batch_dict(self.pre_rows), first_chunk, self.idx)
-        return out + self.compare_first_call(mode, rows, self.eps_q, self.eps_pi, False)

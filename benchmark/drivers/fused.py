@@ -62,14 +62,17 @@ class Driver(_common.FollowedCall):
         self.actor0, self.critic0 = jax.device_get((actor0, critic0))
 
         self.spans.lap("setup/build_learner")
+        # Stored as the program's own ring stores it; the seeded rows are a
+        # transition's (harness/data.py).
         ring_abs = jax.eval_shape(
             lambda: jax.vmap(lambda _: self.loop.inner._init_buffer(self.cap, obs_spec))(
                 jnp.arange(self.n)
             ).data
         )
+        self.rows = data.transition_rows(ring_abs, obs_spec, self.act_dim)
         ring = data.fill_transitions(
             data.data_key(self.seed, 2), ring_abs,
-            slab=traffic.get("fill_slab_rows", 65536),
+            slab=traffic.get("fill_slab_rows", 65536), rows=self.rows,
         )
         self.buffer = BufferState(
             data=ring, ptr=jnp.zeros(self.n, jnp.int32),
@@ -84,16 +87,16 @@ class Driver(_common.FollowedCall):
         _, self.idx, self.eps_q, self.eps_pi = jax.jit(jax.vmap(
             lambda k: draws.burst_draws(k, n_updates, batch, self.act_dim, self.cap)
         ))(self.rng0)
-        self.pre_rows = _common.gather_rows(self.buffer.data, self.idx)
+        self.pre_rows = _common.gather_rows(self.buffer.data, self.idx, self.rows)
 
         self.spans.lap("setup/draws_and_rows")
         metrics = self._dispatch()
         self.spans.lap("setup/first_call")
         self.first = _common.learner_snapshot(self.state, metrics)
         self.pushed_per_call = (self.steps // self.every) * self.every * self.n_envs
-        self.first_pushed = jax.device_get(jax.tree_util.tree_map(
+        self.first_pushed = jax.device_get(data.as_rows(jax.tree_util.tree_map(
             lambda leaf: leaf[:, : self.pushed_per_call], self.buffer.data
-        ))
+        ), self.rows, 2))
         # The second dispatch takes the first one's outputs, whose placement
         # differs from the freshly made inputs': it is the one that settles
         # what the window runs (the first compiles a program of its own).

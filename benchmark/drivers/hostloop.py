@@ -93,14 +93,17 @@ class Driver(_common.FollowedCall):
         self.actor0, self.critic0 = jax.device_get((actor0, critic0))
 
         self.spans.lap("setup/build_trainer")
+        # Stored as the Trainer's own ring stores it; the seeded rows are a
+        # transition's (harness/data.py).
         ring_abs = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct((self.n, self.cap) + x.shape[2:], x.dtype),
             tr.buffer.data,
         )
+        self.rows = data.transition_rows(ring_abs, tr.pool.obs_spec, self.act_dim)
         tr.buffer = None
         ring = data.fill_transitions(
             data.data_key(self.seed, 2), ring_abs,
-            slab=traffic.get("fill_slab_rows", 65536),
+            slab=traffic.get("fill_slab_rows", 65536), rows=self.rows,
         )
         tr.buffer = BufferState(
             data=ring, ptr=jnp.zeros(self.n, jnp.int32),
@@ -113,7 +116,7 @@ class Driver(_common.FollowedCall):
                 k, self.cfg.updates_per_window, self.cfg.batch_size, self.act_dim, self.cap
             )
         ))(self.rng0)
-        self.pre_rows = _common.gather_rows(tr.buffer.data, self.idx)
+        self.pre_rows = _common.gather_rows(tr.buffer.data, self.idx, self.rows)
 
         self.spans.lap("setup/draws_and_rows")
         # First call: one epoch of one window, entered past start_steps and

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmark.drivers import _common, burst
-from benchmark.harness import check, data, draws, reference_trunk, trunk_weights
+from benchmark.harness import check, data, reference_trunk, trunk_weights
 
 TRUNK_KEYS = (
     "hidden", "q_heads", "kv_heads", "head_dim", "layers", "experts",
@@ -86,14 +86,9 @@ def worst_leaves(tree, ref_tree, top: int = 3) -> list:
 class Driver(burst.Driver):
     def __init__(self, cell, config, seed, spans, overrides=None):
         super().__init__(cell, config, seed, spans, overrides)
-        # ``overrides["rehearsal"]`` says whether this is a CPU rehearsal.
-        # ``main.run_cell`` hands a driver no word of its own ``rehearsal``
-        # (PERF.md section 7), so where nobody says, a process whose default
-        # backend is not the TPU rehearses: the published widths run nowhere
-        # else.
-        self.rehearsal = bool(
-            self.overrides.get("rehearsal", jax.default_backend() != "tpu")
-        )
+        # ``overrides["rehearsal"]``, as ``main.run_cell`` hands it, says whether
+        # this is a CPU rehearsal; where nobody says, it is the real thing.
+        self.rehearsal = bool(self.overrides.get("rehearsal", False))
         self.model, self.sac_fields = model_of(config, self.rehearsal)
 
     # ------------------------------------------------------------ set-up
@@ -110,94 +105,24 @@ class Driver(burst.Driver):
         fields.update(self.overrides.get("sac") or {})
         return SACConfig(**fields)
 
-    def setup(self) -> None:
-        from jax.sharding import NamedSharding, PartitionSpec as P
+    def learner_config(self):
+        return self.sac_config(), Spec(self.model)
 
-        from torch_actor_critic_tpu.buffer.replay import init_replay_buffer
-        from torch_actor_critic_tpu.core.types import BufferState
-        from torch_actor_critic_tpu.parallel.dp import DataParallelSAC
-        from torch_actor_critic_tpu.parallel.mesh import make_mesh
-        from torch_actor_critic_tpu.sac.trainer import build_models, make_learner
-
-        self.spans.lap("setup/import")
-        traffic = self.cell["traffic"]
-        self.n_dev = self.cell["chips"]
-        self.cfg = self.sac_config()
-        self.n_updates = self.cfg.updates_per_window
-        self.mesh = make_mesh(dp=self.n_dev, devices=jax.devices()[: self.n_dev])
-        env = Spec(self.model)
-        actor_def, critic_def = build_models(self.cfg, env)
-        self.sac = make_learner(self.cfg, actor_def, critic_def, env.act_dim)
-        self.dp = DataParallelSAC(self.sac, self.mesh)
-
-        self.rng0 = data.state_key(self.seed, 0)
-        self.actor0, self.critic0 = trunk_weights.seeded_params(
+    def seeded_params(self, env):
+        return trunk_weights.seeded_params(
             self.sac, env.example_obs(), data.state_key(self.seed, 1)
         )
-        state = self.dp.init_state(jax.random.key(0), env.example_obs())
-        self.state = _common.with_params(state, self.actor0, self.critic0, self.rng0)
-        del state
-        # Host copies: the burst donates the state these were placed into.
-        self.actor0, self.critic0 = jax.device_get((self.actor0, self.critic0))
 
-        self.spans.lap("setup/build_learner")
-        self.cap = traffic["ring_rows"] // self.n_dev
-        one = jax.eval_shape(
-            lambda: init_replay_buffer(self.cap, env.obs_spec, env.act_dim).data
-        )
-        abstract = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct((self.n_dev,) + x.shape, x.dtype), one
-        )
-        dp_sharding = NamedSharding(self.mesh, P("dp"))
-        ring = data.fill_transitions(
-            data.data_key(self.seed, 2), abstract, slab=traffic["fill_slab_rows"],
-            shardings=jax.tree_util.tree_map(lambda _: dp_sharding, abstract),
-        )
-        self.buffer = BufferState(
-            data=ring,
-            ptr=jax.device_put(np.zeros(self.n_dev, np.int32), dp_sharding),
-            size=jax.device_put(np.full(self.n_dev, self.cap, np.int32), dp_sharding),
-        )
+    @staticmethod
+    def at_rest_bytes(cell: dict, config: dict) -> int:
+        from benchmark.harness import flops_trunk
 
-        self.spans.lap("setup/fill_ring")
-        self.window_rows = self.cfg.update_every
-        step_abs = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(
-                (self.n_dev, traffic["pool_windows"] * self.window_rows) + x.shape[2:],
-                x.dtype,
-            ),
-            abstract,
-        )
-        pool = jax.device_get(data.fill_transitions(data.data_key(self.seed, 3), step_abs))
-        self.pool = [
-            [
-                tuple(
-                    jax.tree_util.tree_map(lambda x: x[:, w * self.window_rows + s], leaf)
-                    for leaf in (pool.states, pool.actions, pool.rewards,
-                                 pool.next_states, pool.done)
-                )
-                for s in range(self.window_rows)
-            ]
-            for w in range(traffic["pool_windows"])
-        ]
-
-        self.spans.lap("setup/chunk_pool")
-        _, idx, self.eps_q, self.eps_pi = jax.jit(
-            draws.dp_burst_draws, static_argnums=(1, 2, 3, 4, 5)
-        )(self.rng0, self.n_dev, self.n_updates, self.cfg.batch_size, env.act_dim, self.cap)
-        self.idx = idx
-        self.pre_rows = _common.gather_rows(self.buffer.data, idx, (0, 1), 1)
-
-        self.spans.lap("setup/draws_and_rows")
-        metrics = self._window(0)
-        self.spans.lap("setup/first_call")
-        self.first = _common.learner_snapshot(self.state, metrics)
-        self.first_choices = jax.device_get(metrics["trunk/choices_first"])
-        self._window(1)
-        self.spans.lap("setup/second_call")
+        return flops_trunk.at_rest_bytes(config["model"], cell["traffic"]["ring_rows"])
 
     def _window(self, i: int):
         m = super()._window(i)
+        if i == 0:  # the call the reference follows
+            self.first_choices = jax.device_get(m["trunk/choices_first"])
         self.counters = {k: v for k, v in m.items() if k.startswith("trunk/") and v.ndim == 0}
         return m
 
@@ -206,16 +131,6 @@ class Driver(burst.Driver):
         return {k: float(v) for k, v in jax.device_get(self.counters).items()}
 
     # ------------------------------------------------------------- check
-    def _rows_of_first_call(self):
-        from torch_actor_critic_tpu.sac.trainer import Trainer
-
-        first_chunk = _common.batch_dict(Trainer._build_chunk(None, self.pool[0]))
-        visible = jnp.full((self.n_updates,), self.window_rows)
-        return jax.jit(jax.vmap(
-            lambda p, ch, i: check.visible_rows(p, ch, i, 0, self.cap, visible),
-            in_axes=(1, 0, 1), out_axes=1,
-        ))(_common.batch_dict(self.pre_rows), first_chunk, self.idx)
-
     def _follow(self, mode: str) -> dict:
         """The reference's account of the first call at ``mode``."""
         if mode not in self._followed:
@@ -270,7 +185,7 @@ class Driver(burst.Driver):
             self.final["step"], self.final["ptr"], self.calls, self.n_updates,
             self.window_rows, self.cap,
         )
-        self._rows, self._followed = self._rows_of_first_call(), {}
+        self._rows, self._followed = self.rows_of_first_call(), {}
         return out + self._compare(self.first, self._follow(mode), self.first_choices)
 
     def control(self, low: str, mode: str):

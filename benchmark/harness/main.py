@@ -76,7 +76,7 @@ def run_cell(
     watchdog = get_watchdog().install()
     spans = spans_mod.Spans(annotate=trace)
     driver = registry.load_driver(cell["driver"], bench_dir)(
-        cell, config, seed, spans
+        cell, config, seed, spans, {"rehearsal": rehearsal}
     )
     driver.setup()
     wd_setup = watchdog.snapshot()
@@ -153,7 +153,6 @@ def run_cell(
         "correct": bool(correct), "attempted": len(windows),
         "failed": 0 if correct else len(windows), "metrics": metrics,
         "device": device, "workload": cell["name"], "seed": seed,
-        "comparisons": {c.name: [c.value, c.limit] for c in comparisons},
     }
     if rehearsal:
         result["rehearsal"] = "cpu"
@@ -163,6 +162,8 @@ def run_cell(
         result["device_kinds_s"] = sorted(
             summary["by_kind"].items(), key=lambda kv: -kv[1]
         )[:25]
+    # last in the line: each number compared, beside its limit
+    result["comparisons"] = {c.name: [c.value, c.limit] for c in comparisons}
     return result
 
 
@@ -197,4 +198,6 @@ def main(argv: t.Sequence[str] | None = None, t_process: float = T_PROCESS) -> i
         trace=bool(args.trace), t_process=t_process,
     )
     print(json.dumps(result), flush=True)
+    for name, (value, limit) in result["comparisons"].items():  # the last lines on stderr
+        print(f"check {name}: {value!r} against {limit!r}", file=sys.stderr, flush=True)
     return 0

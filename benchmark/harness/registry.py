@@ -4,6 +4,11 @@ A later PR adds files and entries and edits none: a configuration is
 ``configs/<config>.json``, a cell is ``workloads/<cell>.json``, a driver is
 ``drivers/<driver>.py`` and a per-layer metric is
 ``layer_metrics/<metric>.py``.  Nothing here names a particular one of them.
+
+A cell the check cannot hold yet is *parked*: its files stay, and
+``parked/<cell>.json`` keeps the entries ``BENCHMARK.json`` had for it, so that
+a later PR puts it back by adding those entries and nothing else.  A run never
+sees a parked cell; the tests rehearse it all the same (``parked=True``).
 """
 
 from __future__ import annotations
@@ -34,8 +39,24 @@ def _load_json(path: str) -> dict:
         return json.load(f)
 
 
-def load_benchmark(root: str = ROOT) -> dict:
-    return _load_json(os.path.join(root, "BENCHMARK.json"))
+def load_benchmark(root: str = ROOT, parked: bool = False) -> dict:
+    """``BENCHMARK.json``; with ``parked``, the parked cells' entries merged
+    into it: a metric the file still has gets the parked cell appended to its
+    ``workloads`` (and keeps the file's bound), any other entry is appended."""
+    bench = _load_json(os.path.join(root, "BENCHMARK.json"))
+    parked_dir = os.path.join(root, bench["paths"][0], "parked")
+    if not parked or not os.path.isdir(parked_dir):
+        return bench
+    for name in sorted(os.listdir(parked_dir)):
+        entries = _load_json(os.path.join(parked_dir, name))
+        for section in ("workloads", "end_to_end", "per_layer"):
+            for entry in entries.get(section, []):
+                kept = next((e for e in bench[section] if e["name"] == entry["name"]), None)
+                if kept is None:
+                    bench[section].append(entry)
+                else:
+                    kept["workloads"] = kept["workloads"] + entry["workloads"]
+    return bench
 
 
 def _module(path: str, name: str):
@@ -97,9 +118,11 @@ def metrics_for(bench: dict, section: str, cell: str) -> t.List[dict]:
     ]
 
 
-def resolve(cell_name: str, root: str = ROOT) -> t.Tuple[dict, dict, dict]:
+def resolve(
+    cell_name: str, root: str = ROOT, parked: bool = False
+) -> t.Tuple[dict, dict, dict]:
     """(benchmark, workload entry merged with its file, configuration)."""
-    bench = load_benchmark(root)
+    bench = load_benchmark(root, parked)
     entry = next((w for w in bench["workloads"] if w["name"] == cell_name), None)
     if entry is None:
         raise BenchmarkError(

@@ -23,11 +23,22 @@ operation of another program, one the table does not know, one with no
 ``tac/`` name, or a fusion that spans two groups (no group holds nine tenths
 of its scoped instructions).
 
+The groups partition the *leaf* time: the sum of the operations that are no
+containers.  The trace's ``busy_s`` is more than that: it is the union of all
+events, and a container (the burst's ``while``) is busy from its first
+operation to its last, the few tens of nanoseconds between two operations
+included.  That remainder, ``container_gap_s``, is nobody's: it is booked to no
+group, it is reported on its own, and it is a fixed cost an operation, so its
+share of ``busy_s`` grows when a program sheds its long operations.  Readers
+that take a share of ``busy_s`` (``trace.unscoped_share``, ``update.mfu``,
+``update.compute_mfu``, ``trunk.mfu``) keep it in their denominator and say so.
+
 ``summary(ctx)`` is what a reader calls.  It answers ``None`` where there is
 nothing to read: no trace, a trace at the default path that is not this run's,
-a program that has no scope table yet.  A table without a single ``tac/`` name
-is an error (a compile cache that handed back another commit's program), never
-a reading of 100% unscoped.
+a program that has no scope table yet; and where the join lost or doubled
+device time (``identity_gap``).  A table without a single ``tac/`` name is an
+error (a compile cache that handed back another commit's program), never a
+reading of 100% unscoped.
 """
 
 from __future__ import annotations
@@ -59,7 +70,10 @@ DATA_MOVEMENT = frozenset({
     "scatter", "dynamic-slice", "dynamic-update-slice", "reshape", "transpose",
     "bitcast", "slice", "concatenate", "pad", "broadcast",
 })
-IDENTITY = 0.02  # the groups' sum against the trace's busy_s
+# How far this reduction may stand from the trace's own (harness/trace.py), on
+# the two quantities both take from the same events: the groups' sum against
+# the trace's sum by operation kind, the busy union against the trace's.
+IDENTITY = 0.02
 # A fusion belongs to a group that holds this share of its scoped instructions:
 # the compiler folds one cast of the sampled batch into a 400-instruction
 # convolution fusion, which stays compute; a gather fused half and half with
@@ -144,9 +158,39 @@ def load(path: str) -> dict:
     return {"devices": devices, "windows": windows, "host": host}
 
 
+def container_gaps(
+    leaves: t.Sequence[trace_mod.Interval],
+    containers: t.Sequence[t.Tuple[float, float, str]],
+) -> dict:
+    """One device's events, clipped to the window: ``leaves`` (the operations
+    that are no containers) and ``containers`` ``(start, end, name)``.  The
+    busy union, the union of the leaves, and what lies between the two by the
+    innermost container over each gap's middle."""
+    covered = trace_mod.union(leaves)
+    containers = sorted(containers)
+    busy = trace_mod.union(covered + [(a, b) for a, b, _ in containers])
+    by: t.Dict[str, float] = {}
+    gaps = trace_mod.subtract(busy, covered)
+    open_, j = [], 0
+    for a, b in gaps:
+        mid = 0.5 * (a + b)
+        while j < len(containers) and containers[j][0] <= mid:
+            open_.append(containers[j])
+            j += 1
+        while open_ and open_[-1][1] <= mid:
+            open_.pop()
+        owner = open_[-1][2] if open_ else "no_container"
+        by[owner] = by.get(owner, 0.0) + (b - a)
+    return {
+        "busy_s": trace_mod.total(busy), "leaf_union_s": trace_mod.total(covered),
+        "intervals": len(gaps), "by_container": by,
+    }
+
+
 def reduce(loaded: dict, scoped: dict | None, window: t.Tuple[float, float]) -> dict:
     """Seconds by group (averaged over chips), what the unscoped time is made
-    of, and the host's seconds and spans by phase, all inside ``window``."""
+    of, the busy time no leaf operation covers, and the host's seconds and
+    spans by phase, all inside ``window``."""
     scoped = check_table(scoped)
     table, module = scoped["table"], scoped["module"] + "("
     lo, hi = window
@@ -158,36 +202,57 @@ def reduce(loaded: dict, scoped: dict | None, window: t.Tuple[float, float]) -> 
     unscoped_ops: t.Dict[str, float] = {}
     reasons: t.Dict[str, float] = {}
     not_compute = 0.0
+    busy_s = leaf_union_s = 0.0
+    gap_intervals = 0
+    gap_by: t.Dict[str, float] = {}
+    known: t.Dict[str, t.Any] = {}  # an event's name -> what the name alone says of it
+
+    def classify(name: str):
+        if trace_mod.is_container(name):
+            return None
+        counts = table.get(instruction_name(name))
+        group, reason = (UNSCOPED, "not_in_table") if counts is None else group_of(counts)
+        named = group != UNSCOPED and not any(k.endswith(INHERITED) for k in counts)
+        scope = None if group == UNSCOPED else max((s_ for s_ in counts if s_), key=counts.get)
+        return group, reason, named, scope, opcode(name) in DATA_MOVEMENT
+
     for dev in loaded["devices"].values():
         runs = sorted((s, s + d) for name, s, d in dev["modules"] if name.startswith(module))
         starts = [ra for ra, _ in runs]
+        leaves, containers = [], []
         for name, s, d in dev["ops"]:
             a, b = max(s, lo), min(s + d, hi)
-            if b <= a or trace_mod.is_container(name):
+            if b <= a:
                 continue
-            counts = table.get(instruction_name(name))
+            if name not in known:
+                known[name] = classify(name)
+            what = known[name]
+            if what is None:
+                containers.append((a, b, name))
+                continue
+            leaves.append((a, b))
+            group, reason, named, scope, moves_data = what
             i = bisect.bisect_right(starts, s) - 1
             if i < 0 or s >= runs[i][1]:
-                group, reason = UNSCOPED, "other_program"
-            elif counts is None:
-                group, reason = UNSCOPED, "not_in_table"
-            else:
-                group, reason = group_of(counts)
+                group, reason, named = UNSCOPED, "other_program", False
             device[group] += (b - a) / n
-            named = group != UNSCOPED and not any(k.endswith(INHERITED) for k in counts)
             if group == UNSCOPED:
                 short = name[:96]
                 unscoped_ops[short] = unscoped_ops.get(short, 0.0) + (b - a) / n
                 reasons[reason] = reasons.get(reason, 0.0) + (b - a) / n
             else:
-                scope = max((s_ for s_ in counts if s_), key=counts.get)
                 by_scope[scope] = by_scope.get(scope, 0.0) + (b - a) / n
                 if not named:
                     inherited[group] += (b - a) / n
-            if (named and group in ("push", "sample")) or (
-                not named and opcode(name) in DATA_MOVEMENT
-            ):
+            if (named and group in ("push", "sample")) or (not named and moves_data):
                 not_compute += (b - a) / n
+        gaps = container_gaps(leaves, containers)
+        busy_s += gaps["busy_s"] / n
+        leaf_union_s += gaps["leaf_union_s"] / n
+        gap_intervals += gaps["intervals"]
+        for owner, v in gaps["by_container"].items():
+            owner = instruction_name(owner)
+            gap_by[owner] = gap_by.get(owner, 0.0) + v / n
     host: t.Dict[str, float] = {}
     host_spans: t.Dict[str, int] = {}
     for name, s, d in loaded["host"]:
@@ -196,17 +261,34 @@ def reduce(loaded: dict, scoped: dict | None, window: t.Tuple[float, float]) -> 
             phase = name[len(HOST_PREFIX):]
             host[phase] = host.get(phase, 0.0) + (b - a)
             host_spans[phase] = host_spans.get(phase, 0) + 1
+    leaf_s = sum(device.values())
     return {
-        "device": device, "inherited": inherited, "leaf_s": sum(device.values()),
+        "device": device, "inherited": inherited, "leaf_s": leaf_s,
+        "busy_s": busy_s, "container_gap_s": busy_s - leaf_union_s,
+        "leaf_overlap_s": leaf_s - leaf_union_s,
+        "container_gap": {
+            "intervals": gap_intervals,
+            "by_container": sorted(gap_by.items(), key=lambda kv: -kv[1])[:6],
+        },
         "by_scope": by_scope, "not_compute_s": not_compute, "unscoped_reasons": reasons,
         "unscoped_ops": sorted(unscoped_ops.items(), key=lambda kv: -kv[1])[:12],
         "host": host, "host_spans": host_spans, "window_s": hi - lo,
     }
 
 
-def identity_gap(summary: dict, busy_s: float) -> float:
-    """How far the groups' sum is from the busy union, as a share of it."""
-    return abs(summary["leaf_s"] - busy_s) / busy_s if busy_s else 0.0
+def identity_gap(summary: dict, trace_summary: dict) -> float:
+    """How far the join stands from the trace's own reduction, as a share: the
+    groups' sum against the trace's sum of leaf operations (the quantity the
+    groups partition), and this reduction's busy union against the trace's.
+    A join that loses or doubles device time (events outside the program's
+    runs dropped, another clipping at the window's edges, two devices averaged
+    otherwise) fails the first, a trace that is not the one reduced the second.
+    The time under a container that no leaf covers is in neither."""
+    pairs = (
+        (summary["leaf_s"], sum(trace_summary["by_kind"].values())),
+        (summary["busy_s"], trace_summary["busy_s"]),
+    )
+    return max((abs(got - ref) / ref if ref else 0.0) for got, ref in pairs)
 
 
 def trace_path(cell_name: str) -> str | None:
@@ -252,17 +334,25 @@ def _summary(ctx) -> dict | None:
     scoped = make_table()
     out = reduce(loaded, scoped, window)
     out["table_compile_s"] = time.perf_counter() - t0
-    out["identity_gap"] = identity_gap(out, ctx.trace["busy_s"])
+    out["identity_gap"] = identity_gap(out, ctx.trace)
+    # per unit of work as the cell's readers count it: a scan iteration of a
+    # fused epoch, else a gradient step
+    per = ctx.n_windows * (ctx.per_window.get("iterations") or ctx.per_window["grad_steps"])
+    out["container_gap_us_per_step"] = 1e6 * out["container_gap_s"] / per if per else None
     print("scopes: " + json.dumps({
         k: out[k] for k in (
             "device", "inherited", "by_scope", "unscoped_reasons", "not_compute_s",
             "unscoped_ops", "host", "host_spans", "table_compile_s", "identity_gap",
+            "leaf_s", "busy_s", "container_gap_s", "container_gap_us_per_step",
+            "container_gap", "leaf_overlap_s",
         )
     }), flush=True)
     return out if out["identity_gap"] <= IDENTITY else None
 
 
 def group_us(ctx, group: str, per: float) -> float | None:
-    """Device microseconds of ``group`` over ``per`` units of work."""
+    """Device microseconds of ``group`` over ``per`` units of work: leaf
+    operations alone, so the groups of a run sum to ``leaf_s``, which is
+    ``busy_s`` less ``container_gap_s`` where no two operations overlap."""
     s = summary(ctx)
     return 1e6 * s["device"][group] / per if s is not None and per else None

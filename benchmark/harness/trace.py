@@ -11,6 +11,7 @@ opens, found on the host plane's lines on the same clock.
 
 from __future__ import annotations
 
+import functools
 import glob
 import os
 import re
@@ -28,6 +29,7 @@ def find_xplane(trace_dir: str) -> str | None:
     return found[-1] if found else None
 
 
+@functools.lru_cache(maxsize=1 << 16)  # a trace names each instruction once a step
 def op_kind(name: str) -> str:
     """``%convolution.12`` -> ``convolution``; fusions keep their flavour
     (``copy_fusion``, ``convolution_fusion``) as the trace names it."""

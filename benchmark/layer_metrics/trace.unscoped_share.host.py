@@ -1,7 +1,10 @@
 """Share of the device's busy time that no ``tac/`` scope names: operations of
 other programs, operations the scope table does not know or gives no scope,
-and fusions that span two groups.  What the instrument still cannot name.  Under a name of its own for the cell
-whose end-to-end metric is env steps."""
+and fusions that span two groups.  What the instrument still cannot name.
+Denominator: the trace's ``busy_s`` (the union of all events), not the leaf
+time the groups partition: the busy time under a container that no operation
+covers (``container_gap_s``) is in it and in no group, this one included.
+Under a name of its own for the cell whose end-to-end metric is env steps."""
 
 from benchmark.harness import scopes
 

@@ -1,7 +1,8 @@
 """Model FLOP/s utilization of the trunk's update while the device is busy:
 the FLOPs a step at the counted assignments (``harness/flops_trunk.py``:
 two trunk passes, one backward pass, recomputation not counted) over the
-device-busy time a step, against the table's bf16 peak."""
+device-busy time a step, against the table's bf16 peak.  Denominator: the
+trace's ``busy_s``, the union of all events."""
 
 from benchmark.harness import flops_trunk, trunk_read
 

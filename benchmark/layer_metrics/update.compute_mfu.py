@@ -3,7 +3,12 @@ step (harness/flops.py) over all device-busy time not proven to be something
 else, against the table's bf16 peak.  Proven: an operation the program itself
 scoped as push or sample, and a data-movement opcode (copy, slice, gather ...)
 with no scope of its own.  Time the instrument cannot name, and time it names
-only by inheritance, stays in the denominator, so the figure can err only low."""
+only by inheritance, stays in the denominator, so the figure can err only low.
+
+Denominator: the trace's ``busy_s`` (the union of all events) less
+``not_compute_s``.  The busy time under the burst's loop that no operation
+covers (``container_gap_s`` of harness/scopes.py) is part of ``busy_s`` and is
+not proven to be anything, so it stays in the denominator too."""
 
 from benchmark.harness import flops, peaks, scopes
 

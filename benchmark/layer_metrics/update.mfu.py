@@ -1,6 +1,9 @@
 """Model FLOP/s utilization of the update program while the device is busy:
 the benchmark's FLOPs a step (harness/flops.py, a chip's own batch) over the
-device-busy time a step, against the table's bf16 peak."""
+device-busy time a step, against the table's bf16 peak.
+
+Denominator: the trace's ``busy_s``, the union of all events, the time under a
+container that no operation covers included."""
 
 from benchmark.harness import flops, peaks
 

@@ -23,6 +23,7 @@ def test_rehearse_cell_on_cpu(cell_name, trace, tmp_path):
     )
     line = json.loads(json.dumps(result))  # the last line a run prints parses
     assert set(line) >= {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(result)[-1] == "comparisons"  # each number beside its limit, last in the line
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 1
     assert line["rehearsal"] == "cpu" and line["device"]["platform"] == "cpu"
     assert line["metrics"] and all(k.startswith("cpu_rehearsal.") for k in line["metrics"])

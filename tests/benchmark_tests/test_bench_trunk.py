@@ -33,9 +33,8 @@ WIDTH_ENDINGS = (  # a vocabulary's size is no width
 
 
 def test_configuration_keeps_every_published_width():
-    """What ``test_configuration_file`` asserts for a configuration, with the
-    width rule by key endings (see ``conftest.py``), and the file against the
-    published numbers: only what ``reduced`` names differs."""
+    """What ``test_configuration_file`` asserts for a configuration, and the
+    file against the published numbers: only what ``reduced`` names differs."""
     bench = registry.load_benchmark()
     entry = next(c for c in bench["configs"] if c["name"] == "sdar30b_a3b_trunk")
     assert set(entry) == {"name", "source", "file", "reduced", "why"}
@@ -89,8 +88,8 @@ def test_cell_entry_names_its_traffic():
 
 def test_the_trunk_cell_holds_a_quarter_of_the_chip_at_rest():
     """The contract's memory floor for this cell, by its own arithmetic: the
-    trunk, its polyak target and Adam's moments, and the ring of histories.
-    (The accepted rule counts rings alone; ``conftest.py``.)"""
+    trunk, its polyak target and Adam's moments, and the ring of histories
+    (``trunkburst.Driver.at_rest_bytes`` is this arithmetic)."""
     _, cell, config = registry.resolve(CELL)
     model = config["model"]
     assert flops_trunk.row_bytes(model) == 139_296
@@ -234,9 +233,10 @@ def test_disagree_share_counts_assignments_not_order():
     assert disagree_share(a.astype(np.float32), b) == pytest.approx(1 / 8)
 
 
-def test_benchmark_json_only_appends():
-    """Every entry the parent commit's BENCHMARK.json has is still there, in
-    place and unchanged but for ``workloads`` lists that grew at their end."""
+def test_benchmark_json_keeps_or_parks_every_entry():
+    """Every entry the BENCHMARK.json of PR 25 had is still there, in the file
+    or parked beside it (``benchmark/parked/``), unchanged but for ``workloads``
+    lists that grew and a bound that shrank."""
     import subprocess
 
     old = subprocess.run(
@@ -244,14 +244,18 @@ def test_benchmark_json_only_appends():
         capture_output=True, text=True, cwd=ROOT,
     )
     if old.returncode:
-        pytest.skip("the parent commit is not in this checkout")
-    old, new = json.loads(old.stdout), registry.load_benchmark()
+        pytest.skip("that commit is not in this checkout")
+    old, new = json.loads(old.stdout), registry.load_benchmark(parked=True)
     for key in ("command", "paths", "run_seconds"):
         assert old[key] == new[key]
     for section in ("configs", "workloads", "end_to_end", "per_layer"):
-        for was, now in zip(old[section], new[section]):
-            grown = dict(now)
+        for was in old[section]:
+            (now,) = [e for e in new[section] if e["name"] == was["name"]]
+            same = dict(now)
             if "workloads" in was:
-                assert now["workloads"][: len(was["workloads"])] == was["workloads"]
-                grown["workloads"] = was["workloads"]
-            assert grown == was
+                assert set(was["workloads"]) <= set(now["workloads"])
+                same["workloads"] = was["workloads"]
+            if "bound" in was:
+                assert now["bound"] <= was["bound"]
+                same["bound"] = was["bound"]
+            assert same == was
