@@ -29,6 +29,29 @@ re-designed for TPU:
   relays every whole ring leaf into a layout of the scatter's own and
   back, once a window, which was 80% of the visual burst's device time
   and 95% of the population programs' (PERF.md, PR 24 and PR 25).
+- **A row rests in the form the gather reads and the push writes**
+  (:func:`stored_row_shape`). The TPU lays an array out in tiles of
+  8 x 128 words over its two minor axes, and no axis of a
+  ``(64, 64, 3)`` uint8 picture fills the 128 lanes: left in that
+  shape, a frame leaf ``(capacity, 64, 64, 3)`` rests with the *rows*
+  in the lanes, the gather wants the picture there, and the compiler
+  bridged the two with a copy of each frame leaf a window, padded
+  twofold: 1,216 of the visual burst's 1,709 us a step and 9.4 GiB of
+  its scratch (PERF.md, PR 28). So a picture whose bytes are whole
+  tiles is stored ``(capacity, bytes / 128, 128)``: tiles of its own,
+  row after row, nothing padded. ``push`` reshapes the chunk it is
+  given (pinned row-major, see :func:`_as_stored`) and updates in
+  place; ``sample`` gathers whole rows as they lie; the learner shapes
+  the sampled batch back (:func:`as_observations`). The burst then
+  holds no instruction that passes over a frame leaf
+  (``tests/test_chip_compile.py``): 426 us a step, of which the gather
+  37 and the push 4, and 0.26 GiB of scratch (PERF.md, PR 30). Every
+  other row rests in its own shape, as before: a vector narrower than a
+  tile has no unpadded form with the row contiguous, the compiler lays
+  it rows-in-lanes and converts what the gather reads once a window
+  (14 us a step for the visual burst's feature leaves; half of the
+  fused population epoch's sampling: PERF.md section 7 has what was
+  tried).
 - **Sampling is uniform with replacement** (``randint`` + ``take``).
   The reference samples *without* replacement via ``random.sample``
   (ref ``replay_buffer.py:46``); at 1e6-slot buffers and batch 64 the
@@ -51,6 +74,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.custom_batching import custom_vmap
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from torch_actor_critic_tpu.buffer.striped import (
     StripedBufferState,
@@ -61,6 +85,26 @@ from torch_actor_critic_tpu.core.types import Batch, BufferState, MultiObservati
 from torch_actor_critic_tpu.telemetry import scopes
 
 
+LANES = 128  # the minor axis of a TPU tile, in elements
+SUBLANES = 8  # its other axis, in 32-bit words
+
+
+def stored_row_shape(row_shape: t.Sequence[int], dtype) -> t.Tuple[int, ...]:
+    """The shape one row of a ring leaf rests in (module docstring).
+
+    A row of three or more axes (a picture) whose elements fill whole
+    tiles (8 x 128 words of 32 bits: 4,096 uint8, 1,024 float32) rests
+    as ``(elements / 128, 128)``: tiles of its own, row after row.
+    Every other row rests in its own shape.
+    """
+    row_shape = tuple(int(d) for d in row_shape)
+    elements = int(np.prod(row_shape))
+    tile = LANES * SUBLANES * max(4 // jnp.dtype(dtype).itemsize, 1)
+    if len(row_shape) >= 3 and elements % tile == 0:
+        return (elements // LANES, LANES)
+    return row_shape
+
+
 def _zeros_like_spec(capacity: int, spec: t.Any) -> t.Any:
     """Build zeroed ring arrays from a pytree of (shape, dtype) specs.
 
@@ -68,7 +112,10 @@ def _zeros_like_spec(capacity: int, spec: t.Any) -> t.Any:
     ``jax.ShapeDtypeStruct`` or a concrete example array).
     """
     return jax.tree_util.tree_map(
-        lambda s: jnp.zeros((capacity,) + tuple(s.shape), s.dtype), spec
+        lambda s: jnp.zeros(
+            (capacity,) + stored_row_shape(s.shape, s.dtype), s.dtype
+        ),
+        spec,
     )
 
 
@@ -165,7 +212,8 @@ def init_replay_buffer(
     ``obs_spec`` is a pytree of ``jax.ShapeDtypeStruct`` (or example
     arrays) describing ONE observation — a flat vector for MLP envs
     (ref ``replay_buffer.py:19-23``) or a ``MultiObservation`` spec for
-    pixel envs.
+    pixel envs. A leaf holds ``capacity`` rows in the shape
+    :func:`stored_row_shape` gives a row of it.
     """
     data = Batch(
         states=_zeros_like_spec(capacity, obs_spec),
@@ -222,6 +270,21 @@ def _write_rows_of_members(axis_size, in_batched, ring, rows, at):
     return ring, True
 
 
+def _as_stored(rows: jax.Array, ring: jax.Array) -> jax.Array:
+    """``rows`` (transitions, or rows as stored) in the shape a row of
+    ``ring`` rests in.  Rows that change shape on the way are pinned
+    row-major: left to itself the compiler carries the chunk's own
+    layout (a picture's rows in the lanes) through the reshape into the
+    update and from there onto the whole ring, which it then relays for
+    the push and back for the gather, once a window (PERF.md, PR 30)."""
+    if rows.shape[1:] == ring.shape[1:]:
+        return rows
+    rows = rows.reshape(rows.shape[:1] + ring.shape[1:])
+    return with_layout_constraint(
+        rows, Layout(major_to_minor=tuple(range(rows.ndim)))
+    )
+
+
 @jax.named_scope(scopes.PUSH)
 def push(state: BufferState, chunk: Batch) -> BufferState:
     """Append a chunk of ``n`` transitions, overwriting oldest on wrap.
@@ -232,7 +295,8 @@ def push(state: BufferState, chunk: Batch) -> BufferState:
     saturates at capacity. ``n`` must be static (it is: the trainer
     always pushes ``update_every``-sized chunks). The rows are written
     in place, contiguously, wrap-around included; no scatter, so the
-    ring keeps the layout it rests in (module docstring).
+    ring keeps the layout it rests in (module docstring). A row of the
+    chunk may come in a transition's shape or in the stored one.
 
     A striped (per-task) ring dispatches to
     :func:`~torch_actor_critic_tpu.buffer.striped.push_striped` — the
@@ -262,7 +326,7 @@ def push(state: BufferState, chunk: Batch) -> BufferState:
     head = jnp.arange(n) >= wrapped
 
     def write(ring, new):
-        new = new.astype(ring.dtype)
+        new = _as_stored(new.astype(ring.dtype), ring)
         # seam[wrapped + j] = new[j]: where ``head``, seam[:n] is the
         # window at ``start``; elsewhere seam[n:] is the window at 0.
         seam = _write_rows(jnp.concatenate([new, new]), new, wrapped)
@@ -282,18 +346,55 @@ def push(state: BufferState, chunk: Batch) -> BufferState:
     )
 
 
+def observation_spec(example_obs: t.Any) -> t.Any:
+    """Shape and dtype of every leaf of one observation: what a learner
+    keeps of the observation it was initialised on, so that sampled
+    rows reach it in that shape however the ring stores them."""
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x)),
+        example_obs,
+    )
+
+
+def _as_observed(rows: jax.Array, one: t.Any) -> jax.Array:
+    """Sampled ``rows`` of one leaf in the shape ``one`` (its entry of an
+    ``obs_spec``) has, where they lie in the form that shape rests in."""
+    if rows.shape[1:] != stored_row_shape(one.shape, one.dtype):
+        return rows
+    return rows.reshape(rows.shape[:1] + tuple(one.shape))
+
+
+@jax.named_scope(scopes.SAMPLE)
+def as_observations(batch: Batch, obs_spec: t.Any) -> Batch:
+    """``batch`` as :func:`sample` hands it on (rows as stored) with
+    every row of ``states`` and ``next_states`` that rests in another
+    shape than its own (:func:`stored_row_shape`) back in the one
+    ``obs_spec`` (:func:`observation_spec`) gives it.  With no
+    ``obs_spec`` the batch is handed on as it is, which is right only
+    where every row rests in its own shape."""
+    if obs_spec is None:
+        return batch
+
+    shaped = lambda obs: jax.tree_util.tree_map(_as_observed, obs, obs_spec)  # noqa: E731
+    return batch.replace(
+        states=shaped(batch.states), next_states=shaped(batch.next_states)
+    )
+
+
 @jax.named_scope(scopes.SAMPLE)
 def sample(state: BufferState, key: jax.Array, batch_size: int) -> Batch:
     """Draw a uniform batch over the valid region ``[0, size)``.
 
     With replacement (deliberate deviation from ref
     ``replay_buffer.py:46``, see module docstring). The gathers are
-    plain ``jnp.take``. On the v5e the gather itself is cheap (29 us
-    a step in the visual burst), but it does not read a ring leaf in
-    the layout the leaf rests in (rows minor-most): the compiler makes
-    one copy of each gathered leaf a window into the gather's layout,
-    30 ms for a ``u8[200000, 64, 64, 3]`` leaf, and that copy is what
-    is left of the ring's traffic after PR 25 (PERF.md sections 5, 7).
+    plain ``jnp.take`` of whole rows **as they are stored**: a frame
+    that rests in tiles leaves as ``(batch, bytes / 128, 128)`` and
+    :func:`as_observations` gives it its shape back (``run_update_burst``
+    does, with the learner's ``obs_spec``). On the v5e a frame row is
+    twelve contiguous kilobytes and the whole sample is 37 us a step in
+    the visual burst (PERF.md, PR 30); until PR 30 the compiler copied
+    each frame leaf into the gather's layout once a window, 30 ms for
+    ``u8[200000, 64, 64, 3]``.
 
     An empty buffer raises eagerly; under ``jit`` the size is traced and
     cannot be checked, so the index range is clamped to ``[0, 1)`` —
@@ -323,6 +424,7 @@ def sample_fused_visual(
     normalize: bool = False,
     impl: str = "auto",
     interpret: bool = False,
+    obs_spec: t.Any = None,
 ) -> Batch:
     """:func:`sample` for visual batches through the fused pixel
     pipeline (``ops/pixels.py``): non-frame leaves gather exactly like
@@ -330,6 +432,10 @@ def sample_fused_visual(
     ``out_dtype`` inside the fused gather, so the sampled frame batch
     never materializes as float32 in HBM (bf16 halves its footprint
     besides).
+
+    The frame rows are gathered as stored, like :func:`sample`'s, and
+    the pipeline is handed the sampled frames in ``obs_spec``'s shape
+    (in the stored one without it): a gather of a gather, the same bits.
 
     Key discipline: with ``augment="none"`` the row draw consumes
     ``key`` exactly as :func:`sample` does, so at ``out_dtype=float32``
@@ -362,12 +468,17 @@ def sample_fused_visual(
         k_idx, (batch_size,), 0, jnp.maximum(state.size, 1)
     )
     take = lambda ring: jnp.take(ring, idx, axis=0)  # noqa: E731
-    gather = lambda ring, offs: jax.named_scope(scopes.DECODE)(  # noqa: E731
-        fused_frame_gather
-    )(
-        ring, idx, offsets=offs, pad=pad, normalize=normalize,
-        out_dtype=out_dtype, impl=impl, interpret=interpret,
-    )
+    rows = jnp.arange(batch_size)
+
+    def gather(ring, offs):
+        frames = take(ring)
+        if obs_spec is not None:
+            frames = _as_observed(frames, obs_spec.frame)
+        return jax.named_scope(scopes.DECODE)(fused_frame_gather)(
+            frames, rows, offsets=offs, pad=pad, normalize=normalize,
+            out_dtype=out_dtype, impl=impl, interpret=interpret,
+        )
+
     d = state.data
     return Batch(
         states=MultiObservation(

@@ -32,6 +32,8 @@ import optax
 from flax import linen as nn
 
 from torch_actor_critic_tpu.buffer.replay import (
+    as_observations,
+    observation_spec,
     push,
     sample,
     sample_fused_visual,
@@ -124,6 +126,10 @@ class SAC:
             if config.target_entropy is not None
             else -float(act_dim)
         )
+        # One observation's shapes, learned in init_state: sampled rows
+        # reach the update in them however the ring stores a row
+        # (buffer/replay.py). None until then: rows arrive as stored.
+        self.obs_spec = None
 
     def default_hyperparams(self) -> t.Dict[str, jax.Array]:
         """The PBT-perturbable hyperparameters as scalar arrays, at
@@ -154,6 +160,7 @@ class SAC:
         functional analogue of ``deepcopy(critic)`` at train start
         (ref ``sac/algorithm.py:194-196``).
         """
+        self.obs_spec = observation_spec(example_obs)
         k_actor, k_critic, k_sample, k_state = jax.random.split(key, 4)
         example_act = jnp.zeros((self.act_dim,))
         critic_params = self.critic_def.init(k_critic, example_obs, example_act)
@@ -470,7 +477,7 @@ class SAC:
         """
         return run_update_burst(
             self.update, self.config, state, buffer_state, chunk,
-            num_updates, axis_name,
+            num_updates, axis_name, self.obs_spec,
         )
 
 
@@ -528,6 +535,7 @@ def run_update_burst(
     chunk: Batch,
     num_updates: int,
     axis_name: str | None = None,
+    obs_spec: t.Any = None,
 ) -> t.Tuple[TrainState, BufferState, Metrics]:
     """The push-then-scan burst shared by every learner (SAC here, TD3
     in :mod:`torch_actor_critic_tpu.td3`): algorithm choice lives
@@ -562,9 +570,12 @@ def run_update_burst(
                 augment=config.frame_augment,
                 pad=config.augment_pad,
                 normalize=config.normalize_pixels,
+                obs_spec=obs_spec,
             )
         else:
-            batch = sample(buf, sample_key, config.batch_size)
+            batch = as_observations(
+                sample(buf, sample_key, config.batch_size), obs_spec
+            )
         st, metrics = update_fn(st, batch, axis_name)
         return (st, buf), metrics
 

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import optax
 from flax import linen as nn
 
+from torch_actor_critic_tpu.buffer.replay import observation_spec
 from torch_actor_critic_tpu.ops.augment import augment_batch
 from torch_actor_critic_tpu.core.types import Batch, BufferState, TrainState
 from torch_actor_critic_tpu.diagnostics import ingraph as diag
@@ -75,6 +76,7 @@ class TD3:
         self.pi_tx = optax.adam(config.lr)
         self.q_tx = optax.adam(config.lr)
         self._adam_core = optax.scale_by_adam()
+        self.obs_spec = None  # as SAC's: learned in init_state
 
     def default_hyperparams(self) -> t.Dict[str, jax.Array]:
         """PBT-perturbable hyperparameters (cf. SAC's): the two
@@ -92,6 +94,7 @@ class TD3:
         """Both target networks start as copies of their online nets
         (the TD3 analogue of the reference's ``deepcopy(critic)`` at
         train start, ref ``sac/algorithm.py:194-196``)."""
+        self.obs_spec = observation_spec(example_obs)
         k_actor, k_critic, k_sample, k_state = jax.random.split(key, 4)
         example_act = jnp.zeros((self.act_dim,))
         actor_params = self.actor_def.init(k_actor, example_obs, k_sample)
@@ -287,5 +290,5 @@ class TD3:
         dispatch per ``update_every`` window)."""
         return run_update_burst(
             self.update, self.config, state, buffer_state, chunk,
-            num_updates, axis_name,
+            num_updates, axis_name, self.obs_spec,
         )

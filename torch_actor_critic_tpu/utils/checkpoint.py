@@ -97,6 +97,49 @@ class CheckpointFormatError(ValueError):
     older step cannot fix it."""
 
 
+def _in_saved_row_shapes(abstract_buffer: BufferState, saved: t.Any) -> BufferState:
+    """``abstract_buffer`` with every leaf in the shape the checkpoint
+    holds it in, where that is the same rows in another row shape
+    (``saved``: the item's Orbax metadata, a tree of dicts by field
+    name). A leaf that differs in anything else is left as asked for,
+    so that Orbax's own error names it."""
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(abstract_buffer)
+    lead = len(abstract_buffer.ptr.shape) + 1  # (devices or members,) rows
+
+    def as_saved(path, leaf):
+        try:
+            node = saved
+            for key in path:
+                node = node[key.name]
+            shape = tuple(node.shape)
+        except (AttributeError, KeyError, TypeError):
+            return leaf
+        if shape == tuple(leaf.shape) or shape[:lead] != tuple(
+            leaf.shape[:lead]
+        ) or int(np.prod(shape)) != int(np.prod(leaf.shape)):
+            return leaf
+        return jax.ShapeDtypeStruct(
+            shape, leaf.dtype, sharding=getattr(leaf, "sharding", None)
+        )
+
+    return jax.tree_util.tree_unflatten(
+        treedef, [as_saved(path, leaf) for path, leaf in leaves]
+    )
+
+
+def _in_asked_shapes(buffer: BufferState, abstract_buffer: BufferState) -> BufferState:
+    """A restored buffer with every leaf in ``abstract_buffer``'s shape
+    (and sharding): the inverse of :func:`_in_saved_row_shapes`."""
+    def stored(x, like):
+        if x.shape == tuple(like.shape):
+            return x
+        x = x.reshape(like.shape)
+        sharding = getattr(like, "sharding", None)
+        return x if sharding is None else jax.device_put(x, sharding)
+
+    return jax.tree_util.tree_map(stored, buffer, abstract_buffer)
+
+
 class Checkpointer:
     def __init__(
         self,
@@ -329,11 +372,17 @@ class Checkpointer:
         prev_level = absl_logger.level
         absl_logger.setLevel(_logging.ERROR)
         try:
-            saved_items = set(self._mgr.item_metadata(epoch).keys())
+            item_metadata = self._mgr.item_metadata(epoch)
+            saved_items = set(item_metadata.keys())
         finally:
             absl_logger.setLevel(prev_level)
         if abstract_buffer is not None and "buffer" in saved_items:
-            items["buffer"] = ocp.args.StandardRestore(abstract_buffer)
+            # A ring leaf is asked for in the shape it was saved in and
+            # reshaped into the one this build keeps it in (buffer/
+            # replay.py stores a row tile by tile since PR 30).
+            items["buffer"] = ocp.args.StandardRestore(
+                _in_saved_row_shapes(abstract_buffer, item_metadata["buffer"])
+            )
         if abstract_arrays is not None and "arrays" in saved_items:
             items["arrays"] = ocp.args.StandardRestore(
                 _unwrap_prng_keys(abstract_arrays)
@@ -347,6 +396,9 @@ class Checkpointer:
         train_state = _rewrap_prng_keys(
             out["train_state"], abstract_train_state
         )
+        if "buffer" in items:
+            out = dict(out)
+            out["buffer"] = _in_asked_shapes(out["buffer"], abstract_buffer)
         if abstract_arrays is None:
             return train_state, out.get("buffer"), dict(out["meta"])
         arrays = out.get("arrays")
