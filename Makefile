@@ -2,29 +2,20 @@
 # runner plus operational helpers. The reference's mlflow/tensorboard/
 # dvc/prefect UI stubs map to the file-based tracking under runs/.
 
-.PHONY: test test-fast bench bench-diff dryrun lint native clean chip-smoke parity multihost serve serve-smoke fault-smoke trace-smoke diag-smoke chaos-smoke pop-smoke cost-smoke mesh-smoke fleet-smoke shard-serve-smoke decouple-smoke visual-smoke scenario-smoke sanitize-smoke replay-smoke coldstart-smoke obs-smoke elastic-smoke
+.PHONY: test test-fast chip-smoke parity multihost serve serve-smoke trace-smoke diag-smoke pop-smoke mesh-smoke cost-smoke fault-smoke chaos-smoke fleet-smoke shard-serve-smoke decouple-smoke visual-smoke sanitize-smoke scenario-smoke replay-smoke coldstart-smoke obs-smoke elastic-smoke dryrun lint native native-asan clean
 
-# Full matrix (CI runs this; ~14 min on a 2-thread host).
+# Full matrix, slow tests included (CI runs this).
 test:
 	python -m pytest tests/ -q
 
 # Iteration default: skips the @pytest.mark.slow tests (>30s each:
 # multi-process launches, long training loops, native ASan build) and
-# the composer wall-runner construction. <5 min.
+# the composer wall-runner construction, and stops at the first failure.
+# The run the driver makes and counts (ROADMAP.md "Tier-1 verify") is
+# this selection on six workers, one file to a worker:
+#   JAX_PLATFORMS=cpu python -m pytest tests/ -q -m "not slow" -n 6 --dist loadfile
 test-fast:
 	python -m pytest tests/ -q -x -m "not slow" --ignore=tests/test_wall_runner_env.py
-
-bench:
-	python bench.py
-
-# Diff two bench artifacts; nonzero exit on a per-stage regression
-# beyond the noise bar (A/B: raw bench JSON lines from runs/tpu/ or
-# BENCH_rNN capture wrappers — truncated tails are partially
-# recovered). See docs/OBSERVABILITY.md "Cost attribution & roofline".
-A ?= BENCH_r04.json
-B ?= BENCH_r05.json
-bench-diff:
-	python scripts/bench_diff.py $(A) $(B)
 
 # The chip: the main path end to end at full width — train, serve,
 # every Pallas kernel against its reference, a fused epoch. Needs a

@@ -184,10 +184,9 @@ PRESETS = {
     # Long wall-runner run (VERDICT r4 #6): the parallel env pool on
     # the real composer task for hours. 1000-step epochs keep
     # metrics.jsonl fine-grained, so a wall-clock cutoff still leaves
-    # a committed trend (composer+visual-SAC runs ~3 env-steps/s on
-    # this 1-core image — 50k steps is a ~5h budget; the pool's
-    # speedup story lives in bench.py's host_envs crossover section,
-    # which a 1-core host cannot demonstrate live).
+    # a committed trend (composer physics on a 1-core image is slow:
+    # 50k steps is a budget of hours, and the env pool's gain cannot
+    # show on one core).
     # learn_alpha: the wall-runner pays dm_control-scale [0,1]-per-step
     # rewards, where the fixed alpha=0.2 entropy bonus swamps the
     # signal (measured on dm:cheetah:run at 100k steps — eval 0.28

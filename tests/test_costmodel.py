@@ -11,10 +11,8 @@ events and ``cost/`` metric columns appear with telemetry on; and
 registry entries from the trainer).
 """
 
-import importlib.util
 import json
 import math
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -465,153 +463,3 @@ def test_request_id_threads_through_spans_and_metrics_costs():
     assert {"rid-42", gen_rid} <= rids
     outcomes = {r["outcome"] for r in log.records()}
     assert outcomes == {"ok"}
-
-
-# -------------------------------------------------------------- bench_diff
-
-
-def _load_bench_diff():
-    path = Path(__file__).resolve().parents[1] / "scripts" / "bench_diff.py"
-    spec = importlib.util.spec_from_file_location("bench_diff", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_diff_flags_regressions(tmp_path):
-    bd = _load_bench_diff()
-    a = {
-        "metric": "sac_grad_steps_per_sec", "value": 1000.0,
-        "serving": {"requests_per_sec": 100.0, "p99_ms": 10.0},
-        "notes": {"x": "ignored"}, "flops_per_step": 123,
-    }
-    good = {
-        "metric": "sac_grad_steps_per_sec", "value": 1050.0,
-        "serving": {"requests_per_sec": 105.0, "p99_ms": 9.0},
-    }
-    bad = {
-        "metric": "sac_grad_steps_per_sec", "value": 400.0,  # -60%
-        "serving": {"requests_per_sec": 100.0, "p99_ms": 30.0},  # +200%
-    }
-    pa, pgood, pbad = (
-        tmp_path / "a.json", tmp_path / "good.json", tmp_path / "bad.json"
-    )
-    pa.write_text(json.dumps(a))
-    pgood.write_text(json.dumps(good))
-    pbad.write_text(json.dumps(bad))
-    assert bd.main([str(pa), str(pgood)]) == 0
-    assert bd.main([str(pa), str(pbad)]) == 1
-    rows, regressions = bd.compare(a, bad, noise_pct=10.0)
-    regressed = {r[0] for r in regressions}
-    assert "value" in regressed
-    assert "serving.p99_ms" in regressed
-    assert "serving.requests_per_sec" not in regressed
-
-
-def test_bench_diff_mfu_and_cost_keys_are_higher_better():
-    """MFU/cost-family regressions flag exactly like goodput (the
-    visual-MFU tentpole's regression detector): bench `mfu` leaves at
-    any nesting depth, metrics.jsonl roofline columns
-    (cost/epoch_mfu, cost/*_achieved_gflops_s) and roofline_frac."""
-    bd = _load_bench_diff()
-    a = {
-        "visual": {
-            "mfu": 0.18,
-            "bf16_fused": {"mfu": 0.21, "grad_steps_per_sec": 900.0},
-        },
-        "cost/epoch_mfu": 0.15,
-        "cost/update_burst_achieved_gflops_s": 120.0,
-        "roofline_frac": 0.5,
-    }
-    b = {
-        "visual": {
-            "mfu": 0.02,  # -89%: THE regression this PR exists to stop
-            "bf16_fused": {"mfu": 0.20, "grad_steps_per_sec": 880.0},
-        },
-        "cost/epoch_mfu": 0.05,
-        "cost/update_burst_achieved_gflops_s": 40.0,
-        "roofline_frac": 0.45,
-    }
-    rows, regressions = bd.compare(a, b, noise_pct=10.0)
-    regressed = {r[0] for r in regressions}
-    assert "visual.mfu" in regressed
-    assert "cost/epoch_mfu" in regressed
-    assert "cost/update_burst_achieved_gflops_s" in regressed
-    assert "visual.bf16_fused.mfu" not in regressed  # within noise
-    # And an IMPROVED mfu must not regress.
-    _, regs_up = bd.compare(b, a, noise_pct=10.0)
-    assert not {r[0] for r in regs_up}
-
-
-def test_bench_stage_budget_scales_to_enforced_timeout(monkeypatch):
-    """BENCH_r05 fix: a stage's internal budget scales to the enforced
-    per-stage timeout so the stage self-terminates (emitting its JSON)
-    inside the parent's hard kill window."""
-    bench_path = Path(__file__).resolve().parents[1] / "bench.py"
-    spec = importlib.util.spec_from_file_location("bench_mod2", bench_path)
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    monkeypatch.delenv("TAC_BENCH_STAGE_BUDGET", raising=False)
-    assert bench.stage_budget(600.0) == 600.0
-    monkeypatch.setenv("TAC_BENCH_STAGE_BUDGET", "200")
-    assert bench.stage_budget(600.0) == pytest.approx(140.0)  # 0.7 * 200
-    assert bench.stage_budget(100.0) == 100.0  # default already fits
-
-    # Per-point subdivision: completed points stream as structured
-    # [bench-point] lines that a killed stage's parent reassembles.
-    stderr = "\n".join([
-        "[bench] sweep batch=64 ...",
-        '[bench-point] {"stage": "sweep", "entry": {"batch": 64, '
-        '"grad_steps_per_sec": 10.0}}',
-        '[bench-point] {"stage": "sweep", "entry": {"batch": 512, '
-        '"grad_steps_per_sec": 9.0}}',
-        "[bench-point] not json — ignored",
-    ])
-    points = bench.collect_points((None, stderr))
-    assert [e["batch"] for e in points["sweep"]] == [64, 512]
-
-
-def test_bench_diff_recovers_truncated_wrapper(tmp_path):
-    """A BENCH_rNN capture wrapper whose tail lost its line start still
-    yields its trailing sections for comparison."""
-    bd = _load_bench_diff()
-    full = json.dumps({
-        "metric": "m", "value": 100.0,
-        "serving": {"requests_per_sec": 50.0},
-        "torch_cpu_steps_per_sec": 10.0,
-    })
-    wrapper = {"n": 1, "cmd": "python bench.py", "rc": 0,
-               "tail": full[37:]}  # cut the front
-    p = tmp_path / "wrap.json"
-    p.write_text(json.dumps(wrapper))
-    rec, partial = bd.load_artifact(str(p))
-    assert partial is True
-    assert rec["torch_cpu_steps_per_sec"] == 10.0
-
-
-# ----------------------------------------------------- bench stage errors
-
-
-def test_bench_stage_errors_are_structured(tmp_path, monkeypatch):
-    """A stage that overruns its (overridden) timeout leaves a
-    structured record — stage name, elapsed, timeout — not an opaque
-    string."""
-    bench_path = Path(__file__).resolve().parents[1] / "bench.py"
-    spec = importlib.util.spec_from_file_location("bench_mod", bench_path)
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    monkeypatch.setenv("TAC_BENCH_STAGE_TIMEOUT", "0.1")
-    diagnostics, stage_errors = [], []
-    res = bench.run_stage_subprocess(
-        "headline", 600, diagnostics, platform="cpu",
-        stage_errors=stage_errors,
-    )
-    assert res is None
-    assert len(stage_errors) == 1
-    rec = stage_errors[0]
-    assert rec["stage"] == "headline"
-    assert rec["timeout_s"] == 0.1  # the override took effect
-    assert rec["elapsed_s"] >= 0.0
-    assert "timeout" in rec["error"]

@@ -146,7 +146,8 @@ def test_adam_single_step_matches_torch():
 
 
 def test_torch_visual_baseline_builds_and_updates():
-    """The visual torch baseline (bench.py's BASELINE-config-5 ratio,
+    """The visual torch baseline (BASELINE.md's config 5, an
+    independent reference that this file compares against:
     baselines/torch_sac.py:build_torch_visual_sac) runs a full SAC
     gradient step at a tiny geometry: actor output contracts hold and
     the update mutates parameters. 36x36 is the smallest square frame

@@ -204,11 +204,12 @@ def test_redq_wide_ensemble_updates():
 
 @pytest.mark.slow
 def test_update_burst_donates_buffer_in_hlo(sac_and_state):
-    """Perf-regression guard: the fused burst's replay buffer MUST be
-    donated (input-output aliased in the compiled HLO). Losing donation
-    would silently deep-copy the multi-GB HBM buffer on every dispatch
-    — the exact host<->device-free replay design the framework trades
-    on (SURVEY.md §7; bench.py measures through this jit signature).
+    """The fused burst's replay buffer MUST be donated (input-output
+    aliased in the compiled HLO). Losing donation would silently
+    deep-copy the multi-GB HBM ring on every dispatch — the ring that
+    stays on the device is the design the framework trades on
+    (SURVEY.md §7; PERF.md section 5 shows what a whole-ring copy
+    costs a window).
 
     Differential: the same burst is compiled with and without the
     buffer in donate_argnums, and the alias-count delta must cover the

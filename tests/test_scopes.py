@@ -243,8 +243,13 @@ def traced_run(tmp_path_factory):
                 (ev.name[len(scopes.HOST_PREFIX):], ev.start_ns, ev.duration_ns, dict(ev.stats))
                 for ev in line.events if ev.name.startswith(scopes.HOST_PREFIX)
             ]
-    epoch = [e for e in rec_events(run_dir) if e["type"] == "epoch"][1]
-    return events, epoch
+    first, epoch = [e for e in rec_events(run_dir) if e["type"] == "epoch"][:2]
+    # the PhaseTimer's own laps of that epoch, in the order it took them
+    before, during = (
+        sum(p["count"] for p in e["phases"].values()) for e in (first, epoch)
+    )
+    laps = [rec.phases[i] for i, _, _ in rec.ring.spans()[before:before + during]]
+    return events, epoch, laps
 
 
 def rec_events(run_dir):
@@ -254,7 +259,7 @@ def rec_events(run_dir):
 
 
 def test_every_phase_is_an_annotation_with_its_window(traced_run):
-    events, epoch = traced_run
+    events, _, _ = traced_run
     assert {name for name, *_ in events} == set(PHASES)
     assert all(int(stats["epoch"]) == 1 for *_, stats in events)
     # a window's act .. burst_dispatch spans share one number; the next
@@ -271,20 +276,28 @@ def test_every_phase_is_an_annotation_with_its_window(traced_run):
 
 
 def test_annotations_partition_the_epoch_like_the_phase_timer(traced_run):
-    events, epoch = traced_run
-    ordered = sorted((start, start + dur) for _, start, dur, _ in events)
-    assert all(b[0] >= a[1] - 1 for a, b in zip(ordered, ordered[1:]))  # no overlap (ns)
+    """Two clocks took the same epoch: the profiler's (annotations) and the
+    host's (PhaseTimer). What must agree exactly is what was taken: the
+    same phases, in the same order, as often. The seconds agree as far as
+    two clocks on a busy host can: a phase's gap is held to a share of the
+    epoch, not to a count of milliseconds."""
+    events, epoch, laps = traced_run
+    ordered = sorted(events, key=lambda ev: ev[1])
+    spans = [(start, start + dur) for _, start, dur, _ in ordered]
+    assert all(b[0] >= a[1] - 1 for a, b in zip(spans, spans[1:]))  # no overlap (ns)
+    assert [name for name, *_ in ordered] == laps
+    wall_s = epoch["wall_s"]
     for name in PHASES:
         traced_s = 1e-9 * sum(dur for n, _, dur, _ in events if n == name)
         timed = epoch["phases"][name]
-        assert len([1 for n, *_ in events if n == name]) == timed["count"]
-        assert traced_s == pytest.approx(timed["total_s"], rel=0.05, abs=2e-3)
+        assert laps.count(name) == timed["count"]
+        assert abs(traced_s - timed["total_s"]) <= 0.05 * wall_s
     covered = 1e-9 * sum(dur for _, _, dur, _ in events)
-    assert 0.8 * epoch["wall_s"] <= covered <= 1.05 * epoch["wall_s"]
+    assert 0.8 * wall_s <= covered <= 1.05 * wall_s
 
 
 def test_param_sync_is_charged_and_booked_to_the_device(traced_run):
-    _, epoch = traced_run
+    _, epoch, _ = traced_run
     sync = epoch["phases"]["param_sync"]
     assert sync["count"] == TINY["steps_per_epoch"] // TINY["update_every"]
     assert sync["total_s"] > 0.0

@@ -12,8 +12,8 @@ hazards:
   traced function's parameters — closure variables are typically
   trace-time constants and stay exempt). Each of these either forces a
   device->host transfer per step or raises a ``TracerArrayConversion``
-  at trace time; on the fused Podracer-style loops one stray sync is
-  the difference between 0.70 and 0.02 MFU (PAPERS.md, BENCH_r03-r05).
+  at trace time; on the fused Podracer-style loops one stray sync
+  serializes the device's queue with the host (PAPERS.md).
 * ``wallclock-in-jit`` — ``time.*`` / ``datetime.now`` in traced code
   reads the clock ONCE at trace time and bakes the value into the
   compiled program: the metric it feeds goes silently constant.

@@ -27,9 +27,9 @@ wall-clock). This subsystem makes compilation a **build artifact**:
   of paying spawn+compile.
 
 Success metric: time-to-first-act for a fresh worker with vs without
-a bundle (``bench.py --stage=coldstart``), and ``live_compiles == 0``
-through a full chaos flood (docs/SERVING.md "Cold start & warm-start
-bundles").
+a bundle (not measured on the chip yet: ROADMAP W5), and
+``live_compiles == 0`` through a full chaos flood (docs/SERVING.md
+"Cold start & warm-start bundles").
 """
 
 from torch_actor_critic_tpu.aot.bundle import (

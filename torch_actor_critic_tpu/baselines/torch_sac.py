@@ -4,9 +4,9 @@ Same semantics and hyperparameter defaults as the reference run config
 (ref ``main.py:147-160``: alpha=0.2 fixed, gamma=0.99, polyak=0.995,
 batch 64, hidden [256,256], lr 3e-4), same squashed-Gaussian math (ref
 ``networks/linear.py:39-51``) and twin-critic Bellman update (ref
-``sac/algorithm.py:30-74``), written functionally and shared by the
-throughput benchmark (``bench.py``) and the return-parity runner
-(``scripts/parity_run.py``) so the two baselines cannot drift.
+``sac/algorithm.py:30-74``), written functionally for the
+return-parity runner (``scripts/parity_run.py``) and the torch
+comparisons in ``tests/test_parity_torch.py``.
 
 This module shares NO code with ``/root/reference`` — it is the
 project's own torch implementation of the published SAC equations.

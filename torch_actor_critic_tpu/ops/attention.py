@@ -385,12 +385,11 @@ def _pad_head_dim(
     )
 
 
-# Auto block-size cap: the chip's block sweep (runs/tpu/
-# bench_20260731T034827Z.json, attention.block_sweep) measured fwd+bwd
-# at [4, 8, 2048, 64] bf16 monotonically improving up to (512, 512) —
-# 16.9 TFLOP/s vs the 6.1 the old (128, 128) default recorded in the
-# same artifact, a 2.8x — so auto picks the largest block in
-# {128, 256, 512} that tiles the sequence.
+# Auto block-size cap: auto picks the largest block in
+# {128, 256, 512} that tiles the sequence (fewer, larger tiles: less
+# grid overhead and K/V re-reading). The one reading of these kernels
+# on record is PERF.md section 5, trunk.flash_roofline, at 512-wide
+# tiles; no ledger line compares block sizes (ROADMAP C5).
 _AUTO_BLOCK_CAP = 512
 
 

@@ -1,9 +1,8 @@
 """Fused replay-sample -> decode -> augment -> cast pixel pipeline.
 
-The TPU bench (BENCH_r03-r05) pins the visual workload at ~0.02 MFU
-while the same chip sustains 0.70 on synthetic bf16 matmuls — and the
-bench's own large-batch bf16 visual probe reaches 0.18, so the headroom
-is real. Part of the gap is the pixel hot path: every gradient step
+The visual workload uses a small share of the chip's arithmetic
+(PERF.md section 5, ``wallrunner_cnn_burst``: ``update.compute_mfu``).
+Part of the gap is the pixel hot path: every gradient step
 gathers a uint8 frame batch from the HBM ring (``buffer/replay.py``),
 round-trips it through pad/crop augmentation (``ops/augment.py``) and
 then materializes it as **float32** inside the CNN trunk

@@ -1,9 +1,9 @@
 """Population training: N independent learners in ONE compiled program.
 
-The chip-utilization answer to a measured fact: the fused burst at the
-reference configuration (batch 64, hidden [256,256]) is latency-bound —
-it achieves ~1-2% MFU while the same chip sustains 70.5% MFU at batch
-8192 x width 4096 (SCALING.md, ``BENCH_r04.json`` sweep). RL fills that
+The chip-utilization answer to the reference configuration's shape:
+one learner at batch 64, hidden [256,256] is latency-bound and leaves
+the MXU nearly empty (what 32 members do on one chip is PERF.md
+section 5, ``cheetah_pop32_fused``). RL fills that
 idle silicon not with bigger batches (which change the algorithm) but
 with MORE SEEDS: every deep-RL result is a multi-seed result, and the
 reference can only obtain seeds by running the whole program N times

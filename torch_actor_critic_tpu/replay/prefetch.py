@@ -21,8 +21,8 @@ XLA cost analysis like every other entry point
 (analysis/contracts.py).
 
 With ``replay_prefetch=False`` the sampler runs synchronously at the
-boundary (the stall the async path exists to hide — ``bench.py
---stage=replay`` measures the difference). Either way the TRAIN loop
+boundary (the stall the async path exists to hide; not measured on
+the chip). Either way the TRAIN loop
 performs the actual device push; the thread only ever touches host
 memory.
 """

@@ -37,11 +37,11 @@ from torch_actor_critic_tpu.telemetry import scopes
 Metrics = t.Dict[str, jax.Array]
 
 
-# The pixel-task training recipe shared by every surface that trains or
-# times the 32x32 PixelPendulum family: the committed evidence runs
+# The pixel-task training recipe shared by every surface that trains
+# the 32x32 PixelPendulum family: the committed evidence runs
 # (scripts/evidence_run.py pixelbal-*/pixelpend-* presets, the
-# runs/train_proof/ pixel proofs) and benchmark_on_device's pixel row. ONE definition so they cannot
-# silently measure different configs. Conv geometry sized for 32x32
+# runs/train_proof/ pixel proofs). ONE definition so they cannot
+# silently train different configs. Conv geometry sized for 32x32
 # frames (the Atari defaults need >=36px); DrQ shift + learned
 # temperature are the stabilizers the committed curves document.
 PIXEL_CONV = dict(
@@ -445,11 +445,11 @@ class PopulationOnDeviceLoop(_EpochPrograms):
     ``lax.scan`` under one ``jit`` — so each dispatch advances N
     complete, independent learning curves (acting included, not just
     gradient steps). This is the Anakin topology (PAPERS.md) stretched
-    over the population axis: the measured idle MXU at the product
-    config (~1-2% MFU while the chip sustains 0.70 — BENCH_r04) is
-    converted into aggregate env-steps/s and grad-steps/s that scale
-    near-linearly in N, because XLA folds the member axis into the
-    matmul tiles.
+    over the population axis: the MXU that one learner at the product
+    config leaves idle is spent on aggregate env steps and gradient
+    steps, because XLA folds the member axis into the matmul tiles
+    (PERF.md section 5, ``cheetah_pop32_fused``; the curve over N is
+    not measured, ROADMAP S5).
 
     Independence contract (pinned by ``tests/test_population_fused.py``):
     members share NOTHING — separate env batches, replay rings,
@@ -854,10 +854,9 @@ class _SpecView:
 def _wrap_and_build(env_cls, config) -> t.Tuple[t.Any, SAC]:
     """History-wrap the env class per config and build its SAC.
 
-    The ONE construction path for both training (``train_on_device``)
-    and benchmarking (``benchmark_on_device``), sharing
-    ``trainer.build_models`` with the host loop — the bench can never
-    time a differently-built model than training uses.
+    The ONE construction path of ``train_on_device``, sharing
+    ``trainer.build_models`` with the host loop, so the fused loop can
+    never train a differently-built model than the Trainer does.
     """
     from torch_actor_critic_tpu.envs.ondevice import history_env
     from torch_actor_critic_tpu.sac.trainer import build_models, make_learner
@@ -1265,77 +1264,3 @@ def train_population_on_device(
         telemetry.close()
     return metrics
 
-
-def benchmark_on_device(
-    env_name: str, steps: int = 500, n_envs: int = 16, update_every: int = 50,
-    history_len: int = 1,
-) -> dict:
-    """Timed fused-loop epoch at the headline model config (hidden
-    [256,256], batch 64 — BASELINE.md); returns env/grad steps per sec
-    for ``bench.py``'s ``on_device`` section. Short names accepted
-    ("pendulum", "cheetah"). ``history_len > 1`` windows the env and
-    times the causal-transformer (sequence) stack instead — the fused
-    long-context path.
-    """
-    import time
-
-    from torch_actor_critic_tpu.envs.ondevice import get_on_device_env
-    from torch_actor_critic_tpu.utils.config import SACConfig
-
-    aliases = {
-        "pendulum": "Pendulum-v1",
-        "cheetah": "cheetah-run-jax",
-        "pixel": "PixelPendulum-v0",
-        # The scenarios/ families (bench.py `scenarios` stage).
-        "multiagent": "multi-pendulum-4",
-        "procedural": "hurdle-runner",
-        "multitask": "pendulum-multitask",
-    }
-    env_cls = get_on_device_env(aliases.get(env_name, env_name))
-    if env_cls is None:
-        from torch_actor_critic_tpu.envs.ondevice import (
-            known_on_device_envs,
-        )
-
-        raise ValueError(
-            f"no on-device twin for {env_name!r}; known envs: "
-            f"{known_on_device_envs()}"
-        )
-    if hasattr(env_cls, "obs_spec"):
-        # Pixel twin: the shared recipe's conv geometry (augmentation
-        # irrelevant here — the bench times bursts, not learning).
-        cfg = SACConfig(
-            hidden_sizes=(256, 256), batch_size=64,
-            history_len=history_len, **PIXEL_CONV,
-        )
-    else:
-        cfg = SACConfig(
-            hidden_sizes=(256, 256), batch_size=64, history_len=history_len
-        )
-    env_cls, sac = _wrap_and_build(env_cls, cfg)
-    loop = loop_class_for(env_cls)(sac, env_cls, n_envs=n_envs)
-    ts, buf, es, key = loop.init(jax.random.key(0), buffer_capacity=200_000)
-    ts, buf, es, key, _ = loop.epoch(
-        ts, buf, es, key, steps=update_every, update_every=update_every,
-        warmup=True,
-    )
-    # compile the measured epoch shape, then time a fresh dispatch
-    ts, buf, es, key, m = loop.epoch(
-        ts, buf, es, key, steps=steps, update_every=update_every
-    )
-    drain(m["loss_q"])
-    t0 = time.perf_counter()
-    ts, buf, es, key, m = loop.epoch(
-        ts, buf, es, key, steps=steps, update_every=update_every
-    )
-    drain(m["loss_q"])
-    dt = time.perf_counter() - t0
-    out = {
-        "env": aliases.get(env_name, env_name),
-        "n_envs": n_envs,
-        "env_steps_per_sec": round(steps * n_envs / dt, 1),
-        "grad_steps_per_sec": round(steps / dt, 1),
-    }
-    if history_len > 1:
-        out["history_len"] = history_len
-    return out

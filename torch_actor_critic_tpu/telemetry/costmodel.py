@@ -1,9 +1,8 @@
 """Per-program compute-cost attribution: FLOPs, roofline, MFU.
 
-The TPU bench record shows the chip ~70%-capable (0.70 MFU on large
-synthetic matmuls, BENCH_r03-r05) but ~2%-used on the realistic
-workload — and nothing in telemetry/ could say WHICH program eats the
-gap, or whether it is compute- or memory-bound. This module turns
+The realistic workloads use a small share of the chip's arithmetic
+(PERF.md section 5), and a run needs to say WHICH program eats the
+gap, and whether it is compute- or memory-bound. This module turns
 "MFU is low" into "program X is memory-bound at 0.4 FLOPs/byte":
 
 - :class:`CostRegistry` — a process-wide registry (one per process,

@@ -229,10 +229,9 @@ class SACConfig:
     # (sac/algorithm.py update_burst). At the reference's tiny model
     # the per-step kernels are launch-bound on TPU; unrolling trades
     # compile time and code size for less loop overhead. 1 = plain
-    # scan; 0 = auto (5 on the TPU backend — the chip-measured best at
-    # the reference config, +12% over plain scan: burst_unroll section
-    # of runs/tpu/bench_20260731T034827Z.json — 1 elsewhere, where the
-    # gain is small and the unrolled scan body compiles ~3x slower). The knob
+    # scan; 0 = auto (5 on the TPU backend, 1 elsewhere, where the
+    # unrolled scan body only compiles slower; the rule rests on no
+    # ledger line: ROADMAP D3). The knob
     # is semantics-preserving (exact-equality pinned in
     # tests/test_sac_update.py), so auto-tuning it is safe.
     burst_unroll: int = 0
@@ -364,8 +363,8 @@ class SACConfig:
     replay_refill: int = 0
     # Stage refill chunks on a background thread (double-buffered) so
     # the host→device copy hides behind the update burst; False
-    # samples synchronously at the window boundary (the measured
-    # stall, bench.py --stage=replay).
+    # samples synchronously at the window boundary (the stall the
+    # thread exists to hide).
     replay_prefetch: bool = True
 
     # Offline training (train.py --offline): no env in the loop — the
@@ -385,7 +384,7 @@ class SACConfig:
     # burst_dispatch/drain/sentinel/checkpoint), per-epoch device HBM
     # watermarks and a JSONL event stream under the tracker run dir.
     # Off by default: the disabled hot path carries zero telemetry work
-    # (bench.py `telemetry_overhead` pins the enabled cost at <5%).
+    # (pinned by tests/test_telemetry.py; what on costs is not measured).
     telemetry: bool = False
     # Learning-health diagnostics tier (diagnostics/,
     # docs/OBSERVABILITY.md "Learning-health diagnostics"): in-graph
@@ -395,8 +394,7 @@ class SACConfig:
     #   "light" — scalar diagnostics (grad global-norms, update-to-
     #             param ratios, Q stats, action saturation, per-burst
     #             loss maxima) + dp replica-skew + the recompilation
-    #             watchdog; bench.py `diagnostics_overhead` holds this
-    #             within the 5% bar on the CPU smoke config;
+    #             watchdog;
     #   "full"  — light + the on-device fixed-bucket TD-error
     #             histogram (merged host-side into the telemetry
     #             histogram schema).
@@ -440,8 +438,7 @@ class SACConfig:
     # /metrics endpoint on `--obs-port` (0 = ephemeral), and `obs/`
     # columns in metrics.jsonl. Off by default: zero threads, zero
     # sockets, metric keys identical to a pre-PR-19 build (pinned by
-    # tests/test_obs.py; bench.py `obs_overhead` holds the enabled
-    # cost within the 5% bar).
+    # tests/test_obs.py).
     obs: bool = False
     obs_interval_s: float = 2.0
     obs_port: int = 0
@@ -808,8 +805,8 @@ class SACConfig:
 
     @property
     def resolved_burst_unroll(self) -> int:
-        """``burst_unroll`` with 0 resolved by backend: 5 on TPU (the
-        chip-measured best at the reference config), 1 elsewhere. The
+        """``burst_unroll`` with 0 resolved by backend: 5 on TPU, 1
+        elsewhere (a rule no ledger line bears out yet: ROADMAP D3). The
         resolution happens at trace time, when the backend is known."""
         if self.burst_unroll:
             return self.burst_unroll

@@ -6,8 +6,8 @@ for the device measures the enqueue. :func:`drain` waits by a host
 arrive before the producer ran — and hands the value back, so the same
 call doubles as a checksum of what was timed.
 
-Every timing site in the framework (bench.py sections, the trainer's
-per-epoch steps/sec metrics, the fused on-device loop benchmark) drains
+Every timing site in the framework (the trainer's per-epoch steps/sec
+metrics, the fused on-device loop's, ``benchmark/``'s windows) drains
 through :func:`drain`.
 """
 
