@@ -55,7 +55,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from torch_actor_critic_tpu.buffer.replay import init_replay_buffer, push
 from torch_actor_critic_tpu.core.types import Batch, BufferState, TrainState
 from torch_actor_critic_tpu.diagnostics import ingraph as diag
-from torch_actor_critic_tpu.parallel import sharding as tp_sharding
+from torch_actor_critic_tpu.parallel import chunk_block, sharding as tp_sharding
 from torch_actor_critic_tpu.parallel.mesh import global_device_put
 from torch_actor_critic_tpu.telemetry import scopes
 
@@ -188,10 +188,23 @@ def shard_chunk_from_local(
     each host contributes only the transitions its own envs produced —
     no global chunk is ever staged in host RAM. Single-process meshes
     reduce exactly to :func:`shard_chunk`.
+
+    A chunk whose leaves are the views of one block, as the Trainer
+    stages it, crosses as that block in ONE transfer and is taken apart
+    on the device (:mod:`~torch_actor_critic_tpu.parallel.chunk_block`);
+    any other chunk (the prefetcher's refill, separately allocated
+    leaves), and any chunk on a mesh that reaches past this process,
+    crosses leaf by leaf. Same arrays, shapes, dtypes and shardings
+    either way.
     """
     if sp is None:
         sp = mesh.shape.get("sp", 1)
     specs = _batch_specs(chunk_local, sp)
+    placed = chunk_block.place_block(
+        chunk_local, _shardings(mesh, specs), NamedSharding(mesh, P("dp"))
+    )
+    if placed is not None:
+        return placed
 
     def put(x, s):
         sharding = NamedSharding(mesh, s)

@@ -45,6 +45,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from torch_actor_critic_tpu.buffer.replay import init_replay_buffer, push
 from torch_actor_critic_tpu.core.types import Batch, BufferState, TrainState
+from torch_actor_critic_tpu.parallel import chunk_block
 from torch_actor_critic_tpu.sac.algorithm import Metrics
 from torch_actor_critic_tpu.telemetry import scopes
 
@@ -163,7 +164,18 @@ class PopulationLearner:
     def place_chunk(self, chunk: Batch) -> Batch:
         """Device placement for a host-built chunk with leading axes
         ``(n_members, window, ...)`` (the trainer's staging layout with
-        one env per member)."""
+        one env per member). As ``shard_chunk_from_local``: a chunk
+        that is the views of one block crosses in one transfer
+        (:mod:`~torch_actor_critic_tpu.parallel.chunk_block`), any other
+        leaf by leaf."""
+        over_dp = self._sharding  # None: one device, nothing committed
+        placed = chunk_block.place_block(
+            chunk,
+            over_dp and jax.tree_util.tree_map(lambda _: over_dp, chunk),
+            over_dp,
+        )
+        if placed is not None:
+            return placed
         if self._sharding is None:
             return jax.tree_util.tree_map(jnp.asarray, chunk)
         return self._place(chunk)

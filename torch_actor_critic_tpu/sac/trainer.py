@@ -44,6 +44,7 @@ from torch_actor_critic_tpu.envs.wrappers import is_visual_env
 from torch_actor_critic_tpu.models import Actor, DoubleCritic, VisualActor, VisualDoubleCritic
 from torch_actor_critic_tpu.parallel import (
     DataParallelSAC,
+    chunk_block,
     init_sharded_buffer,
     make_mesh,
     shard_chunk_from_local,
@@ -455,6 +456,10 @@ class Trainer:
                 sink_max_bytes=int(self.config.telemetry_max_mb * 1e6),
             )
         self.telemetry = telemetry
+        # Which way this Trainer's chunks crossed to the device is
+        # counted where the choice is made, for the whole process; the
+        # epoch event reports what was added since here.
+        self._chunk_transfers_at_start = dict(chunk_block.transfers)
         # Compute-cost attribution (telemetry/costmodel.py): with
         # telemetry on, the first update epoch registers the burst's
         # XLA cost analysis (one extra lowering+compile, off the step
@@ -833,19 +838,54 @@ class Trainer:
     def _build_chunk(self, staging) -> Batch:
         """``staging`` is a list (one entry per lockstep step) of batched
         transition tuples with leading axis ``n_envs``; the chunk stacks
-        them to leading axes ``(n_envs, window)``."""
+        them to leading axes ``(n_envs, window)``.
 
-        def stack_field(idx):
-            return jax.tree_util.tree_map(
-                lambda *xs: np.stack(xs, axis=1), *[tr[idx] for tr in staging]
+        The leaves are views of ONE freshly allocated block
+        (:func:`~torch_actor_critic_tpu.parallel.chunk_block.block_views`),
+        written here once and never again, so that
+        ``shard_chunk_from_local`` can move the window in one transfer.
+        Values, dtypes and shapes are what stacking leaf by leaf gives.
+        Reads nothing of ``self``."""
+        first = Batch(*staging[0][:5])
+        # One column a leaf: that leaf at every staged step, in the
+        # order the Batch flattens (its fields' order is the tuple's).
+        columns = [
+            [np.asarray(x) for x in column]
+            for column in zip(*(
+                jax.tree_util.tree_leaves(tuple(tr[:5])) for tr in staging
+            ))
+        ]
+        # rewards and done are float32 in the chunk whatever was staged.
+        as_f32 = jax.tree_util.tree_leaves(
+            jax.tree_util.tree_map(lambda _: False, first).replace(
+                rewards=True, done=True
             )
-
-        return Batch(
-            states=stack_field(0),
-            actions=stack_field(1),
-            rewards=stack_field(2).astype(np.float32),
-            next_states=stack_field(3),
-            done=stack_field(4).astype(np.float32),
+        )
+        # A leaf lies in the block as its rows lie in memory (rows fetched
+        # from a device are not in C order), the window axis before them:
+        # writing a step is then a plain copy, as np.stack's was.
+        specs = []
+        for column, f32 in zip(columns, as_f32):
+            row = column[0]
+            if any(x.shape != row.shape for x in column):  # as np.stack refuses
+                raise ValueError(
+                    "all staged steps of a leaf must have the same shape, "
+                    f"got {sorted({x.shape for x in column})}"
+                )
+            specs.append((
+                (len(staging),) + row.shape[1:],
+                np.float32 if f32
+                else np.result_type(*{x.dtype for x in column}),
+                (0,) + tuple(
+                    1 + axis for axis in chunk_block.memory_order(row[0])
+                ),
+            ))
+        views = chunk_block.block_views(columns[0][0].shape[0], specs)
+        for view, column in zip(views, columns):
+            for step, rows in enumerate(column):
+                view[:, step] = rows
+        return jax.tree_util.tree_unflatten(
+            jax.tree_util.tree_structure(first), views
         )
 
     # Staging seams (overridden by decoupled/learner.py, where the host
@@ -1725,6 +1765,10 @@ class Trainer:
             if rec is not None:
                 rec.inc("env_steps", env_steps_this_epoch)
                 rec.inc("grad_steps", grad_steps_this_epoch)
+                for name, count in chunk_block.transfers.items():
+                    rec.counters[name] = float(
+                        count - self._chunk_transfers_at_start[name]
+                    )
                 extra = {
                     "step": step,
                     "env_steps": env_steps_this_epoch,
