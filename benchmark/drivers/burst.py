@@ -21,6 +21,35 @@ from benchmark.drivers import _common
 from benchmark.harness import check, data, draws
 
 
+def c_ordered(pool):
+    """A fetched pool with every leaf laid out C-ordered: the same bytes under
+    other strides.
+
+    ``jax.device_get`` hands a leaf back in the device's axis order (on the
+    v5e a frame leaf's step axis fastest: one staged step's 12,288 bytes lie
+    400 bytes apart), and stacking a window from that cost 2.2 ms where rows
+    of their own cost 0.8 (my chip runs, PR 35 / 36).  No env hands a trainer
+    such rows, so set-up lays the pool out once, before the window."""
+    return jax.tree_util.tree_map(np.ascontiguousarray, pool)
+
+
+def staged_windows(pool, n_windows: int, rows: int) -> list:
+    """A pool ``Batch`` (leaves ``(n_dev, n_windows * rows, ...)``) as what
+    ``Trainer._build_chunk`` stacks: for each window its ``rows`` staged
+    steps, each the env's five-tuple."""
+    return [
+        [
+            tuple(
+                jax.tree_util.tree_map(lambda x: x[:, w * rows + s], leaf)
+                for leaf in (pool.states, pool.actions, pool.rewards,
+                             pool.next_states, pool.done)
+            )
+            for s in range(rows)
+        ]
+        for w in range(n_windows)
+    ]
+
+
 class Driver(_common.FollowedCall):
     def __init__(self, cell, config, seed, spans, overrides=None):
         self.cell, self.config, self.seed, self.spans = cell, config, seed, spans
@@ -108,20 +137,10 @@ class Driver(_common.FollowedCall):
             ),
             self.rows,
         )
-        pool = jax.device_get(
+        pool = c_ordered(jax.device_get(
             data.fill_transitions(data.data_key(self.seed, 3), step_abs)
-        )
-        self.pool = [
-            [
-                tuple(
-                    jax.tree_util.tree_map(lambda x: x[:, w * self.window_rows + s], leaf)
-                    for leaf in (pool.states, pool.actions, pool.rewards,
-                                 pool.next_states, pool.done)
-                )
-                for s in range(self.window_rows)
-            ]
-            for w in range(traffic["pool_windows"])
-        ]
+        ))
+        self.pool = staged_windows(pool, traffic["pool_windows"], self.window_rows)
 
         self.spans.lap("setup/chunk_pool")
         # What the first call will draw, and the rows it will find there.

@@ -28,6 +28,36 @@ def percentile(values: t.Sequence[float], q: float) -> float:
     return ordered[max(math.ceil(q * len(ordered)) - 1, 0)]
 
 
+def window_account(windows, spans) -> dict:
+    """For a builder's eye (the driver reads none of it): where a window's
+    time went by the harness's own spans, how the windows' lengths were
+    spread, and what the longest window spent where."""
+    lengths = [1e3 * (b - a) for a, b in windows]
+    n = len(lengths)
+    longest = max(range(n), key=lengths.__getitem__)
+    t_a, t_b = windows[longest]
+    tenths = (lengths[i * n // 10:(i + 1) * n // 10] for i in range(10))
+    return {
+        "window_spans_ms": {
+            k: 1e3 * v / n for k, v in spans.totals().items() if k != "window"
+        },
+        "window_ms": {
+            "p50": percentile(lengths, 0.5), "p95": percentile(lengths, 0.95),
+            "p99": percentile(lengths, 0.99), "max": lengths[longest],
+            # the mean length in each tenth of the run: a level that moves inside
+            # a run is the machine's, one that differs from run to run the process's
+            "tenths": [sum(part) / len(part) for part in tenths if part],
+        },
+        "window_longest": {
+            "index": longest, "of": n,
+            "spans_ms": {
+                name: 1e3 * d for name, t0, d in spans.records
+                if name != "window" and t_a <= t0 <= t_b
+            },
+        },
+    }
+
+
 def end_to_end(bench, cell, windows, per_window, setup_s) -> dict:
     """The cell's end-to-end metrics over all the work and all the time of
     the window: from the first window's start to the last one's end."""
@@ -157,6 +187,7 @@ def run_cell(
     if rehearsal:
         result["rehearsal"] = "cpu"
     result["setup_spans_s"] = setup_spans
+    result.update(window_account(windows, spans))
     if trace and summary is not None:
         result["breakdown"] = trace_mod.breakdown(summary)
         result["device_kinds_s"] = sorted(
@@ -197,7 +228,14 @@ def main(argv: t.Sequence[str] | None = None, t_process: float = T_PROCESS) -> i
         bench, cell, config, seed=args.seed, seconds=args.seconds,
         trace=bool(args.trace), t_process=t_process,
     )
-    print(json.dumps(result), flush=True)
-    for name, (value, limit) in result["comparisons"].items():  # the last lines on stderr
-        print(f"check {name}: {value!r} against {limit!r}", file=sys.stderr, flush=True)
+    emit(result)
     return 0
+
+
+def emit(result: dict) -> None:
+    """The end of a run, correct or not: the result as the last line of
+    standard output, then each number compared beside its limit as the last
+    lines of standard error (what the driver's record keeps of a run at fault)."""
+    print(json.dumps(result), flush=True)
+    for name, (value, limit) in result["comparisons"].items():
+        print(f"check {name}: {value!r} against {limit!r}", file=sys.stderr, flush=True)

@@ -51,3 +51,12 @@ class Spans:
         for name, _, dur in self.records:
             out[name] = out.get(name, 0.0) + dur
         return out
+
+
+def ms_per_window(ctx, name: str) -> float | None:
+    """Mean milliseconds a window of the run spent under span ``name``; nothing
+    where the run recorded no such span (what a per-layer reader hands back)."""
+    totals = ctx.spans.totals()
+    if name not in totals or not ctx.n_windows:
+        return None
+    return 1e3 * totals[name] / ctx.n_windows

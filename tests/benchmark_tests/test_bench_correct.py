@@ -2,6 +2,8 @@
 reference against the program, the control one precision lower, and a timed
 path broken underneath a whole run."""
 
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -117,10 +119,12 @@ def test_data_parallel_burst_on_four_devices_matches_the_reference():
 
 
 @pytest.mark.parametrize("fault", ["state_unchanged", "loss_altered"])
-def test_a_broken_timed_path_comes_out_not_correct(fault, monkeypatch):
+def test_a_broken_timed_path_comes_out_not_correct(fault, monkeypatch, capsys):
     """The whole of a run with the timed path broken underneath: a burst that
     returns its parameters unchanged, or one whose loss is altered where it is
-    produced."""
+    produced.  Such a run still ends as every run ends: ``comparisons`` last
+    in its result line and the ``check ...`` lines last on standard error,
+    each value beside its limit (all the driver's record keeps of it)."""
     from torch_actor_critic_tpu.parallel.dp import DataParallelSAC
 
     real = DataParallelSAC.update_burst
@@ -144,3 +148,14 @@ def test_a_broken_timed_path_comes_out_not_correct(fault, monkeypatch):
     failed = {k for k, (v, lim) in result["comparisons"].items() if v > lim}
     expect = "param_change.worst_leaf_gap" if fault == "state_unchanged" else "loss_q.rel_gap"
     assert expect in failed, result["comparisons"]
+    capsys.readouterr()
+    main.emit(result)
+    out, err = capsys.readouterr()
+    line = json.loads(out.strip().splitlines()[-1])
+    assert line["correct"] is False and list(line)[-1] == "comparisons"
+    assert err.strip().splitlines() == [
+        f"check {name}: {value!r} against {limit!r}"
+        for name, (value, limit) in line["comparisons"].items()
+    ]
+    value, limit = line["comparisons"][expect]
+    assert value > limit and f"check {expect}: {value!r} against {limit!r}" in err

@@ -236,7 +236,10 @@ def test_disagree_share_counts_assignments_not_order():
 def test_benchmark_json_keeps_or_parks_every_entry():
     """Every entry the BENCHMARK.json of PR 25 had is still there, in the file
     or parked beside it (``benchmark/parked/``), unchanged but for ``workloads``
-    lists that grew and a bound that shrank."""
+    lists that grew and a bound that shrank, or that a ``benchmark`` PR refitted
+    to the spreads it read (PR 36, ``PERF.md`` section 2: the two bounds the
+    visual cell's host share outgrew)."""
+    refitted = {"grad_steps_per_s": 0.055, "window_ms.p95": 0.04}
     import subprocess
 
     old = subprocess.run(
@@ -256,6 +259,6 @@ def test_benchmark_json_keeps_or_parks_every_entry():
                 assert set(was["workloads"]) <= set(now["workloads"])
                 same["workloads"] = was["workloads"]
             if "bound" in was:
-                assert now["bound"] <= was["bound"]
+                assert now["bound"] <= was["bound"] or now["bound"] == refitted[was["name"]]
                 same["bound"] = was["bound"]
             assert same == was
