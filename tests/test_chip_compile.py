@@ -30,10 +30,11 @@ from torch_actor_critic_tpu.buffer.replay import (
 )
 from torch_actor_critic_tpu.core.types import Batch, BufferState
 from torch_actor_critic_tpu.models import Actor, DoubleCritic
-from torch_actor_critic_tpu.ops import pixels
+from torch_actor_critic_tpu.ops import moe, pixels
 from torch_actor_critic_tpu.ops.attention import flash_attention
 from torch_actor_critic_tpu.parallel import DataParallelSAC, make_mesh
 from torch_actor_critic_tpu.sac import SAC
+from torch_actor_critic_tpu.telemetry import scopes
 from torch_actor_critic_tpu.utils.config import SACConfig
 
 OBS_DIM, ACT_DIM = 17, 6  # HalfCheetah-v5, the reference flagship
@@ -147,6 +148,25 @@ def _as_large_as(hlo_text, elements, op):
     return found
 
 
+def _expert_layer_rows(hlo_text, op):
+    """The rows that every gather (its result; XLA:TPU lowers one to a fusion
+    that keeps its name) or scatter (its updates) of the expert layer moves."""
+    shape_of = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", hlo_text))
+    rows = []
+    for line in hlo_text.splitlines():
+        if scopes.TRUNK_MOE_EXPERTS not in line:
+            continue
+        if op == "gather":
+            m = re.search(r"%[\w.\-]+ = (\w+\[[\d,]*\])\S* (?:gather|fusion)\(.*/gather\"", line)
+            moved = m.group(1) if m else None
+        else:
+            m = re.search(r" scatter\(([^)]*)\)", line)
+            moved = shape_of[re.findall(r"%([\w.\-]+)", m.group(1))[2]] if m else None
+        if moved:
+            rows.append(int(re.findall(r"\d+", moved.split("[", 1)[1])[0]))
+    return rows
+
+
 def _flash_grouped(devices, block_length):
     """The three flash kernels at the SDAR trunk's shapes: 32 query heads
     over 4 shared key/value heads of 128 (read through the index maps),
@@ -201,10 +221,21 @@ def _trunk_burst(devices):
     ).compile().as_text()
     assert "ragged-dot" in text and text.count("tpu_custom_call") >= 3
     # Neither a scatter nor a gather whose result is as large as a ring leaf:
-    # the sample's gather is batch-sized, the expert layer's dispatch gather
-    # and combine scatter-add are 2 * tokens rows of the trunk's width.
+    # the sample's gather is batch-sized.
     assert _as_large_as(text, rows * history * OBS_DIM, "scatter") == []
     assert _as_large_as(text, rows * history * OBS_DIM, "gather") == []
+    # The expert layer gathers and scatter-adds a piece at a time, forward
+    # and backward, and runs only the pieces that hold a held row: a chunk is
+    # 2 * tokens rows here (16,384), and none of it moves in one operation.
+    for op in ("gather", "scatter"):
+        moved = _expert_layer_rows(text, op)
+        assert moved and max(moved) <= moe.PIECE_ROWS < 2 * 8 * history, (op, moved)
+    # The burst's state keeps the expert kernels as they rest: the transposed
+    # layout the input-gradient products want stays inside the layer (without
+    # ``moe._if_any`` XLA carries it up into the scan's state, a relayout of
+    # every kernel's gradient every step and half as much scratch again).
+    kernels = set(re.findall(r"f32\[(?:1,)?4,(?:256,128|128,256)\]\{([\d,]+)", text))
+    assert kernels and kernels <= {"2,1,0", "3,2,1,0"}, kernels
 
 
 # What may carry a whole ring leaf through a program without passing over

@@ -197,59 +197,168 @@ def _share(p, u, lo, hi, **kw):
     return out, plan
 
 
+def _held(p, lo=4, hi=8):
+    return {**p, **{k: p[k][lo:hi] for k in ("w_gate", "w_up", "w_down")}}
+
+
+def _one_chunk_form(p, u, lo, hi, bf16):
+    """The layer as it was written before the pieces: every sorted row gathered
+    at once, one grouped product a kernel over them all, one scatter-add."""
+    top_e, top_w = moe.route(u, p["router"], 4)
+    plan = moe.plan_assignments(top_e, (lo, hi))
+    tok = plan.order // 4
+    live = (jnp.arange(plan.order.shape[0]) < plan.n_rows)[:, None]
+    w = jnp.where(plan.held, top_w, 0.0).reshape(-1)[plan.order][:, None]
+    mxu = lambda x: x.astype(jnp.bfloat16) if bf16 else x  # noqa: E731
+    dot = lambda x, k: jax.lax.ragged_dot(  # noqa: E731
+        mxu(x), mxu(p[k][lo:hi]), plan.sizes, preferred_element_type=jnp.float32
+    )
+    xs = u[tok]
+    y = dot(jax.nn.silu(dot(xs, "w_gate")) * dot(xs, "w_up"), "w_down")
+    return jnp.zeros_like(u).at[tok].add(jnp.where(live, y, 0) * jnp.where(live, w, 0))
+
+
+# How the 92 assignments that experts 4-7 hold of `_expert_weights` fall into
+# chunks (``chunk_rows``) and a chunk into pieces (``moe.PIECE_ROWS``).
+LIVE = 92
+SPLITS = pytest.mark.parametrize("chunk_rows,piece_rows", [
+    pytest.param(None, None, id="default"),
+    pytest.param(LIVE + 1, None, id="one-row-under-the-chunk"),
+    pytest.param(LIVE, None, id="the-chunk-to-the-row"),
+    pytest.param(LIVE - 1, None, id="one-row-over-the-chunk"),
+    pytest.param(64, 16, id="two-chunks-of-four-pieces"),
+    pytest.param(LIVE, 23, id="four-whole-pieces"),
+    pytest.param(96, 32, id="the-last-piece-part-held"),
+])
+
+
+@pytest.fixture
+def pieces(monkeypatch):
+    def of(rows):
+        if rows is not None:
+            monkeypatch.setattr(moe, "PIECE_ROWS", rows)
+    return of
+
+
+def _starved(p, u):
+    """Inputs and a router under which no token chooses experts 4-7."""
+    return {**p, "router": p["router"].at[:, 4:8].set(-1.0)}, jnp.abs(u)
+
+
 @PRECISIONS
-def test_the_shares_partial_sums_add_up_to_the_whole_layer(mode, bf16):
+@SPLITS
+def test_the_shares_partial_sums_add_up_to_the_whole_layer(
+    chunk_rows, piece_rows, mode, bf16, pieces
+):
     """Guide section 4's share test: the partial sums of all four shares of
     4 experts add up to the uncut reference's layer output over all 16
     (attention is upstream of the split and counted once).  Tolerance:
-    float32 sums in another order."""
+    float32 sums in another order.  However the held rows fall into chunks
+    and pieces, a share is the one-chunk form's to the bit at float32: a
+    token's terms are added in the same order."""
+    pieces(piece_rows)
     p, u = _expert_weights()
     whole, _ = reference_trunk._moe(
         p, u, dict(experts_held=[0, 16], experts_per_tok=4), mode
     )
-    shares = [_share(p, u, lo, lo + 4, bf16_dots=bf16)[0] for lo in (0, 4, 8, 12)]
+    shares, plans = zip(*(
+        _share(p, u, lo, lo + 4, chunk_rows=chunk_rows, bf16_dots=bf16)
+        for lo in (0, 4, 8, 12)
+    ))
+    assert int(plans[1].n_rows) == LIVE
     np.testing.assert_allclose(sum(shares), whole, atol=2e-5)
     one, _ = reference_trunk._moe(
-        {**p, **{k: p[k][4:8] for k in ("w_gate", "w_up", "w_down")}}, u,
-        dict(experts_held=[4, 8], experts_per_tok=4), mode,
+        _held(p), u, dict(experts_held=[4, 8], experts_per_tok=4), mode
     )
     np.testing.assert_allclose(shares[1], one, atol=2e-5)  # the same terms left out
+    before = _one_chunk_form(p, u, 4, 8, bf16)
+    if bf16:  # the CPU's bfloat16 product blocks a row's sum by the batch's size
+        np.testing.assert_allclose(shares[1], before, atol=1e-6)
+    else:
+        np.testing.assert_array_equal(shares[1], before)
 
 
 @PRECISIONS
-@pytest.mark.parametrize("chunk_rows", [None, 64])
-def test_no_token_is_dropped_when_every_token_chooses_the_same_expert(chunk_rows, mode, bf16):
+@pytest.mark.parametrize("chunk_rows,piece_rows", [(None, None), (64, None), (64, 16), (None, 16)])
+def test_no_token_is_dropped_when_every_token_chooses_the_same_expert(
+    chunk_rows, piece_rows, mode, bf16, pieces
+):
     """Every token's first choice is expert 5, held here: it gets all 96
     tokens, four and a half times a balanced expert's 24, in one chunk or in
-    six, and the output is the dense one."""
+    six, a chunk in one piece or in many, and the output is the dense one."""
+    pieces(piece_rows)
     p, u = _expert_weights()
     u = jnp.abs(u)  # positive inputs, so a positive router column wins for every token
     p["router"] = (p["router"] * 1e-3).at[:, 5].set(1.0)
     out, plan = _share(p, u, 4, 8, chunk_rows=chunk_rows, bf16_dots=bf16)
     assert int(plan.sizes[1]) == u.shape[0] and int(plan.n_rows) >= u.shape[0]
     want, _ = reference_trunk._moe(
-        {**p, **{k: p[k][4:8] for k in ("w_gate", "w_up", "w_down")}}, u,
-        dict(experts_held=[4, 8], experts_per_tok=4), mode,
+        _held(p), u, dict(experts_held=[4, 8], experts_per_tok=4), mode
     )
     np.testing.assert_allclose(out, want, atol=2e-5)
 
 
 @PRECISIONS
-def test_expert_layer_gradients_match_the_dense_form_under_vmap(mode, bf16):
+@pytest.mark.parametrize("starved", [False, True], ids=["held", "no-row-held"])
+def test_a_share_nobody_chooses_is_zero_and_so_are_its_gradients(starved, mode, bf16):
+    """No assignment lands on experts 4-7: the share's output and its five
+    gradients are zeros (the dense form's too), and nothing of it runs. The
+    same inputs with the published router are the control."""
+    p, u = _expert_weights()
+    p, u = _starved(p, u) if starved else (p, jnp.abs(u))
+    loss = lambda u, p: jnp.sum(_share(p, u, 4, 8, bf16_dots=bf16)[0] ** 2)  # noqa: E731
+    out, plan = _share(p, u, 4, 8, bf16_dots=bf16)
+    grads = jax.tree_util.tree_leaves(jax.grad(loss, (0, 1))(u, p))
+    assert (int(plan.n_rows) == 0) == starved
+    assert all(bool(jnp.all(g == 0)) for g in [out] + grads) == starved
+    want, _ = reference_trunk._moe(
+        _held(p), u, dict(experts_held=[4, 8], experts_per_tok=4), mode
+    )
+    np.testing.assert_allclose(out, want, atol=2e-5)
+
+
+@PRECISIONS
+def test_rows_past_the_held_ones_reach_no_result(mode, bf16, pieces, monkeypatch):
+    """The buffers a loop fills piece by piece start uninitialised on the chip
+    (``lax.empty``): with NaN in their place, the output and the five
+    gradients are what cleared buffers give, to the bit, in two chunks of four
+    pieces each with its last piece part held."""
+    pieces(16)
+    p, u = _expert_weights()
+    loss = lambda u, p: jnp.sum(  # noqa: E731
+        _share(p, u, 4, 8, chunk_rows=64, bf16_dots=bf16)[0] ** 2
+    )
+    results = []
+    for fill in (jnp.zeros, lambda shape, dtype: jnp.full(shape, jnp.nan, dtype)):
+        monkeypatch.setattr(moe, "_buffer", fill)
+        out, _ = _share(p, u, 4, 8, chunk_rows=64, bf16_dots=bf16)
+        results.append([out] + jax.tree_util.tree_leaves(jax.grad(loss, (0, 1))(u, p)))
+    for cleared, poisoned in zip(*results):
+        np.testing.assert_array_equal(poisoned, cleared)
+
+
+@PRECISIONS
+@SPLITS
+def test_expert_layer_gradients_match_the_dense_form_under_vmap(
+    chunk_rows, piece_rows, mode, bf16, pieces
+):
     """The hand-written backward pass against autodiff of the dense form, and
     the same under ``vmap`` (the data-parallel burst maps the update over its
-    device axis), where both passes run a mapped element at a time."""
+    device axis), where both passes run a mapped element at a time.  A
+    kernel's gradient is summed chunk by chunk, so where a chunk's edge falls
+    changes the order of that sum and nothing else."""
+    pieces(piece_rows)
     p, u = _expert_weights()
+    chunk_rows = 64 if chunk_rows is None else chunk_rows  # the case this test had
 
     def dense(u, p):
         out, _ = reference_trunk._moe(
-            {**p, **{k: p[k][4:8] for k in ("w_gate", "w_up", "w_down")}}, u,
-            dict(experts_held=[4, 8], experts_per_tok=4), mode,
+            _held(p), u, dict(experts_held=[4, 8], experts_per_tok=4), mode
         )
         return jnp.sum(out ** 2)
 
     sparse = lambda u, p: jnp.sum(  # noqa: E731
-        _share(p, u, 4, 8, chunk_rows=64, bf16_dots=bf16)[0] ** 2
+        _share(p, u, 4, 8, chunk_rows=chunk_rows, bf16_dots=bf16)[0] ** 2
     )
     want = jax.grad(dense, (0, 1))(u, p)
     got = jax.grad(sparse, (0, 1))(u, p)

@@ -18,20 +18,52 @@ see PERF.md for why not a kernel of our own).
 
 How many assignments land here is data. The worst case is all ``N * top_k``
 of them; the expected number is ``N * top_k * (hi - lo) / num_experts``. The
-ragged batch is therefore processed in chunks of ``chunk_rows`` sorted rows
-under ``lax.cond``: a balanced router runs one chunk, an unbalanced one runs
-as many as it needs, and memory is one chunk's whatever the imbalance.
+ragged batch is therefore processed in chunks of ``chunk_rows`` sorted rows:
+a balanced router runs one chunk, an unbalanced one runs as many as it needs
+(the further ones under ``lax.cond``), and memory is one chunk's whatever the
+imbalance. A chunk is what the grouped products see, and they skip its dead
+tiles themselves. Everything else that costs in proportion to rows (the
+dispatch gather, the casts, the silu-multiply, the masks, the combine)
+follows the *counted* rows: it runs in pieces of ``PIECE_ROWS`` rows under a
+loop whose trip count is the chunk's held rows, so the pieces past them are
+never run. At the trunk cell's routing a call holds 6,041 to 9,808 rows where
+8,192 are expected, by layer and seed and hardly by batch (the program's
+counter over 4 seeds, 4 layers and 6 batches, counted on the CPU; 7,818 to
+8,636 on the chip, seed 3700022001): no fixed size sits just over that, which
+is why the pieces follow the count and the chunk keeps its margin. Before,
+all of it passed over the chunk's 16,384 rows (my chip runs, PR 37, one step
+of the cell, microseconds before -> after): scatter-adds 17,740 -> 9,250,
+gathers 6,250 -> 2,890, casts, silu-multiply and masks 11,140 -> 4,540, the
+zeros and sums of the kernels' gradients 7,240 -> 150, the grouped products
+28,990 -> 28,470; the layer alone, forward and backward, 17.03 -> 12.23 ms.
 
-Rows are dispatched by one gather (the sorted rows' token index) and
-combined by one scatter-add onto their tokens. On the v5e the scatter-add of
-16,384 rows of 2,048 floats takes 1.95 ms where the gather-only form (eight
-gathers of 8,192 rows through the inverse permutation, most of them of
-assignments held elsewhere) took 3.43 ms and a second sort (my chip run, PR
-26). The backward pass is written the same way (``jax.custom_vjp``: autodiff
+Rows are dispatched by a gather (the sorted rows' token index) and combined
+by a scatter-add onto their tokens, a piece at a time and the pieces in
+order, so a token's terms are added in the order of its sorted rows. On the
+v5e a scatter-add costs 92 ns a row of 2,048 floats whatever its size (1.41 ms
+for 16,384 rows, 47 us for 512: my chip runs, PR 37) and a gather of bfloat16
+rows 28 ns; the gather-only form (eight gathers of 8,192 rows through the
+inverse permutation, most of them of assignments held elsewhere) took 3.43 ms
+and a second sort (my chip run, PR 26). Pieces of 512, 1,024 and 2,048 rows
+read 12.84, 12.97 and 16.31 ms for the layer (my chip run, PR 37). A buffer a
+loop fills piece by piece is not cleared first (``lax.empty``: 2.3 ms a step
+of zeros otherwise): a piece is whole tiles of the grouped product, so every
+tile the product visits lies in a piece that was written, and rows of no
+group do not reach its results (NaN there changes nothing, on the chip and on
+the CPU: my chip run, PR 37, and ``tests/test_trunk.py``).
+
+The backward pass is written the same way (``jax.custom_vjp``: autodiff
 would turn the dispatch gather into a scatter and the combine into a gather
 on its own), recomputing a chunk's hidden activations from its gathered
-rows, so the layer keeps no residual but its inputs. Under ``vmap`` both
-passes run one mapped element at a time.
+rows, so the layer keeps no residual but its inputs. The first chunk's
+kernel gradients are the gradients; only a further chunk adds to them (a sum
+of three whole kernels, 302 MB of float32, 1.1 ms). Both passes stand under
+one ``lax.cond`` on there being a held row at all: besides skipping a call
+that holds none, it keeps XLA's layout assignment from carrying the
+transposed kernel layout the input-gradient products want up into the
+burst's state (it cost a float32 relayout of every kernel's gradient every
+step and 3.5 GB: sandbox compiles, PR 37). Under ``vmap`` both passes run
+one mapped element at a time.
 """
 
 from __future__ import annotations
@@ -90,10 +122,14 @@ def plan_assignments(top_e: jax.Array, held: t.Tuple[int, int]) -> Plan:
 
 
 def default_chunk_rows(n_tokens: int, top_k: int, n_held: int, n_experts: int) -> int:
-    """Twice the expected number of held assignments, in whole 512-row tiles,
-    at most all of them."""
+    """Twice the expected number of held assignments, in whole pieces, at most
+    all of them. A chunk sizes the buffers and what one grouped product is
+    handed, no longer the row work (pieces, module docstring): so it keeps the
+    margin that lets a router 1.2 times over the expected rows (the most the
+    cell's counter read, PR 37) and a good deal worse run one chunk, since a
+    second one costs the backward pass 1.1 ms of kernel-gradient sums."""
     expected = n_tokens * top_k * n_held / n_experts
-    rows = -(-int(2 * expected) // 512) * 512
+    rows = -(-int(2 * expected) // PIECE_ROWS) * PIECE_ROWS
     return max(min(rows, n_tokens * top_k), 1)
 
 
@@ -103,6 +139,10 @@ _BY_GROUP = jax.lax.RaggedDotDimensionNumbers(
     dot_dimension_numbers=(((0,), (0,)), ((), ())),
     lhs_ragged_dimensions=[0], rhs_group_dimensions=[],
 )
+
+# Rows of one piece of a chunk: what one gather, one elementwise pass or one
+# scatter-add moves. One 512-row tile of XLA:TPU's grouped product.
+PIECE_ROWS = 512
 
 
 def _mxu(x, bf16_dots: bool):
@@ -114,77 +154,128 @@ def _mxu(x, bf16_dots: bool):
     the same time alone; my chip run, PR 26), but handed bfloat16 it reads
     half the bytes, and inside the burst the grouped products went from 41.6
     to 28-31 ms a step."""
-    if bf16_dots and x.dtype == jnp.float32:
-        return x.astype(jnp.bfloat16)
-    return x
+    return x.astype(_mxu_dtype(x.dtype, bf16_dots))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _gmm(x, w, sizes, bf16_dots):
+def _mxu_dtype(dtype, bf16_dots: bool):
+    return jnp.bfloat16 if bf16_dots and dtype == jnp.float32 else dtype
+
+
+def _gmm(x, w, sizes):
     """``x`` ``(rows, k)`` sorted by group times ``w`` ``(groups, k, n)``."""
-    return jax.lax.ragged_dot(
-        _mxu(x, bf16_dots), _mxu(w, bf16_dots), sizes,
-        preferred_element_type=jnp.float32,
+    return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=jnp.float32)
+
+
+def _gmm_by_group(x, g, sizes):
+    """``(groups, k, n)``: each group's rows of ``x`` ``(rows, k)`` against
+    its rows of ``g`` ``(rows, n)``."""
+    return jax.lax.ragged_dot_general(
+        x, g, sizes, _BY_GROUP, preferred_element_type=jnp.float32
     )
 
 
-def _gmm_fwd(x, w, sizes, bf16_dots):
-    return _gmm(x, w, sizes, bf16_dots), (x, w, sizes)
-
-
-def _gmm_bwd(bf16_dots, res, g):
-    x, w, sizes = res
-    g = _mxu(g, bf16_dots)
-    dx = jax.lax.ragged_dot(
-        g, _mxu(jnp.swapaxes(w, 1, 2), bf16_dots), sizes,
-        preferred_element_type=jnp.float32,
-    )
-    dw = jax.lax.ragged_dot_general(
-        _mxu(x, bf16_dots), g, sizes, _BY_GROUP, preferred_element_type=jnp.float32
-    )
-    return dx.astype(x.dtype), dw.astype(w.dtype), None
-
-
-_gmm.defvjp(_gmm_fwd, _gmm_bwd)
-
-
-def _core(xs, w_gate, w_up, w_down, sizes, bf16_dots):
-    """The expert network on a ragged batch sorted by expert."""
-    hidden = jax.nn.silu(_gmm(xs, w_gate, sizes, bf16_dots)) * _gmm(
-        xs, w_up, sizes, bf16_dots
-    )
-    return _gmm(hidden, w_down, sizes, bf16_dots)
+def _silu_mul(a, b):
+    return jax.nn.silu(a) * b
 
 
 class _Chunk(t.NamedTuple):
     flat: jax.Array   # (R,) flat assignment index n*K+k of each sorted row
     tok: jax.Array    # (R,) its token
-    w: jax.Array      # (R,) its routing weight, 0 past the held rows
     live: jax.Array   # (R, 1) whether the row is a held assignment at all
     sizes: jax.Array  # (E_held,) rows of each expert inside the chunk
+    n_live: jax.Array  # () held rows inside the chunk
 
 
-def _chunk(plan: Plan, top_w, c, rows: int) -> _Chunk:
-    k = top_w.shape[1]
+def _chunk(plan: Plan, k: int, c, rows: int) -> _Chunk:
     r0 = c * rows
     flat = jax.lax.dynamic_slice(plan.order, (r0,), (rows,))
     live = r0 + jnp.arange(rows, dtype=jnp.int32) < plan.n_rows
-    w = jnp.where(live, jnp.take(top_w.reshape(-1), flat), 0.0)
     ends = jnp.minimum(plan.starts + plan.sizes, r0 + rows)
     sizes = jnp.maximum(ends - jnp.maximum(plan.starts, r0), 0)
-    return _Chunk(flat, flat // k, w, live[:, None], sizes)
+    return _Chunk(
+        flat, flat // k, live[:, None], sizes, jnp.clip(plan.n_rows - r0, 0, rows)
+    )
 
 
-def _over_chunks(plan: Plan, rows: int, total: int, body, init):
-    """``body(c, carry)`` for every chunk that holds a held row."""
+# What a buffer that a loop fills piece by piece starts as: nothing (module
+# docstring). A test puts NaN there.
+_buffer = jax.lax.empty
+
+
+def _weights(top_w, flat):
+    """The routing weights ``(rows, 1)`` of the assignments ``flat``."""
+    return jnp.take(top_w.reshape(-1), flat)[:, None]
+
+
+def _pieces(ch: _Chunk, body, init):
+    """``body(r0, rows_of, carry)`` for every piece of the chunk that holds a
+    held row, in order: ``r0`` is the piece's first row and ``rows_of(x)`` its
+    rows of a chunk-long ``x``. The trip count is data: the pieces past the
+    held rows are never run."""
+    rows = ch.tok.shape[0]
+    piece = min(PIECE_ROWS, rows)
+
+    def step(i, carry):
+        r0 = i * piece
+
+        def rows_of(x):
+            return jax.lax.dynamic_slice_in_dim(x, r0, piece, axis=0)
+
+        return body(r0, rows_of, carry)
+
+    return jax.lax.fori_loop(0, -(-ch.n_live // piece), step, init)
+
+
+def _filled(ch: _Chunk, width: int, dtype, piece_of):
+    """A chunk-long ``(R, width)`` buffer whose pieces with a held row are
+    ``piece_of(rows_of)``; the rows past them are never written and belong to
+    no group."""
+    def body(r0, rows_of, buf):
+        return jax.lax.dynamic_update_slice_in_dim(buf, piece_of(rows_of), r0, axis=0)
+
+    return _pieces(ch, body, _buffer((ch.tok.shape[0], width), dtype))
+
+
+def _dispatched(ch: _Chunk, x):
+    """The chunk's rows of ``x`` ``(N, H)``: one gather a piece."""
+    return _filled(
+        ch, x.shape[1], x.dtype, lambda rows_of: jnp.take(x, rows_of(ch.tok), axis=0)
+    )
+
+
+def _combined(ch: _Chunk, out, piece_of):
+    """``out`` with the chunk's held rows ``piece_of(rows_of)`` added onto
+    their tokens: one scatter-add a piece, the pieces in order, so a token's
+    terms are added in the order of its sorted rows. Rows past the held ones
+    belong to no group: whatever the grouped product left there is cut off."""
+    def body(r0, rows_of, out):
+        return out.at[rows_of(ch.tok)].add(jnp.where(rows_of(ch.live), piece_of(rows_of), 0))
+
+    return _pieces(ch, body, out)
+
+
+def _hidden(ch: _Chunk, xs, w_gate, w_up, bf16_dots: bool):
+    """Gate and up products of the dispatched rows, and the hidden
+    activations as the down product takes them."""
+    a, b = _gmm(xs, w_gate, ch.sizes), _gmm(xs, w_up, ch.sizes)
+    hidden = _filled(
+        ch, a.shape[1], _mxu_dtype(a.dtype, bf16_dots),
+        lambda rows_of: _mxu(_silu_mul(rows_of(a), rows_of(b)), bf16_dots),
+    )
+    return a, b, hidden
+
+
+def _over_chunks(plan: Plan, rows: int, total: int, body, first):
+    """``body(c, carry)`` for every chunk past the first that holds a held
+    row; ``first`` is the carry the first chunk left."""
     n_chunks = -(-total // rows)
     if n_chunks == 1:
-        return body(0, init)
+        return first
 
     def step(c, carry):
         return jax.lax.cond(c * rows < plan.n_rows, lambda x: body(c, x), lambda x: x, carry)
 
-    return jax.lax.fori_loop(0, n_chunks, step, init)
+    return jax.lax.fori_loop(1, n_chunks, step, first)
 
 
 def _padded(plan: Plan, rows: int) -> Plan:
@@ -196,43 +287,100 @@ def _padded(plan: Plan, rows: int) -> Plan:
     return plan._replace(order=jnp.pad(plan.order, (0, pad)))
 
 
+def _if_any(run):
+    """``run(u, w_gate, w_up, w_down, top_w, plan, ...)`` where the call holds
+    a row at all, else zeros (module docstring)."""
+    def guarded(*operands):
+        def nothing(*operands):
+            return jax.tree_util.tree_map(
+                lambda s: jnp.zeros(s.shape, s.dtype), jax.eval_shape(run, *operands)
+            )
+
+        plan = operands[5]
+        return jax.lax.cond(plan.n_rows > 0, run, nothing, *operands)
+
+    return guarded
+
+
 def _forward(rows: int, bf16_dots: bool, u, w_gate, w_up, w_down, top_w, plan: Plan):
     total = top_w.size
     padded = _padded(plan, rows)
+    # A token's row is rounded before it is gathered: the same values, half
+    # the rows of a chunk and half the bytes.
+    x = _mxu(u, bf16_dots)
 
     def body(c, out):
-        ch = _chunk(padded, top_w, c, rows)
-        y = _core(jnp.take(u, ch.tok, axis=0), w_gate, w_up, w_down, ch.sizes, bf16_dots)
-        # Rows past the held ones belong to no group: whatever the grouped
-        # product left there is cut off, and they add 0 to some token.
-        return out.at[ch.tok].add(jnp.where(ch.live, y, 0) * ch.w[:, None])
+        ch = _chunk(padded, top_w.shape[1], c, rows)
+        gate, up, down = (_mxu(w, bf16_dots) for w in (w_gate, w_up, w_down))
+        _, _, hidden = _hidden(ch, _dispatched(ch, x), gate, up, bf16_dots)
+        y = _gmm(hidden, down, ch.sizes)
+        return _combined(ch, out, lambda rows_of: rows_of(y) * _weights(top_w, rows_of(ch.flat)))
 
-    return _over_chunks(plan, rows, total, body, jnp.zeros_like(u))
+    return _over_chunks(plan, rows, total, body, body(0, jnp.zeros_like(u)))
 
 
 def _backward(rows: int, bf16_dots: bool, u, w_gate, w_up, w_down, top_w, plan: Plan, g):
     total = top_w.size
     padded = _padded(plan, rows)
+    x = _mxu(u, bf16_dots)
+
+    def chunk(c, du, dw):
+        """The chunk's kernel gradients, and ``du`` and ``dw`` with its rows'
+        added. A chunk's activations are computed again from its gathered
+        rows, as the forward pass computed them."""
+        ch = _chunk(padded, top_w.shape[1], c, rows)
+        gate, up, down = (_mxu(w, bf16_dots) for w in (w_gate, w_up, w_down))
+        # The input gradients' products take a kernel transposed.
+        t_gate, t_up, t_down = (jnp.swapaxes(w, 1, 2) for w in (gate, up, down))
+        xs = _dispatched(ch, x)
+        a, b, hidden = _hidden(ch, xs, gate, up, bf16_dots)
+        y = _gmm(hidden, down, ch.sizes)
+
+        def through_down(r0, rows_of, carry):
+            gy, dw = carry
+            g_rows = jnp.take(g, rows_of(ch.tok), axis=0)
+            live, flat = rows_of(ch.live), rows_of(ch.flat)
+            piece = _mxu(jnp.where(live, g_rows * _weights(top_w, flat), 0), bf16_dots)
+            # d out / d weight of a row is <y_row, g_row>.
+            d_rows = jnp.sum(jnp.where(live, rows_of(y) * g_rows, 0), axis=-1)
+            return (
+                jax.lax.dynamic_update_slice_in_dim(gy, piece, r0, axis=0),
+                dw.at[flat].add(d_rows),
+            )
+
+        gy, dw = _pieces(
+            ch, through_down, (_buffer((rows, g.shape[1]), _mxu_dtype(g.dtype, bf16_dots)), dw)
+        )
+        d_hidden = _gmm(gy, t_down, ch.sizes)
+
+        def through_act(r0, rows_of, carry):
+            _, vjp = jax.vjp(_silu_mul, rows_of(a), rows_of(b))
+            return tuple(
+                jax.lax.dynamic_update_slice_in_dim(buf, _mxu(d, bf16_dots), r0, axis=0)
+                for buf, d in zip(carry, vjp(rows_of(d_hidden)))
+            )
+
+        da, db = _pieces(
+            ch, through_act, tuple(_buffer(hidden.shape, hidden.dtype) for _ in range(2))
+        )
+        dx_gate, dx_up = _gmm(da, t_gate, ch.sizes), _gmm(db, t_up, ch.sizes)
+        kernels = (
+            _gmm_by_group(xs, da, ch.sizes), _gmm_by_group(xs, db, ch.sizes),
+            _gmm_by_group(hidden, gy, ch.sizes),
+        )
+        du = _combined(ch, du, lambda rows_of: rows_of(dx_gate) + rows_of(dx_up))
+        return kernels, du, dw
 
     def body(c, carry):
-        du, dg, dup, ddown, dw = carry
-        ch = _chunk(padded, top_w, c, rows)
-        y, vjp = jax.vjp(
-            lambda xs, a, b, d: _core(xs, a, b, d, ch.sizes, bf16_dots),
-            jnp.take(u, ch.tok, axis=0), w_gate, w_up, w_down,
-        )
-        g_rows = jnp.take(g, ch.tok, axis=0)
-        dxs, a, b, d = vjp(jnp.where(ch.live, g_rows * ch.w[:, None], 0))
-        # d out / d weight of a row is <y_row, g_row>.
-        dw_rows = jnp.sum(jnp.where(ch.live, y * g_rows, 0), axis=-1)
-        dw = dw.reshape(-1).at[ch.flat].add(dw_rows).reshape(dw.shape)
-        return (
-            du.at[ch.tok].add(jnp.where(ch.live, dxs, 0)),
-            dg + a, dup + b, ddown + d, dw,
-        )
+        kernels, du, dw = carry
+        more, du, dw = chunk(c, du, dw)
+        # A kernel's gradient is summed chunk by chunk.
+        return tuple(k + m for k, m in zip(kernels, more)), du, dw
 
-    zeros = jax.tree_util.tree_map(jnp.zeros_like, (u, w_gate, w_up, w_down, top_w))
-    return _over_chunks(plan, rows, total, body, zeros)
+    first = chunk(0, jnp.zeros_like(u), jnp.zeros(top_w.size, top_w.dtype))
+    kernels, du, dw = _over_chunks(plan, rows, total, body, first)
+    dw = dw.reshape(top_w.shape)
+    return (du, *(k.astype(w.dtype) for k, w in zip(kernels, (w_gate, w_up, w_down))), dw)
 
 
 @functools.lru_cache(maxsize=None)
@@ -244,10 +392,10 @@ def _experts_for(rows: int, bf16_dots: bool):
     has no batched form on the TPU, and a batched ``lax.cond`` would run
     every chunk of every element."""
     forward = jax.custom_batching.sequential_vmap(
-        functools.partial(_forward, rows, bf16_dots)
+        _if_any(functools.partial(_forward, rows, bf16_dots))
     )
     backward = jax.custom_batching.sequential_vmap(
-        functools.partial(_backward, rows, bf16_dots)
+        _if_any(functools.partial(_backward, rows, bf16_dots))
     )
 
     @jax.custom_vjp
@@ -284,6 +432,8 @@ def expert_ffn(
     if chunk_rows is None:
         chunk_rows = default_chunk_rows(n, k, n_held, num_experts or n_held)
     chunk_rows = min(chunk_rows, n * k)
+    if chunk_rows > PIECE_ROWS:  # whole pieces
+        chunk_rows = -(-chunk_rows // PIECE_ROWS) * PIECE_ROWS
     plan = plan_assignments(top_e, held)
     top_w = jnp.where(plan.held, top_w, 0.0)  # an absent term has no gradient here
     experts = _experts_for(chunk_rows, bool(bf16_dots))
