@@ -19,7 +19,9 @@ one process at a time, so this parent never imports JAX):
    bucket programs warm, ``/act`` at several batch sizes (deterministic
    and sampled), ``/healthz``, ``/metrics``, SIGTERM -> exit 0.
 3. ``kernels`` — flash attention forward+backward against
-   ``reference_attention`` (128 and 64 lanes), one sequence-policy update
+   ``reference_attention`` (128 and 64 lanes), the trunk's one pass ahead of
+   those kernels (head norm, rotary, heads first) there and back against the
+   composition it replaces, at the trunk cell's shapes, one sequence-policy update
    burst, and the fused pixel kernel bit for bit against
    ``gather_frames_reference`` — compiled, not interpreted.
 4. ``fused``   — one ``--on-device true`` epoch through ``train``.
@@ -389,6 +391,7 @@ def phase_kernels(args) -> dict:
     from torch_actor_critic_tpu.ops import pixels
     from torch_actor_critic_tpu.ops.attention import (
         flash_attention,
+        qk_norm_rope,
         reference_attention,
     )
     from torch_actor_critic_tpu.sac import SAC
@@ -432,6 +435,29 @@ def phase_kernels(args) -> dict:
             "causal": causal, "t": t, "d": d, "lanes": lanes,
             "max_abs_err": float(jnp.max(jnp.abs(out_f - out_r))),
         })
+
+    # The one pass between q_proj and the kernels (head norm, rotary, heads
+    # first), there and back, against the composition it replaces, at the
+    # trunk cell's shapes: 8 x 1024 tokens, 32 query and 4 key/value heads of
+    # 128. Float32 either way, so sums in another order at most.
+    batch, t = (1, 64) if args.rehearsal else (8, 1024)
+    pos = 3 + jnp.arange(t)
+    facts["qk_rope"] = []
+    for heads in (32, 4):
+        ky, kw, kg, key = jax.random.split(key, 4)
+        y = jax.random.normal(ky, (batch, t, heads, 128), jnp.float32)
+        w = 1.0 + 0.1 * jax.random.normal(kw, (128,), jnp.float32)
+        g = jax.random.normal(kg, (batch, heads, t, 128), jnp.float32)
+        (out_f, vjp_f), (out_r, vjp_r) = (
+            jax.vjp(lambda y, w: qk_norm_rope(y, w, pos, 1e6, 1e-6, impl), y, w)
+            for impl in ("interpret" if interpret else "pallas", "xla")
+        )
+        gaps = [
+            float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+            for a, b in zip((out_f, *vjp_f(g)), (out_r, *vjp_r(g)))
+        ]
+        require(max(gaps) < 1e-5, "the one pass left the composition", gaps=gaps)
+        facts["qk_rope"].append({"heads": heads, "t": t, "rel_gaps": gaps})
 
     # The sequence policy at its shipped shape: attention sees
     # [64, 4, 8, 16] (batch 64, 4 heads, 8 steps of history, d_model 64).

@@ -14,12 +14,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from flax import linen as nn
+
 from torch_actor_critic_tpu.models import SequenceActor, SequenceDoubleCritic
 from jax import shard_map
+from torch_actor_critic_tpu.ops import attention as attention_ops
 from torch_actor_critic_tpu.ops.attention import (
     attention,
     blockwise_attention,
     flash_attention,
+    qk_norm_rope,
     reference_attention,
 )
 from torch_actor_critic_tpu.parallel import make_mesh
@@ -373,3 +377,137 @@ def test_flash_surface_has_no_offset_masking():
     assert {"q_offset", "k_offset"} <= set(
         inspect.signature(attention.blockwise_attention).parameters
     )
+
+
+# ------------------------- between the projections and the kernels, each way
+
+
+def _composed(y, weight, pos):
+    """What the one pass replaces: ``RMSNorm``'s arithmetic, ``rotary`` and
+    the heads' transposition, one after another (``impl='xla'`` is this
+    composition, held to its spelling here)."""
+    var = jnp.mean(y * y, axis=-1, keepdims=True)
+    normed = y * jax.lax.rsqrt(var + 1e-6) * weight
+    return attention_ops.rotary(normed, pos, 1e6).transpose(0, 2, 1, 3)
+
+
+def _one_pass(y, weight, pos):
+    return qk_norm_rope(y, weight, pos, 1e6, 1e-6, "interpret")
+
+
+def _remat(fn):
+    """``fn`` inside a block that ``nn.remat`` recomputes, as the trunk's
+    first block is."""
+
+    class Block(nn.Module):
+        @nn.compact
+        def __call__(self, y, weight, pos):
+            return fn(y, weight, pos)
+
+    return lambda y, weight, pos: nn.remat(Block)().apply({}, y, weight, pos)
+
+
+def _vmapped(fn):
+    """``fn`` under the burst's ``vmap`` over its device axis: activations
+    and weights batched, positions shared."""
+    return lambda y, weight, pos: jax.vmap(fn, in_axes=(0, 0, None))(
+        jnp.stack([y, 2.0 * y]), jnp.stack([weight, weight + 0.5]), pos
+    )
+
+
+WRAPS = {"plain": lambda fn: fn, "vmap": _vmapped, "remat": _remat}
+
+
+def _close(got, want, rel=1e-6):
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_allclose(got, want, atol=rel * float(jnp.max(jnp.abs(want))))
+
+
+@pytest.mark.parametrize("heads", [32, 4], ids=["32-heads", "4-heads"])
+@pytest.mark.parametrize("pos_offset", [0, 37])
+@pytest.mark.parametrize("wrap", sorted(WRAPS))
+def test_one_pass_norm_rope_transpose_matches_the_composition(wrap, pos_offset, heads):
+    """Output and the gradients to the projection's output and to the norm's
+    weight, for the trunk's 32 query heads and for 4 (its key/value heads, or
+    an ungrouped layer's), tables made at ``pos`` (a chunk that starts at 37
+    as under sequence sharding): float32, the output to 1e-6 of the largest
+    value, the gradients to 1e-5."""
+    k = jax.random.split(jax.random.key(heads), 3)
+    b, t, d = 2, 16, 128
+    y = jax.random.normal(k[0], (b, t, heads, d))
+    weight = 1.0 + 0.1 * jax.random.normal(k[1], (d,))
+    pos = pos_offset + jnp.arange(t)
+    got_fn, want_fn = WRAPS[wrap](_one_pass), WRAPS[wrap](_composed)
+    want = want_fn(y, weight, pos)
+    cot = jax.random.normal(k[2], want.shape)
+    _close(got_fn(y, weight, pos), want)
+    _close(WRAPS[wrap](lambda *a: qk_norm_rope(*a, 1e6, 1e-6, "xla"))(y, weight, pos), want)
+    grads = lambda fn: jax.grad(  # noqa: E731
+        lambda y, w: jnp.sum(fn(y, w, pos) * cot), (0, 1)
+    )(y, weight)
+    for got, want in zip(grads(got_fn), grads(want_fn)):
+        _close(got, want, rel=1e-5)  # the weight's sums 2,048 rows in another order
+
+
+def test_the_passes_blocks_tile_whole_lanes_and_sublanes():
+    fits = attention_ops._pass_fits
+    assert fits(1024, 32, 128, jnp.float32) and fits(8, 1, 256, jnp.float32)
+    assert not fits(1024, 32, 64, jnp.float32)  # half a lane row a head
+    assert not fits(12, 4, 128, jnp.float32)  # no block of whole sublanes
+    assert not fits(1024, 32, 128, jnp.bfloat16)  # float32 statistics, float32 tiles
+    # four heads a block (a kernel's body is unrolled over them), 2 MiB of rows
+    assert attention_ops._pass_block(1024, 32, 128) == (1024, 4)
+    assert attention_ops._pass_block(2048, 4, 128) == (1024, 4)
+    assert attention_ops._pass_block(16, 3, 256) == (16, 3)
+    # on a CPU 'auto' composes, and never reaches a kernel
+    y = jnp.ones((1, 8, 2, 128))
+    jaxpr = jax.make_jaxpr(lambda y: qk_norm_rope(y, jnp.ones(128), jnp.arange(8), 1e6, 1e-6))(y)
+    assert "pallas_call" not in str(jaxpr)
+
+
+@pytest.mark.parametrize("kv_heads", [8, 1], ids=["ungrouped", "group-8"])
+@pytest.mark.parametrize("block_length", [1, 4])
+def test_flash_gradients_from_compact_row_statistics(block_length, kv_heads):
+    """The backward kernels read ``lse`` and ``delta`` as the forward kernel
+    and ``delta``'s reduce wrote them, a row of statistics in the lanes
+    ``(batch * heads, 1, seq)``: no lane-wide copy is kept, and output and
+    gradients are the dense reference's, two q blocks by two k blocks."""
+    k = jax.random.split(jax.random.key(block_length), 4)
+    q = jax.random.normal(k[0], (2, 8, 16, 8))
+    kk, v = (jax.random.normal(x, (2, kv_heads, 16, 8)) for x in k[1:3])
+    cot = jax.random.normal(k[3], q.shape)
+    flash = lambda q, k, v: flash_attention(q, k, v, True, 8, 8, True, 128, block_length)  # noqa: E731
+    ref = lambda q, k, v: reference_attention(q, k, v, True, block_length=block_length)  # noqa: E731
+    out, residuals = attention_ops._flash_fwd(q, kk, v, True, 8, 8, True, 128, block_length, False)
+    assert residuals[-1].shape == (2 * 8, 1, 16)
+    np.testing.assert_allclose(out, ref(q, kk, v), atol=5e-6)
+    for got, want in zip(jax.vjp(flash, q, kk, v)[1](cot), jax.vjp(ref, q, kk, v)[1](cot)):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def test_the_way_out_is_the_transposition():
+    """The kernels' ``(batch, heads, seq, d)`` reaches ``o_proj`` as
+    ``transpose`` + ``reshape`` make it, ``(batch, seq, heads * d)`` with a
+    head's ``d`` together, and the cotangent comes back the same way (XLA's
+    own relayouts: a pass of ours there measured slower, PERF.md section 6)."""
+    from torch_actor_critic_tpu.models.sequence import GroupedQueryAttention, TrunkSpec
+
+    spec = TrunkSpec(hidden=16, q_heads=4, kv_heads=2, head_dim=8, bf16_dots=False)
+    seen = {}
+
+    def spy(q, k, v, causal=True, **mask):
+        seen["shapes"] = (q.shape, k.shape, v.shape)
+        # head h's row t holds 100 h + t in every column
+        out = 100.0 * jnp.arange(4.0)[None, :, None, None] + jnp.arange(6.0)[None, None, :, None]
+        return jnp.broadcast_to(out, q.shape)
+
+    layer = GroupedQueryAttention(spec, attention_fn=spy)
+    u = jnp.ones((2, 6, 16))
+    params = layer.init(jax.random.key(0), u, jnp.arange(6))
+    # an o_proj that keeps the first 16 columns: heads 0 and 1
+    params["params"]["o_proj"]["kernel"] = jnp.eye(32, 16)
+    out = layer.apply(params, u, jnp.arange(6))
+    assert seen["shapes"] == ((2, 4, 6, 8), (2, 2, 6, 8), (2, 2, 6, 8))
+    want = jnp.concatenate([jnp.broadcast_to(100.0 * h + jnp.arange(6.0)[:, None], (6, 8)) for h in (0, 1)], -1)
+    np.testing.assert_array_equal(out[0], want)

@@ -216,10 +216,29 @@ def _trunk_burst(devices):
     index = jax.ShapeDtypeStruct((1,), jnp.int32)
     ring = BufferState(data=ring_of(rows), ptr=index, size=index)
     chunk = ring_of(cfg.update_every)
-    text = learner._build_burst(cfg.update_every, state, ring, chunk).lower(
+    compiled = learner._build_burst(cfg.update_every, state, ring, chunk).lower(
         state, ring, chunk
-    ).compile().as_text()
+    ).compile()
+    text = compiled.as_text()
     assert "ragged-dot" in text and text.count("tpu_custom_call") >= 3
+    # ISSUE 39: q's one pass ahead of the flash kernels is taken under the
+    # burst's ``vmap`` (the target's and the online trunk's forward, and one
+    # back), and under the attention scope nothing else transposes or copies
+    # a float32 activation of q's size between the projections' layout and
+    # the kernels', nor broadcasts the row statistics across lanes, but XLA's
+    # two relayouts of ``o_proj``'s cotangent in the one backward pass (the
+    # parent's burst at the cell's widths holds 29 of these, this one 8).
+    kinds = [_kernel_kind(name) for name in _kernels(text)]
+    assert kinds.count("qk-rope") >= 2 and kinds.count("qk-rope-bwd") >= 1, kinds
+    left = _relayouts_round_the_kernels(
+        text, cfg.batch_size, history, cfg.trunk_q_heads, cfg.trunk_head_dim
+    )
+    assert len(left) <= 2 and all(what.startswith("copy") for what, _ in left), left
+    # The compiler's account of this cut program's peak: 2,177,838,592 B at the
+    # parent, 2,170,855,424 with the pass (the residuals kept are the same:
+    # q_proj's output for the pass back, the kernels' q, k, v and o; ``lse``
+    # 128 times smaller). A layout carried into the burst's state shows here.
+    assert compiled.memory_analysis().peak_memory_in_bytes < 2.175e9
     # Neither a scatter nor a gather whose result is as large as a ring leaf:
     # the sample's gather is batch-sized.
     assert _as_large_as(text, rows * history * OBS_DIM, "scatter") == []
@@ -236,6 +255,180 @@ def _trunk_burst(devices):
     # every kernel's gradient every step and half as much scratch again).
     kernels = set(re.findall(r"f32\[(?:1,)?4,(?:256,128|128,256)\]\{([\d,]+)", text))
     assert kernels and kernels <= {"2,1,0", "3,2,1,0"}, kernels
+
+
+def _kernels(hlo_text):
+    """Names of the Mosaic kernels' instructions."""
+    return re.findall(
+        r"%([\w.\-]+) = [^=]*? custom-call\(.*custom_call_target=\"tpu_custom_call\"",
+        hlo_text,
+    )
+
+
+def _kernel_kind(name):
+    from benchmark.harness import trace
+
+    return trace.op_kind(name)
+
+
+def _reads_as_attention(kind):
+    """``benchmark/harness/trace.py::kind_seconds``'s rule for the tag that
+    ``trunk.flash_roofline`` reads (``harness/trunk_read.py::FLASH``)."""
+    from benchmark.harness import trace, trunk_read
+
+    return trace.kind_seconds({"by_kind": {kind: 1.0}}, trunk_read.FLASH) == 1.0
+
+
+def _relayouts_round_the_kernels(hlo_text, batch, history, heads, d):
+    """Instructions under the attention scope (fused ones too) that write a
+    float32 array of q's size in the projections' layout ``[batch, history,
+    heads, d]`` or the kernels' ``[batch, heads, history, d]`` /
+    ``[batch * heads, history, d]`` by a ``transpose`` or a ``copy``, or a
+    lane-wide copy of the row statistics ``[batch * heads, history, 128]`` by
+    a ``broadcast``: what ISSUE 39 took out of the program, as ``(what, the
+    instruction's line)``. (Under the burst's ``vmap`` the shapes carry a
+    leading 1.)"""
+    q_forms = {
+        f"{batch},{history},{heads},{d}", f"{batch},{heads},{history},{d}",
+        f"{batch * heads},{history},{d}",
+    }
+    found = []
+    for line in hlo_text.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?%([\w.\-]+) = f32\[(?:1,)?([\d,]*)\]\S* (transpose|copy|broadcast)\(",
+            line,
+        )
+        if not m or scopes.TRUNK_ATTENTION not in line:
+            continue
+        name, dims, op = m.groups()
+        if (op == "broadcast" and dims == f"{batch * heads},{history},128") or (
+            op != "broadcast" and dims in q_forms
+        ):
+            found.append((f"{op} {name} f32[{dims}]", line))
+    return found
+
+
+def _trunk_attention_passes(devices):
+    """ISSUE 39: one attention layer of ``sdar30b_a3b_trunk_burst``,
+    ``x + GroupedQueryAttention(RMSNorm(x))`` at the cell's shapes (8 x 1024
+    tokens of 2048, 32 query over 4 key/value heads of 128, ``bf16_dots``),
+    forward and gradient. Between ``q_proj`` and the forward kernel q is read
+    once and written once (at the parent the norm's reduce, its multiply,
+    rotary and the transposing copy: four instructions over 33,554,432
+    elements); the row statistics reach the backward kernels as the forward
+    kernel and ``delta``'s reduce wrote them (before: two broadcasts to
+    ``f32[256,1024,128]`` and a slice out of one); none of the parent's three
+    relayouts of a q-sized float32 activation is left in this program (inside
+    the burst XLA still relays ``o_proj``'s cotangent twice: ``trunk-burst-ring``);
+    and the new pass is no operation of the kind ``trunk.flash_roofline``
+    divides by."""
+    from flax import linen as nn
+
+    from torch_actor_critic_tpu.models import sequence
+
+    spec = sequence.TrunkSpec()
+    batch, history = 8, 1024
+    q_elements = batch * history * spec.q_heads * spec.head_dim
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, x, pos):
+            with jax.named_scope(scopes.TRUNK_ATTENTION):
+                u = sequence.RMSNorm(spec.rms_eps, name="input_norm")(x)
+                return x + sequence.GroupedQueryAttention(spec, name="attention")(u, pos)
+
+    layer, pos = Layer(), jnp.arange(history)
+    x = _shape((batch, history, spec.hidden), jnp.float32, devices[0])
+    params = _on(devices[0], jax.eval_shape(layer.init, jax.random.key(0), x, pos))
+
+    def forward(params, x):
+        return layer.apply(params, x, pos)
+
+    def compile_(fn):
+        return jax.jit(fn).lower(params, x).compile()
+
+    forward_text = compile_(forward).as_text()
+    gradient = compile_(
+        jax.grad(lambda params, x: jnp.sum(forward(params, x) ** 2), (0, 1))
+    )
+    gradient_text = gradient.as_text()
+
+    # the reader's kind is the flash kernels', and theirs alone
+    for text, flash, passes in (
+        (forward_text, 1, ["qk-rope"]), (gradient_text, 3, ["qk-rope", "qk-rope-bwd"]),
+    ):
+        kinds = [_kernel_kind(name) for name in _kernels(text)]
+        assert [k for k in kinds if _reads_as_attention(k)] == ["attention"] * flash, kinds
+        assert sorted(k for k in kinds if not _reads_as_attention(k)) == passes, kinds
+        assert _relayouts_round_the_kernels(
+            text, batch, history, spec.q_heads, spec.head_dim
+        ) == []
+
+    # what touches an array of q's size ahead of the forward kernel: the pass
+    entry, ops_of = _entry(forward_text)
+    kernel = next(
+        n for n in entry
+        if _reads_as_attention(_kernel_kind(n)) and entry[n][1] == "custom-call"
+    )
+    ahead, stack = set(), [kernel]
+    while stack:
+        for operand in entry.get(stack.pop(), ((), "", (), None))[2]:
+            if operand in entry and operand not in ahead:
+                ahead.add(operand)
+                stack.append(operand)
+    passes_over_q = []
+    for name in ahead:
+        result, op, operands, callee = entry[name]
+        shapes = result + [s for o in operands if o in entry for s in entry[o][0]]
+        product = callee is not None and "convolution" in ops_of.get(callee, ())
+        if (
+            op not in ("bitcast", "get-tuple-element", "parameter", "tuple")
+            and not product
+            and any(_elements(s) == q_elements for s in shapes)
+        ):
+            passes_over_q.append(name)
+    assert [_kernel_kind(n) for n in passes_over_q] == ["qk-rope"], passes_over_q
+
+    # no slice out of a lane-wide copy of the row statistics either
+    lane_wide = f"f32[{batch * spec.q_heads},{history},128]"
+    shape_of = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", gradient_text))
+    slices = [
+        m for m in re.findall(r" slice\(%([\w.\-]+)", gradient_text)
+        if shape_of.get(m) == lane_wide
+    ]
+    assert slices == [], slices
+    # the gradient's program: 1,214,467,072 B at the parent by the compiler's
+    # account, 941,444,608 with the pass
+    assert gradient.memory_analysis().peak_memory_in_bytes < 1.0e9
+
+
+def _entry(hlo_text):
+    """``{name: (result shapes, op, operand names, callee)}`` of the entry
+    computation, and ``{computation: its instructions' ops}``."""
+    ops_of, body, entry = {}, None, {}
+    for line in hlo_text.splitlines():
+        header = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if header:
+            body, in_entry = header.group(2), bool(header.group(1))
+            ops_of[body] = set()
+            continue
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([a-z][a-z0-9\-]*)\((.*)", line)
+        if not m or body is None:
+            continue
+        name, result, op, rest = m.groups()
+        ops_of[body].add(op)
+        if in_entry:
+            callee = re.search(r"calls=%?([\w.\-]+)", rest)
+            entry[name] = (
+                re.findall(r"\w+\[[\d,]*\]", result), op,
+                re.findall(r"%([\w.\-]+)", rest.split("),")[0]),
+                callee.group(1) if callee else None,
+            )
+    return entry, ops_of
+
+
+def _elements(shape):
+    return int(np.prod([int(d) for d in re.findall(r"\d+", shape.split("[", 1)[1])] or [1]))
 
 
 # What may carry a whole ring leaf through a program without passing over
@@ -525,6 +718,7 @@ CASES = [
     pytest.param(_flash_grouped, (1,), id="flash-grouped-causal"),
     pytest.param(_flash_grouped, (4,), id="flash-grouped-block4"),
     pytest.param(_trunk_burst, (), id="trunk-burst-ring"),
+    pytest.param(_trunk_attention_passes, (), id="trunk-attention-passes"),
     pytest.param(
         _visual_burst_passes_over_no_frame_leaf, (),
         id="no-whole-leaf-pass-visual-burst",

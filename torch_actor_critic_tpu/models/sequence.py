@@ -29,7 +29,11 @@ from flax import linen as nn
 
 from torch_actor_critic_tpu.models.mlp import Dense, torch_linear_kernel_init
 from torch_actor_critic_tpu.ops import moe
-from torch_actor_critic_tpu.ops.attention import attention as sdpa
+from torch_actor_critic_tpu.ops.attention import (
+    attention as sdpa,
+    qk_norm_rope,
+    rms_norm,
+)
 from torch_actor_critic_tpu.ops.distributions import squashed_gaussian_sample
 from torch_actor_critic_tpu.telemetry import scopes
 
@@ -177,21 +181,25 @@ class RMSNorm(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         weight = self.param("weight", nn.initializers.ones, (x.shape[-1],))
-        xf = x.astype(jnp.float32)
-        var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-        return (xf * jax.lax.rsqrt(var + self.eps)).astype(x.dtype) * weight
+        return rms_norm(x, weight, self.eps)
 
 
-def rotary(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
-    """Rotate-half rotary positions on ``x`` ``(B, T, heads, d)`` at the
-    global positions ``pos`` ``(T,)``."""
-    d = x.shape[-1]
-    inv_freq = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angles = pos.astype(jnp.float32)[:, None] * inv_freq[None, :]
-    angles = jnp.concatenate([angles, angles], axis=-1)[None, :, None, :]
-    x1, x2 = x[..., : d // 2], x[..., d // 2:]
-    rotated = jnp.concatenate([-x2, x1], axis=-1)
-    return x * jnp.cos(angles) + rotated * jnp.sin(angles)
+class HeadNormRope(nn.Module):
+    """A projection's heads on their way to the kernels, ``(B, T, heads, d)``
+    to ``(B, heads, T, d)``: :class:`RMSNorm` over a head (the same ``weight``
+    ``(d,)`` under the same name), rotary at the positions ``pos`` and the
+    heads' transposition, as one function
+    (:func:`ops.attention.qk_norm_rope`: ``impl`` chooses its one pass each
+    way on a TPU or the three composed)."""
+
+    eps: float
+    theta: float
+    impl: str = "auto"
+
+    @nn.compact
+    def __call__(self, y: jax.Array, pos: jax.Array) -> jax.Array:
+        weight = self.param("weight", nn.initializers.ones, (y.shape[-1],))
+        return qk_norm_rope(y, weight, pos, self.theta, self.eps, self.impl)
 
 
 def _linear(features: int, dtype, name: str) -> nn.Dense:
@@ -226,19 +234,35 @@ class GroupedQueryAttention(nn.Module):
         q = _linear(sp.q_heads * d, dtype, "q_proj")(u).reshape(b, s, sp.q_heads, d)
         k = _linear(sp.kv_heads * d, dtype, "k_proj")(u).reshape(b, s, sp.kv_heads, d)
         v = _linear(sp.kv_heads * d, dtype, "v_proj")(u).reshape(b, s, sp.kv_heads, d)
-        q = rotary(RMSNorm(sp.rms_eps, name="q_norm")(q), pos, sp.rope_theta)
-        k = rotary(RMSNorm(sp.rms_eps, name="k_norm")(k), pos, sp.rope_theta)
-        # (batch, heads, seq, d) for the kernels. Reading (batch, seq, heads, d)
-        # in place, a head as a column block of the kernels' index maps, was
-        # tried and measured slower on the v5e (PERF.md, PR 26).
-        heads_first = lambda y: y.transpose(0, 2, 1, 3)  # noqa: E731
+        # (batch, heads, seq, d) for the kernels. The kernels reading (batch,
+        # seq, heads, d) in place, a head as a column block of their index
+        # maps, measured slower on the v5e (PR 26's builder; the figure is no
+        # longer on record), so the heads are transposed, and for q, whose
+        # bytes are eight times k's, in the one pass that norms and rotates it:
+        # one kernel over q_proj's output and one back, wherever the
+        # attention_fn is the one that reaches the flash kernels (the host
+        # mirror's xla_attention keeps every kernel off a program compiled for
+        # the CPU). k stays composed: XLA keeps what it writes itself in fast
+        # memory for the kernels, which read each key block many times, a
+        # kernel's output it does not, and the step measured slower with k in
+        # the pass (PERF.md section 6, PR 39).
+        kernels = self.attention_fn is default_attention
+        q = HeadNormRope(
+            sp.rms_eps, sp.rope_theta, "auto" if kernels else "xla", name="q_norm"
+        )(q, pos)
+        k = HeadNormRope(sp.rms_eps, sp.rope_theta, "xla", name="k_norm")(k, pos)
         out = self.attention_fn(
-            heads_first(q), heads_first(k), heads_first(v), causal=True,
+            q, k, v.transpose(0, 2, 1, 3), causal=True,
             block_length=sp.block_length,
             # float32 tiles, one bfloat16 pass on the MXU: the TPU's default
             # precision for a float32 product, inside the kernels too.
             bf16_dots=sp.bf16_dots and dtype == jnp.float32,
         )
+        # Back to (batch, seq, heads * d) for o_proj: XLA folds this
+        # transposition into the product going forward and pays two relayouts
+        # of the cotangent coming back; a kernel of our own that turned the
+        # cotangent and wrote delta in one pass measured 1.7% slower for the
+        # whole step (XLA then keeps less of the step in fast memory).
         out = out.transpose(0, 2, 1, 3).reshape(b, s, sp.q_heads * d)
         return _linear(sp.hidden, dtype, "o_proj")(out)
 

@@ -23,7 +23,25 @@ Three implementations of the same math, one contract:
   backward is *also* Pallas (FlashAttention-2 style: forward saves the
   per-row logsumexp; dQ and dK/dV kernels recompute probability tiles
   from it), so training gets the kernel in both directions. Head dims
-  are zero-padded to the 128-lane width transparently.
+  are zero-padded to the 128-lane width transparently. The residual of the
+  row statistics is private to the ``custom_vjp`` and rests compact:
+  ``lse``, and the backward's ``delta = rowsum(dO * O)``, are
+  ``(batch·heads, 1, seq)`` with a q block's rows in the lanes. The forward
+  kernel writes ``lse`` in that form, one pass over ``dO`` and ``O`` writes
+  ``delta`` in it, and the backward kernels read both as written: nothing
+  is sliced out of, or broadcast into, a lane-wide ``(batch·heads, seq,
+  128)`` copy in HBM.
+
+Between the SDAR trunk's ``q_proj`` and those kernels lies one pass each way,
+:func:`qk_norm_rope` (kernels ``qk_rope`` and ``qk_rope_bwd`` in a trace,
+never ``attention``, which is how the benchmark finds the flash kernels): the
+per-head RMS norm, rotary and the heads' transposition read the projection's
+output once and write the kernels' ``(batch, heads, seq, d)`` once, and the
+backward reads the kernels' ``dq`` and the saved projection output and writes
+the projection's cotangent in its own layout. ``k`` and ``v`` (an eighth of
+q's bytes each) are composed and transposed by XLA, and so is the kernels'
+output on its way to ``o_proj`` and that product's cotangent on its way back:
+a pass of our own there measured slower (PERF.md section 6, PR 39).
 
 All take ``(batch, heads, seq, head_dim)`` arrays. ``q_offset`` /
 ``k_offset`` are *global* position offsets of the local q/k chunks —
@@ -340,15 +358,16 @@ def _flash_kernel(
             # is defensive only, and the backward relies on finite lse
             # (it has no isneginf path; extending this kernel to
             # ring-attention offsets would need those guards back).
-            # Stored broadcast across a 128-lane axis: Mosaic requires
-            # (8, 128)-tileable output blocks, so a (1, block_q) row
-            # vector is not lowerable — same layout as the reference
-            # TPU kernel's l/m residuals (jax pallas ops flash_attention,
-            # MIN_BLOCK_SIZE lanes).
+            # Stored compact, a q block's statistics as one row in the
+            # lanes of a ``(batch·heads, 1, seq)`` array (the unit axis makes
+            # the ``(1, block_q)`` block legal for Mosaic): the backward
+            # kernels read it back in that form, so no lane-wide copy of the
+            # statistics (128 times their size) is written, sliced or
+            # broadcast in HBM.
             lse = jnp.where(
                 l == 0.0, NEG_INF, m_ref[:, 0] + jnp.log(jnp.where(l == 0.0, 1.0, l))
             )
-            lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref.shape[1:])
+            lse_ref[0, 0] = lse
 
 
 def _head_group(h: int, hkv: int) -> int:
@@ -476,11 +495,9 @@ def _flash_forward(
                      memory_space=pltpu.VMEM),
     ]
     if save_lse:
-        out_shape.append(
-            jax.ShapeDtypeStruct((b * h, tq, _LANE), jnp.float32)
-        )
+        out_shape.append(jax.ShapeDtypeStruct((b * h, 1, tq), jnp.float32))
         out_specs.append(
-            pl.BlockSpec((1, block_q, _LANE), lambda bh, iq, j: (bh, iq, 0),
+            pl.BlockSpec((1, 1, block_q), lambda bh, iq, j: (bh, 0, iq),
                          memory_space=pltpu.VMEM)
         )
     outs = pl.pallas_call(
@@ -517,7 +534,7 @@ def _flash_forward(
     )(qr, kr, vr)
     out = outs[0].reshape(b, h, tq, dp)[..., :d]
     if save_lse:
-        return out, outs[1][:, :, 0].reshape(b, h, tq)
+        return out, outs[1]
     return out
 
 
@@ -579,11 +596,11 @@ def _flash_bwd_dq_kernel(
         v_blk = v_ref[0]
         do = do_ref[0]
         p = _attn_probs(
-            q, k_blk, lse_ref[0][:, 0], scale, causal, iq, j, block_q, block_k,
+            q, k_blk, lse_ref[0, 0], scale, causal, iq, j, block_q, block_k,
             block_length, mxu_dtype,
         )
         dpv = _acc_dot(do, v_blk, ((1,), (1,)), mxu_dtype)
-        ds = p * (dpv - delta_ref[0][:, 0][:, None])
+        ds = p * (dpv - delta_ref[0, 0][:, None])
         dq_acc[:] += _acc_dot(ds, k_blk, ((1,), (0,)), mxu_dtype) * scale
 
     @pl.when(j == n_kb - 1)
@@ -629,12 +646,12 @@ def _flash_bwd_dkv_kernel(
         v_blk = v_ref[0]
         do = do_ref[0]
         p = _attn_probs(
-            q, k_blk, lse_ref[0][:, 0], scale, causal, i, jk, block_q, block_k,
+            q, k_blk, lse_ref[0, 0], scale, causal, i, jk, block_q, block_k,
             block_length, mxu_dtype,
         )
         dv_acc[:] += _acc_dot(p, do, ((0,), (0,)), mxu_dtype)
         dpv = _acc_dot(do, v_blk, ((1,), (1,)), mxu_dtype)
-        ds = p * (dpv - delta_ref[0][:, 0][:, None])
+        ds = p * (dpv - delta_ref[0, 0][:, None])
         dk_acc[:] += _acc_dot(ds, q, ((0,), (0,)), mxu_dtype) * scale
 
     @pl.when(sweep == n_sweep - 1)
@@ -662,30 +679,23 @@ def _flash_backward(
     # arrive wider (e.g. an f32 loss over a bf16 output) — align it so
     # _acc_dot never downcasts a genuine input unasked.
     g = g.astype(q.dtype)
-    # Δ = rowsum(dO ∘ O): cheap elementwise reduce, fused by XLA; padded
+    # Δ = rowsum(dO ∘ O): one reduce over g and o, fused by XLA and written
+    # once, in the row form the kernels read (see the forward's lse); padded
     # head columns of o/g are zero so padding doesn't perturb it.
     delta = jnp.sum(
         g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
-    ).reshape(b * h, tq)
+    ).reshape(b * h, 1, tq)
     q, k, v, g = _pad_head_dim(q, k, v, g, lanes=pad_lanes)
     dp = q.shape[-1]
     qr = q.reshape(b * h, tq, dp)
     kr = k.reshape(b * hkv, tk, dp)
     vr = v.reshape(b * hkv, tk, dp)
     gr = g.reshape(b * h, tq, dp)
-    # Row stats enter the kernels broadcast across a 128-lane axis —
-    # (1, block_q) blocks are not (8, 128)-tileable on TPU (see the
-    # matching note in the forward's lse output).
-    lse_r = jnp.broadcast_to(
-        lse.reshape(b * h, tq)[:, :, None], (b * h, tq, _LANE)
-    )
-    delta = jnp.broadcast_to(delta[:, :, None], (b * h, tq, _LANE))
-
     qspec = pl.BlockSpec((1, block_q, dp), lambda bh, x, y: (bh, x, 0),
                          memory_space=pltpu.VMEM)
     kspec_dq = pl.BlockSpec((1, block_k, dp), lambda bh, iq, j: (bh // group, j, 0),
                             memory_space=pltpu.VMEM)
-    rowspec = pl.BlockSpec((1, block_q, _LANE), lambda bh, x, y: (bh, x, 0),
+    rowspec = pl.BlockSpec((1, 1, block_q), lambda bh, x, y: (bh, 0, x),
                            memory_space=pltpu.VMEM)
     dq = pl.pallas_call(
         functools.partial(
@@ -702,7 +712,7 @@ def _flash_backward(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(qr, kr, vr, gr, lse_r, delta)
+    )(qr, kr, vr, gr, lse, delta)
 
     # dK/dV sweep: the grid's second axis is the k block, q innermost. Row
     # bkv of k/v is read by the q rows bkv * group .. + group - 1; step i of
@@ -716,8 +726,12 @@ def _flash_backward(
                             memory_space=pltpu.VMEM)
     kspec_kv = pl.BlockSpec((1, block_k, dp), lambda bh, jk, i: (bh, jk, 0),
                             memory_space=pltpu.VMEM)
-    rowspec_kv = pl.BlockSpec((1, block_q, _LANE), lambda bh, jk, i: (*q_row(bh, i), 0),
-                              memory_space=pltpu.VMEM)
+
+    def row_kv(bh, jk, i):
+        head, block = q_row(bh, i)
+        return head, 0, block
+
+    rowspec_kv = pl.BlockSpec((1, 1, block_q), row_kv, memory_space=pltpu.VMEM)
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel,
@@ -740,7 +754,7 @@ def _flash_backward(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(qr, kr, vr, gr, lse_r, delta)
+    )(qr, kr, vr, gr, lse, delta)
 
     dq = dq.reshape(b * h, tq, dp)[..., :d].reshape(b, h, tq, d)
     dk = dk.reshape(b * hkv, tk, dp)[..., :d].reshape(b, hkv, tk, d)
@@ -814,6 +828,228 @@ def _flash_bwd(
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
+
+
+# --------------------------------------------------------------------------
+# Between the projections and the kernels: one pass each way
+# --------------------------------------------------------------------------
+
+# A block: some rows of a few heads. 2 MiB (all 1,024 rows of four heads at the
+# trunk cell's shapes) is the most whose double buffers the default scoped VMEM
+# holds for the pass back (three blocks a program); the trunk cell's window read
+# 1,888 ms with it, 1,895 at 1 MiB, 1,917 at 256 KiB (PERF.md section 6, PR 39).
+_PASS_BLOCK_BYTES = 2 << 20
+_PASS_BLOCK_HEADS = 4  # at most: a kernel's body is unrolled over them
+
+
+def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+    """``x / sqrt(mean(x^2) + eps) * weight`` over the last axis, the
+    statistics in float32."""
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * weight
+
+
+def _rope_angles(pos: jax.Array, d: int, theta: float) -> jax.Array:
+    """Rotate-half rotary's angles ``(T, d)`` at the positions ``pos``: the
+    ``d / 2`` frequencies, once for each half of a head."""
+    inv_freq = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angles = pos.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    return jnp.concatenate([angles, angles], axis=-1)
+
+
+def rotary(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
+    """Rotate-half rotary positions on ``x`` ``(B, T, heads, d)`` at the
+    global positions ``pos`` ``(T,)``."""
+    d = x.shape[-1]
+    angles = _rope_angles(pos, d, theta)[None, :, None, :]
+    x1, x2 = x[..., : d // 2], x[..., d // 2:]
+    rotated = jnp.concatenate([-x2, x1], axis=-1)
+    return x * jnp.cos(angles) + rotated * jnp.sin(angles)
+
+
+def rope_tables(pos: jax.Array, d: int, theta: float):
+    """``cos`` and ``sin`` ``(T, d)`` of :func:`rotary` at the positions
+    ``pos``, the sine signed: ``rotate_half(x) * sin`` is
+    ``roll(x, d / 2) * signed_sin``, the half rotation a roll of the lanes."""
+    angles = _rope_angles(pos, d, theta)
+    sign = jnp.where(jnp.arange(d) < d // 2, -1.0, 1.0)
+    return jnp.cos(angles), jnp.sin(angles) * sign
+
+
+def _pass_block(t: int, heads: int, d: int) -> tuple[int | None, int]:
+    """A block's rows and heads: the most heads up to ``_PASS_BLOCK_HEADS``
+    that divide ``heads``, and the most rows that divide ``t``, are whole
+    sublanes and keep the float32 block inside ``_PASS_BLOCK_BYTES``."""
+    hb = max(n for n in range(1, _PASS_BLOCK_HEADS + 1) if heads % n == 0)
+    fits = [
+        r for r in range(8, t + 1, 8)
+        if t % r == 0 and 4 * r * hb * d <= _PASS_BLOCK_BYTES
+    ]
+    return max(fits, default=None), hb
+
+
+def _pass_fits(t: int, heads: int, d: int, dtype) -> bool:
+    """Whether the pass has blocks for histories of ``t`` and ``heads`` heads
+    of ``d``: float32, whole lanes a head, whole sublanes a block."""
+    return (
+        dtype == jnp.float32 and d % _LANE == 0
+        and _pass_block(t, heads, d)[0] is not None
+    )
+
+
+def _half_roll(x: jax.Array) -> jax.Array:
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.roll(x, x.shape[-1] // 2, x.ndim - 1)
+
+
+def _qk_rope_kernel(y_ref, w_ref, cos_ref, sin_ref, o_ref, *, d: int, eps: float):
+    """One ``(batch, row block, head block)`` program: each head of the
+    block's rows normalised (statistics in float32), weighted, rotated and written
+    to its own ``(rows, d)`` plane of the heads-first output."""
+    w, cos, sin = w_ref[...], cos_ref[...], sin_ref[...]
+    for h in range(o_ref.shape[1]):
+        x = y_ref[0, :, h * d:(h + 1) * d]
+        r = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+        xn = x * r * w
+        o_ref[0, h] = xn * cos + _half_roll(xn) * sin
+
+
+def _qk_rope_bwd_kernel(
+    g_ref, y_ref, w_ref, cos_ref, sin_ref, dy_ref, dw_ref, *, d: int, eps: float
+):
+    """The same pass the other way: the kernels' heads-first cotangent and
+    the saved projection output in, the projection's cotangent out in its
+    own layout, and this block's share of the weight's gradient (eight
+    sublanes of partial sums; the caller adds the blocks)."""
+    w, cos, sin = w_ref[...], cos_ref[...], sin_ref[...]
+    dw = jnp.zeros(dw_ref.shape[3:], jnp.float32)
+    for h in range(g_ref.shape[1]):
+        x = y_ref[0, :, h * d:(h + 1) * d]
+        g = g_ref[0, h]
+        r = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+        n = x * r
+        dxn = g * cos + _half_roll(g * sin)  # the roll is its own transpose
+        dw = dw + jnp.sum((dxn * n).reshape(-1, *dw.shape), axis=0)
+        dn = dxn * w
+        dy_ref[0, :, h * d:(h + 1) * d] = r * (
+            dn - n * jnp.mean(dn * n, axis=-1, keepdims=True)
+        )
+    dw_ref[0, 0, 0] = dw
+
+
+def _pass_specs(b: int, t: int, heads: int, d: int):
+    """The grid ``(batch, row block, head block)`` (heads innermost: a row
+    block's tables stay) and the blocks of a pass: the projection's layout
+    ``(batch, seq, heads·d)``, the kernels' ``(batch, heads, seq, d)``, a
+    table's rows ``(seq, d)``, the norm's weight ``(1, d)``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, hb = _pass_block(t, heads, d)
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    return (b, t // rows, heads // hb), dict(
+        flat=vmem((1, rows, hb * d), lambda i, j, g: (i, j, g)),
+        heads_first=vmem((1, hb, rows, d), lambda i, j, g: (i, g, j, 0)),
+        table=vmem((rows, d), lambda i, j, g: (j, 0)),
+        weight=vmem((1, d), lambda i, j, g: (0, 0)),
+        share=vmem((1, 1, 1, 8, d), lambda i, j, g: (i, j, g, 0, 0)),
+    )
+
+
+def _pass_call(kernel, name, grid, in_specs, out_specs, out_shape, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=out_shape,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        # The default scoped VMEM holds these blocks; a raised limit is taken
+        # from what XLA keeps of the whole program's buffers there (the trunk
+        # step's k and Adam operands left fast memory, PERF.md section 6).
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+        ),
+        interpret=interpret,
+        # The trace names a kernel by this: ``benchmark``'s flash roofline
+        # reads the kind ``attention``, which the pass's kernels must not match.
+        name=name,
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _qk_norm_rope_pass(y, weight, pos, theta, eps, interpret):
+    return _qk_rope_fwd(y, weight, pos, theta, eps, interpret)[0]
+
+
+def _qk_rope_fwd(y, weight, pos, theta, eps, interpret):
+    b, t, heads, d = y.shape
+    grid, spec = _pass_specs(b, t, heads, d)
+    out = _pass_call(
+        functools.partial(_qk_rope_kernel, d=d, eps=eps), "qk_rope", grid,
+        [spec["flat"], spec["weight"], spec["table"], spec["table"]],
+        spec["heads_first"], jax.ShapeDtypeStruct((b, heads, t, d), y.dtype),
+        interpret,
+    )(y.reshape(b, t, heads * d), weight.reshape(1, d), *rope_tables(pos, d, theta))
+    return out, (y, weight, pos)
+
+
+def _qk_rope_bwd(theta, eps, interpret, res, g):
+    y, weight, pos = res
+    b, t, heads, d = y.shape
+    grid, spec = _pass_specs(b, t, heads, d)
+    dy, dw = _pass_call(
+        functools.partial(_qk_rope_bwd_kernel, d=d, eps=eps), "qk_rope_bwd", grid,
+        [spec["heads_first"], spec["flat"], spec["weight"], spec["table"], spec["table"]],
+        [spec["flat"], spec["share"]],
+        [
+            jax.ShapeDtypeStruct((b, t, heads * d), y.dtype),
+            jax.ShapeDtypeStruct((*grid, 8, d), jnp.float32),
+        ],
+        interpret,
+    )(g, y.reshape(b, t, heads * d), weight.reshape(1, d), *rope_tables(pos, d, theta))
+    return dy.reshape(y.shape), jnp.sum(dw, axis=(0, 1, 2, 3)).astype(weight.dtype), None
+
+
+_qk_norm_rope_pass.defvjp(_qk_rope_fwd, _qk_rope_bwd)
+
+
+def qk_norm_rope(
+    y: jax.Array,
+    weight: jax.Array,
+    pos: jax.Array,
+    theta: float,
+    eps: float,
+    impl: str = "auto",
+) -> jax.Array:
+    """A projection's output ``(batch, seq, heads, d)`` to the kernels'
+    ``(batch, heads, seq, d)``: per-head RMS norm (float32 statistics) times
+    ``weight`` ``(d,)``, rotate-half rotary at the positions ``pos``
+    ``(seq,)``, and the heads' transposition.
+
+    ``'pallas'`` is one pass over the projection's output (read once,
+    written once) and one back (the kernels' ``dq`` and the saved projection
+    output in, the projection's cotangent out in its own layout), for
+    float32, ``d`` a multiple of 128 and ``seq`` of 8; ``'interpret'`` the
+    same kernels in the Pallas interpreter. ``'xla'`` composes
+    :func:`rms_norm`, :func:`rotary` and ``transpose``, which XLA:TPU makes
+    four passes over ``y`` of (PERF.md section 6); it is what the kernels are
+    held to by the tests. ``'auto'``: the pass on a TPU where it has blocks,
+    like :func:`attention` and with its CAUTION. The kernels are named
+    ``qk_rope`` / ``qk_rope_bwd`` in a trace, not ``attention``."""
+    if impl == "auto":
+        on_tpu = jax.default_backend() == "tpu"
+        b, t, heads, d = y.shape
+        impl = "pallas" if on_tpu and _pass_fits(t, heads, d, y.dtype) else "xla"
+    if impl == "xla":
+        return rotary(rms_norm(y, weight, eps), pos, theta).transpose(0, 2, 1, 3)
+    return _qk_norm_rope_pass(y, weight, pos, theta, eps, impl == "interpret")
 
 
 def attention(
