@@ -257,6 +257,60 @@ def _trunk_burst(devices):
     assert kernels and kernels <= {"2,1,0", "3,2,1,0"}, kernels
 
 
+def _hybrid_trunk_burst(devices):
+    """The ``nemotron_h`` trunk's burst as the benchmark's cell builds it, at
+    the cell's own sizes (one period of eleven layers at the published widths,
+    this chip's share of heads and experts, 1024 x batch 4, every block
+    recomputed): the chunked scan, the flash kernels without q's pass, the
+    two-kernel grouped products and the router's top 22 of 512 lower for the
+    v5e, and the compiler's account of the step fits the chip."""
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.drivers import trunkburst
+    from benchmark.harness import registry, spans
+    from torch_actor_critic_tpu.sac.trainer import build_models, make_learner
+
+    _, cell, config = registry.resolve("nemotron3_super_trunk_burst")
+    driver = registry.load_driver(cell["driver"])(
+        cell, config, 1, spans.Spans(), {"rehearsal": False}
+    )
+    cfg, env = driver.sac_config(), trunkburst.Spec(driver.model)
+    assert (cfg.trunk_pattern, cfg.trunk_hidden, cfg.trunk_remat) == ("EMEMEMEMEM*", 4096, 11)
+    sac = make_learner(cfg, *build_models(cfg, env), env.act_dim)
+    learner = DataParallelSAC(sac, make_mesh(dp=1, devices=devices[:1]))
+    state = jax.eval_shape(sac.init_state, jax.random.key(0), env.example_obs())
+
+    def ring_of(n):
+        one = jax.eval_shape(lambda: init_replay_buffer(n, env.obs_spec, env.act_dim).data)
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct((1,) + x.shape, x.dtype), one
+        )
+
+    index = jax.ShapeDtypeStruct((1,), jnp.int32)
+    ring = BufferState(data=ring_of(cell["traffic"]["ring_rows"]), ptr=index, size=index)
+    chunk = ring_of(cfg.update_every)
+    compiled = learner._build_burst(cfg.update_every, state, ring, chunk).lower(
+        state, ring, chunk
+    ).compile()
+    mem = compiled.memory_analysis()
+    n_params = sum(x.size for x in jax.tree_util.tree_leaves(state.critic_params))
+    assert 566e6 < n_params < 570e6
+    # trunk, target and Adam's moments rest in the arguments and are updated in
+    # place; the whole step fits the chip's 15.75 GiB (read at PR 40: 13.42 GB)
+    assert mem.alias_size_in_bytes >= 16 * n_params
+    assert mem.peak_memory_in_bytes < 14.5e9, mem.peak_memory_in_bytes
+    text = compiled.as_text()
+    kinds = [_kernel_kind(name) for name in _kernels(text)]
+    assert "ragged-dot" in text and "qk-rope" not in kinds and len(kinds) >= 3, kinds
+    # the expert layer still moves a piece at a time
+    for op in ("gather", "scatter"):
+        moved = _expert_layer_rows(text, op)
+        assert moved and max(moved) <= moe.PIECE_ROWS, (op, moved)
+
+
 def _kernels(hlo_text):
     """Names of the Mosaic kernels' instructions."""
     return re.findall(
@@ -719,6 +773,7 @@ CASES = [
     pytest.param(_flash_grouped, (4,), id="flash-grouped-block4"),
     pytest.param(_trunk_burst, (), id="trunk-burst-ring"),
     pytest.param(_trunk_attention_passes, (), id="trunk-attention-passes"),
+    pytest.param(_hybrid_trunk_burst, (), id="hybrid-trunk-burst-at-size"),
     pytest.param(
         _visual_burst_passes_over_no_frame_leaf, (),
         id="no-whole-leaf-pass-visual-burst",

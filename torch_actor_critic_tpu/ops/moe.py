@@ -1,8 +1,16 @@
 """A sparse-expert feed-forward layer of which this chip holds a share.
 
-The published layer routes every token to the ``top_k`` largest of
-``num_experts`` softmax probabilities, renormalises those ``top_k`` and sums
-``w_e * W_down^e (silu(W_gate^e u) * W_up^e u)`` over them. A deployment
+A published layer routes every token to ``top_k`` of ``num_experts`` and sums
+its chosen experts' outputs by their weights. Two forms are written here, and
+a layer takes its own from its spec (``models/sequence.py::TrunkSpec``).
+*Routing* (:func:`route`): the ``top_k`` largest softmax probabilities,
+renormalised; or sigmoid scores, chosen by score plus a correction bias,
+weighted by the scores alone, renormalised and scaled. *Expert*
+(``FORMS``): the gated ``W_down^e (silu(W_gate^e u) * W_up^e u)`` with three
+kernels, or the plain ``W_down^e relu(W_up^e u)^2`` with two. The experts work
+in whatever width ``u`` has (a latent one where the layer projects down before
+them and up after). Plan, chunks, pieces, dispatch and combine are one code
+for every form. A deployment
 shares the experts of a layer among chips: this chip holds experts
 ``held = (lo, hi)`` and computes the terms of the chosen experts it holds;
 the other terms belong to other chips and are left out here (no stand-in for
@@ -69,10 +77,13 @@ one mapped element at a time.
 from __future__ import annotations
 
 import functools
+import operator
 import typing as t
 
 import jax
 import jax.numpy as jnp
+
+from torch_actor_critic_tpu.telemetry import scopes
 
 
 class Plan(t.NamedTuple):
@@ -85,22 +96,34 @@ class Plan(t.NamedTuple):
     n_rows: jax.Array  # () rows that landed on held experts
 
 
-def route(u: jax.Array, w_router: jax.Array, top_k: int):
+def route(
+    u: jax.Array, w_router: jax.Array, top_k: int, scoring: str = "softmax",
+    bias: jax.Array | None = None, scale: float = 1.0,
+):
     """``(top_e, top_w)``: each token's ``top_k`` experts of all and their
-    renormalised softmax weights. The router's product runs at ``highest``
-    precision: the choice is discrete, and a near-tie must flip only on what
-    came in, never on this product's own rounding."""
+    weights. ``scoring="softmax"``: the largest probabilities, renormalised.
+    ``"sigmoid"``: scores ``s = sigmoid(u W_r)``; the chosen are the largest of
+    ``s + bias`` (the correction bias moves the choice alone), their weights
+    ``scale * s / (sum of the chosen s + 1e-20)``. The router's product runs
+    at ``highest`` precision: the choice is discrete, and a near-tie must flip
+    only on what came in, never on this product's own rounding."""
     logits = jnp.dot(
         u.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
-    p = jax.nn.softmax(logits, axis=-1)
-    _, top_e = jax.lax.top_k(jax.lax.stop_gradient(p), top_k)
+    if scoring == "softmax":
+        p = select = jax.nn.softmax(logits, axis=-1)
+    else:
+        p = jax.nn.sigmoid(logits)
+        select = p if bias is None else p + bias
+    _, top_e = jax.lax.top_k(jax.lax.stop_gradient(select), top_k)
     # The chosen probabilities by a mask, not by top_k's own values or a
     # gather: either one's gradient is a scatter of N * top_k scalars.
     chosen = top_e[:, :, None] == jnp.arange(p.shape[-1], dtype=top_e.dtype)
     top_p = jnp.sum(jnp.where(chosen, p[:, None, :], 0.0), axis=-1)
-    return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if scoring == "softmax":
+        return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_e, scale * top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
 
 
 def plan_assignments(top_e: jax.Array, held: t.Tuple[int, int]) -> Plan:
@@ -163,19 +186,31 @@ def _mxu_dtype(dtype, bf16_dots: bool):
 
 def _gmm(x, w, sizes):
     """``x`` ``(rows, k)`` sorted by group times ``w`` ``(groups, k, n)``."""
-    return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=jnp.float32)
+    with jax.named_scope(scopes.TRUNK_MOE_PRODUCTS):
+        return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=jnp.float32)
 
 
 def _gmm_by_group(x, g, sizes):
     """``(groups, k, n)``: each group's rows of ``x`` ``(rows, k)`` against
     its rows of ``g`` ``(rows, n)``."""
-    return jax.lax.ragged_dot_general(
-        x, g, sizes, _BY_GROUP, preferred_element_type=jnp.float32
-    )
+    with jax.named_scope(scopes.TRUNK_MOE_PRODUCTS):
+        return jax.lax.ragged_dot_general(
+            x, g, sizes, _BY_GROUP, preferred_element_type=jnp.float32
+        )
 
 
 def _silu_mul(a, b):
     return jax.nn.silu(a) * b
+
+
+def _relu2(a):
+    return jnp.square(jax.nn.relu(a))
+
+
+# An expert's form: the function of its first products (one a kernel going
+# in) that the down product takes. The number of kernels going in is the
+# function's number of arguments.
+FORMS = {"silu_gated": _silu_mul, "relu2": _relu2}
 
 
 class _Chunk(t.NamedTuple):
@@ -254,15 +289,16 @@ def _combined(ch: _Chunk, out, piece_of):
     return _pieces(ch, body, out)
 
 
-def _hidden(ch: _Chunk, xs, w_gate, w_up, bf16_dots: bool):
-    """Gate and up products of the dispatched rows, and the hidden
-    activations as the down product takes them."""
-    a, b = _gmm(xs, w_gate, ch.sizes), _gmm(xs, w_up, ch.sizes)
+def _hidden(ch: _Chunk, xs, w_in, form: str, bf16_dots: bool):
+    """The products of the dispatched rows with the kernels going in, and the
+    hidden activations as the down product takes them."""
+    pre = tuple(_gmm(xs, w, ch.sizes) for w in w_in)
+    act = FORMS[form]
     hidden = _filled(
-        ch, a.shape[1], _mxu_dtype(a.dtype, bf16_dots),
-        lambda rows_of: _mxu(_silu_mul(rows_of(a), rows_of(b)), bf16_dots),
+        ch, pre[0].shape[1], _mxu_dtype(pre[0].dtype, bf16_dots),
+        lambda rows_of: _mxu(act(*(rows_of(a) for a in pre)), bf16_dots),
     )
-    return a, b, hidden
+    return pre, hidden
 
 
 def _over_chunks(plan: Plan, rows: int, total: int, body, first):
@@ -288,21 +324,21 @@ def _padded(plan: Plan, rows: int) -> Plan:
 
 
 def _if_any(run):
-    """``run(u, w_gate, w_up, w_down, top_w, plan, ...)`` where the call holds
-    a row at all, else zeros (module docstring)."""
+    """``run(u, w_in, w_down, top_w, plan, ...)`` where the call holds a row
+    at all, else zeros (module docstring)."""
     def guarded(*operands):
         def nothing(*operands):
             return jax.tree_util.tree_map(
                 lambda s: jnp.zeros(s.shape, s.dtype), jax.eval_shape(run, *operands)
             )
 
-        plan = operands[5]
+        plan = operands[4]
         return jax.lax.cond(plan.n_rows > 0, run, nothing, *operands)
 
     return guarded
 
 
-def _forward(rows: int, bf16_dots: bool, u, w_gate, w_up, w_down, top_w, plan: Plan):
+def _forward(rows: int, bf16_dots: bool, form: str, u, w_in, w_down, top_w, plan: Plan):
     total = top_w.size
     padded = _padded(plan, rows)
     # A token's row is rounded before it is gathered: the same values, half
@@ -311,15 +347,15 @@ def _forward(rows: int, bf16_dots: bool, u, w_gate, w_up, w_down, top_w, plan: P
 
     def body(c, out):
         ch = _chunk(padded, top_w.shape[1], c, rows)
-        gate, up, down = (_mxu(w, bf16_dots) for w in (w_gate, w_up, w_down))
-        _, _, hidden = _hidden(ch, _dispatched(ch, x), gate, up, bf16_dots)
+        *going_in, down = (_mxu(w, bf16_dots) for w in (*w_in, w_down))
+        _, hidden = _hidden(ch, _dispatched(ch, x), going_in, form, bf16_dots)
         y = _gmm(hidden, down, ch.sizes)
         return _combined(ch, out, lambda rows_of: rows_of(y) * _weights(top_w, rows_of(ch.flat)))
 
     return _over_chunks(plan, rows, total, body, body(0, jnp.zeros_like(u)))
 
 
-def _backward(rows: int, bf16_dots: bool, u, w_gate, w_up, w_down, top_w, plan: Plan, g):
+def _backward(rows: int, bf16_dots: bool, form: str, u, w_in, w_down, top_w, plan: Plan, g):
     total = top_w.size
     padded = _padded(plan, rows)
     x = _mxu(u, bf16_dots)
@@ -329,11 +365,11 @@ def _backward(rows: int, bf16_dots: bool, u, w_gate, w_up, w_down, top_w, plan: 
         added. A chunk's activations are computed again from its gathered
         rows, as the forward pass computed them."""
         ch = _chunk(padded, top_w.shape[1], c, rows)
-        gate, up, down = (_mxu(w, bf16_dots) for w in (w_gate, w_up, w_down))
+        *going_in, down = (_mxu(w, bf16_dots) for w in (*w_in, w_down))
         # The input gradients' products take a kernel transposed.
-        t_gate, t_up, t_down = (jnp.swapaxes(w, 1, 2) for w in (gate, up, down))
+        *t_in, t_down = (jnp.swapaxes(w, 1, 2) for w in (*going_in, down))
         xs = _dispatched(ch, x)
-        a, b, hidden = _hidden(ch, xs, gate, up, bf16_dots)
+        pre, hidden = _hidden(ch, xs, going_in, form, bf16_dots)
         y = _gmm(hidden, down, ch.sizes)
 
         def through_down(r0, rows_of, carry):
@@ -354,21 +390,23 @@ def _backward(rows: int, bf16_dots: bool, u, w_gate, w_up, w_down, top_w, plan: 
         d_hidden = _gmm(gy, t_down, ch.sizes)
 
         def through_act(r0, rows_of, carry):
-            _, vjp = jax.vjp(_silu_mul, rows_of(a), rows_of(b))
+            _, vjp = jax.vjp(FORMS[form], *(rows_of(a) for a in pre))
             return tuple(
                 jax.lax.dynamic_update_slice_in_dim(buf, _mxu(d, bf16_dots), r0, axis=0)
                 for buf, d in zip(carry, vjp(rows_of(d_hidden)))
             )
 
-        da, db = _pieces(
-            ch, through_act, tuple(_buffer(hidden.shape, hidden.dtype) for _ in range(2))
+        d_pre = _pieces(
+            ch, through_act, tuple(_buffer(hidden.shape, hidden.dtype) for _ in pre)
         )
-        dx_gate, dx_up = _gmm(da, t_gate, ch.sizes), _gmm(db, t_up, ch.sizes)
+        dx = tuple(_gmm(d, t_w, ch.sizes) for d, t_w in zip(d_pre, t_in))
         kernels = (
-            _gmm_by_group(xs, da, ch.sizes), _gmm_by_group(xs, db, ch.sizes),
+            *(_gmm_by_group(xs, d, ch.sizes) for d in d_pre),
             _gmm_by_group(hidden, gy, ch.sizes),
         )
-        du = _combined(ch, du, lambda rows_of: rows_of(dx_gate) + rows_of(dx_up))
+        du = _combined(
+            ch, du, lambda rows_of: functools.reduce(operator.add, (rows_of(d) for d in dx))
+        )
         return kernels, du, dw
 
     def body(c, carry):
@@ -379,32 +417,31 @@ def _backward(rows: int, bf16_dots: bool, u, w_gate, w_up, w_down, top_w, plan: 
 
     first = chunk(0, jnp.zeros_like(u), jnp.zeros(top_w.size, top_w.dtype))
     kernels, du, dw = _over_chunks(plan, rows, total, body, first)
-    dw = dw.reshape(top_w.shape)
-    return (du, *(k.astype(w.dtype) for k, w in zip(kernels, (w_gate, w_up, w_down))), dw)
+    *d_in, d_down = (k.astype(w.dtype) for k, w in zip(kernels, (*w_in, w_down)))
+    return du, tuple(d_in), d_down, dw.reshape(top_w.shape)
 
 
 @functools.lru_cache(maxsize=None)
-def _experts_for(rows: int, bf16_dots: bool):
-    """The expert layer for chunks of ``rows``, with its hand-written
-    backward pass. Under ``vmap`` (the data-parallel burst maps the update
-    over its device axis, a population over its members) both passes run
-    once a mapped element, in turn (``sequential_vmap``): the grouped product
-    has no batched form on the TPU, and a batched ``lax.cond`` would run
-    every chunk of every element."""
+def _experts_for(rows: int, bf16_dots: bool, form: str = "silu_gated"):
+    """The expert layer of ``form`` for chunks of ``rows``, with its
+    hand-written backward pass. Under ``vmap`` (the data-parallel burst maps
+    the update over its device axis, a population over its members) both
+    passes run once a mapped element, in turn (``sequential_vmap``): the
+    grouped product has no batched form on the TPU, and a batched
+    ``lax.cond`` would run every chunk of every element."""
     forward = jax.custom_batching.sequential_vmap(
-        _if_any(functools.partial(_forward, rows, bf16_dots))
+        _if_any(functools.partial(_forward, rows, bf16_dots, form))
     )
     backward = jax.custom_batching.sequential_vmap(
-        _if_any(functools.partial(_backward, rows, bf16_dots))
+        _if_any(functools.partial(_backward, rows, bf16_dots, form))
     )
 
     @jax.custom_vjp
-    def experts(u, w_gate, w_up, w_down, top_w, plan):
-        return forward(u, w_gate, w_up, w_down, top_w, plan)
+    def experts(u, w_in, w_down, top_w, plan):
+        return forward(u, w_in, w_down, top_w, plan)
 
-    def fwd(u, w_gate, w_up, w_down, top_w, plan):
-        out = forward(u, w_gate, w_up, w_down, top_w, plan)
-        return out, (u, w_gate, w_up, w_down, top_w, plan)
+    def fwd(u, w_in, w_down, top_w, plan):
+        return forward(u, w_in, w_down, top_w, plan), (u, w_in, w_down, top_w, plan)
 
     def bwd(res, g):
         return (*backward(*res, g), None)
@@ -414,19 +451,21 @@ def _experts_for(rows: int, bf16_dots: bool):
 
 
 def expert_ffn(
-    u: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+    u: jax.Array, w_gate: jax.Array | None, w_up: jax.Array, w_down: jax.Array,
     top_e: jax.Array, top_w: jax.Array, held: t.Tuple[int, int],
     chunk_rows: int | None = None, num_experts: int | None = None,
-    bf16_dots: bool = False,
+    bf16_dots: bool = False, form: str = "silu_gated",
 ) -> t.Tuple[jax.Array, Plan]:
     """This chip's partial sum of the expert layer for tokens ``u`` ``(N, H)``.
 
     ``w_gate``/``w_up``: ``(hi - lo, H, F)``, ``w_down``: ``(hi - lo, F, H)``,
-    the held experts' kernels. ``top_e``/``top_w``: :func:`route`'s choices
-    over all experts. Returns the ``(N, H)`` sum over each token's chosen
-    experts that are held, and the :class:`Plan` (its counters).
-    ``bf16_dots`` rounds the float32 operands of every grouped product to
-    bfloat16 (float32 accumulation and output), forward and backward."""
+    the held experts' kernels; ``form`` (``FORMS``) says what an expert
+    computes with them, and a form without a gate (``"relu2"``) takes
+    ``w_gate=None``. ``top_e``/``top_w``: :func:`route`'s choices over all
+    experts. Returns the ``(N, H)`` sum over each token's chosen experts that
+    are held, and the :class:`Plan` (its counters). ``bf16_dots`` rounds the
+    float32 operands of every grouped product to bfloat16 (float32
+    accumulation and output), forward and backward."""
     n, k = top_e.shape
     n_held = held[1] - held[0]
     if chunk_rows is None:
@@ -436,5 +475,6 @@ def expert_ffn(
         chunk_rows = -(-chunk_rows // PIECE_ROWS) * PIECE_ROWS
     plan = plan_assignments(top_e, held)
     top_w = jnp.where(plan.held, top_w, 0.0)  # an absent term has no gradient here
-    experts = _experts_for(chunk_rows, bool(bf16_dots))
-    return experts(u, w_gate, w_up, w_down, top_w, plan), plan
+    w_in = (w_up,) if w_gate is None else (w_gate, w_up)
+    experts = _experts_for(chunk_rows, bool(bf16_dots), form)
+    return experts(u, w_in, w_down, top_w, plan), plan

@@ -200,15 +200,18 @@ class SAC:
 
     def _features_apply(self, params, obs):
         """One pass of the shared trunk: the last step's features, and the
-        expert layers' statistics ``{"sizes": (layers, held experts),
-        "choices": (layers, tokens, experts a token)}``."""
+        statistics of its layers that hold experts, in the stack's order:
+        ``{"sizes": (layers, held experts), "choices": (layers, tokens,
+        experts a token)}``."""
         h, sown = self.critic_def.apply(
             params, obs, method=self.critic_def.features, mutable=["moe_stats"]
         )
         layers = sown["moe_stats"]["trunk"]
         names = sorted(layers, key=lambda n: int(n.rsplit("_", 1)[1]))
+        # A layer's expert module is the one child that sowed anything.
+        sown_by = [next(iter(layers[n].values())) for n in names]
         stats = {
-            k: jnp.stack([layers[n]["moe"][k][0] for n in names])
+            k: jnp.stack([layer[k][0] for layer in sown_by])
             for k in ("sizes", "choices")
         }
         return h, stats

@@ -271,9 +271,9 @@ def build_models(config: SACConfig, env) -> t.Tuple[t.Any, t.Any]:
             SequenceDoubleCritic,
         )
 
-        if config.trunk_block == "sdar_moe":
-            # One trunk for actor and critics, its block from the
-            # configuration (models/sequence.py, SACConfig.trunk_*).
+        if config.shared_trunk:
+            # One trunk for actor and critics, its layers from the
+            # configuration's pattern (models/sequence.py, SACConfig.trunk_*).
             from torch_actor_critic_tpu.models import (
                 SharedTrunkActor,
                 SharedTrunkCritic,

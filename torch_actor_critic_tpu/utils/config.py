@@ -103,15 +103,21 @@ class SACConfig:
     seq_d_model: int = 64
     seq_num_heads: int = 4
     seq_num_layers: int = 2
-    # The history trunk's block (models/sequence.py). "transformer" is the
-    # small pre-LN block above, one trunk in the actor and one in every
-    # critic, sized by seq_*. "sdar_moe" is the decoder layer of
-    # SDAR-30B-A3B (RMSNorm, rotary positions, grouped-query block-causal
+    # The history trunk (models/sequence.py). With trunk_block
+    # "transformer" and no trunk_pattern it is the small pre-LN stack above,
+    # one trunk in the actor and one in every critic, sized by seq_*.
+    # Otherwise it is a published decoder stack as ONE trunk that the critic
+    # loss trains, the actor reads through stop_gradient and the polyak
+    # target covers: trunk_pattern is its layers, one letter each ("S": an
+    # SDAR block, attention then sparse experts; "M": a Mamba-2 mixer; "*":
+    # an attention mixer; "E": an expert mixer), and trunk_* are its
+    # published widths and what this chip holds of each layer. trunk_block
+    # "sdar_moe" is short for trunk_layers SDAR blocks: SDAR-30B-A3B's
+    # decoder (RMSNorm, rotary positions, grouped-query block-causal
     # attention with per-head q/k norm, a sparse-expert feed-forward of
-    # which this chip holds experts trunk_experts_held) as ONE trunk that
-    # the critic loss trains, the actor reads through stop_gradient and the
-    # polyak target covers; trunk_* are its published widths and the cut.
+    # which this chip holds experts trunk_experts_held).
     trunk_block: str = "transformer"
+    trunk_pattern: str = ""
     trunk_hidden: int = 2048
     trunk_q_heads: int = 32
     trunk_kv_heads: int = 4
@@ -124,6 +130,23 @@ class SACConfig:
     trunk_block_length: int = 4  # causal across blocks, full inside one
     trunk_rope_theta: float = 1e6
     trunk_rms_eps: float = 1e-6
+    # What a layer of another family than SDAR's takes (nemotron_h): no
+    # norm or rotary on q and k; sigmoid routing by score plus a correction
+    # bias, the weights scaled; plain relu(.)^2 experts in a latent width
+    # beside a shared expert on the full one; the state-space mixer's held
+    # heads and groups and its sizes.
+    trunk_qk_norm_rope: bool = True
+    trunk_router: str = "softmax"  # or "sigmoid"
+    trunk_routed_scale: float = 1.0
+    trunk_expert_form: str = "silu_gated"  # or "relu2" (ops/moe.py FORMS)
+    trunk_expert_latent: int = 0  # 0: the experts work in trunk_hidden
+    trunk_shared_expert_width: int = 0  # 0: no shared expert
+    trunk_ssm_heads: int = 0  # held here, in whole groups
+    trunk_ssm_head_dim: int = 64
+    trunk_ssm_groups: int = 1  # held here
+    trunk_ssm_state: int = 128
+    trunk_ssm_conv: int = 4
+    trunk_ssm_chunk: int = 128
     trunk_q_hidden: int = 256  # width of the Q heads' hidden layer
     trunk_remat: int = 0  # the first n blocks are recomputed in the backward pass
     # compute_dtype float32 means the TPU's default precision for a float32
@@ -474,7 +497,12 @@ class SACConfig:
                 f"trunk_block must be 'transformer' or 'sdar_moe', got "
                 f"{self.trunk_block!r}"
             )
-        if self.trunk_block == "sdar_moe":
+        if set(self.trunk_pattern) - set("SM*E"):
+            raise ValueError(
+                f"trunk_pattern={self.trunk_pattern!r} is one letter a layer of "
+                "'S', 'M', '*' and 'E'"
+            )
+        if self.shared_trunk:
             lo, hi = self.trunk_experts_held
             if not 0 <= lo < hi <= self.trunk_experts:
                 raise ValueError(
@@ -486,15 +514,32 @@ class SACConfig:
                     f"trunk_q_heads={self.trunk_q_heads} must be a multiple of "
                     f"trunk_kv_heads={self.trunk_kv_heads}"
                 )
+            if self.trunk_router not in ("softmax", "sigmoid") or (
+                self.trunk_expert_form not in ("silu_gated", "relu2")
+            ):
+                raise ValueError(
+                    f"trunk_router={self.trunk_router!r} must be 'softmax' or "
+                    f"'sigmoid' and trunk_expert_form={self.trunk_expert_form!r} "
+                    "'silu_gated' or 'relu2'"
+                )
+            if "M" in self.trunk_pattern and (
+                self.trunk_ssm_heads < 1
+                or self.trunk_ssm_heads % max(self.trunk_ssm_groups, 1)
+            ):
+                raise ValueError(
+                    f"a state-space layer holds whole groups: trunk_ssm_heads="
+                    f"{self.trunk_ssm_heads} must be a positive multiple of "
+                    f"trunk_ssm_groups={self.trunk_ssm_groups}"
+                )
             # The shared trunk rewires the SAC losses alone; fail at
             # construction, like the augment/pixel gates in build_models.
             if self.algorithm != "sac" or self.parity_pi_obs or (
                 self.diagnostics != "off"
             ):
                 raise ValueError(
-                    "trunk_block='sdar_moe' trains one shared trunk by the SAC "
-                    "critic loss: it needs algorithm='sac', parity_pi_obs=False "
-                    "and diagnostics='off'"
+                    "a shared trunk (trunk_pattern, trunk_block='sdar_moe') is "
+                    "trained by the SAC critic loss: it needs algorithm='sac', "
+                    "parity_pi_obs=False and diagnostics='off'"
                 )
         if not (len(self.filters) == len(self.kernel_sizes) == len(self.strides)):
             raise ValueError(
@@ -787,6 +832,12 @@ class SACConfig:
                 "device-actor path reads post-burst params directly, so "
                 "there is no mirror to run stale."
             )
+
+    @property
+    def shared_trunk(self) -> bool:
+        """Whether the history trunk is the one shared stack of
+        ``trunk_pattern`` (``models/sequence.py::TrunkSpec``)."""
+        return bool(self.trunk_pattern) or self.trunk_block == "sdar_moe"
 
     @property
     def updates_per_window(self) -> int:
