@@ -198,7 +198,7 @@ def _trunk_burst(devices):
     cfg = SACConfig(
         trunk_block="sdar_moe", history_len=history, batch_size=8, update_every=10,
         buffer_size=rows, burst_unroll=1, trunk_hidden=256, trunk_q_heads=8,
-        trunk_kv_heads=2, trunk_head_dim=128, trunk_layers=1, trunk_experts=16,
+        trunk_kv_heads=2, trunk_head_dim=128, trunk_layers=1, trunk_experts=128,
         trunk_experts_held=(0, 4), trunk_experts_per_tok=4, trunk_expert_width=128,
     )
     spec = jax.ShapeDtypeStruct((history, OBS_DIM), jnp.float32)
@@ -234,11 +234,12 @@ def _trunk_burst(devices):
         text, cfg.batch_size, history, cfg.trunk_q_heads, cfg.trunk_head_dim
     )
     assert len(left) <= 2 and all(what.startswith("copy") for what, _ in left), left
-    # The compiler's account of this cut program's peak: 2,177,838,592 B at the
-    # parent, 2,170,855,424 with the pass (the residuals kept are the same:
-    # q_proj's output for the pass back, the kernels' q, k, v and o; ``lse``
-    # 128 times smaller). A layout carried into the burst's state shows here.
-    assert compiled.memory_analysis().peak_memory_in_bytes < 2.175e9
+    # The compiler's account of this cut program's peak: with 16 experts
+    # 2,177,838,592 B at PR 39's parent and 2,170,855,424 with q's pass; with
+    # the cell's 128 (PR 41) 2,198,296,576 at the parent and 2,198,294,528
+    # with the selection's kernels. A layout carried into the burst's state,
+    # or a kernel that moves what XLA keeps, shows here.
+    assert compiled.memory_analysis().peak_memory_in_bytes < 2.2025e9
     # Neither a scatter nor a gather whose result is as large as a ring leaf:
     # the sample's gather is batch-sized.
     assert _as_large_as(text, rows * history * OBS_DIM, "scatter") == []
@@ -255,15 +256,28 @@ def _trunk_burst(devices):
     # every kernel's gradient every step and half as much scratch again).
     kernels = set(re.findall(r"f32\[(?:1,)?4,(?:256,128|128,256)\]\{([\d,]+)", text))
     assert kernels and kernels <= {"2,1,0", "3,2,1,0"}, kernels
+    # ISSUE 41: the selection's kernels under the burst's ``vmap`` (128
+    # experts: the cell's; with 16 the rounds are XLA's), and no sort or mask
+    _selection_is_a_pass(text, cfg.batch_size * history, cfg.trunk_experts_per_tok, 128)
 
 
-def _hybrid_trunk_burst(devices):
+def _selection_is_a_pass(hlo_text, tokens, top_k, experts):
+    """ISSUE 41: the router takes its ``top_k`` of ``experts`` by the
+    selection's kernels, forward and backward; no ``sort`` stands under the
+    router's scope (``lax.top_k`` lowered to whole sorts of a token's
+    scores), and nothing of the size tokens x top_k x experts is formed,
+    in memory or inside a fusion."""
+    under_route = [line for line in hlo_text.splitlines() if scopes.TRUNK_MOE_ROUTE in line]
+    assert under_route and not [line for line in under_route if " sort(" in line]
+    kinds = [_kernel_kind(name) for name in _kernels(hlo_text)]
+    assert kinds.count("router-top-k") >= 2 and kinds.count("router-top-k-bwd") >= 1, kinds
+    assert not re.search(rf"\[(?:1,)?{tokens},{top_k},{experts}\]", hlo_text)
+
+
+def _compile_hybrid_trunk_burst(devices):
     """The ``nemotron_h`` trunk's burst as the benchmark's cell builds it, at
-    the cell's own sizes (one period of eleven layers at the published widths,
-    this chip's share of heads and experts, 1024 x batch 4, every block
-    recomputed): the chunked scan, the flash kernels without q's pass, the
-    two-kernel grouped products and the router's top 22 of 512 lower for the
-    v5e, and the compiler's account of the step fits the chip."""
+    the cell's own sizes, compiled for the described v5e: ``(cell's
+    configuration, abstract state, compiled burst)``."""
     import sys
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -278,7 +292,6 @@ def _hybrid_trunk_burst(devices):
         cell, config, 1, spans.Spans(), {"rehearsal": False}
     )
     cfg, env = driver.sac_config(), trunkburst.Spec(driver.model)
-    assert (cfg.trunk_pattern, cfg.trunk_hidden, cfg.trunk_remat) == ("EMEMEMEMEM*", 4096, 11)
     sac = make_learner(cfg, *build_models(cfg, env), env.act_dim)
     learner = DataParallelSAC(sac, make_mesh(dp=1, devices=devices[:1]))
     state = jax.eval_shape(sac.init_state, jax.random.key(0), env.example_obs())
@@ -295,13 +308,26 @@ def _hybrid_trunk_burst(devices):
     compiled = learner._build_burst(cfg.update_every, state, ring, chunk).lower(
         state, ring, chunk
     ).compile()
+    return cfg, state, compiled
+
+
+def _hybrid_trunk_burst(devices):
+    """The ``nemotron_h`` trunk's burst as the benchmark's cell builds it, at
+    the cell's own sizes (one period of eleven layers at the published widths,
+    this chip's share of heads and experts, 1024 x batch 4, every block
+    recomputed): the chunked scan, the flash kernels without q's pass, the
+    two-kernel grouped products and the router's top 22 of 512 lower for the
+    v5e, and the compiler's account of the step fits the chip."""
+    cfg, state, compiled = _compile_hybrid_trunk_burst(devices)
+    assert (cfg.trunk_pattern, cfg.trunk_hidden, cfg.trunk_remat) == ("EMEMEMEMEM*", 4096, 11)
     mem = compiled.memory_analysis()
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(state.critic_params))
     assert 566e6 < n_params < 570e6
     # trunk, target and Adam's moments rest in the arguments and are updated in
-    # place; the whole step fits the chip's 15.75 GiB (read at PR 40: 13.42 GB)
+    # place; the whole step fits the chip's 15.75 GiB as it did before the
+    # selection's kernels (read at PR 40: 13.42 GB; at PR 41: see PERF.md)
     assert mem.alias_size_in_bytes >= 16 * n_params
-    assert mem.peak_memory_in_bytes < 14.5e9, mem.peak_memory_in_bytes
+    assert mem.peak_memory_in_bytes < 13.43e9, mem.peak_memory_in_bytes
     text = compiled.as_text()
     kinds = [_kernel_kind(name) for name in _kernels(text)]
     assert "ragged-dot" in text and "qk-rope" not in kinds and len(kinds) >= 3, kinds
@@ -309,6 +335,9 @@ def _hybrid_trunk_burst(devices):
     for op in ("gather", "scatter"):
         moved = _expert_layer_rows(text, op)
         assert moved and max(moved) <= moe.PIECE_ROWS, (op, moved)
+    _selection_is_a_pass(
+        text, cfg.batch_size * cfg.history_len, cfg.trunk_experts_per_tok, cfg.trunk_experts
+    )
 
 
 def _kernels(hlo_text):

@@ -361,25 +361,61 @@ def test_the_stack_is_built_from_the_pattern():
         SACConfig(**{**SMALL, "trunk_ssm_heads": 3})
 
 
-def test_the_sdar_burst_lowers_to_what_it_did_before_the_pattern():
-    """``ops/moe.py`` and ``SequenceTrunk`` were generalised under the SDAR
-    cell's feet: its data-parallel burst lowers to the very text it lowered to
-    on the parent commit of PR 40 (read there with this recipe), so the chip
-    runs the program it ran."""
-    import hashlib
-
-    from test_trunk import _burst_text
+def test_the_sdar_burst_chooses_and_learns_what_it_did_with_top_k_and_the_mask(monkeypatch):
+    """The selection changed ``route`` under the SDAR cell's feet (PR 41; until
+    then this test pinned that burst's lowered text, PR 40): its small
+    data-parallel burst, run with the selection and with ``lax.top_k`` and the
+    mask in ``route``'s place, reports the same choices to the element and
+    leaves the same state within float32 rounding."""
+    from test_trunk import route_by_sort_and_mask
+    from torch_actor_critic_tpu.core.types import Batch
+    from torch_actor_critic_tpu.parallel.dp import (
+        DataParallelSAC, init_sharded_buffer, shard_chunk,
+    )
+    from torch_actor_critic_tpu.parallel.mesh import make_mesh
 
     cfg = SACConfig(
         trunk_block="sdar_moe", history_len=64, batch_size=4, update_every=3, buffer_size=256,
         burst_unroll=1, trunk_hidden=64, trunk_q_heads=4, trunk_kv_heads=2, trunk_head_dim=16,
         trunk_layers=2, trunk_experts=16, trunk_experts_held=(2, 6), trunk_experts_per_tok=4,
-        trunk_expert_width=48, trunk_remat=1, trunk_report_choices=True,
+        trunk_expert_width=48, trunk_remat=1, trunk_report_choices=True, trunk_bf16_dots=False,
     )
-    text = _burst_text(cfg, jax.ShapeDtypeStruct((64, 5), jnp.float32), 3)
-    assert hashlib.sha256(text.encode()).hexdigest() == (
-        "6e07a2c925e8fe142dc3d515d113047fcb7d4205a4c64c46c5a62c7784084290"
+    spec = jax.ShapeDtypeStruct((64, 5), jnp.float32)
+    env = types.SimpleNamespace(act_dim=3, act_limit=1.0, obs_spec=spec)
+    mesh = make_mesh(dp=1, devices=jax.devices()[:1])
+    k = jax.random.split(jax.random.key(11), 5)
+    rows = Batch(
+        states=jax.random.normal(k[0], (1, 40, 64, 5)),
+        actions=jax.random.uniform(k[1], (1, 40, 3), minval=-1.0, maxval=1.0),
+        rewards=jax.random.normal(k[2], (1, 40)),
+        next_states=jax.random.normal(k[3], (1, 40, 64, 5)),
+        done=(jax.random.uniform(k[4], (1, 40)) < 0.3).astype(jnp.float32),
     )
+
+    def burst(route):
+        if route is not None:
+            monkeypatch.setattr(moe, "route", route)
+        learner = DataParallelSAC(make_learner(cfg, *build_models(cfg, env), 3), mesh)
+        state = learner.init_state(jax.random.key(5), jnp.zeros(spec.shape))
+        ring = init_sharded_buffer(256, spec, 3, mesh)
+        state, _, metrics = learner.update_burst(state, ring, shard_chunk(rows, mesh), 3)
+        return jax.device_get((state, metrics))
+
+    (state, metrics), (want_state, want) = burst(None), burst(route_by_sort_and_mask)
+    assert metrics["trunk/choices_first"].shape == (2, 4 * 64, 4)
+    np.testing.assert_array_equal(metrics["trunk/choices_first"], want["trunk/choices_first"])
+    for tree, want_tree in (
+        (state.critic_params, want_state.critic_params),
+        (state.actor_params, want_state.actor_params),
+        (state.target_critic_params, want_state.target_critic_params),
+    ):
+        # Three Adam steps of 3e-4 each. Adam divides a gradient by its own
+        # size, so where one is all rounding (a router's, a sum of terms that
+        # cancel) the two programs' last bits move a parameter by a few
+        # hundredths of a step (read: 1.1e-5 in 37 of a router's 1,024).
+        for a, b in zip(jax.tree_util.tree_leaves(tree), jax.tree_util.tree_leaves(want_tree)):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=0.03 * 3 * cfg.lr)
+    assert float(metrics["loss_q"]) == pytest.approx(float(want["loss_q"]), rel=1e-6)
 
 
 @pytest.fixture(scope="module")

@@ -167,6 +167,93 @@ def test_bf16_dots_round_operands_and_keep_float32_tiles():
     assert float(jnp.max(jnp.abs(low - flash_attention(q, k, v, True, 128, 128, True)))) > 1e-4
 
 
+# --------------------------------------------------------------- the router
+
+
+def route_by_sort_and_mask(
+    u, w_router, top_k, scoring="softmax", bias=None, scale=1.0, impl=None
+):
+    """``ops.moe.route`` as it stood before PR 41, kept as what the
+    selection is held to: ``lax.top_k`` (whole sorts of a token's scores on
+    the TPU) and the chosen scores by a mask over tokens x top_k x experts
+    (``impl``: ``route``'s signature; there is one form of this)."""
+    logits = jnp.dot(
+        u.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    if scoring == "softmax":
+        p = select = jax.nn.softmax(logits, axis=-1)
+    else:
+        p = jax.nn.sigmoid(logits)
+        select = p if bias is None else p + bias
+    _, top_e = jax.lax.top_k(jax.lax.stop_gradient(select), top_k)
+    chosen = top_e[:, :, None] == jnp.arange(p.shape[-1], dtype=top_e.dtype)
+    top_p = jnp.sum(jnp.where(chosen, p[:, None, :], 0.0), axis=-1)
+    if scoring == "softmax":
+        return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_e, scale * top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
+
+
+def _scores_with_ties(n, experts, biased, seed):
+    """Scores a router could give, ``(n, experts)``, and a bias: random
+    rows; rows on a grid of sixty-fourths, so that many scores are equal;
+    and, with a bias (on the same grid), rows whose scores all differ and
+    tie only once the bias is added."""
+    k = jax.random.split(jax.random.key(seed), 4)
+    p = jax.nn.sigmoid(jax.random.normal(k[0], (n, experts)))
+    third = n // 3
+    p = p.at[:third].set(jnp.round(p[:third] * 64) / 64)
+    if not biased:
+        return p, None
+    bias = jnp.round(jax.random.uniform(k[1], (experts,), minval=-4, maxval=4)) / 64
+    apart = jax.random.permutation(k[2], experts)[None, :] / (64.0 * experts)
+    tied = jnp.round(p[third:2 * third] * 16) / 16 - bias  # p + bias on a coarser grid
+    return p.at[third:2 * third].set(jnp.where(tied > 0, tied, apart)), bias
+
+
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+@pytest.mark.parametrize("experts,top_k", [(16, 4), (128, 8), (512, 22)])
+def test_the_selection_is_top_k_and_the_mask_to_the_element(experts, top_k, scoring, impl):
+    """``ops.moe.top_scores`` (the rounds as XLA composes them, and the
+    kernels in the interpreter) against ``lax.top_k`` and the mask: the same
+    experts in the same order whatever ties there are, the same scores, the
+    same weights, the same gradients; none to the bias. 300 tokens are no
+    whole tile."""
+    n, biased = 300, scoring == "sigmoid"
+    p, bias = _scores_with_ties(n, experts, biased, seed=experts + top_k)
+    select = p if bias is None else p + bias
+    ordered = jnp.sort(select, -1)
+    assert int(jnp.sum(ordered[:, 1:] == ordered[:, :-1])) > n // 2  # equal scores there are
+    _, want_e = jax.lax.top_k(select, top_k)
+    top_e, top_p = moe.top_scores(p, bias, top_k, impl)
+    np.testing.assert_array_equal(top_e, want_e)
+    np.testing.assert_array_equal(top_p, jnp.take_along_axis(p, want_e, axis=-1))
+    # through the router: the weights to the bit, the gradients to rounding
+    k = jax.random.split(jax.random.key(top_k), 3)
+    u = jax.random.normal(k[0], (n, 32))
+    u = u.at[:40].set(u[0])
+    w_r = jax.random.normal(k[1], (32, experts)) * 0.3
+    w_r = w_r.at[:, 5].set(w_r[:, 3])  # two experts every token scores alike
+    if biased:
+        bias = bias.at[5].set(bias[3])
+    cot = jax.random.normal(k[2], (n, top_k))
+    want = route_by_sort_and_mask(u, w_r, top_k, scoring, bias, 2.5)
+    got = moe.route(u, w_r, top_k, scoring, bias, 2.5, impl)
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1], want[1])
+
+    def loss(route):
+        return lambda u, w, b: jnp.sum(route(u, w, top_k, scoring, b, 2.5)[1] * cot)
+
+    wrt = (0, 1, 2) if biased else (0, 1)
+    want_g = jax.grad(loss(route_by_sort_and_mask), wrt)(u, w_r, bias)
+    got_g = jax.grad(loss(lambda *a: moe.route(*a, impl)), wrt)(u, w_r, bias)
+    for a, b in zip(got_g[:2], want_g[:2]):
+        assert float(jnp.max(jnp.abs(a - b))) <= 1e-6 * float(jnp.max(jnp.abs(b)))
+    assert not biased or not np.any(got_g[2])
+
+
 # ------------------------------------------------------------ expert layer
 
 
@@ -379,6 +466,28 @@ def test_trunk_forward_matches_the_reference():
     got = trunk.apply({"params": state.critic_params["params"]["trunk"]}, obs)
     want, _ = reference_trunk.trunk(state.critic_params["params"]["trunk"], obs, MODEL, "highest")
     np.testing.assert_allclose(got, want, atol=2e-5)  # float32, another order of sums
+
+
+def test_a_trunk_that_keeps_the_kernels_off_keeps_the_selections_off_too(monkeypatch):
+    """The Trainer's host mirror is compiled for the CPU beside a TPU, and
+    ``auto`` is resolved by the process's default backend: a trunk handed
+    ``xla_attention`` takes the selection as XLA composes it too, so its
+    program holds no kernel (128 experts: a size the kernels have blocks for)."""
+    from torch_actor_critic_tpu.models.sequence import SparseMoE, xla_attention
+
+    cfg, _ = _learner(trunk_experts=128, trunk_experts_held=(8, 16))
+    spec = TrunkSpec.from_config(cfg)
+    obs = _batch(1).states
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mirror = SequenceTrunk(spec=spec, attention_fn=xla_attention)
+    params = mirror.init(jax.random.key(0), obs)
+    text = jax.jit(mirror.apply).lower(params, obs).as_text()
+    assert "tpu_custom_call" not in text and "router_top_k" not in text
+    assert np.all(np.isfinite(mirror.apply(params, obs)))
+    # the layer by itself reaches for the kernels, which a CPU cannot lower
+    layer, u = SparseMoE(spec), jnp.zeros((4, HISTORY, spec.hidden))
+    with pytest.raises(Exception, match="[Ii]nterpret|CPU|cpu"):
+        jax.jit(layer.apply).lower(jax.eval_shape(layer.init, jax.random.key(0), u), u)
 
 
 def test_the_stated_precision_rounds_the_kernels_operands_on_the_cpu_too():

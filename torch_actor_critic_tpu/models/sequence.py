@@ -317,10 +317,16 @@ class SparseMoE(nn.Module):
 
     Sows ``sizes`` (tokens of every held expert) and ``choices`` (every
     token's chosen experts, of all) into the ``moe_stats`` collection for
-    whoever applies the trunk with that collection mutable."""
+    whoever applies the trunk with that collection mutable.
+
+    ``selection`` is the ``impl`` of the router's selection
+    (:func:`ops.moe.top_scores`): its kernels on a TPU, or ``"xla"`` from a
+    block whose ``attention_fn`` keeps every kernel off the program (the
+    host mirror's, compiled for the CPU beside a TPU)."""
 
     spec: TrunkSpec
     dtype: t.Any = jnp.float32
+    selection: str = "auto"
 
     @nn.compact
     def __call__(self, u: jax.Array) -> jax.Array:
@@ -341,15 +347,15 @@ class SparseMoE(nn.Module):
             "w_down", _expert_kernel_init, (hi - lo, sp.expert_width, width)
         )
         with jax.named_scope(scopes.TRUNK_MOE_ROUTE):
-            if sp.router == "softmax":
-                top_e, top_w = moe.route(x, w_router, sp.experts_per_tok)
-            else:
+            bias = None
+            if sp.router != "softmax":
                 # Moves the choice alone, so no gradient reaches it: it is a
                 # parameter that training by gradient leaves where it was.
                 bias = self.param("router_bias", nn.initializers.zeros, (sp.experts,))
-                top_e, top_w = moe.route(
-                    x, w_router, sp.experts_per_tok, sp.router, bias, sp.routed_scale
-                )
+            top_e, top_w = moe.route(
+                x, w_router, sp.experts_per_tok, sp.router, bias, sp.routed_scale,
+                self.selection,
+            )
         v = x
         if sp.expert_latent:
             with jax.named_scope(scopes.TRUNK_MOE_LATENT):
@@ -447,6 +453,12 @@ class MambaMixer(nn.Module):
             return _linear(hidden, dtype, "out_proj")(y)
 
 
+def _selection(attention_fn) -> str:
+    """The router's selection beside ``attention_fn``: kernels where it is
+    the one that reaches the flash kernels."""
+    return "auto" if attention_fn is default_attention else "xla"
+
+
 class SDARBlock(nn.Module):
     """``h = x + Attn(RMSNorm(x))``, ``y = h + MoE(RMSNorm(h))``."""
 
@@ -463,7 +475,7 @@ class SDARBlock(nn.Module):
             )(RMSNorm(sp.rms_eps, name="input_norm")(x), pos)
         with jax.named_scope(scopes.TRUNK_MOE_ROUTE):
             u = RMSNorm(sp.rms_eps, name="post_attention_norm")(h)
-        return h + SparseMoE(sp, name="moe")(u)
+        return h + SparseMoE(sp, selection=_selection(self.attention_fn), name="moe")(u)
 
 
 class MixerBlock(nn.Module):
@@ -487,7 +499,9 @@ class MixerBlock(nn.Module):
         if self.kind == STATE_SPACE:
             return x + MambaMixer(sp, self.dtype, name="mixer")(u)
         if self.kind == EXPERTS:
-            return x + SparseMoE(sp, self.dtype, name="mixer")(u)
+            return x + SparseMoE(
+                sp, self.dtype, _selection(self.attention_fn), name="mixer"
+            )(u)
         with jax.named_scope(scopes.TRUNK_ATTENTION):
             return x + GroupedQueryAttention(
                 sp, self.attention_fn, self.dtype, name="mixer"

@@ -5,7 +5,8 @@ its chosen experts' outputs by their weights. Two forms are written here, and
 a layer takes its own from its spec (``models/sequence.py::TrunkSpec``).
 *Routing* (:func:`route`): the ``top_k`` largest softmax probabilities,
 renormalised; or sigmoid scores, chosen by score plus a correction bias,
-weighted by the scores alone, renormalised and scaled. *Expert*
+weighted by the scores alone, renormalised and scaled; the choice itself is
+one selection pass (:func:`top_scores`, below). *Expert*
 (``FORMS``): the gated ``W_down^e (silu(W_gate^e u) * W_up^e u)`` with three
 kernels, or the plain ``W_down^e relu(W_up^e u)^2`` with two. The experts work
 in whatever width ``u`` has (a latent one where the layer projects down before
@@ -15,6 +16,33 @@ shares the experts of a layer among chips: this chip holds experts
 ``held = (lo, hi)`` and computes the terms of the chosen experts it holds;
 the other terms belong to other chips and are left out here (no stand-in for
 the exchange). The router always looks at all ``num_experts``.
+
+The selection (:func:`top_scores`) takes a token's ``top_k`` of
+``num_experts`` scores in descending order, equal scores by the lower index
+first: ``lax.top_k``'s answer to the element, which the references' stable
+sorts hold it to. It does not sort. ``lax.top_k`` lowers on XLA:TPU to a whole
+sort of a token's scores with their indices (22 of 512 over 4,096 tokens:
+0.335-0.367 ms a call, fifteen calls a step of the hybrid trunk cell), and
+the chosen scores were then picked by a mask over tokens x ``top_k`` x
+experts, because a gather's gradient is a scatter of ``N * top_k`` scalars.
+A round of the selection has the chosen score in hand where it finds the
+index, so choice and scores are one pass and nothing is gathered; the
+gradient puts each round's cotangent back at its index by ``top_k``
+compare-selects over a tile, no scatter and no mask in memory. On a TPU both
+directions are kernels over tiles of ``TOKENS_TILE`` tokens (names
+``router_top_k`` / ``router_top_k_bwd`` in a trace); everywhere else, and
+for the tests, XLA composes the same rounds. Read on the v5e (my chip runs,
+PR 41; ten steps of the hybrid cell's burst, one process, one state): ``top_k``
+and the mask 2,265.9 ms; the rounds composed by XLA (each a reduction over
+``(N, E)`` that carries score, index and ``p``) with the selects fused into the
+router's backward products 2,291.2; the forward kernel with those selects
+2,261.5 (XLA fuses the chain into both products and computes it twice, slowly
+in ``du``'s; as a kernel's output it is written once); both kernels 2,196.3
+with tiles of 256 tokens, 2,205.2 with 128, 2,203.0 with 512. ``route`` alone,
+forward / forward and gradient, 4,096 tokens of 4,096 to 512 scores, 22
+chosen: 1.012 / 2.188 ms before, 0.945 / 2.482 composed, 0.665 / 1.879 with
+the kernels; 8,192 tokens of 2,048 to 128, 8 chosen (SDAR's): 0.496 / 0.865,
+0.402 / 0.566, 0.400 / 0.702.
 
 No token is ever dropped and there is no capacity factor: the assignments
 that land on held experts are sorted by expert (a stable sort, so tokens keep
@@ -98,7 +126,7 @@ class Plan(t.NamedTuple):
 
 def route(
     u: jax.Array, w_router: jax.Array, top_k: int, scoring: str = "softmax",
-    bias: jax.Array | None = None, scale: float = 1.0,
+    bias: jax.Array | None = None, scale: float = 1.0, impl: str = "auto",
 ):
     """``(top_e, top_w)``: each token's ``top_k`` experts of all and their
     weights. ``scoring="softmax"``: the largest probabilities, renormalised.
@@ -106,24 +134,249 @@ def route(
     ``s + bias`` (the correction bias moves the choice alone), their weights
     ``scale * s / (sum of the chosen s + 1e-20)``. The router's product runs
     at ``highest`` precision: the choice is discrete, and a near-tie must flip
-    only on what came in, never on this product's own rounding."""
+    only on what came in, never on this product's own rounding. The choice
+    and the chosen scores are :func:`top_scores`'s (``impl``)."""
     logits = jnp.dot(
         u.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
     if scoring == "softmax":
-        p = select = jax.nn.softmax(logits, axis=-1)
+        p, bias = jax.nn.softmax(logits, axis=-1), None
     else:
         p = jax.nn.sigmoid(logits)
-        select = p if bias is None else p + bias
-    _, top_e = jax.lax.top_k(jax.lax.stop_gradient(select), top_k)
-    # The chosen probabilities by a mask, not by top_k's own values or a
-    # gather: either one's gradient is a scatter of N * top_k scalars.
-    chosen = top_e[:, :, None] == jnp.arange(p.shape[-1], dtype=top_e.dtype)
-    top_p = jnp.sum(jnp.where(chosen, p[:, None, :], 0.0), axis=-1)
+    top_e, top_p = top_scores(p, bias, top_k, impl)
     if scoring == "softmax":
         return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     return top_e, scale * top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
+
+
+# Tokens of one tile of the selection's kernels (module docstring: 128 and
+# 512 read slower inside the burst).
+TOKENS_TILE = 256
+
+
+def top_scores(p: jax.Array, bias: jax.Array | None, top_k: int, impl: str = "auto"):
+    """``(top_e, top_p)``, both ``(N, top_k)``: for each row of the scores
+    ``p`` ``(N, E)`` its ``top_k`` largest of ``p + bias`` (of ``p`` without
+    one) in descending order, equal scores by the lower index first (what
+    ``lax.top_k`` gives, to the element), and ``p`` there. The gradient goes
+    to ``p`` at the chosen places; none to ``bias``.
+
+    ``top_k`` rounds of *the largest, the first index that holds it, strike
+    it out*; a round reads ``p`` where it chose, so the chosen scores come
+    with the choice. ``'xla'`` composes the rounds (each one reduction over
+    ``(N, E)``, the gradient a chain of selects), ``'pallas'`` runs them
+    over a tile of ``TOKENS_TILE`` tokens resident in VMEM and the gradient
+    over such a tile (float32, ``E`` whole lanes), ``'interpret'`` those
+    kernels in the Pallas interpreter; ``'auto'`` the kernels on a TPU where
+    they have blocks, chosen at trace time like
+    :func:`ops.attention.qk_norm_rope`, with its CAUTION (module docstring:
+    what each form read on the chip)."""
+    if impl == "auto":
+        fits = p.dtype == jnp.float32 and p.shape[-1] % 128 == 0
+        impl = "pallas" if jax.default_backend() == "tpu" and fits else "xla"
+    return _top_scores(p, bias, top_k, p.shape[-1], impl)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _top_scores(p, bias, top_k, num_experts, impl):
+    return _top_scores_fwd(p, bias, top_k, num_experts, impl)[0]
+
+
+def _top_scores_fwd(p, bias, top_k, num_experts, impl):
+    if impl == "xla":
+        out = _rounds(p, bias, top_k)
+    else:
+        out = _rounds_kernel(p, bias, top_k, impl == "interpret")
+    return out, (out[0], bias)
+
+
+def _rounds(p, bias, top_k):
+    """The rounds as XLA composes them: a round is one reduction over the
+    pairs (score, index) that are left, the larger score and of equal scores
+    the lower index, carrying ``p``. What is left is what comes after the
+    last choice in that order, so nothing is struck out in memory."""
+    n, e = p.shape
+    select = p if bias is None else p + bias
+    index = jax.lax.broadcasted_iota(jnp.int32, (n, e), 1)
+    carried = (select, index) if bias is None else (select, index, p)
+    lowest = (-jnp.inf, e, 0.0)[:len(carried)]
+
+    def first_largest(x, y):
+        x_wins = (x[0] > y[0]) | ((x[0] == y[0]) & (x[1] < y[1]))
+        return tuple(jnp.where(x_wins, a, b) for a, b in zip(x, y))
+
+    top_e, top_p, last = [], [], None
+    for _ in range(top_k):
+        left = carried
+        if last is not None:
+            m, i = last
+            after = (select < m) | ((select == m) & (index > i))
+            left = (jnp.where(after, select, -jnp.inf), *carried[1:])
+        m, i, *chosen = jax.lax.reduce(
+            left, tuple(jnp.asarray(v, a.dtype) for v, a in zip(lowest, left)),
+            first_largest, (1,),
+        )
+        top_e.append(i)
+        top_p.append(chosen[0] if chosen else m)
+        last = (m[:, None], i[:, None])
+    return jnp.stack(top_e, axis=1), jnp.stack(top_p, axis=1)
+
+
+def _rounds_kernel_body(p_ref, *refs, top_k: int, biased: bool):
+    """One tile of tokens by all experts, turned once so that the tokens lie
+    along the lanes and the experts along the sublanes (a reduction over
+    experts is then elementwise but for its last eight). A round scans the
+    experts eight at a time, in order: it strikes the last round's choice out
+    of the scores left in ``s_ref``, and keeps for each sublane and token the
+    largest score so far, where it stood and ``p`` there (a strictly larger
+    one replaces it, so the first of equal scores stays); eight candidates a
+    token are then one sublane reduction each."""
+    from jax.experimental import pallas as pl
+
+    if biased:  # choose by p + bias, hand back p: both are kept, turned
+        bias_ref, e_ref, w_ref, s_ref, pt_ref = refs
+        pt_ref[...] = p_ref[...].T
+        s_ref[...] = pt_ref[...] + bias_ref[...]
+    else:  # the score chosen by is the score handed back
+        (e_ref, w_ref, s_ref), pt_ref = refs, None
+        s_ref[...] = p_ref[...].T
+    n_experts, tile = s_ref.shape
+    sublane = jax.lax.broadcasted_iota(jnp.int32, (8, tile), 0)
+
+    def round_(r, struck):
+        best = jnp.full((8, tile), -jnp.inf, jnp.float32)
+        slab = jnp.zeros((8, tile), jnp.int32)
+        score = jnp.zeros((8, tile), jnp.float32)
+        for j in range(n_experts // 8):
+            rows = slice(8 * j, 8 * j + 8)
+            s = jnp.where(sublane == struck - 8 * j, -jnp.inf, s_ref[rows, :])
+            s_ref[rows, :] = s
+            larger = s > best
+            best = jnp.where(larger, s, best)
+            slab = jnp.where(larger, j, slab)
+            if pt_ref is not None:
+                score = jnp.where(larger, pt_ref[rows, :], score)
+        expert = 8 * slab + sublane
+        m = jnp.max(best, axis=0, keepdims=True)
+        chosen = jnp.min(jnp.where(best == m, expert, n_experts), axis=0, keepdims=True)
+        if pt_ref is not None:
+            m = jnp.sum(jnp.where(expert == chosen, score, 0.0), axis=0, keepdims=True)
+        e_ref[pl.ds(r, 1), :] = chosen
+        w_ref[pl.ds(r, 1), :] = m
+        return chosen
+
+    jax.lax.fori_loop(0, top_k, round_, jnp.full((1, tile), -1, jnp.int32))
+
+
+# ``jax.jit`` round a kernel's call: a program that calls it at many places
+# (the hybrid burst: twenty) traces and lowers it once a shape (4.8 s of the
+# burst's 14.5 s of lowering otherwise: sandbox, PR 41), and XLA inlines the
+# calls, each under its call site's scopes.
+_traced_once = functools.partial(jax.jit, static_argnums=(2, 3))
+
+
+@_traced_once
+def _rounds_kernel(p, bias, top_k, interpret):
+    """:func:`_rounds_kernel_body` over tiles of ``TOKENS_TILE`` tokens, the
+    tokens padded to whole tiles. The scores go in as the router's product
+    leaves them, tokens first (asked for experts first, XLA lays the product
+    itself out that way and turns its 64 MB operand instead: five times the
+    product's time by the compiler's own estimate, sandbox compile, PR 41);
+    the choices come out rounds first and XLA turns those."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, e = p.shape
+    tile = min(TOKENS_TILE, -(-n // 128) * 128)
+    padded = -(-n // tile) * tile
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    picked = vmem((top_k, tile), lambda i: (0, i))
+    operands = [jnp.pad(p, ((0, padded - n), (0, 0)))]
+    in_specs = [vmem((tile, e), lambda i: (i, 0))]
+    turned = [pltpu.VMEM((e, tile), jnp.float32)]
+    if bias is not None:
+        operands.append(bias.astype(p.dtype).reshape(e, 1))
+        in_specs.append(vmem((e, 1), lambda i: (0, 0)))
+        turned = 2 * turned
+    top_e, top_p = pl.pallas_call(
+        functools.partial(_rounds_kernel_body, top_k=top_k, biased=bias is not None),
+        out_shape=[
+            jax.ShapeDtypeStruct((top_k, padded), jnp.int32),
+            jax.ShapeDtypeStruct((top_k, padded), p.dtype),
+        ],
+        grid=(padded // tile,),
+        in_specs=in_specs,
+        out_specs=[picked, picked],
+        scratch_shapes=turned,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="router_top_k",
+    )(*operands)
+    return top_e[:, :n].T, top_p[:, :n].T
+
+
+def _top_scores_bwd(top_k, num_experts, impl, res, g):
+    top_e, bias = res
+    _, g = g  # the choice is integers
+    if impl == "xla":
+        dp = _chosen_to_scores(top_e, g, num_experts)
+    else:
+        dp = _chosen_to_scores_kernel(top_e, g, num_experts, impl == "interpret")
+    return dp, None if bias is None else jnp.zeros_like(bias)
+
+
+def _chosen_to_scores(top_e, g, num_experts):
+    """``(N, E)``: ``g`` at the chosen places and zero elsewhere. A token
+    chooses an expert once at most: a chain of selects, no sum, no scatter."""
+    experts = jnp.arange(num_experts, dtype=top_e.dtype)
+    dp = jnp.zeros((top_e.shape[0], num_experts), g.dtype)
+    for r in range(top_e.shape[1]):
+        dp = jnp.where(top_e[:, r, None] == experts, g[:, r, None], dp)
+    return dp
+
+
+def _chosen_to_scores_body(e_ref, g_ref, dp_ref):
+    """:func:`_chosen_to_scores` for one tile of tokens, eight at a time."""
+    from jax.experimental import pallas as pl
+
+    experts = jax.lax.broadcasted_iota(jnp.int32, (8, dp_ref.shape[1]), 1)
+
+    def eight(i, carry):
+        rows = pl.ds(pl.multiple_of(8 * i, 8), 8)
+        top_e, g = e_ref[rows, :], g_ref[rows, :]
+        dp = jnp.zeros(experts.shape, dp_ref.dtype)
+        for r in range(top_e.shape[1]):
+            dp = jnp.where(top_e[:, r:r + 1] == experts, g[:, r:r + 1], dp)
+        dp_ref[rows, :] = dp
+        return carry
+
+    jax.lax.fori_loop(0, dp_ref.shape[0] // 8, eight, 0)
+
+
+@_traced_once
+def _chosen_to_scores_kernel(top_e, g, num_experts, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, k = top_e.shape
+    tile = min(TOKENS_TILE, -(-n // 8) * 8)
+    padded = -(-n // tile) * tile
+    top_e, g = (jnp.pad(x, ((0, padded - n), (0, 0))) for x in (top_e, g))
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        _chosen_to_scores_body,
+        out_shape=jax.ShapeDtypeStruct((padded, num_experts), g.dtype),
+        grid=(padded // tile,),
+        in_specs=2 * [vmem((tile, k), lambda i: (i, 0))],
+        out_specs=vmem((tile, num_experts), lambda i: (i, 0)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="router_top_k_bwd",
+    )(top_e, g)[:n]
+
+
+_top_scores.defvjp(_top_scores_fwd, _top_scores_bwd)
 
 
 def plan_assignments(top_e: jax.Array, held: t.Tuple[int, int]) -> Plan:
