@@ -14,6 +14,7 @@ from torch_actor_critic_tpu.core.types import Batch
 from torch_actor_critic_tpu.parallel.mesh import make_mesh
 from torch_actor_critic_tpu.sac.trainer import Trainer, build_models, make_learner
 from torch_actor_critic_tpu.telemetry import PHASES, TelemetryRecorder, classify_epoch, scopes
+from torch_actor_critic_tpu.telemetry.recorder import CHILDREN
 from torch_actor_critic_tpu.utils.config import SACConfig
 
 HLO = """HloModule jit_toy, is_scheduled=true
@@ -260,7 +261,8 @@ def rec_events(run_dir):
 
 def test_every_phase_is_an_annotation_with_its_window(traced_run):
     events, _, _ = traced_run
-    assert {name for name, *_ in events} == set(PHASES)
+    # the phases, and the parts of the spans the window's functions open
+    assert {name for name, *_ in events} == set(PHASES) | set(CHILDREN)
     assert all(int(stats["epoch"]) == 1 for *_, stats in events)
     # a window's act .. burst_dispatch spans share one number; the next
     # window's spans (its param_sync waits for that burst) the next one
@@ -271,8 +273,25 @@ def test_every_phase_is_an_annotation_with_its_window(traced_run):
     full = [w for w, names in by_window.items() if "burst_dispatch" in names]
     assert len(full) == windows and full == list(range(min(full), min(full) + windows))
     for w in full:
-        assert by_window[w].count("env_step") == TINY["update_every"]
+        # stage, place_chunk and burst_dispatch each interrupt the window's
+        # last env_step (three pieces here), which goes on after the
+        # dispatch under the next number (one piece from the window before;
+        # the first window's lies in the epoch before, outside the trace)
+        assert by_window[w].count("env_step") == TINY["update_every"] + 3 - (w == min(full))
         assert by_window[w].count("burst_dispatch") == 1
+        for name in ("stage", "place_chunk", "place_chunk/transfer", "place_chunk/unpack"):
+            assert by_window[w].count(name) == 1, name
+    # who opened what: the spans under the env_step they interrupt, the
+    # parts under their span, the Trainer's own phases under nothing
+    parents = {name: {stats.get("parent", "") for n, _, _, stats in events if n == name}
+               for name in set(PHASES) | set(CHILDREN)}
+    assert parents["stage"] == parents["place_chunk"] == parents["burst_dispatch"] == {"env_step"}
+    assert parents["place_chunk/transfer"] == parents["place_chunk/unpack"] == {"place_chunk"}
+    assert parents["drain/reduce"] == parents["drain/fetch"] == {"drain"}
+    assert parents["act"] == parents["env_step"] == parents["param_sync"] == {""}
+    # the epoch's drain waits for the last window dispatched and says so
+    (fetch,) = [stats for n, _, _, stats in events if n == "drain/fetch"]
+    assert int(fetch["window"]) == max(full)
 
 
 def test_annotations_partition_the_epoch_like_the_phase_timer(traced_run):
@@ -282,6 +301,7 @@ def test_annotations_partition_the_epoch_like_the_phase_timer(traced_run):
     two clocks on a busy host can: a phase's gap is held to a share of the
     epoch, not to a count of milliseconds."""
     events, epoch, laps = traced_run
+    events = [ev for ev in events if ev[0] in PHASES]  # the parts nest inside
     ordered = sorted(events, key=lambda ev: ev[1])
     spans = [(start, start + dur) for _, start, dur, _ in ordered]
     assert all(b[0] >= a[1] - 1 for a, b in zip(spans, spans[1:]))  # no overlap (ns)

@@ -249,11 +249,17 @@ def test_jsonl_schema_roundtrip_and_phase_coverage(off_and_on):
         assert 0.8 * ev["wall_s"] <= covered <= 1.1 * ev["wall_s"]
         # act/env_step run every step; the window phases once per window.
         # The host actor's mirror refresh (param_sync, once after every
-        # burst) interrupts an act, which is then charged in two spans.
+        # burst) interrupts an act, which is then charged in two spans;
+        # a window's stage, place_chunk and burst_dispatch, opened by the
+        # functions that do the work, each interrupt an env_step.
         windows = TINY["steps_per_epoch"] // TINY["update_every"]
         syncs = ev["phases"]["param_sync"]["count"]
         assert windows - 1 <= syncs <= windows
-        assert ev["phases"]["env_step"]["count"] == TINY["steps_per_epoch"]
+        assert ev["phases"]["env_step"]["count"] == (
+            TINY["steps_per_epoch"] + 3 * windows
+        )
+        for name in ("stage", "place_chunk"):
+            assert ev["phases"][name]["count"] == windows
         assert ev["phases"]["act"]["count"] == TINY["steps_per_epoch"] + syncs
         assert ev["phases"]["burst_dispatch"]["count"] == windows
         assert ev["env_steps"] == TINY["steps_per_epoch"]
@@ -268,9 +274,11 @@ def test_recorder_snapshot_matches_run(off_and_on):
         TINY["epochs"] * TINY["steps_per_epoch"]
     )
     # 2 full epochs of spans accumulated at run level (an act that a
-    # param_sync interrupts is two spans)
+    # param_sync interrupts is two spans; an env_step that a window's
+    # stage, place_chunk and burst_dispatch interrupt is four)
+    windows = TINY["epochs"] * TINY["steps_per_epoch"] // TINY["update_every"]
     assert snap["phases"]["env_step"]["count"] == (
-        TINY["epochs"] * TINY["steps_per_epoch"]
+        TINY["epochs"] * TINY["steps_per_epoch"] + 3 * windows
     )
     assert snap["phases"]["act"]["count"] == (
         TINY["epochs"] * TINY["steps_per_epoch"]

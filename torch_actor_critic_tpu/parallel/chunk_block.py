@@ -45,6 +45,8 @@ import numpy as np
 from jax import lax
 from jax.sharding import Sharding
 
+from torch_actor_critic_tpu.telemetry import recorder as spans
+
 __all__ = [
     "ALIGN", "block_views", "find_block", "memory_order", "place_block",
     "transfers",
@@ -241,7 +243,9 @@ def place_block(
     ``jnp.asarray`` would put it and the leaves stay there, uncommitted
     like it. ``None`` where the leaves are no such block or the sharding
     reaches past this process: the caller then places them leaf by leaf.
-    Either way :data:`transfers` counts it."""
+    Either way :data:`transfers` counts it. The block's crossing is the
+    span ``place_chunk/transfer``; the dispatch of the program that takes
+    the leaves out of it is ``place_chunk/unpack``."""
     leaves, treedef = jax.tree_util.tree_flatten(chunk)
     local = block_sharding is None or block_sharding.is_fully_addressable
     found = find_block(leaves) if local else None
@@ -250,10 +254,15 @@ def place_block(
         return None
     transfers["chunk/packed_transfers"] += 1
     block, layout = found
-    if block_sharding is None:
-        return _unpack_program(None, layout, treedef, None)(jnp.asarray(block))
     program = _unpack_program(
         block_sharding, layout, treedef,
-        tuple(treedef.flatten_up_to(shardings)),
+        None if block_sharding is None
+        else tuple(treedef.flatten_up_to(shardings)),
     )
-    return program(jax.device_put(block, block_sharding))
+    with spans.span(spans.PLACE_TRANSFER):
+        if block_sharding is None:
+            on_device = jnp.asarray(block)
+        else:
+            on_device = jax.device_put(block, block_sharding)
+    with spans.span(spans.PLACE_UNPACK):
+        return program(on_device)

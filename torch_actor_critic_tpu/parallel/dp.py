@@ -57,6 +57,7 @@ from torch_actor_critic_tpu.core.types import Batch, BufferState, TrainState
 from torch_actor_critic_tpu.diagnostics import ingraph as diag
 from torch_actor_critic_tpu.parallel import chunk_block, sharding as tp_sharding
 from torch_actor_critic_tpu.parallel.mesh import global_device_put
+from torch_actor_critic_tpu.telemetry import recorder as spans
 from torch_actor_critic_tpu.telemetry import scopes
 
 # Per-device metrics whose cross-replica spread (pmax - pmin) is the
@@ -195,16 +196,9 @@ def shard_chunk_from_local(
     any other chunk (the prefetcher's refill, separately allocated
     leaves), and any chunk on a mesh that reaches past this process,
     crosses leaf by leaf. Same arrays, shapes, dtypes and shardings
-    either way.
+    either way. The window's ``place_chunk`` span; ``packed=`` on it
+    says which way the chunk crossed.
     """
-    if sp is None:
-        sp = mesh.shape.get("sp", 1)
-    specs = _batch_specs(chunk_local, sp)
-    placed = chunk_block.place_block(
-        chunk_local, _shardings(mesh, specs), NamedSharding(mesh, P("dp"))
-    )
-    if placed is not None:
-        return placed
 
     def put(x, s):
         sharding = NamedSharding(mesh, s)
@@ -212,7 +206,18 @@ def shard_chunk_from_local(
             return jax.device_put(x, sharding)
         return jax.make_array_from_process_local_data(sharding, np.asarray(x))
 
-    return jax.tree_util.tree_map(put, chunk_local, specs)
+    with spans.span(spans.PLACE_CHUNK) as span:
+        if sp is None:
+            sp = mesh.shape.get("sp", 1)
+        specs = _batch_specs(chunk_local, sp)
+        placed = chunk_block.place_block(
+            chunk_local, _shardings(mesh, specs), NamedSharding(mesh, P("dp"))
+        )
+        span.tag(packed=int(placed is not None))
+        if placed is None:
+            with spans.span(spans.PLACE_TRANSFER):
+                placed = jax.tree_util.tree_map(put, chunk_local, specs)
+        return placed
 
 
 class DataParallelSAC:
@@ -507,14 +512,18 @@ class DataParallelSAC:
     ) -> t.Tuple[TrainState, BufferState, t.Dict[str, jax.Array]]:
         """Push per-device chunks and run ``num_updates`` DP gradient
         steps as one device dispatch. ``chunk`` leaves have leading axes
-        ``(n_dev, per_dev, ...)`` (see :func:`shard_chunk`)."""
-        if self._burst is None or self._burst[0] != num_updates:
-            self._burst = (
-                num_updates,
-                self._build_burst(num_updates, state, buffer, chunk),
-            )
-            self.burst_abstract = scopes.abstract_of(state, buffer, chunk)
-        return self._burst[1](state, buffer, chunk)
+        ``(n_dev, per_dev, ...)`` (see :func:`shard_chunk`). The window's
+        ``burst_dispatch`` span: the call until it returns (``build=1``
+        where it builds the program first)."""
+        with spans.span(spans.BURST_DISPATCH) as span:
+            if self._burst is None or self._burst[0] != num_updates:
+                span.tag(build=1)
+                self._burst = (
+                    num_updates,
+                    self._build_burst(num_updates, state, buffer, chunk),
+                )
+                self.burst_abstract = scopes.abstract_of(state, buffer, chunk)
+            return self._burst[1](state, buffer, chunk)
 
     def burst_jit(self, num_updates: int):
         """The cached jitted burst for ``num_updates`` (None before its
@@ -536,22 +545,25 @@ class DataParallelSAC:
 
         Pure per-ring data movement (no collectives): ``jax.vmap`` of
         the single-ring ``push`` over the device axis, jitted with the
-        at-rest shardings.
+        at-rest shardings. A ``burst_dispatch`` span like
+        :meth:`update_burst`: it dispatches the window's device work.
         """
-        if self._push is None:
-            sp = self.effective_sp
-            if self._sp_active:
-                self._check_sp_shapes(chunk)
-            buf_sh = _shardings(self.mesh, _buffer_specs(buffer, sp))
-            chunk_sh = _shardings(self.mesh, _batch_specs(chunk, sp))
+        with spans.span(spans.BURST_DISPATCH) as span:
+            if self._push is None:
+                span.tag(build=1)
+                sp = self.effective_sp
+                if self._sp_active:
+                    self._check_sp_shapes(chunk)
+                buf_sh = _shardings(self.mesh, _buffer_specs(buffer, sp))
+                chunk_sh = _shardings(self.mesh, _batch_specs(chunk, sp))
 
-            self._push = jax.jit(
-                jax.vmap(push),
-                in_shardings=(buf_sh, chunk_sh),
-                out_shardings=buf_sh,
-                donate_argnums=(0,),
-            )
-        return self._push(buffer, chunk)
+                self._push = jax.jit(
+                    jax.vmap(push),
+                    in_shardings=(buf_sh, chunk_sh),
+                    out_shardings=buf_sh,
+                    donate_argnums=(0,),
+                )
+            return self._push(buffer, chunk)
 
     # ------------------------------------------------------------- acting
 

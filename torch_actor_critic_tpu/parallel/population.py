@@ -47,6 +47,7 @@ from torch_actor_critic_tpu.buffer.replay import init_replay_buffer, push
 from torch_actor_critic_tpu.core.types import Batch, BufferState, TrainState
 from torch_actor_critic_tpu.parallel import chunk_block
 from torch_actor_critic_tpu.sac.algorithm import Metrics
+from torch_actor_critic_tpu.telemetry import recorder as spans
 from torch_actor_critic_tpu.telemetry import scopes
 
 
@@ -167,18 +168,23 @@ class PopulationLearner:
         one env per member). As ``shard_chunk_from_local``: a chunk
         that is the views of one block crosses in one transfer
         (:mod:`~torch_actor_critic_tpu.parallel.chunk_block`), any other
-        leaf by leaf."""
-        over_dp = self._sharding  # None: one device, nothing committed
-        placed = chunk_block.place_block(
-            chunk,
-            over_dp and jax.tree_util.tree_map(lambda _: over_dp, chunk),
-            over_dp,
-        )
-        if placed is not None:
+        leaf by leaf. The window's ``place_chunk`` span; ``packed=`` on
+        it says which way the chunk crossed."""
+        with spans.span(spans.PLACE_CHUNK) as span:
+            over_dp = self._sharding  # None: one device, nothing committed
+            placed = chunk_block.place_block(
+                chunk,
+                over_dp and jax.tree_util.tree_map(lambda _: over_dp, chunk),
+                over_dp,
+            )
+            span.tag(packed=int(placed is not None))
+            if placed is None:
+                with spans.span(spans.PLACE_TRANSFER):
+                    if over_dp is None:
+                        placed = jax.tree_util.tree_map(jnp.asarray, chunk)
+                    else:
+                        placed = self._place(chunk)
             return placed
-        if self._sharding is None:
-            return jax.tree_util.tree_map(jnp.asarray, chunk)
-        return self._place(chunk)
 
     # ----------------------------------------------------------- the burst
 
@@ -208,15 +214,19 @@ class PopulationLearner:
         Dispatches inside a ``train/population_burst`` watchdog scope:
         once the trainer marks the ``train/`` regime steady, any XLA
         compile landing here is flagged as a hot-path recompile
-        anomaly (docs/OBSERVABILITY.md)."""
-        fn = self._bursts.get(num_updates)
-        if fn is None:
-            fn = self._bursts[num_updates] = self._build_burst(num_updates)
-            self.burst_abstract = scopes.abstract_of(state, buffer, chunk)
+        anomaly (docs/OBSERVABILITY.md). The window's ``burst_dispatch``
+        span: the call until it returns (``build=1`` where it builds the
+        program first)."""
         from torch_actor_critic_tpu.diagnostics.watchdog import get_watchdog
 
-        with get_watchdog().source("train/population_burst"):
-            return fn(state, buffer, chunk)
+        with spans.span(spans.BURST_DISPATCH) as span:
+            fn = self._bursts.get(num_updates)
+            if fn is None:
+                span.tag(build=1)
+                fn = self._bursts[num_updates] = self._build_burst(num_updates)
+                self.burst_abstract = scopes.abstract_of(state, buffer, chunk)
+            with get_watchdog().source("train/population_burst"):
+                return fn(state, buffer, chunk)
 
     # Cost-registry key: matches the watchdog source scope above.
     burst_cost_name = "train/population_burst"
@@ -235,10 +245,13 @@ class PopulationLearner:
         return scopes.scope_table_for(fn, *self.burst_abstract)
 
     def push_chunk(self, buffer: BufferState, chunk: Batch) -> BufferState:
-        """Warmup-path store (no gradient steps), vmapped per member."""
-        if self._push is None:
-            self._push = jax.jit(jax.vmap(push), donate_argnums=(0,))
-        return self._push(buffer, chunk)
+        """Warmup-path store (no gradient steps), vmapped per member. A
+        ``burst_dispatch`` span like :meth:`update_burst`."""
+        with spans.span(spans.BURST_DISPATCH) as span:
+            if self._push is None:
+                span.tag(build=1)
+                self._push = jax.jit(jax.vmap(push), donate_argnums=(0,))
+            return self._push(buffer, chunk)
 
     # ------------------------------------------------------------- acting
 

@@ -32,6 +32,7 @@ from torch_actor_critic_tpu.core.types import Batch, BufferState, TrainState
 from torch_actor_critic_tpu.envs.ondevice import EnvState
 from torch_actor_critic_tpu.utils.sync import drain
 from torch_actor_critic_tpu.sac.algorithm import SAC
+from torch_actor_critic_tpu.telemetry import recorder as spans
 from torch_actor_critic_tpu.telemetry import scopes
 
 Metrics = t.Dict[str, jax.Array]
@@ -391,13 +392,19 @@ class OnDeviceLoop(_EpochPrograms):
         device dispatch for the whole call. ``warmup=True`` collects
         with uniform-random actions and skips updates (the reference's
         ``start_steps``/``update_after`` phase, ref
-        ``sac/algorithm.py:227-228,273``)."""
+        ``sac/algorithm.py:227-228,273``). A ``burst_dispatch`` span: the
+        call of the program until it returns (``build=1`` where it
+        builds the program first)."""
         from torch_actor_critic_tpu.diagnostics.watchdog import get_watchdog
 
         args = (train_state, buffer, env_states, act_key)
-        fn = self._epoch_program((steps, update_every, warmup), args)
-        with get_watchdog().source(self.epoch_cost_name):
-            return fn(*args)
+        sig = (steps, update_every, warmup)
+        with spans.span(spans.BURST_DISPATCH) as span:
+            if sig not in self._epoch_fns:
+                span.tag(build=1)
+            fn = self._epoch_program(sig, args)
+            with get_watchdog().source(self.epoch_cost_name):
+                return fn(*args)
 
 
 def loop_class_for(env_cls) -> type:
@@ -671,13 +678,19 @@ class PopulationOnDeviceLoop(_EpochPrograms):
         """One population epoch: ``steps`` vectorized env steps times
         ``n_envs`` envs times ``n_members`` members, with a fused
         gradient burst per ``update_every`` window per member — one
-        device dispatch for everything."""
+        device dispatch for everything. A ``burst_dispatch`` span: the
+        call of the program until it returns (``build=1`` where it
+        builds the program first)."""
         from torch_actor_critic_tpu.diagnostics.watchdog import get_watchdog
 
         args = (state, buffer, env_states, act_keys)
-        fn = self._epoch_program((steps, update_every, warmup), args)
-        with get_watchdog().source(self.epoch_cost_name):
-            return fn(*args)
+        sig = (steps, update_every, warmup)
+        with spans.span(spans.BURST_DISPATCH) as span:
+            if sig not in self._epoch_fns:
+                span.tag(build=1)
+            fn = self._epoch_program(sig, args)
+            with get_watchdog().source(self.epoch_cost_name):
+                return fn(*args)
 
     # ------------------------------------------------------------------- pbt
 

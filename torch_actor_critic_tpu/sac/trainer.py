@@ -58,6 +58,7 @@ from torch_actor_critic_tpu.resilience.sentinel import (
 )
 from torch_actor_critic_tpu.sac.algorithm import SAC
 from torch_actor_critic_tpu.telemetry import TelemetryRecorder
+from torch_actor_critic_tpu.telemetry import recorder as spans
 from torch_actor_critic_tpu.utils.checkpoint import Checkpointer
 from torch_actor_critic_tpu.utils.config import SACConfig
 from torch_actor_critic_tpu.utils.normalize import (
@@ -71,20 +72,19 @@ from torch_actor_critic_tpu.utils.tracking import Tracker
 
 logger = logging.getLogger(__name__)
 
-# Integer indices into telemetry.PHASES, hoisted to module constants so
-# the hot loop's instrumentation is `rec.begin(_PH_ENV)` — no dict or
-# attribute lookups per phase mark (docs/OBSERVABILITY.md).
-(
-    _PH_ACT,
-    _PH_ENV,
-    _PH_STAGE,
-    _PH_PLACE,
-    _PH_BURST,
-    _PH_DRAIN,
-    _PH_SENTINEL,
-    _PH_CKPT,
-    _PH_SYNC,
-) = range(9)
+# The phases the Trainer marks itself (integer indices into
+# telemetry.PHASES), hoisted to module constants so the hot loop's
+# instrumentation is `rec.begin(_PH_ENV)` — no dict or attribute lookups
+# per phase mark (docs/OBSERVABILITY.md). The device window's spans
+# (stage, place_chunk, burst_dispatch, drain) are opened by the
+# functions that do the work, through telemetry.recorder.span.
+_PH_ACT = spans.ACT
+_PH_ENV = spans.ENV_STEP
+_PH_BURST = spans.BURST_DISPATCH
+_PH_DRAIN = spans.DRAIN
+_PH_SENTINEL = spans.SENTINEL
+_PH_CKPT = spans.CHECKPOINT
+_PH_SYNC = spans.PARAM_SYNC
 
 
 def build_models(config: SACConfig, env) -> t.Tuple[t.Any, t.Any]:
@@ -456,6 +456,11 @@ class Trainer:
                 sink_max_bytes=int(self.config.telemetry_max_mb * 1e6),
             )
         self.telemetry = telemetry
+        if telemetry is not None:
+            # The spans the window's own functions open (stage,
+            # place_chunk, burst_dispatch, drain) are charged to the
+            # process's current recorder: this one, until close().
+            spans.install(telemetry)
         # Which way this Trainer's chunks crossed to the device is
         # counted where the choice is made, for the whole process; the
         # epoch event reports what was added since here.
@@ -845,48 +850,49 @@ class Trainer:
         written here once and never again, so that
         ``shard_chunk_from_local`` can move the window in one transfer.
         Values, dtypes and shapes are what stacking leaf by leaf gives.
-        Reads nothing of ``self``."""
-        first = Batch(*staging[0][:5])
-        # One column a leaf: that leaf at every staged step, in the
-        # order the Batch flattens (its fields' order is the tuple's).
-        columns = [
-            [np.asarray(x) for x in column]
-            for column in zip(*(
-                jax.tree_util.tree_leaves(tuple(tr[:5])) for tr in staging
-            ))
-        ]
-        # rewards and done are float32 in the chunk whatever was staged.
-        as_f32 = jax.tree_util.tree_leaves(
-            jax.tree_util.tree_map(lambda _: False, first).replace(
-                rewards=True, done=True
-            )
-        )
-        # A leaf lies in the block as its rows lie in memory (rows fetched
-        # from a device are not in C order), the window axis before them:
-        # writing a step is then a plain copy, as np.stack's was.
-        specs = []
-        for column, f32 in zip(columns, as_f32):
-            row = column[0]
-            if any(x.shape != row.shape for x in column):  # as np.stack refuses
-                raise ValueError(
-                    "all staged steps of a leaf must have the same shape, "
-                    f"got {sorted({x.shape for x in column})}"
+        Reads nothing of ``self``. The window's ``stage`` span."""
+        with spans.span(spans.STAGE):
+            first = Batch(*staging[0][:5])
+            # One column a leaf: that leaf at every staged step, in the
+            # order the Batch flattens (its fields' order is the tuple's).
+            columns = [
+                [np.asarray(x) for x in column]
+                for column in zip(*(
+                    jax.tree_util.tree_leaves(tuple(tr[:5])) for tr in staging
+                ))
+            ]
+            # rewards and done are float32 in the chunk whatever was staged.
+            as_f32 = jax.tree_util.tree_leaves(
+                jax.tree_util.tree_map(lambda _: False, first).replace(
+                    rewards=True, done=True
                 )
-            specs.append((
-                (len(staging),) + row.shape[1:],
-                np.float32 if f32
-                else np.result_type(*{x.dtype for x in column}),
-                (0,) + tuple(
-                    1 + axis for axis in chunk_block.memory_order(row[0])
-                ),
-            ))
-        views = chunk_block.block_views(columns[0][0].shape[0], specs)
-        for view, column in zip(views, columns):
-            for step, rows in enumerate(column):
-                view[:, step] = rows
-        return jax.tree_util.tree_unflatten(
-            jax.tree_util.tree_structure(first), views
-        )
+            )
+            # A leaf lies in the block as its rows lie in memory (rows fetched
+            # from a device are not in C order), the window axis before them:
+            # writing a step is then a plain copy, as np.stack's was.
+            specs = []
+            for column, f32 in zip(columns, as_f32):
+                row = column[0]
+                if any(x.shape != row.shape for x in column):  # as np.stack refuses
+                    raise ValueError(
+                        "all staged steps of a leaf must have the same shape, "
+                        f"got {sorted({x.shape for x in column})}"
+                    )
+                specs.append((
+                    (len(staging),) + row.shape[1:],
+                    np.float32 if f32
+                    else np.result_type(*{x.dtype for x in column}),
+                    (0,) + tuple(
+                        1 + axis for axis in chunk_block.memory_order(row[0])
+                    ),
+                ))
+            views = chunk_block.block_views(columns[0][0].shape[0], specs)
+            for view, column in zip(views, columns):
+                for step, rows in enumerate(column):
+                    view[:, step] = rows
+            return jax.tree_util.tree_unflatten(
+                jax.tree_util.tree_structure(first), views
+            )
 
     # Staging seams (overridden by decoupled/learner.py, where the host
     # list becomes a bounded StagingBuffer with backpressure and the
@@ -1393,17 +1399,12 @@ class Trainer:
                 env_steps_this_epoch += n
 
                 # --- device window: push or push+update (ref :273-283) ---
+                # The window's stage, place_chunk and burst_dispatch
+                # are spans of the functions that do the work; each
+                # interrupts env_step and hands back to it.
                 window_full = (step + 1) % cfg.update_every == 0
-                if rec is not None:
-                    # What follows this step and its device window.
-                    after = _PH_DRAIN if epoch_ended else _PH_ACT
-                    rec.begin(_PH_STAGE if window_full else after)
                 if window_full:
                     local_chunk = self._drain_window(staging)
-                    if rec is not None:
-                        rec.begin(
-                            _PH_PLACE if local_chunk is not None else after
-                        )
                 # A None chunk (decoupled only: the admission gate
                 # dropped staged transitions below one fixed-size
                 # window) skips this boundary's device work entirely —
@@ -1423,8 +1424,6 @@ class Trainer:
                         chunk = shard_chunk_from_local(
                             local_chunk, self.mesh, sp=self.dp.effective_sp,
                         )
-                    if rec is not None:
-                        rec.begin(_PH_BURST)
                     if step > cfg.update_after:
                         # (config validation guarantees host_actor here)
                         if cfg.actor_param_lag and step + 1 >= cfg.start_steps:
@@ -1497,9 +1496,11 @@ class Trainer:
                         # bitwise-historical; the refill rows land for
                         # the NEXT window's sampling.
                         self._maybe_refill()
-                    if rec is not None:
-                        rec.window += 1
-                        rec.begin(after)
+                if rec is not None:
+                    # What follows this step and its device window: the
+                    # next act, or the epoch's own fetches (the drain
+                    # span of utils.sync.drain nests inside them).
+                    rec.begin(_PH_DRAIN if epoch_ended else _PH_ACT)
 
                 step += 1
 
@@ -1877,6 +1878,7 @@ class Trainer:
             for line in self.obs.slo.report().splitlines():
                 logger.info("%s", line)
         if self.telemetry is not None:
+            spans.uninstall(self.telemetry)
             self.telemetry.close()
         self.pool.close()
 

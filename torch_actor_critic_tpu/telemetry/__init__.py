@@ -9,7 +9,10 @@ host<->TPU boundary:
   span ring, aggregated per epoch. No host<->device syncs and no
   per-step allocation when enabled; when disabled the Trainer holds
   ``telemetry=None`` and the hot path degenerates to one predicted
-  pointer comparison per phase mark (docs/OBSERVABILITY.md).
+  pointer comparison per phase mark (docs/OBSERVABILITY.md). The
+  device window's spans (stage, place_chunk, burst_dispatch, drain) are
+  opened by the functions that do the work (``recorder.span``) and
+  charged to the recorder installed as the process's current one.
 - :mod:`histogram` — fixed-bucket latency histogram (bounded memory),
   shared with :mod:`~torch_actor_critic_tpu.serve.metrics` so training
   and serving percentiles come from one estimator.

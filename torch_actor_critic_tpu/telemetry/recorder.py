@@ -2,10 +2,13 @@
 
 Design constraints (the tentpole contract, docs/OBSERVABILITY.md):
 
-- **Zero code when disabled.** The Trainer stores ``telemetry=None``
-  and every instrumentation point is ``if rec is not None: rec.begin(i)``
-  over a loop-local — one always-false predicted branch per phase mark,
-  no calls, no allocation, no events. Disabled-mode metrics are
+- **Next to no code when disabled.** The Trainer stores
+  ``telemetry=None`` and every instrumentation point of its own is
+  ``if rec is not None: rec.begin(i)`` over a loop-local — one
+  always-false predicted branch per phase mark, no calls, no allocation,
+  no events. The device window's four spans (below) are then their
+  trace annotations and nothing more: a TraceMe no-op each while no
+  trace is taken, no ring, no clock read. Disabled-mode metrics are
   byte-identical to an uninstrumented build (pinned by
   tests/test_telemetry.py).
 - **No host<->device syncs when enabled.** Every measurement is a
@@ -25,12 +28,33 @@ charges everything since the previous lap (or :meth:`mark`) to phase
 the breakdown answers "where did the time go" without leaving gaps
 (the acceptance check ``make trace-smoke`` asserts the coverage).
 
-The Trainer names a phase where it starts: ``begin(i)`` laps the phase
-that was open and opens ``i``, also as a ``jax.profiler.TraceAnnotation``
-named ``tac/host/<phase>`` with ``window=`` and ``epoch=``: the same
-partition on the profiler's clock, beside the device operations, in
-whatever trace is being taken (``--profile-epochs``, the benchmark's).
-With no trace active an annotation is a TraceMe no-op.
+The Trainer names a phase of its own where it starts: ``begin(i)`` laps
+the phase that was open and opens ``i``, also as a
+``jax.profiler.TraceAnnotation`` named ``tac/host/<phase>`` with
+``window=``, ``parent=`` and ``epoch=``: the same partition on the
+profiler's clock, beside the device operations, in whatever trace is
+being taken (``--profile-epochs``, the benchmark's). With no trace
+active an annotation is a TraceMe no-op.
+
+The device window times itself: ``stage``, ``place_chunk``,
+``burst_dispatch`` and ``drain`` are opened by the functions that do the
+work (``Trainer._build_chunk``, ``shard_chunk_from_local`` /
+``PopulationLearner.place_chunk``, ``update_burst`` / ``push_chunk`` /
+the fused loops' ``epoch``, ``utils.sync.drain``) through :class:`span`,
+whoever calls them. A span always is the annotation; it is also a lap
+of the recorder that is installed as the process's current one
+(:func:`install`: the Trainer installs its own), and nothing more where
+none is. A span opened while a phase is open names it as its ``parent``
+and hands back to it when it closes, so the phases still partition the
+epoch. :data:`CHILDREN` are the parts of a span, nested inside it and
+timed by their own clock reads; they are no part of the partition.
+
+The window number is this module's own counter, advanced when a
+``burst_dispatch`` span closes: ``stage``, ``place_chunk`` and
+``burst_dispatch`` carry the number of the window being made ready,
+``drain`` and its parts the number of the last window dispatched, the
+one they wait for. The open phase, the window and the epoch are the
+learner thread's: the instrumented functions are called from no other.
 """
 
 from __future__ import annotations
@@ -42,10 +66,7 @@ import typing as t
 import jax
 import numpy as np
 
-from torch_actor_critic_tpu.telemetry.costmodel import (
-    PHASE_PLANES,
-    classify_epoch,
-)
+from torch_actor_critic_tpu.telemetry.costmodel import classify_epoch
 from torch_actor_critic_tpu.telemetry.memory import device_memory_watermarks
 from torch_actor_critic_tpu.telemetry.profiler import ProfilerWindow
 from torch_actor_critic_tpu.telemetry.scopes import HOST_PREFIX
@@ -53,7 +74,10 @@ from torch_actor_critic_tpu.telemetry.sinks import JsonlSink, format_summary
 
 logger = logging.getLogger(__name__)
 
-__all__ = ["PHASES", "PhaseTimer", "SpanRing", "TelemetryRecorder"]
+__all__ = [
+    "CHILDREN", "PHASES", "PhaseTimer", "SpanRecord", "SpanRing",
+    "TelemetryRecorder", "current", "install", "span", "uninstall", "window",
+]
 
 # The Trainer step classification (ISSUE 3 / docs/OBSERVABILITY.md): indices
 # are the lap() argument — integer phase ids keep the hot path free of
@@ -69,7 +93,180 @@ PHASES: t.Tuple[str, ...] = (
     "checkpoint",     # Orbax save dispatch
     "param_sync",     # device->host actor mirror: waits for the burst
 )
+# The parts of a phase span, named under it. They nest inside their
+# parent and are no part of the partition: an epoch event reports them
+# under ``children``, never under ``phases``.
+CHILDREN: t.Tuple[str, ...] = (
+    "place_chunk/transfer",  # the device_put of the block, or the leaf-by-leaf puts
+    "place_chunk/unpack",    # dispatch of the program that takes the leaves out of the block
+    "drain/reduce",          # dispatch of the scalar reduction
+    "drain/fetch",           # the device_get of that scalar: the wait
+)
+SPAN_NAMES = PHASES + CHILDREN
+# The ids :class:`span` and ``begin`` take: an index into SPAN_NAMES.
+(
+    ACT, ENV_STEP, STAGE, PLACE_CHUNK, BURST_DISPATCH, DRAIN, SENTINEL,
+    CHECKPOINT, PARAM_SYNC,
+    PLACE_TRANSFER, PLACE_UNPACK, DRAIN_REDUCE, DRAIN_FETCH,
+) = range(len(SPAN_NAMES))
+_N_PHASES = len(PHASES)  # ids below it partition; the parts come after
+_HOST_NAMES = tuple(HOST_PREFIX + name for name in SPAN_NAMES)
+# These wait for the last window dispatched and carry its number.
+_WAITS = frozenset((DRAIN, DRAIN_REDUCE, DRAIN_FETCH))
 SCHEMA_VERSION = 1
+_NAN = float("nan")
+
+# The learner thread's state (module docstring): the installed recorder,
+# the window being made ready, the epoch, and the phase that is open
+# with its parent and its annotation.
+_current: "TelemetryRecorder | None" = None
+_window = 0
+_epoch = 0
+_open = -1
+_open_parent = -1
+_annotation: t.Any = None
+
+
+def install(recorder: "TelemetryRecorder | None") -> "TelemetryRecorder | None":
+    """Make ``recorder`` the one the spans of this process are charged
+    to (``None``: none). Returns the one that was installed."""
+    global _current
+    previous, _current = _current, recorder
+    return previous
+
+
+def uninstall(recorder: "TelemetryRecorder") -> None:
+    """Take ``recorder`` out if it is the installed one."""
+    global _current
+    if _current is recorder:
+        _current = None
+
+
+def current() -> "TelemetryRecorder | None":
+    return _current
+
+
+def window() -> int:
+    """The number of the window being made ready."""
+    return _window
+
+
+def _window_of(phase: int) -> int:
+    return _window - 1 if phase in _WAITS else _window
+
+
+def _annotate(phase: int, parent: int):
+    """The one place a phase or span becomes a trace annotation, entered:
+    its name, its window, the phase it was opened under, the epoch."""
+    annotation = jax.profiler.TraceAnnotation(
+        _HOST_NAMES[phase],
+        window=_window_of(phase),
+        parent=SPAN_NAMES[parent] if parent >= 0 else "",
+        epoch=_epoch,
+    )
+    annotation.__enter__()
+    return annotation
+
+
+def _switch(
+    phase: int, parent: int, recorder: "TelemetryRecorder | None",
+    dispatched: bool = False,
+) -> int:
+    """Close the open phase, charged to ``recorder`` as a lap where
+    there is one, and open ``phase`` (``-1``: none) under ``parent``.
+    ``dispatched``: the phase that closes was a window's dispatch, and
+    what opens belongs to the next window. Returns what was open."""
+    global _open, _open_parent, _annotation, _window
+    prev = _open
+    if prev >= 0:
+        if recorder is not None:
+            recorder.lap(prev, _open_parent)
+        _annotation.__exit__(None, None, None)
+    elif recorder is not None:
+        recorder.timer.mark()
+    if dispatched:
+        _window += 1
+    _open, _open_parent = phase, parent
+    if phase >= 0:
+        _annotation = _annotate(phase, parent)
+    return prev
+
+
+def _thread_os_times() -> t.Tuple[float, float]:
+    """What the operating system says of the calling thread: the CPU
+    seconds it has run, and the seconds it has stood runnable on a run
+    queue (``/proc/thread-self/schedstat``, second field). NaN where the
+    platform does not say."""
+    try:
+        cpu = time.thread_time()
+    except (AttributeError, OSError):
+        cpu = _NAN
+    try:
+        with open("/proc/thread-self/schedstat", "rb") as f:
+            runq = 1e-9 * int(f.read().split()[1])
+    except (OSError, ValueError, IndexError):
+        runq = _NAN
+    return cpu, runq
+
+
+class span:
+    """One host span of the device window, opened where the work happens:
+    ``with span(STAGE): ...``.
+
+    Always the ``tac/host/<name>`` annotation with ``window=``,
+    ``parent=`` and ``epoch=`` (a TraceMe no-op while no trace is being
+    taken). With a recorder installed also one record of its
+    :class:`SpanRing` and a charge to the span's sum, count and maximum.
+
+    A phase (an id of :data:`PHASES`) closes the phase that is open,
+    names it as its parent and hands back to it on the way out, as the
+    Trainer's ``param_sync`` does. A part (an id of :data:`CHILDREN`)
+    nests inside whatever is open and leaves it open. ``os_wait``: a
+    part the thread waits in; with a recorder installed it also keeps
+    the thread's CPU time and run-queue delay over the span.
+    """
+
+    __slots__ = ("phase", "os_wait", "_back", "_annotation", "_t0", "_os0")
+
+    def __init__(self, phase: int, os_wait: bool = False):
+        self.phase = phase
+        self.os_wait = os_wait
+
+    def __enter__(self) -> "span":
+        phase = self.phase
+        if phase < _N_PHASES:
+            self._back = (_open, _open_parent)
+            _switch(phase, _open, _current)
+            return self
+        self._annotation = _annotate(phase, _open)
+        self._t0 = None
+        recorder = _current
+        if recorder is not None:
+            self._os0 = _thread_os_times() if self.os_wait else None
+            self._t0 = recorder._clock()
+        return self
+
+    def tag(self, **metadata) -> None:
+        """Say more of the open span on its annotation (``packed=1``,
+        ``build=1``): what is known only once the work is under way."""
+        # a phase that handed over to another and back is annotated anew
+        open_ = _annotation if self.phase < _N_PHASES else self._annotation
+        open_.set_metadata(**metadata)
+
+    def __exit__(self, *exc) -> None:
+        phase = self.phase
+        if phase < _N_PHASES:
+            _switch(*self._back, _current, dispatched=phase == BURST_DISPATCH)
+            return
+        recorder = _current
+        if recorder is not None and self._t0 is not None:
+            dur = recorder._clock() - self._t0
+            cpu = runq = _NAN
+            if self._os0 is not None:
+                cpu, runq = _thread_os_times()
+                cpu, runq = cpu - self._os0[0], runq - self._os0[1]
+            recorder.charge(phase, _open, self._t0, dur, cpu, runq)
+        self._annotation.__exit__(None, None, None)
 
 
 class PhaseTimer:
@@ -124,44 +321,94 @@ class PhaseTimer:
         }
 
 
+class SpanRecord(t.NamedTuple):
+    """One retained span with everything the ring keeps of it. ``parent``
+    is the id of the phase it was opened under (``-1``: none);
+    ``thread_cpu_s`` and ``runq_wait_s`` are ``None`` but for a span that
+    asked for them (:class:`span`, ``os_wait``) on a platform that says."""
+
+    phase: int
+    parent: int
+    window: int
+    start: float
+    duration: float
+    thread_cpu_s: float | None
+    runq_wait_s: float | None
+
+
 class SpanRing:
     """Preallocated ring of the most recent spans.
 
-    Three fixed numpy arrays (phase id, start time, duration) and a
-    wrapping cursor: recording is three scalar stores, reading
-    (:meth:`spans`) materializes only on demand. This is the drill-down
-    companion to the per-epoch aggregates — "which individual step
-    stalled" — without ever growing.
+    Fixed numpy arrays (phase id, start time, duration; the phase it was
+    opened under, its window, and for a span that waits the thread's CPU
+    time and run-queue delay) and a wrapping cursor: recording is a few
+    scalar stores, reading materializes only on demand. This is the
+    drill-down companion to the per-epoch aggregates, "which individual
+    step stalled", without ever growing.
+
+    :meth:`spans` hands back the phases' laps, the partition, as
+    ``(phase, start, duration)``: ids below ``n_phases`` where that is
+    given. :meth:`records` hands back every retained span, parts
+    included, with all its fields.
     """
 
-    def __init__(self, capacity: int = 4096):
+    def __init__(self, capacity: int = 4096, n_phases: int | None = None):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = capacity
+        self.n_phases = n_phases
         self._phase = np.zeros(capacity, np.int16)
         self._t0 = np.zeros(capacity, np.float64)
         self._dur = np.zeros(capacity, np.float64)
+        self._parent = np.full(capacity, -1, np.int16)
+        self._window = np.zeros(capacity, np.int64)
+        self._cpu = np.full(capacity, _NAN, np.float64)
+        self._runq = np.full(capacity, _NAN, np.float64)
         self._cursor = 0
         self.total = 0
 
-    def record(self, phase: int, t0: float, dur: float) -> None:
+    def record(
+        self, phase: int, t0: float, dur: float, parent: int = -1,
+        window: int = 0, cpu: float = _NAN, runq: float = _NAN,
+    ) -> None:
         i = self._cursor
         self._phase[i] = phase
         self._t0[i] = t0
         self._dur[i] = dur
+        self._parent[i] = parent
+        self._window[i] = window
+        self._cpu[i] = cpu
+        self._runq[i] = runq
         self._cursor = (i + 1) % self.capacity
         self.total += 1
 
-    def spans(self) -> t.List[t.Tuple[int, float, float]]:
-        """Retained spans, oldest first."""
+    def _retained(self) -> t.Iterable[int]:
         n = min(self.total, self.capacity)
         if n < self.capacity:
-            idx = range(n)
-        else:
-            idx = [(self._cursor + k) % self.capacity for k in range(n)]
+            return range(n)
+        return [(self._cursor + k) % self.capacity for k in range(n)]
+
+    def spans(self) -> t.List[t.Tuple[int, float, float]]:
+        """Retained laps of the phases, oldest first."""
+        bound = self.n_phases
         return [
             (int(self._phase[i]), float(self._t0[i]), float(self._dur[i]))
-            for i in idx
+            for i in self._retained()
+            if bound is None or self._phase[i] < bound
+        ]
+
+    def records(self) -> t.List[SpanRecord]:
+        """Every retained span, oldest first."""
+        def told(x):
+            return None if x != x else float(x)
+
+        return [
+            SpanRecord(
+                int(self._phase[i]), int(self._parent[i]), int(self._window[i]),
+                float(self._t0[i]), float(self._dur[i]),
+                told(self._cpu[i]), told(self._runq[i]),
+            )
+            for i in self._retained()
         ]
 
 
@@ -177,16 +424,17 @@ class TelemetryRecorder:
     def __init__(
         self,
         run_dir: t.Any | None = None,
-        phases: t.Sequence[str] = PHASES,
         ring_capacity: int = 4096,
         profile_epochs: t.Optional[t.Tuple[int, int]] = None,
         clock: t.Callable[[], float] = time.perf_counter,
         sink_max_bytes: int = 0,
     ):
-        self.phases = tuple(phases)
+        # The partition; the timer also keeps a row for each of CHILDREN
+        # behind it (the ids :class:`span` takes).
+        self.phases = PHASES
         self._clock = clock
-        self.timer = PhaseTimer(len(self.phases), clock)
-        self.ring = SpanRing(ring_capacity)
+        self.timer = PhaseTimer(len(SPAN_NAMES), clock)
+        self.ring = SpanRing(ring_capacity, n_phases=_N_PHASES)
         self.counters: t.Dict[str, float] = {}
         self.epochs_recorded = 0
         # Run-level accumulation (summary()/snapshot() aggregate the
@@ -195,15 +443,10 @@ class TelemetryRecorder:
         self._run_counts = [0] * len(self.phases)
         self._run_maxs = [0.0] * len(self.phases)
         self._t_epoch: float | None = None
-        # begin()/end(): the open phase (-1: none), its annotation, and
-        # the identifiers every annotation carries. The Trainer bumps
-        # `window` after each burst dispatch, so a window's host spans
-        # and the dispatch of the burst they fed share one number.
-        self.open_phase = -1
-        self._annotation = None
-        self._annotation_names = tuple(HOST_PREFIX + p for p in self.phases)
-        self.window = 0
-        self._epoch = 0
+        # The longest span of the epoch that kept the thread's CPU time
+        # and run-queue delay (a ``drain/fetch``): what a window that
+        # waited leaves behind in the epoch event.
+        self._longest_wait: dict | None = None
         self.last_memory: dict | None = None
         # Host/device/input epoch attribution (costmodel.classify_epoch)
         # — rolling counts per class plus frac sums, surfaced by
@@ -240,8 +483,9 @@ class TelemetryRecorder:
         """Advance the lap mark without charging a phase (region entry)."""
         self.timer.mark()
 
-    def lap(self, phase: int) -> None:
-        """Charge time since the previous lap/mark to ``phase``.
+    def lap(self, phase: int, parent: int = -1) -> None:
+        """Charge time since the previous lap/mark to ``phase``, opened
+        under ``parent``.
 
         Inlined timer + ring update (same-module peers): this runs up
         to a few times per Trainer step, and the flattened body saves
@@ -261,45 +505,75 @@ class TelemetryRecorder:
         ring._phase[i] = phase
         ring._t0[i] = t0
         ring._dur[i] = dt
+        ring._parent[i] = parent
+        ring._window[i] = _window_of(phase)
+        ring._cpu[i] = _NAN
+        ring._runq[i] = _NAN
         ring._cursor = (i + 1) % ring.capacity
         ring.total += 1
+
+    def charge(
+        self, phase: int, parent: int, t0: float, dur: float,
+        cpu: float = _NAN, runq: float = _NAN,
+    ) -> None:
+        """A part of a span (an id of :data:`CHILDREN`) that ran from
+        ``t0`` for ``dur`` under ``parent``: its sum, count and maximum,
+        and one record of the ring. The lap mark stays where it is."""
+        timer = self.timer
+        timer.sums[phase] += dur
+        timer.counts[phase] += 1
+        if dur > timer.maxs[phase]:
+            timer.maxs[phase] = dur
+        window = _window_of(phase)
+        self.ring.record(phase, t0, dur, parent, window, cpu, runq)
+        if cpu == cpu or runq == runq:  # the platform said either
+            longest = self._longest_wait
+            if longest is None or dur > longest["s"]:
+                self._longest_wait = {
+                    "span": SPAN_NAMES[phase], "window": window, "s": dur,
+                    "thread_cpu_s": cpu, "runq_wait_s": runq,
+                }
 
     def inc(self, name: str, value: float = 1.0) -> None:
         """Bump a named counter (epoch-granularity: not for the step
         path — counters allocate on first use)."""
         self.counters[name] = self.counters.get(name, 0.0) + value
 
+    @property
+    def open_phase(self) -> int:
+        """The phase that is open (``-1``: none): the Trainer's own or a
+        span's, whichever was opened last."""
+        return _open
+
+    @property
+    def window(self) -> int:
+        """The number of the window being made ready (the module's)."""
+        return _window
+
+    @window.setter
+    def window(self, value: int) -> None:
+        # benchmark/tools/record_scoped_trace.py still advances it by hand
+        global _window
+        _window = int(value)
+
     def begin(self, phase: int) -> int:
         """Close the open phase (charged as :meth:`lap` charges it) and
         open ``phase``; ``-1`` opens none. Returns the phase that was
         open, so that a phase nested in another can hand back to it."""
-        prev = self.open_phase
-        if prev >= 0:
-            self.lap(prev)
-            self._annotation.__exit__(None, None, None)
-        else:
-            self.timer.mark()
-        self.open_phase = phase
-        if phase >= 0:
-            self._annotation = jax.profiler.TraceAnnotation(
-                self._annotation_names[phase],
-                window=self.window, epoch=self._epoch,
-            )
-            self._annotation.__enter__()
-        return prev
+        return _switch(phase, -1, self)
 
     def end(self) -> None:
         """Close the open phase; what follows is charged to nothing."""
-        self.begin(-1)
+        _switch(-1, -1, self)
 
     # ------------------------------------------------------ epoch boundary
 
     def epoch_begin(self, epoch: int) -> None:
+        global _epoch
         self.profiler.epoch_begin(epoch)
-        if self.open_phase >= 0:  # an epoch that raised left it open
-            self._annotation.__exit__(None, None, None)
-            self.open_phase = -1
-        self._epoch = int(epoch)
+        if _open >= 0:  # an epoch that raised left it open
+            _switch(-1, -1, None)
+        _epoch = int(epoch)
         self._t_epoch = self.timer.mark()
 
     def epoch_end(self, epoch: int, extra: t.Mapping[str, t.Any] | None = None) -> dict:
@@ -308,7 +582,9 @@ class TelemetryRecorder:
         window, and reset the epoch timer. Returns the event dict."""
         now = self._clock()
         wall_s = now - self._t_epoch if self._t_epoch is not None else 0.0
-        phases = self.timer.stats(self.phases)
+        stats = self.timer.stats(SPAN_NAMES)
+        phases = {k: v for k, v in stats.items() if k in self.phases}
+        children = {k: v for k, v in stats.items() if k in CHILDREN}
         for i in range(len(self.phases)):
             self._run_sums[i] += self.timer.sums[i]
             self._run_counts[i] += self.timer.counts[i]
@@ -330,10 +606,25 @@ class TelemetryRecorder:
                 for k, v in phases.items()
             },
         }
-        # Host/device/input attribution rides the epoch event whenever
-        # the phase classification is the Trainer's (custom phase sets skip
-        # it rather than misclassify).
-        if wall_s > 0 and any(p in PHASE_PLANES for p in phases):
+        # The parts of the spans, beside the partition and never in it,
+        # and what the epoch's longest wait leaves behind.
+        if children:
+            event["children"] = {
+                k: {
+                    "total_s": round(v["total_s"], 6),
+                    "count": v["count"],
+                    "max_s": round(v["max_s"], 6),
+                }
+                for k, v in children.items()
+            }
+        if self._longest_wait is not None:
+            event["longest_wait"] = {
+                k: round(v, 6) if isinstance(v, float) else v
+                for k, v in self._longest_wait.items()
+            }
+            self._longest_wait = None
+        # Host/device/input attribution rides the epoch event.
+        if wall_s > 0 and phases:
             attr = classify_epoch(phases, wall_s)
             event["attribution"] = attr
             self.last_attribution = attr

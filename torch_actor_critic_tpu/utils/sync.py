@@ -16,6 +16,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from torch_actor_critic_tpu.telemetry import recorder as spans
+
 __all__ = ["drain"]
 
 
@@ -26,22 +28,27 @@ def drain(x) -> float:
     only a few bytes cross the wire) or an already-scalar value. The
     returned float is the reduced value — usable as a checksum, but the
     point is the side effect: when this returns, the producer chain has
-    executed.
+    executed. The window's ``drain`` span, with the dispatch of the
+    reduction (``drain/reduce``) and the fetch (``drain/fetch``) as its
+    parts.
     """
-    if isinstance(x, jax.Array):
+    with spans.span(spans.DRAIN):
+        if not isinstance(x, jax.Array):
+            return float(x)
         if not x.is_fully_addressable:
             # Multi-host sharded array: a global reduce would need a
             # collective outside jit. Fetching this process's first
             # local shard drains the local device queue, which is all a
             # local wall-clock needs.
-            shard = x.addressable_shards[0].data
-            return float(jax.device_get(jnp.sum(shard, dtype=jnp.float32)))
+            x = x.addressable_shards[0].data
         # Reduce in f32: summing in x's own dtype would overflow bf16
         # (max ~3.4e38 but 8-bit mantissa loses integer exactness past
         # 256) or wrap small ints, making the checksum claim false.
+        with spans.span(spans.DRAIN_REDUCE):
+            total = jnp.sum(x, dtype=jnp.float32)
         # The fetch is an EXPLICIT jax.device_get: the drain is the
         # hot path's one intentional device->host transfer, so it must
         # stay legal under the --sanitize transfer guard
-        # (docs/ANALYSIS.md "Runtime sanitizers").
-        return float(jax.device_get(jnp.sum(x, dtype=jnp.float32)))
-    return float(x)
+        # (docs/ANALYSIS.md "Runtime sanitizers"). It is the wait.
+        with spans.span(spans.DRAIN_FETCH, os_wait=True):
+            return float(jax.device_get(total))
