@@ -259,6 +259,7 @@ def _trunk_burst(devices):
     # ISSUE 41: the selection's kernels under the burst's ``vmap`` (128
     # experts: the cell's; with 16 the rounds are XLA's), and no sort or mask
     _selection_is_a_pass(text, cfg.batch_size * history, cfg.trunk_experts_per_tok, 128)
+    _plan_sorts_the_held_candidates(text, cfg.batch_size * history, cfg.trunk_experts_per_tok, 4)
 
 
 def _selection_is_a_pass(hlo_text, tokens, top_k, experts):
@@ -272,6 +273,24 @@ def _selection_is_a_pass(hlo_text, tokens, top_k, experts):
     kinds = [_kernel_kind(name) for name in _kernels(hlo_text)]
     assert kinds.count("router-top-k") >= 2 and kinds.count("router-top-k-bwd") >= 1, kinds
     assert not re.search(rf"\[(?:1,)?{tokens},{top_k},{experts}\]", hlo_text)
+
+
+def _plan_sorts_the_held_candidates(hlo_text, tokens, top_k, n_held):
+    """ISSUE 43: every ``sort`` under the expert layer's scope is the plan's
+    (``moe.plan_assignments``), of one operand (the packed word: no index
+    beside the key, no comparator over two) and of no more elements than a
+    call's candidates, tokens x the fewer of ``top_k`` and the held experts;
+    the parent sorted tokens x ``top_k`` keys with an iota."""
+    sorts = [
+        line for line in hlo_text.splitlines()
+        if scopes.TRUNK_MOE_EXPERTS in line and " sort(" in line
+    ]
+    assert sorts and all(scopes.TRUNK_MOE_PLAN in line for line in sorts), sorts
+    for line in sorts:
+        result, operands = re.search(r"= (.*?) sort\(([^)]*)\)", line).groups()
+        assert operands.count("%") == 1 and not result.startswith("("), line
+        sorted_shape = re.match(r"\w+\[[\d,]*\]", result).group(0)
+        assert _elements(sorted_shape) <= tokens * min(top_k, n_held), line
 
 
 def _compile_hybrid_trunk_burst(devices):
@@ -337,6 +356,10 @@ def _hybrid_trunk_burst(devices):
         assert moved and max(moved) <= moe.PIECE_ROWS, (op, moved)
     _selection_is_a_pass(
         text, cfg.batch_size * cfg.history_len, cfg.trunk_experts_per_tok, cfg.trunk_experts
+    )
+    lo, hi = cfg.trunk_experts_held
+    _plan_sorts_the_held_candidates(
+        text, cfg.batch_size * cfg.history_len, cfg.trunk_experts_per_tok, hi - lo
     )
 
 

@@ -45,12 +45,45 @@ the kernels; 8,192 tokens of 2,048 to 128, 8 chosen (SDAR's): 0.496 / 0.865,
 0.402 / 0.566, 0.400 / 0.702.
 
 No token is ever dropped and there is no capacity factor: the assignments
-that land on held experts are sorted by expert (a stable sort, so tokens keep
-their order inside an expert), their rows are gathered into one ragged batch,
-and the three expert products are grouped matrix products over it
-(``jax.lax.ragged_dot``: XLA:TPU lowers it to a Mosaic grouped-matmul kernel
-that visits only the row tiles some group owns, forward and both transposes;
-see PERF.md for why not a kernel of our own).
+that land on held experts are put in order by expert, inside an expert by
+flat index ``n * top_k + k`` (so tokens keep their order inside an expert),
+their rows are gathered into one ragged batch, and the three expert products
+are grouped matrix products over it (``jax.lax.ragged_dot``: XLA:TPU lowers it
+to a Mosaic grouped-matmul kernel that visits only the row tiles some group
+owns, forward and both transposes; see PERF.md for why not a kernel of our
+own).
+
+The order is the plan's (:func:`plan_assignments`), and it orders what this
+chip can hold, not every token's every choice. It was a stable ``argsort`` of
+one key a choice (the held expert, or one past them for "held elsewhere"):
+on XLA:TPU a sort of two operands, key and iota, under a comparator over
+both, 90,112 elements in the hybrid trunk cell of which a call holds 1,408,
+fifteen calls a step; nothing reads the order past the held rows. Now one
+``int32`` word a candidate, expert in the high part and flat index in the
+low, so that one operand sorts under the default comparator, equal experts
+keep their order by construction and the order is the sorted word modulo
+``N * top_k``; the sort is asked for unstable, the words being distinct (asked
+for stable, XLA:TPU puts the iota back: sandbox compile, PR 43). A token's
+choices are distinct, so where fewer experts are held than a token chooses
+the candidates are the pairs (held expert, token), ``N * n_held`` of them
+(32,768 in the hybrid cell), the ``k`` that chose the expert found by the
+compare that counts the experts' rows and carried in the word; where as many
+or more are held (SDAR's 16 of 8 chosen) the candidates are the ``N * top_k``
+assignments. Both are one code that reads two static shapes. There a token's
+held choices have to be distinct, as :func:`route`'s are: of two choices of
+one held expert in one token only the later would get a row.
+
+Every form was tried inside the whole burst on the chip, one process, five
+windows of ten steps each, ms a step by the windows' median (v5e, my chip
+runs, PR 43): the hybrid trunk cell with the stable ``argsort`` 216.97; one
+word a key over every choice 211.79; the candidates counted the shorter way
+as well, which is what stands here, 207.49; no sort at all (a row's
+assignment found by comparing a running count of the candidates with the
+row's number, 2,048 rows at a time, only where rows are held) 208.26, and that
+form runs its blocks in a loop that the burst's map over its device axis has
+to take one element at a time. In the trace a call's sort went from 0.670 to
+0.071 ms, 10.06 to 1.06 ms a step. The SDAR cell, whose candidates are every
+choice either way: 188.93 with the ``argsort``, 186.47 with one word a key.
 
 How many assignments land here is data. The worst case is all ``N * top_k``
 of them; the expected number is ``N * top_k * (hi - lo) / num_experts``. The
@@ -117,7 +150,7 @@ from torch_actor_critic_tpu.telemetry import scopes
 class Plan(t.NamedTuple):
     """Integer bookkeeping of one routing decision (carries no gradient)."""
 
-    order: jax.Array  # (N*K,) flat assignment index n*K+k, sorted by held expert
+    order: jax.Array  # (N*min(K, E_held),) flat assignment index n*K+k, sorted by held expert
     held: jax.Array   # (N, K) bool: the assignment's expert is held here
     starts: jax.Array  # (E_held,) first sorted row of each held expert
     sizes: jax.Array  # (E_held,) rows of each held expert
@@ -380,21 +413,53 @@ _top_scores.defvjp(_top_scores_fwd, _top_scores_bwd)
 
 
 def plan_assignments(top_e: jax.Array, held: t.Tuple[int, int]) -> Plan:
+    """The :class:`Plan` of the choices ``top_e`` ``(N, K)`` for the experts
+    ``held = (lo, hi)``: ``order[:n_rows]`` is the held assignments by expert
+    and inside an expert by flat index ``n * K + k`` (what a stable sort of
+    every assignment by its held expert gives, to the element); past
+    ``n_rows`` it holds indices in bounds that every reader masks. **A token's
+    choices have to be distinct wherever they are held** (:func:`route`
+    strikes out what it chose; choices held elsewhere may repeat), so a token
+    holds at most ``min(K, hi - lo)`` rows here and ``order`` is ``N`` times
+    that long; where fewer experts are held than a token chooses, a second
+    choice of one held expert by one token gets no row.
+
+    One sort of one ``int32`` word a candidate, the pairs (held expert,
+    token) where fewer experts are held than a token chooses, otherwise the
+    assignments themselves (module docstring)."""
     lo, hi = held
     n_held = hi - lo
     n, k = top_e.shape
-    flat = top_e.reshape(-1).astype(jnp.int32) - lo
-    is_held = (flat >= 0) & (flat < n_held)
-    key = jnp.where(is_held, flat, n_held)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    sizes = jnp.sum(
-        key[:, None] == jnp.arange(n_held, dtype=jnp.int32)[None, :], axis=0,
-        dtype=jnp.int32,
-    )
-    return Plan(
-        order=order, held=is_held.reshape(n, k),
-        starts=jnp.cumsum(sizes) - sizes, sizes=sizes, n_rows=jnp.sum(sizes),
-    )
+    total = n * k
+    if (n_held + 1) * total > jnp.iinfo(jnp.int32).max:
+        raise ValueError(
+            f"{n} tokens x {k} choices x {n_held} held experts: the plan's "
+            "packed key (expert, flat index) does not fit an int32"
+        )
+    with jax.named_scope(scopes.TRUNK_MOE_PLAN):
+        expert = top_e.astype(jnp.int32) - lo
+        is_held = (expert >= 0) & (expert < n_held)
+        experts = jnp.arange(n_held, dtype=jnp.int32)
+        if n_held < k:
+            chose = expert.T[None, :, :] == experts[:, None, None]  # (E_held, K, N)
+            chosen = jnp.any(chose, axis=1)
+            rounds = jnp.arange(k, dtype=jnp.int32)[None, :, None]
+            flat = jnp.arange(n, dtype=jnp.int32) * k + jnp.max(
+                jnp.where(chose, rounds, 0), axis=1
+            )
+            word = jnp.where(chosen, experts[:, None], n_held) * total + flat
+            sizes = jnp.sum(chosen, axis=1, dtype=jnp.int32)
+        else:
+            key = jnp.where(is_held, expert, n_held).reshape(-1)
+            word = key * total + jnp.arange(total, dtype=jnp.int32)
+            sizes = jnp.sum(key[:, None] == experts[None, :], axis=0, dtype=jnp.int32)
+        # Distinct among the live rows, and ties past ``n_rows`` are equal
+        # values: asked for a stable sort, XLA:TPU sorts an iota beside them.
+        order = jax.lax.rem(jax.lax.sort(word.reshape(-1), is_stable=False), total)
+        return Plan(
+            order=order, held=is_held, starts=jnp.cumsum(sizes) - sizes, sizes=sizes,
+            n_rows=jnp.sum(sizes),
+        )
 
 
 def default_chunk_rows(n_tokens: int, top_k: int, n_held: int, n_experts: int) -> int:
@@ -554,10 +619,10 @@ def _hidden(ch: _Chunk, xs, w_in, form: str, bf16_dots: bool):
     return pre, hidden
 
 
-def _over_chunks(plan: Plan, rows: int, total: int, body, first):
+def _over_chunks(plan: Plan, rows: int, body, first):
     """``body(c, carry)`` for every chunk past the first that holds a held
     row; ``first`` is the carry the first chunk left."""
-    n_chunks = -(-total // rows)
+    n_chunks = -(-plan.order.shape[0] // rows)
     if n_chunks == 1:
         return first
 
@@ -592,7 +657,6 @@ def _if_any(run):
 
 
 def _forward(rows: int, bf16_dots: bool, form: str, u, w_in, w_down, top_w, plan: Plan):
-    total = top_w.size
     padded = _padded(plan, rows)
     # A token's row is rounded before it is gathered: the same values, half
     # the rows of a chunk and half the bytes.
@@ -605,11 +669,10 @@ def _forward(rows: int, bf16_dots: bool, form: str, u, w_in, w_down, top_w, plan
         y = _gmm(hidden, down, ch.sizes)
         return _combined(ch, out, lambda rows_of: rows_of(y) * _weights(top_w, rows_of(ch.flat)))
 
-    return _over_chunks(plan, rows, total, body, body(0, jnp.zeros_like(u)))
+    return _over_chunks(plan, rows, body, body(0, jnp.zeros_like(u)))
 
 
 def _backward(rows: int, bf16_dots: bool, form: str, u, w_in, w_down, top_w, plan: Plan, g):
-    total = top_w.size
     padded = _padded(plan, rows)
     x = _mxu(u, bf16_dots)
 
@@ -669,7 +732,7 @@ def _backward(rows: int, bf16_dots: bool, form: str, u, w_in, w_down, top_w, pla
         return tuple(k + m for k, m in zip(kernels, more)), du, dw
 
     first = chunk(0, jnp.zeros_like(u), jnp.zeros(top_w.size, top_w.dtype))
-    kernels, du, dw = _over_chunks(plan, rows, total, body, first)
+    kernels, du, dw = _over_chunks(plan, rows, body, first)
     *d_in, d_down = (k.astype(w.dtype) for k, w in zip(kernels, (*w_in, w_down)))
     return du, tuple(d_in), d_down, dw.reshape(top_w.shape)
 
@@ -715,7 +778,10 @@ def expert_ffn(
     the held experts' kernels; ``form`` (``FORMS``) says what an expert
     computes with them, and a form without a gate (``"relu2"``) takes
     ``w_gate=None``. ``top_e``/``top_w``: :func:`route`'s choices over all
-    experts. Returns the ``(N, H)`` sum over each token's chosen experts that
+    experts, **distinct inside a token wherever they are held** (choices
+    held elsewhere may repeat): the plan counts one row for a token and a
+    held expert, so a second choice of that expert by the token would be
+    left out (:func:`plan_assignments`). Returns the ``(N, H)`` sum over each token's chosen experts that
     are held, and the :class:`Plan` (its counters). ``bf16_dots`` rounds the
     float32 operands of every grouped product to bfloat16 (float32
     accumulation and output), forward and backward."""
@@ -723,10 +789,10 @@ def expert_ffn(
     n_held = held[1] - held[0]
     if chunk_rows is None:
         chunk_rows = default_chunk_rows(n, k, n_held, num_experts or n_held)
-    chunk_rows = min(chunk_rows, n * k)
+    plan = plan_assignments(top_e, held)
+    chunk_rows = min(chunk_rows, plan.order.shape[0])
     if chunk_rows > PIECE_ROWS:  # whole pieces
         chunk_rows = -(-chunk_rows // PIECE_ROWS) * PIECE_ROWS
-    plan = plan_assignments(top_e, held)
     top_w = jnp.where(plan.held, top_w, 0.0)  # an absent term has no gradient here
     w_in = (w_up,) if w_gate is None else (w_gate, w_up)
     experts = _experts_for(chunk_rows, bool(bf16_dots), form)

@@ -46,6 +46,7 @@ TRUNK_ATTENTION = "tac/trunk/attention"
 TRUNK_MOE_ROUTE = "tac/trunk/moe/route"
 TRUNK_MOE_EXPERTS = "tac/trunk/moe/experts"
 TRUNK_MOE_PRODUCTS = "tac/trunk/moe/experts/products"  # the grouped products alone
+TRUNK_MOE_PLAN = "tac/trunk/moe/experts/plan"  # ops/moe.py::plan_assignments: the held rows' order
 TRUNK_MOE_LATENT = "tac/trunk/moe/latent"  # projections into and out of the experts' width
 TRUNK_MOE_SHARED = "tac/trunk/moe/shared"  # the expert every token passes
 # A state-space mixer: its two projections, the short convolution, the
@@ -57,9 +58,9 @@ TRUNK_SSM_GATE_NORM = "tac/trunk/ssm/gate_norm"
 SCOPES = (
     PUSH, SAMPLE, DECODE, CRITIC, ACTOR, ALPHA, OPTIMIZER, POLYAK,
     ALLREDUCE, COLLECT_ACT, COLLECT_ENV, TRUNK_EMBED, TRUNK_ATTENTION,
-    TRUNK_MOE_ROUTE, TRUNK_MOE_EXPERTS, TRUNK_MOE_PRODUCTS, TRUNK_MOE_LATENT,
-    TRUNK_MOE_SHARED, TRUNK_SSM_PROJ, TRUNK_SSM_CONV, TRUNK_SSM_SCAN,
-    TRUNK_SSM_GATE_NORM,
+    TRUNK_MOE_ROUTE, TRUNK_MOE_EXPERTS, TRUNK_MOE_PRODUCTS, TRUNK_MOE_PLAN,
+    TRUNK_MOE_LATENT, TRUNK_MOE_SHARED, TRUNK_SSM_PROJ, TRUNK_SSM_CONV,
+    TRUNK_SSM_SCAN, TRUNK_SSM_GATE_NORM,
 )
 HOST_PREFIX = "tac/host/"  # the recorder's phase annotations
 
