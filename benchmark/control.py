@@ -4,9 +4,12 @@ For each seed: the program as configured against the reference (``sound``),
 and the control, which is the reference put in the program's place one
 precision lower (the configuration file's ``control.reference_mode``) against
 the reference, on the same recorded updates.  ``--variants sound,program_low``
-adds the program's own lower-precision path (``control.program``).  Run on the chip at the cell's own size:
+adds the program's own lower-precision path (``control.program``);
+``--sound-only`` leaves the control's run out, so that topping a table of
+sound seeds up costs one reference run a seed and not two.  Run on the chip at
+the cell's own size:
 
-    python3 benchmark/control.py --workload <cell> --seeds 1,2,3 [--windows 4]
+    python3 benchmark/control.py --workload <cell> --seeds 1,2,3 [--windows 4] [--sound-only]
 
 Prints one JSON line for each seed and variant with every number compared.
 The benchmark's own runs never call this.
@@ -37,13 +40,35 @@ def readings(cell, config, seed, overrides, windows, bench_dir=None, low=None):
     return out
 
 
-def main() -> int:
+def seed_lines(
+    cell, config, seeds, variants=("sound",), windows=4, sound_only=False, overrides=None
+):
+    """One dict for each seed and variant: the sound readings and, unless
+    ``sound_only``, the control's under ``<control's mode>:<name>``.  A run
+    that crashes gives its error: a control that crashes has failed."""
+    for seed in seeds:
+        for variant in variants:
+            over = dict(overrides or {})
+            if variant == "program_low":
+                over.update(config["control"].get("program") or {})
+            low = None
+            if variant == "sound" and not sound_only:
+                low = config["control"]["reference_mode"]
+            try:
+                values = readings(cell, config, seed, over, windows, low=low)
+            except Exception as e:  # noqa: BLE001
+                values = {"error": repr(e)[:300]}
+            yield {"seed": seed, "variant": variant, **values}
+
+
+def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seeds", required=True)
     parser.add_argument("--windows", type=int, default=4)
     parser.add_argument("--variants", default="sound")
-    args = parser.parse_args()
+    parser.add_argument("--sound-only", action="store_true")
+    args = parser.parse_args(argv)
     from benchmark.harness import registry
 
     _, cell, config = registry.resolve(args.workload)
@@ -53,17 +78,11 @@ def main() -> int:
 
     enable_persistent_cache()
     print(json.dumps({"device": jax.devices()[0].device_kind, "workload": cell["name"]}))
-    for seed in (int(s) for s in args.seeds.split(",")):
-        for variant in args.variants.split(","):
-            overrides = config["control"].get("program") if variant == "program_low" else None
-            try:
-                values = readings(
-                    cell, config, seed, overrides, args.windows,
-                    low=config["control"]["reference_mode"] if variant == "sound" else None,
-                )
-            except Exception as e:  # noqa: BLE001 — a control that crashes has failed
-                values = {"error": repr(e)[:300]}
-            print(json.dumps({"seed": seed, "variant": variant, **values}), flush=True)
+    seeds = [int(s) for s in args.seeds.split(",")]
+    for line in seed_lines(
+        cell, config, seeds, args.variants.split(","), args.windows, args.sound_only
+    ):
+        print(json.dumps(line), flush=True)
     return 0
 
 

@@ -1,8 +1,9 @@
 """Replay-fed learner on the ``nemotron_h`` history trunk: ``trunkburst``'s
 window, comparison and fifth number (the share of the first update's expert
 choices on which program and reference disagree) with this family's own
-spec, seeded weights (``harness/hybrid_weights.py``) and reference
-(``harness/reference_nemotron_trunk.py``).
+spec, seeded weights (``harness/hybrid_weights.py``) and reference: the
+shared SAC step of ``harness/reference_trunk.py`` over this family's forward
+(``harness/reference_nemotron_trunk.py::features``).
 
 The program takes the stack from ``SACConfig.trunk_pattern`` and what this
 chip holds of each layer kind from the ``trunk_*`` counts; nothing here names
@@ -14,7 +15,9 @@ from __future__ import annotations
 import jax
 
 from benchmark.drivers import _common, trunkburst
-from benchmark.harness import data, flops_hybrid, hybrid_weights, reference_nemotron_trunk
+from benchmark.harness import (
+    data, flops_hybrid, hybrid_weights, reference_nemotron_trunk, reference_trunk,
+)
 
 TRUNK_KEYS = (
     "hidden", "pattern", "q_heads", "kv_heads", "head_dim", "experts", "experts_per_tok",
@@ -60,9 +63,9 @@ class Driver(trunkburst.Driver):
             sac = {k: self.sac_fields[k] for k in _common.SAC_CONSTANTS}
 
             def account(actor, critic, rows, eps_q, eps_pi):
-                state, lq, lp, chosen, terms = reference_nemotron_trunk.follow(
-                    reference_nemotron_trunk.init_state(actor, critic), rows, eps_q, eps_pi,
-                    self.model, sac, mode,
+                state, lq, lp, chosen, terms = reference_trunk.follow(
+                    reference_trunk.init_state(actor, critic), rows, eps_q, eps_pi,
+                    self.model, sac, mode, reference_nemotron_trunk.features,
                 )
                 return {
                     "loss_q": lq, "loss_pi": lp, "actor": state["actor"],

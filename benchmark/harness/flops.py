@@ -1,10 +1,11 @@
 """Operations and bytes one SAC gradient step needs, from the sizes in a
 configuration file.
 
-The weighting is ``bench.py::sac_flops_per_step`` / ``visual_flops_per_step``
-(copied; the original hard-codes the conv widths, this reads them): dense
-multiply-accumulates times two, a backward pass at twice its forward, the
-frozen critic of the policy loss at forward plus an input-only backward.
+The weighting is that of the repo's first benchmark script, which left the
+tree at PR 31 (copied from it; it hard-coded the conv widths, this reads
+them): dense multiply-accumulates times two, a backward pass at twice its
+forward, the frozen critic of the policy loss at forward plus an input-only
+backward.
 Elementwise work, Adam and polyak are left out, and recomputed operations
 do not count.
 """

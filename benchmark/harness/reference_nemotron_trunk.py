@@ -1,14 +1,15 @@
 """Plain reference for the ``nemotron_h`` history trunk: the hybrid stack's
-forward from its published equations, the SAC losses on one shared trunk,
-their gradients, Adam and polyak, in float32 ``jax.numpy``.
+forward from its published equations, in float32 ``jax.numpy``.  The SAC step
+on it (losses on one shared trunk, their gradients, Adam and polyak) is
+``reference_trunk.update``, which takes this file's ``features``.
 
 It imports nothing of the program.  Parameters are read by the names of the
 program's checkpoint layout, the random draws of a step are inputs, and every
 matrix product of the model goes through ``reference._mm`` (``highest``,
 ``bf16_operands`` or the control's ``fp8_operands``, in the backward pass
-too), as in ``reference_trunk.py``, whose norm, heads and SAC step this file
-shares.  The router's product alone is always at ``highest``, as in the
-program, and the reference makes its own choices.
+too), as in ``reference_trunk.py``, whose norm this file shares.  The
+router's product alone is always at ``highest``, as in the program, and the
+reference makes its own choices.
 
 The stack (``NVIDIA-Nemotron-3-Super-120B-A12B``'s ``config.json``,
 ``model_type`` ``nemotron_h``): every layer is ``h <- h + Mixer(RMSNorm(h))``,
@@ -63,8 +64,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from benchmark.harness.reference import _LOW, _adam, _mm, _rounder, init_state  # noqa: F401
-from benchmark.harness.reference_trunk import _rms, policy_head, q_heads
+from benchmark.harness.reference import _LOW, _mm, _rounder
+from benchmark.harness.reference_trunk import _rms
 
 
 def _group_rms(x, weight, groups: int, eps: float):
@@ -206,67 +207,3 @@ def trunk(p, obs, model: dict, mode: str):
 def features(critic_p, obs, model: dict, mode: str):
     out, chosen = trunk(critic_p["params"]["trunk"], obs, model, mode)
     return out[:, -1], chosen
-
-
-def update(state, batch, eps_q, eps_pi, model: dict, sac: dict, mode: str = "highest"):
-    """One gradient step on the shared trunk: ``reference_trunk.update``'s
-    step (two trunk passes, the critic loss trains the online trunk, the
-    policy loss reads its features as constants against the updated Q heads)
-    over this family's ``features``."""
-    alpha, gamma = sac["alpha"], sac["gamma"]
-
-    def q_loss(critic_p, b, e):
-        h_next, _ = features(state["target"], b["next_states"], model, mode)
-        a2, logp2 = policy_head(state["actor"], h_next, e, model, mode)
-        q_t = jnp.min(q_heads(state["target"], h_next, a2, mode), axis=0)
-        backup = jax.lax.stop_gradient(
-            sac["reward_scale"] * b["rewards"] + gamma * (1.0 - b["done"]) * (q_t - alpha * logp2)
-        )
-        h, chosen = features(critic_p, b["states"], model, mode)
-        q = q_heads(critic_p, h, b["actions"], mode)
-        loss = jnp.sum(jnp.mean((q - backup[None, :]) ** 2, axis=-1))
-        return loss, (jax.lax.stop_gradient(h), chosen)
-
-    def mean_q_loss(p):
-        loss, aux = jax.vmap(lambda b, e: q_loss(p, b, e))(batch, eps_q)
-        return jnp.mean(loss), aux
-
-    (loss_q, (h, chosen)), g_q = jax.value_and_grad(mean_q_loss, has_aux=True)(state["critic"])
-    step, q_mu, q_nu, count = _adam(g_q, state["q_mu"], state["q_nu"], state["count"], sac["lr"])
-    critic_p = jax.tree_util.tree_map(jnp.add, state["critic"], step)
-
-    def pi_loss(actor_p, h_d, e):
-        pi, logp = policy_head(actor_p, h_d, e, model, mode)
-        q_pi = jnp.min(q_heads(critic_p, h_d, pi, mode), axis=0)
-        terms = jnp.abs(jnp.mean(alpha * logp)) + jnp.abs(jnp.mean(q_pi))
-        return jnp.mean(alpha * logp - q_pi), terms
-
-    def mean_pi_loss(p):
-        loss, terms = jax.vmap(lambda h_d, e: pi_loss(p, h_d, e))(h, eps_pi)
-        return jnp.mean(loss), jnp.mean(terms)
-
-    (loss_pi, pi_terms), g_pi = jax.value_and_grad(mean_pi_loss, has_aux=True)(state["actor"])
-    step, pi_mu, pi_nu, _ = _adam(g_pi, state["pi_mu"], state["pi_nu"], state["count"], sac["lr"])
-    actor_p = jax.tree_util.tree_map(jnp.add, state["actor"], step)
-
-    rho = sac["polyak"]
-    target = jax.tree_util.tree_map(
-        lambda tgt, src: rho * tgt + (1.0 - rho) * src, state["target"], critic_p
-    )
-    new = {
-        "actor": actor_p, "critic": critic_p, "target": target,
-        "pi_mu": pi_mu, "pi_nu": pi_nu, "q_mu": q_mu, "q_nu": q_nu, "count": count,
-    }
-    return new, loss_q, loss_pi, chosen, pi_terms
-
-
-def follow(state, batches, eps_q, eps_pi, model: dict, sac: dict, mode: str = "highest"):
-    """As ``reference_trunk.follow``: the final state, the mean losses, the
-    first update's choices and the mean size of the policy loss's two terms."""
-
-    def body(st, xs):
-        st, lq, lp, chosen, terms = update(st, *xs, model, sac, mode)
-        return st, (lq, lp, chosen, terms)
-
-    state, (lq, lp, chosen, terms) = jax.lax.scan(body, state, (batches, eps_q, eps_pi))
-    return state, jnp.mean(lq), jnp.mean(lp), chosen[0], jnp.mean(terms)

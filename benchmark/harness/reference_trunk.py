@@ -168,12 +168,20 @@ def policy_head(actor_p, h, eps, model: dict, mode: str):
     return _squash(mu, log_std, eps, model["act_limit"])
 
 
-def update(state, batch, eps_q, eps_pi, model: dict, sac: dict, mode: str = "highest"):
+def update(
+    state, batch, eps_q, eps_pi, model: dict, sac: dict, mode: str = "highest",
+    features=features,
+):
     """One gradient step on the shared trunk.  ``batch`` leaves and the noises
     carry the stream axis ``D`` first (the data-parallel replicas whose
     gradients are averaged).  Returns the new state, ``loss_q``, ``loss_pi``,
     the online pass's choices ``(D, layers, tokens, top_k)`` and the size of
     the policy loss's two terms.
+
+    ``features(critic_p, obs, model, mode)`` is the trunk's forward, from the
+    critic's parameters and a batch of histories to the last step's features
+    ``(batch, hidden)`` and the routed choices: this family's by default,
+    another family's reference hands in its own and shares the step.
 
     Two trunk passes a step: the target trunk on ``next_states`` feeds the
     target Q heads and the policy head that draws ``a'``; the online trunk on
@@ -233,13 +241,17 @@ def update(state, batch, eps_q, eps_pi, model: dict, sac: dict, mode: str = "hig
     return new, loss_q, loss_pi, chosen, pi_terms
 
 
-def follow(state, batches, eps_q, eps_pi, model: dict, sac: dict, mode: str = "highest"):
-    """Follow ``steps`` updates (leaves ``(steps, D, batch, ...)``).  Returns
-    the final state, the mean losses, the first update's choices and the mean
-    size of the policy loss's two terms (``|alpha logp| + |min Q|``)."""
+def follow(
+    state, batches, eps_q, eps_pi, model: dict, sac: dict, mode: str = "highest",
+    features=features,
+):
+    """Follow ``steps`` updates (leaves ``(steps, D, batch, ...)``) through
+    the trunk ``features`` computes.  Returns the final state, the mean
+    losses, the first update's choices and the mean size of the policy loss's
+    two terms (``|alpha logp| + |min Q|``)."""
 
     def body(st, xs):
-        st, lq, lp, chosen, terms = update(st, *xs, model, sac, mode)
+        st, lq, lp, chosen, terms = update(st, *xs, model, sac, mode, features)
         return st, (lq, lp, chosen, terms)
 
     state, (lq, lp, chosen, terms) = jax.lax.scan(body, state, (batches, eps_q, eps_pi))

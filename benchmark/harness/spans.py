@@ -1,7 +1,10 @@
 """Host spans on the host clock, mirrored into the profiler's trace.
 
-The benchmark records spans from its own files, around the calls into each
-layer; spans inside the program are a later (tracing) change.  ``Spans`` keeps
+The harness's own spans, from outside the program: set-up's phases, each
+measured window (``bench/window``: the traced window's edges) and the calls a
+driver makes in it, which ``main.window_account`` and ``trace.breakdown``
+read.  No per-layer metric reads them: the host loop is read by the program's
+own ``tac/host/`` spans (``window_spans.py``).  ``Spans`` keeps
 ``(name, start, duration)`` in memory on ``time.perf_counter`` and, while a
 trace is being taken, opens a ``jax.profiler.TraceAnnotation`` of the same
 name, so that the reduction finds the span on the device trace's clock.
@@ -52,11 +55,3 @@ class Spans:
             out[name] = out.get(name, 0.0) + dur
         return out
 
-
-def ms_per_window(ctx, name: str) -> float | None:
-    """Mean milliseconds a window of the run spent under span ``name``; nothing
-    where the run recorded no such span (what a per-layer reader hands back)."""
-    totals = ctx.spans.totals()
-    if name not in totals or not ctx.n_windows:
-        return None
-    return 1e3 * totals[name] / ctx.n_windows

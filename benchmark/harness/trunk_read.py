@@ -10,6 +10,7 @@ from __future__ import annotations
 from benchmark.harness import peaks, scopes, trace as trace_mod
 
 GROUPED_PRODUCT = "ragged-dot-none"  # XLA:TPU's name for a lowered ragged_dot
+EXPERT_PRODUCTS = "tac/trunk/moe/experts/products"  # where ``ops/moe.py`` calls them
 FLASH = "attention"  # the Pallas kernels, named for the function that calls them
 
 
@@ -31,6 +32,21 @@ def kernel_seconds(ctx, kind: str) -> float | None:
     if ctx.trace is None:
         return None
     return trace_mod.kind_seconds(ctx.trace, kind) or None
+
+
+def grouped_product_seconds(ctx) -> float | None:
+    """Device seconds of the routed experts' grouped products over the traced
+    window, whatever implements them: the operations under the scope
+    ``EXPERT_PRODUCTS`` and XLA:TPU's own grouped-product kernels, which it
+    puts in the place of a ``ragged_dot`` under a name of its own and without
+    the program's scope (my chip run, PR 40: no instruction of the compiled
+    burst carries the scope; a kernel of our own would, and is then read
+    here with no reader edited)."""
+    if ctx.trace is None:
+        return None
+    scoped_us = scope_us_per_step(ctx, EXPERT_PRODUCTS) or 0.0
+    spent = 1e-6 * scoped_us * steps(ctx) + (kernel_seconds(ctx, GROUPED_PRODUCT) or 0.0)
+    return spent or None
 
 
 def counters(ctx) -> dict | None:

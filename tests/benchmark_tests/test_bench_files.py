@@ -3,9 +3,9 @@ fits its schema, and can be added as new files plus entries."""
 
 import json
 import os
-import re
 import shutil
 
+import bench_cut
 import pytest
 from bench_cut import ROOT
 
@@ -13,14 +13,6 @@ from benchmark.harness import registry
 
 BENCH = registry.load_benchmark()
 WHOLE = registry.load_benchmark(parked=True)  # with the parked cells' entries
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-WIDTH_ENDINGS = (  # what ``reduced`` may never name; a vocabulary's size is no width
-    "hidden_size", "intermediate_size", "latent_size", "state_size", "hidden_sizes",
-    "_dim", "_rank", "_width", "_per_tok", "filters", "kernel_sizes", "strides",
-    "dense_size", "features",
-)
 
 
 def test_top_level_keys_and_limits():
@@ -40,58 +32,22 @@ def test_top_level_keys_and_limits():
 
 @pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
 def test_configuration_file(entry):
-    assert set(entry) == {"name", "source", "file", "reduced", "why"}
-    assert NAME.match(entry["name"]) and len(entry["why"]) <= 200
-    assert entry["file"].startswith(BENCH["paths"][0] + "/")
-    cfg = registry.load_config(entry["name"])
-    assert cfg["reduced"] == entry["reduced"]
-    # a width is told by its key's ending (``num_hidden_layers`` is a depth, the
-    # one cut every catalog model needs), a head size by the word
-    assert not any(key.endswith(WIDTH_ENDINGS) or "head" in key for key in entry["reduced"])
-    assert any(w["config"] == entry["name"] for w in BENCH["workloads"])
-    assert cfg["reference_mode"] in ("highest", "bf16_operands")
-    assert cfg["control"]["reference_mode"] == "fp8_operands"
+    """Its entry and file fit; ``reduced`` names no width (a key's ending tells
+    one: ``bench_cut.WIDTH_ENDINGS``) and, where it names a count of heads,
+    groups or experts held, the file says of what deployment and how."""
+    bench_cut.check_configuration(BENCH, entry)
 
 
 @pytest.mark.parametrize("entry", WHOLE["workloads"], ids=lambda w: w["name"])
 def test_workload_file_and_driver(entry):
-    assert set(entry) == {"name", "config", "traffic", "chips", "why"}
-    assert NAME.match(entry["name"]) and NAME.match(entry["traffic"])
-    assert 1 <= len(entry["why"]) <= 200 and "\n" not in entry["why"]
-    _, cell, config = registry.resolve(entry["name"], parked=True)
-    assert cell["why"] == entry["why"]
-    assert set(cell["limits"]) == {"loss_q", "loss_pi", "adam_nu", "param_change"}
-    driver = registry.load_driver(cell["driver"])
-    for method in ("setup", "window", "per_window", "free", "check", "control", "at_rest_bytes"):
-        assert callable(getattr(driver, method)), method
-    reported = [m["name"] for m in registry.metrics_for(WHOLE, "end_to_end", entry["name"])]
-    assert "setup_s" in reported and len(reported) >= 2
-    assert registry.metrics_for(WHOLE, "per_layer", entry["name"])
+    bench_cut.check_workload(WHOLE, entry)
 
 
 @pytest.mark.parametrize(
     "metric", WHOLE["end_to_end"] + WHOLE["per_layer"], ids=lambda m: m["name"]
 )
 def test_metric_entry(metric):
-    end_to_end = metric in WHOLE["end_to_end"]
-    keys = {"name", "unit", "better", "source"} | (
-        {"bound"} if end_to_end else {"layer", "moves"}
-    )
-    assert set(metric) - {"workloads"} == keys
-    assert NAME.match(metric["name"]) and UNIT.match(metric["unit"])
-    assert metric["better"] in ("lower", "higher") and metric["source"] in SOURCES
-    cells = {w["name"] for w in WHOLE["workloads"]}
-    assert set(metric.get("workloads", cells)) <= cells
-    if end_to_end:
-        assert metric["source"] in ("host_clock", "device_trace")
-        assert 0.01 <= metric["bound"] <= 0.1
-    else:
-        assert callable(registry.load_layer_metric(metric["name"]))
-        moved = next(m for m in WHOLE["end_to_end"] if m["name"] == metric["moves"])
-        # every cell that reads this metric reports the metric it moves
-        assert set(metric.get("workloads", cells)) <= set(moved.get("workloads", cells))
-        if metric["name"].endswith("_roofline") or "mfu" in metric["name"]:
-            assert metric["unit"] == "%"
+    bench_cut.check_metric(WHOLE, metric)
 
 
 def test_names_are_unique():

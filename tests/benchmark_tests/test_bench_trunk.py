@@ -5,12 +5,13 @@ skips an expert)."""
 
 import json
 import os
+import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from bench_cut import ROOT, cut
+from bench_cut import ROOT, check_configuration, cut
 
 from benchmark import control
 from benchmark.harness import flops_trunk, registry, spans
@@ -26,25 +27,17 @@ PUBLISHED = {
     "num_hidden_layers": 48, "num_key_value_heads": 4, "rms_norm_eps": 1e-06,
     "rope_theta": 1000000, "tie_word_embeddings": False, "vocab_size": 151936,
 }
-WIDTH_ENDINGS = (  # a vocabulary's size is no width
-    "hidden_size", "intermediate_size", "latent_size", "state_size", "_dim", "_rank",
-    "_width", "_per_tok",
-)
 
 
 def test_configuration_keeps_every_published_width():
-    """What ``test_configuration_file`` asserts for a configuration, and the
-    file against the published numbers: only what ``reduced`` names differs."""
+    """What ``test_configuration_file`` asserts for a configuration (no width
+    in ``reduced``, by ``bench_cut``'s rule), and the file against the
+    published numbers: only what ``reduced`` names differs."""
     bench = registry.load_benchmark()
     entry = next(c for c in bench["configs"] if c["name"] == "sdar30b_a3b_trunk")
-    assert set(entry) == {"name", "source", "file", "reduced", "why"}
-    assert len(entry["why"]) <= 200 and entry["file"].startswith(bench["paths"][0] + "/")
-    cfg = registry.load_config(entry["name"])
-    assert cfg["reduced"] == entry["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
-    assert not any(key.endswith(WIDTH_ENDINGS) or "head" in key for key in entry["reduced"])
+    cfg = check_configuration(bench, entry)
+    assert cfg["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
     assert cfg["reference_mode"] == "bf16_operands"
-    assert cfg["control"]["reference_mode"] == "fp8_operands"
-    assert any(w["config"] == entry["name"] for w in bench["workloads"])
     for key, value in PUBLISHED.items():
         if key not in cfg["reduced"]:
             assert cfg[key] == value, key
@@ -80,7 +73,7 @@ def test_cell_entry_names_its_traffic():
         "update.device_us_per_step", "update.push_us_per_step", "update.sample_us_per_step",
         "update.compute_us_per_step", "trace.unscoped_share", "device.idle_share",
         # the window stages and places rows, the expert layer gathers and scatters
-        "host.stage_place_ms", "ops.copy_gather_us_per_step",
+        "host.span_stage_ms", "host.span_place_chunk_ms", "ops.copy_gather_us_per_step",
     }
     reported = {m["name"] for m in registry.metrics_for(bench, "end_to_end", CELL)}
     assert reported == {"grad_steps_per_s", "setup_s"}
@@ -119,6 +112,49 @@ def test_flops_arithmetic():
     assert moved / 819e9 > flops_trunk.expert_flops_per_step(model, balanced, balanced) / 197e12
     assert flops_trunk.roofline_seconds(197e12, 1.0, peaks) == pytest.approx(1.0)
     assert flops_trunk.roofline_seconds(1.0, 819e9, peaks) == pytest.approx(1.0)
+
+
+@pytest.mark.parametrize("cell_name, reader, count", [
+    (CELL, "trunk.moe_experts_roofline", "flops_trunk"),
+    ("nemotron3_super_trunk_burst", "trunk.latent_experts_roofline", "flops_hybrid"),
+])
+def test_an_expert_roofline_reads_a_scoped_kernel_where_there_is_no_ragged_dot(
+    cell_name, reader, count
+):
+    """A grouped-product kernel of our own carries the program's scope
+    ``tac/trunk/moe/experts/products`` and not XLA:TPU's name: both families'
+    readers find its time through ``trunk_read.grouped_product_seconds``, add
+    XLA's kernels to it where both run, and are silent where neither does."""
+    import importlib
+
+    from benchmark.harness import trunk_read
+
+    flops = importlib.import_module("benchmark.harness." + count)
+    _, cell, config = registry.resolve(cell_name)
+    model, rows = config["model"], (7040.0, 7040.0)
+    counters = {"trunk/held_assignments": rows[0], "trunk/held_assignments_target": rows[1]}
+
+    def ctx(by_scope, by_kind):
+        return types.SimpleNamespace(
+            trace={"busy_s": 5.0, "by_kind": by_kind}, scope_summary={"by_scope": by_scope},
+            cell=cell, config=config, n_windows=2, per_window={"grad_steps": 10},
+            device={"kind": "TPU v5 lite"},
+            driver=types.SimpleNamespace(model=model, trunk_counters=lambda: counters),
+        )
+
+    read = registry.load_layer_metric(reader)
+    least = flops.roofline_seconds(
+        flops.expert_flops_per_step(model, *rows), flops.expert_bytes_per_step(model, *rows),
+        {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9},
+    )
+    scoped = {trunk_read.EXPERT_PRODUCTS: 20 * 30e-3, "tac/trunk/moe/experts": 1.0}
+    ours = read(ctx(scoped, {"fusion": 1.0}))  # 30 ms a step under the scope, no ragged-dot
+    assert ours == pytest.approx(100 * least / 30e-3) and 0 < ours < 100
+    both = read(ctx(scoped, {"fusion": 1.0, trunk_read.GROUPED_PRODUCT: 20 * 10e-3}))
+    assert both == pytest.approx(100 * least / 40e-3)
+    named = read(ctx({"tac/trunk/moe/experts": 1.0}, {trunk_read.GROUPED_PRODUCT: 20 * 10e-3}))
+    assert named == pytest.approx(100 * least / 10e-3)  # today's programs: the name alone
+    assert read(ctx({"tac/trunk/moe/experts": 1.0}, {"fusion": 1.0})) is None
 
 
 @pytest.fixture(scope="module")
@@ -204,6 +240,20 @@ def test_control_one_precision_lower_comes_out_not_correct():
     assert any(values["fp8_operands:" + n] > limit for n in numbers), values
 
 
+def test_sound_only_reads_a_seed_without_the_controls_run():
+    """``control.py --sound-only``: a seed's sound readings, every number
+    compared, and no run of the float8 control (no ``fp8_operands:`` key)."""
+    _, cell, config = cut(CELL)
+    (line,) = control.seed_lines(
+        cell, config, [37], windows=1, sound_only=True, overrides={"rehearsal": True}
+    )
+    assert (line["seed"], line["variant"]) == (37, "sound") and "error" not in line
+    numbers = ("loss_q.rel_gap", "loss_pi.gap_over_terms", "adam_nu.worst_leaf_gap",
+               "param_change.worst_leaf_gap", "router_choices.disagree_share")
+    assert all(line[n] <= cell["limits"]["loss_q"] for n in numbers), line
+    assert not any(key.startswith(config["control"]["reference_mode"]) for key in line)
+
+
 def test_a_trunk_that_skips_an_expert_comes_out_not_correct(monkeypatch):
     """The program with one held expert's terms left out (its rows counted
     to no group) against the reference that computes them."""
@@ -238,8 +288,11 @@ def test_benchmark_json_keeps_or_parks_every_entry():
     or parked beside it (``benchmark/parked/``), unchanged but for ``workloads``
     lists that grew and a bound that shrank, or that a ``benchmark`` PR refitted
     to the spreads it read (PR 36, ``PERF.md`` section 2: the two bounds the
-    visual cell's host share outgrew)."""
+    visual cell's host share outgrew), or that a ``benchmark`` PR retired and
+    said why (PR 44: the harness's outside span, whose inside twins
+    ``host.span_stage_ms`` and ``host.span_place_chunk_ms`` every burst cell has)."""
     refitted = {"grad_steps_per_s": 0.055, "window_ms.p95": 0.04}
+    retired = {"host.stage_place_ms"}
     import subprocess
 
     old = subprocess.run(
@@ -253,6 +306,9 @@ def test_benchmark_json_keeps_or_parks_every_entry():
         assert old[key] == new[key]
     for section in ("configs", "workloads", "end_to_end", "per_layer"):
         for was in old[section]:
+            if was["name"] in retired:
+                assert all(e["name"] != was["name"] for e in new[section])
+                continue
             (now,) = [e for e in new[section] if e["name"] == was["name"]]
             same = dict(now)
             if "workloads" in was:
