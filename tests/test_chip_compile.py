@@ -186,6 +186,33 @@ def _flash_grouped(devices, block_length):
     ]
 
 
+def _flash_window(devices):
+    """The three flash kernels at a sliding layer's shapes in
+    ``laguna_s21_trunk_burst``: 18 query heads over 2 shared key/value heads
+    of 128, histories of 4,096, a window of 512: the grids run over the two
+    key blocks (three query blocks, for dK/dV) a row of 512-wide blocks can
+    see where the causal sweep runs over eight, and the forward kernel keeps
+    the kind the benchmark's flash readers find it by (inside the cell's
+    burst all 25 kernels of the five layers read so: the sandbox compile at
+    size, PERF.md section 4)."""
+    q = _shape((2, 18, 4096, 128), jnp.float32, devices[0])
+    kv = _shape((2, 2, 4096, 128), jnp.float32, devices[0])
+
+    def fwd(q, k, v):
+        # a kernel is named for the scope that calls it: the layer's module, `attention`
+        with jax.named_scope("attention"):
+            return flash_attention(q, k, v, True, None, None, False, 128, 1, True, 512)
+
+    grads = jax.grad(lambda q, k, v: fwd(q, k, v).sum(), (0, 1, 2))
+    for fn, n_kernels in ((fwd, 1), (grads, 3)):
+        text = jax.jit(fn).lower(q, kv, kv).compile().as_text()
+        kinds = [_kernel_kind(name) for name in _kernels(text)]
+        assert len(kinds) == n_kernels and (fn is grads or _reads_as_attention(kinds[0])), kinds
+    assert [x.shape for x in jax.eval_shape(grads, q, kv, kv)] == [
+        q.shape, kv.shape, kv.shape
+    ]
+
+
 def _trunk_burst(devices):
     """The shared-trunk burst over the cell's ring of histories (8,192 rows
     of 1024 x 17, the trunk itself at a cut width so that this compiles in
@@ -823,6 +850,7 @@ CASES = [
     pytest.param(_population_programs, (8,), id="population-burst-and-epoch"),
     pytest.param(_flash_grouped, (1,), id="flash-grouped-causal"),
     pytest.param(_flash_grouped, (4,), id="flash-grouped-block4"),
+    pytest.param(_flash_window, (), id="flash-window-512-of-4096"),
     pytest.param(_trunk_burst, (), id="trunk-burst-ring"),
     pytest.param(_trunk_attention_passes, (), id="trunk-attention-passes"),
     pytest.param(_hybrid_trunk_burst, (), id="hybrid-trunk-burst-at-size"),

@@ -173,10 +173,11 @@ def test_bf16_dots_round_operands_and_keep_float32_tiles():
 def route_by_sort_and_mask(
     u, w_router, top_k, scoring="softmax", bias=None, scale=1.0, impl=None
 ):
-    """``ops.moe.route`` as it stood before PR 41, kept as what the
-    selection is held to: ``lax.top_k`` (whole sorts of a token's scores on
-    the TPU) and the chosen scores by a mask over tokens x top_k x experts
-    (``impl``: ``route``'s signature; there is one form of this)."""
+    """``ops.moe.route`` with the selection as it stood before PR 41, kept
+    as what the selection is held to: ``lax.top_k`` (whole sorts of a token's
+    scores on the TPU) and the chosen scores by a mask over tokens x top_k x
+    experts (``impl``: ``route``'s signature; there is one form of this).
+    ``scale`` multiplies either router's renormalised weights (PR 45)."""
     logits = jnp.dot(
         u.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
@@ -190,7 +191,7 @@ def route_by_sort_and_mask(
     chosen = top_e[:, :, None] == jnp.arange(p.shape[-1], dtype=top_e.dtype)
     top_p = jnp.sum(jnp.where(chosen, p[:, None, :], 0.0), axis=-1)
     if scoring == "softmax":
-        return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        return top_e, scale * (top_p / jnp.sum(top_p, axis=-1, keepdims=True))
     return top_e, scale * top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
 
 

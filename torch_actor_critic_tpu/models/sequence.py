@@ -30,9 +30,11 @@ from flax import linen as nn
 from torch_actor_critic_tpu.models.mlp import Dense, torch_linear_kernel_init
 from torch_actor_critic_tpu.ops import moe, ssm
 from torch_actor_critic_tpu.ops.attention import (
+    Rope,
     attention as sdpa,
     qk_norm_rope,
     rms_norm,
+    rotary,
 )
 from torch_actor_critic_tpu.ops.distributions import squashed_gaussian_sample
 from torch_actor_critic_tpu.telemetry import scopes
@@ -53,8 +55,9 @@ def _auto_batch(obs_seq: jax.Array, *rest: jax.Array):
 
 
 def default_attention(q, k, v, causal=True, **mask):
-    """``mask``: ``block_length`` / ``bf16_dots`` of :func:`ops.attention.attention`
-    (the SDAR block passes them; the transformer block passes none)."""
+    """``mask``: ``block_length`` / ``bf16_dots`` / ``window`` of
+    :func:`ops.attention.attention` (a decoder block passes them, ``window`` on
+    a sliding layer alone; the transformer block passes none)."""
     return sdpa(q, k, v, causal=causal, **mask)
 
 
@@ -143,11 +146,21 @@ class TransformerBlock(nn.Module):
         return x + h
 
 
-# A layer's kind, one letter of ``TrunkSpec.pattern``.
+# A layer's kind, one letter of ``TrunkSpec.pattern``
+# (``utils/config.py::TRUNK_LAYER_KINDS`` says each in words).
 SDAR_BLOCK = "S"  # two sublayers a block: attention, then sparse experts
 STATE_SPACE = "M"  # one Mamba-2 mixer
 ATTENTION = "*"  # one attention mixer
 EXPERTS = "E"  # one expert mixer
+# Blocks of two sublayers in a stack that mixes attention kinds (``laguna``):
+# full or sliding-window attention, then sparse experts (capital) or a dense
+# gated feed-forward (small).
+FULL_EXPERTS, WINDOW_EXPERTS = "F", "W"
+FULL_DENSE, WINDOW_DENSE = "f", "w"
+# (sets: the empty kind of a layer that names none is in no set, as it is in every string)
+TWO_SUBLAYERS = frozenset((SDAR_BLOCK, FULL_EXPERTS, WINDOW_EXPERTS, FULL_DENSE, WINDOW_DENSE))
+WINDOWED = frozenset((WINDOW_EXPERTS, WINDOW_DENSE))
+DENSE_FFN = frozenset((FULL_DENSE, WINDOW_DENSE))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,14 +169,22 @@ class TrunkSpec:
     string of kinds (``pattern``), one set of widths for them, and what this
     chip holds of each (``SACConfig.trunk_*``).
 
-    ``pattern`` is one letter a layer (the four above); left empty it is ``layers``
-    SDAR blocks. SDAR-30B-A3B (``sdar_moe``) is ``"S" * layers`` with the
-    defaults below; ``nemotron_h`` is a string of ``M``, ``*`` and ``E`` with
-    ``qk_norm_rope`` off, sigmoid routing, plain ``relu2`` experts in a latent
-    width beside a shared expert, and the ``ssm_*`` sizes. A chip's share of
-    a layer is in the counts: ``experts_held`` of ``experts`` (the router
-    looks at all), ``q_heads`` / ``kv_heads`` and ``ssm_heads`` /
-    ``ssm_groups`` as many as are held (no mixer reads a head's index)."""
+    ``pattern`` is one letter a layer (the kinds above); left empty it is ``layers``
+    SDAR blocks. Which family sets what: SDAR-30B-A3B (``sdar_moe``) is
+    ``"S" * layers`` with the defaults below; ``nemotron_h`` is a string of
+    ``M``, ``*`` and ``E`` with ``qk_norm_rope`` off, sigmoid routing, plain
+    ``relu2`` experts in a latent width beside a shared expert, and the
+    ``ssm_*`` sizes; ``laguna`` is a string of ``F``, ``W``, ``f`` and ``w``
+    with ``qk_norm`` off (rotary without the per-head norm), ``head_gate``,
+    ``dense_width``, softmax routing times ``routed_scale`` beside a shared
+    expert, and what differs by attention kind: a sliding layer's ``window``,
+    its ``window_q_heads`` and ``window_rope_theta`` over the whole head, a
+    full layer's ``q_heads`` and ``rope_theta`` over ``rope_share`` of the
+    head under YaRN (``rope_yarn_factor``, ``rope_yarn_positions``). A chip's
+    share of a layer is in the counts: ``experts_held`` of ``experts`` (the
+    router looks at all), ``q_heads`` / ``window_q_heads`` / ``kv_heads`` and
+    ``ssm_heads`` / ``ssm_groups`` as many as are held (no mixer reads a
+    head's index)."""
 
     hidden: int = 2048
     pattern: str = ""
@@ -185,7 +206,7 @@ class TrunkSpec:
     bf16_dots: bool = True
     qk_norm_rope: bool = True  # per-head norm and rotary positions on q and k
     router: str = "softmax"  # or "sigmoid", chosen by score plus a bias
-    routed_scale: float = 1.0  # sigmoid routing's scale of the weights
+    routed_scale: float = 1.0  # the scale of the chosen experts' renormalised weights
     expert_form: str = "silu_gated"  # ops.moe.FORMS
     expert_latent: int = 0  # the width the routed experts work in; 0: hidden
     shared_expert_width: int = 0  # an expert every token passes; 0: none
@@ -195,6 +216,22 @@ class TrunkSpec:
     ssm_state: int = 128
     ssm_conv: int = 4
     ssm_chunk: int = 128
+    qk_norm: bool = True  # with qk_norm_rope: the per-head norm before rotary
+    head_gate: bool = False  # a sigmoid gate a head on attention's output
+    dense_width: int = 0  # the dense gated feed-forward of an "f" or "w" block
+    # By attention kind: a sliding layer ("W", "w") sees the ``window`` latest
+    # positions, holds ``window_q_heads`` query heads (0: ``q_heads``) and
+    # rotates the whole head by ``window_rope_theta`` (0: ``rope_theta``).
+    window: int = 0
+    window_q_heads: int = 0
+    window_rope_theta: float = 0.0
+    # Every other attention layer: ``rope_theta`` over the first ``rope_share``
+    # of the head, under YaRN where ``rope_yarn_factor`` is above 1
+    # (:class:`ops.attention.Rope`; YaRN's betas and its scale of cosine and
+    # sine are that module's constants).
+    rope_share: float = 1.0
+    rope_yarn_factor: float = 1.0
+    rope_yarn_positions: int = 0
 
     @classmethod
     def from_config(cls, config) -> "TrunkSpec":
@@ -204,6 +241,23 @@ class TrunkSpec:
     @property
     def kinds(self) -> str:
         return self.pattern or SDAR_BLOCK * self.layers
+
+    def q_heads_of(self, kind: str) -> int:
+        return (kind in WINDOWED and self.window_q_heads) or self.q_heads
+
+    def window_of(self, kind: str) -> int | None:
+        return self.window if kind in WINDOWED else None
+
+    def rope_of(self, kind: str) -> float | Rope:
+        """One ``theta`` over the whole head where that is all, else what
+        the layer's kind rotates by."""
+        if kind in WINDOWED:
+            return self.window_rope_theta or self.rope_theta
+        if self.rope_share == 1.0 and self.rope_yarn_factor <= 1.0:
+            return self.rope_theta
+        return Rope(
+            self.rope_theta, self.rope_share, self.rope_yarn_factor, self.rope_yarn_positions
+        )
 
 
 class RMSNorm(nn.Module):
@@ -252,22 +306,29 @@ def _expert_kernel_init(key, shape, dtype=jnp.float32):
 
 
 class GroupedQueryAttention(nn.Module):
-    """``W_o Attn(rope(norm(W_q u)), rope(norm(W_k u)), W_v u)`` with
-    ``q_heads`` query heads reading ``kv_heads`` shared key/value heads
-    under the block-causal mask (``block_length`` 1: the causal one). A spec
-    with ``qk_norm_rope`` off hands ``W_q u`` and ``W_k u`` to the kernels as
-    they are: no norm, no positions."""
+    """``W_o [g * Attn(rope(norm(W_q u)), rope(norm(W_k u)), W_v u)]`` with
+    the layer kind's query heads reading ``kv_heads`` shared key/value heads
+    under the block-causal mask (``block_length`` 1: the causal one), inside
+    the kind's window if it has one. Which family sets what: ``sdar_moe``
+    norms and rotates q and k (``qk_norm_rope``, ``qk_norm``); ``nemotron_h``
+    turns ``qk_norm_rope`` off and hands ``W_q u`` and ``W_k u`` to the
+    kernels as they are, no norm, no positions; ``laguna`` turns ``qk_norm``
+    off alone (rotary by the layer's kind, :meth:`TrunkSpec.rope_of`, and no
+    norm), takes head count and window by ``kind`` and multiplies each
+    head's output by ``g = sigmoid(W_g u)``, one gate a head
+    (``head_gate``)."""
 
     spec: TrunkSpec
     attention_fn: AttentionFn = default_attention
     dtype: t.Any = jnp.float32
+    kind: str = ""  # the layer's letter: head count, window and rotary by kind
 
     @nn.compact
     def __call__(self, u: jax.Array, pos: jax.Array) -> jax.Array:
         sp, dtype = self.spec, self.dtype
         b, s, _ = u.shape
-        d = sp.head_dim
-        q = _linear(sp.q_heads * d, dtype, "q_proj")(u).reshape(b, s, sp.q_heads, d)
+        d, heads = sp.head_dim, sp.q_heads_of(self.kind)
+        q = _linear(heads * d, dtype, "q_proj")(u).reshape(b, s, heads, d)
         k = _linear(sp.kv_heads * d, dtype, "k_proj")(u).reshape(b, s, sp.kv_heads, d)
         v = _linear(sp.kv_heads * d, dtype, "v_proj")(u).reshape(b, s, sp.kv_heads, d)
         # (batch, heads, seq, d) for the kernels. The kernels reading (batch,
@@ -283,8 +344,13 @@ class GroupedQueryAttention(nn.Module):
         # kernels, which read each key block many times, a kernel's output it
         # does not, and the step measured slower with k in the pass (PERF.md
         # section 6, PR 39). A spec without it (nemotron_h's) has no such
-        # pass to ride in: q and k are transposed as v is.
-        if sp.qk_norm_rope:
+        # pass to ride in: q and k are transposed as v is, and so are
+        # laguna's behind their rotary, which XLA composes.
+        if sp.qk_norm_rope and not sp.qk_norm:
+            rope = sp.rope_of(self.kind)
+            q = rotary(q, pos, rope).transpose(0, 2, 1, 3)
+            k = rotary(k, pos, rope).transpose(0, 2, 1, 3)
+        elif sp.qk_norm_rope:
             kernels = self.attention_fn is default_attention
             q = HeadNormRope(
                 sp.rms_eps, sp.rope_theta, "auto" if kernels else "xla", name="q_norm"
@@ -292,20 +358,28 @@ class GroupedQueryAttention(nn.Module):
             k = HeadNormRope(sp.rms_eps, sp.rope_theta, "xla", name="k_norm")(k, pos)
         else:
             q, k = q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)
+        window = sp.window_of(self.kind)
         out = self.attention_fn(
             q, k, v.transpose(0, 2, 1, 3), causal=True,
             block_length=sp.block_length,
             # float32 tiles, one bfloat16 pass on the MXU: the TPU's default
             # precision for a float32 product, inside the kernels too.
             bf16_dots=sp.bf16_dots and dtype == jnp.float32,
+            # a sliding layer alone names its window: an attention_fn that
+            # knows none (the ring's) refuses it and never drops it
+            **({} if window is None else {"window": window}),
         )
         # Back to (batch, seq, heads * d) for o_proj: XLA folds this
         # transposition into the product going forward and pays two relayouts
         # of the cotangent coming back; a kernel of our own that turned the
         # cotangent and wrote delta in one pass measured 1.7% slower for the
         # whole step (XLA then keeps less of the step in fast memory).
-        out = out.transpose(0, 2, 1, 3).reshape(b, s, sp.q_heads * d)
-        return _linear(sp.hidden, dtype, "o_proj")(out)
+        out = out.transpose(0, 2, 1, 3)
+        if sp.head_gate:
+            with jax.named_scope(scopes.TRUNK_ATTENTION_GATE):
+                gate = jax.nn.sigmoid(_linear(heads, dtype, "g_proj")(u))
+                out = out * gate[..., None].astype(out.dtype)
+        return _linear(sp.hidden, dtype, "o_proj")(out.reshape(b, s, heads * d))
 
 
 class SparseMoE(nn.Module):
@@ -459,20 +533,50 @@ def _selection(attention_fn) -> str:
     return "auto" if attention_fn is default_attention else "xla"
 
 
-class SDARBlock(nn.Module):
-    """``h = x + Attn(RMSNorm(x))``, ``y = h + MoE(RMSNorm(h))``."""
+class DenseFFN(nn.Module):
+    """``W_down (silu(W_gate v) * W_up v)``, ``width`` wide: the dense gated
+    feed-forward, whole on every chip."""
+
+    width: int
+    dtype: t.Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, v: jax.Array) -> jax.Array:
+        pre = moe.FORMS["silu_gated"](
+            _linear(self.width, self.dtype, "gate_proj")(v),
+            _linear(self.width, self.dtype, "up_proj")(v),
+        )
+        return _linear(v.shape[-1], self.dtype, "down_proj")(pre)
+
+
+class DecoderBlock(nn.Module):
+    """``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``: a block of
+    two sublayers, by ``kind``. ``S`` (``sdar_moe``): attention and sparse
+    experts as the spec has them. ``F`` / ``W`` / ``f`` / ``w`` (``laguna``):
+    full or sliding-window attention (head count, window and rotary by kind),
+    then sparse experts or, small letter, the dense feed-forward."""
 
     spec: TrunkSpec
+    kind: str = SDAR_BLOCK
     attention_fn: AttentionFn = default_attention
     dtype: t.Any = jnp.float32
 
     @nn.compact
     def __call__(self, x: jax.Array, pos: jax.Array) -> jax.Array:
-        sp = self.spec
-        with jax.named_scope(scopes.TRUNK_ATTENTION):
+        sp, kind = self.spec, self.kind
+        # a kind's scope starts with tac/trunk/attention: its readers sum them
+        by_kind = scopes.TRUNK_ATTENTION if kind == SDAR_BLOCK else (
+            scopes.TRUNK_ATTENTION_SLIDING if kind in WINDOWED
+            else scopes.TRUNK_ATTENTION_FULL
+        )
+        with jax.named_scope(by_kind):
             h = x + GroupedQueryAttention(
-                sp, self.attention_fn, self.dtype, name="attention"
+                sp, self.attention_fn, self.dtype, kind, name="attention"
             )(RMSNorm(sp.rms_eps, name="input_norm")(x), pos)
+        if kind in DENSE_FFN:
+            with jax.named_scope(scopes.TRUNK_DENSE_FFN):
+                u = RMSNorm(sp.rms_eps, name="post_attention_norm")(h)
+                return h + DenseFFN(sp.dense_width, self.dtype, name="mlp")(u)
         with jax.named_scope(scopes.TRUNK_MOE_ROUTE):
             u = RMSNorm(sp.rms_eps, name="post_attention_norm")(h)
         return h + SparseMoE(sp, selection=_selection(self.attention_fn), name="moe")(u)
@@ -567,7 +671,7 @@ class SequenceTrunk(nn.Module):
             x = _linear(sp.hidden, self.dtype, "embed")(obs_seq)
         pos = pos_offset + jnp.arange(obs_seq.shape[1])
         for i, kind in enumerate(sp.kinds):
-            block = SDARBlock if kind == SDAR_BLOCK else MixerBlock
+            block = DecoderBlock if kind in TWO_SUBLAYERS else MixerBlock
             its_kind = {} if kind == SDAR_BLOCK else {"kind": kind}
             # Recomputing a block saves its residuals (about 1 GB for an SDAR
             # block at the published widths and 8,192 tokens) for a fifth more

@@ -48,11 +48,16 @@ All take ``(batch, heads, seq, head_dim)`` arrays. ``q_offset`` /
 the hook that lets ring attention apply a correct causal mask when the
 sequence axis is sharded across devices.
 
-Two generalisations, shared by all three: ``block_length`` ``b`` widens the
+Three generalisations, shared by all three: ``block_length`` ``b`` widens the
 causal mask to *block-causal* (position ``i`` sees ``j`` iff
 ``j // b <= i // b``: causal across blocks of ``b``, full inside one;
-``b = 1`` is the causal mask, by the same code as before), and grouped
-heads: ``k``/``v`` may carry ``heads // group`` heads, query head ``i``
+``b = 1`` is the causal mask, by the same code as before); ``window`` ``w``
+narrows it to a *sliding window* (``i`` sees ``j`` only if ``j > i - w``: the
+``w`` latest positions, its own among them; ``None`` is no window, and the
+kernels then lower as they did before there was one), the kernels visiting
+only the key blocks a row of query blocks can see, at both edges
+(:func:`_k_block_span`: a block outside is neither computed nor fetched); and
+grouped heads: ``k``/``v`` may carry ``heads // group`` heads, query head ``i``
 then reads key/value head ``i // group``. The XLA paths repeat ``k`` and
 ``v``; the kernels read the shared head through their index maps, so no
 repeated copy is ever written (the dK/dV kernel sweeps the group's query
@@ -61,6 +66,7 @@ heads in its innermost grid axis and sums them in VMEM).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 import typing as t
@@ -71,11 +77,16 @@ import jax.numpy as jnp
 NEG_INF = float("-inf")
 
 
-def _visible(q_pos, k_pos, block_length: int = 1):
-    """The (block-)causal mask: ``k_pos // b <= q_pos // b``."""
+def _visible(q_pos, k_pos, block_length: int = 1, window: int | None = None):
+    """The (block-)causal mask, ``k_pos // b <= q_pos // b``, inside a sliding
+    ``window`` if there is one: ``k_pos > q_pos - window``."""
     if block_length == 1:
-        return q_pos >= k_pos
-    return k_pos < (q_pos // block_length + 1) * block_length
+        seen = q_pos >= k_pos
+    else:
+        seen = k_pos < (q_pos // block_length + 1) * block_length
+    if window is None:
+        return seen
+    return seen & (k_pos > q_pos - window)
 
 
 def _repeat_kv(q: jax.Array, k: jax.Array, v: jax.Array):
@@ -95,6 +106,7 @@ def reference_attention(
     q_offset: jax.Array | int = 0,
     k_offset: jax.Array | int = 0,
     block_length: int = 1,
+    window: int | None = None,
 ) -> jax.Array:
     """Plain softmax(QK^T/sqrt(d))V with the full score matrix."""
     k, v = _repeat_kv(q, k, v)
@@ -103,7 +115,9 @@ def reference_attention(
         tq, tk = scores.shape[-2], scores.shape[-1]
         q_pos = q_offset + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
         k_pos = k_offset + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-        scores = jnp.where(_visible(q_pos, k_pos, block_length), scores, NEG_INF)
+        scores = jnp.where(
+            _visible(q_pos, k_pos, block_length, window), scores, NEG_INF
+        )
     # Rows with no visible key (possible when k_offset > q position, as
     # happens for future chunks in ring attention) would softmax to NaN;
     # zero them instead to match the online-softmax convention.
@@ -126,6 +140,7 @@ def online_block_update(
     k_end: jax.Array | int | None = None,
     scale: float | None = None,
     block_length: int = 1,
+    window: int | None = None,
 ) -> t.Tuple[jax.Array, jax.Array, jax.Array]:
     """One online-softmax accumulation step against a K/V block.
 
@@ -150,7 +165,7 @@ def online_block_update(
             valid = k_pos < k_end
         if causal:
             q_pos = q_offset + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
-            valid = valid & _visible(q_pos, k_pos, block_length)
+            valid = valid & _visible(q_pos, k_pos, block_length, window)
         scores = jnp.where(valid, scores, NEG_INF)
     m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
     # exp(-inf - -inf) = NaN; a fully-masked row keeps m_new == -inf and
@@ -181,6 +196,7 @@ def blockwise_attention(
     k_offset: jax.Array | int = 0,
     block_k: int = 256,
     block_length: int = 1,
+    window: int | None = None,
 ) -> jax.Array:
     """Online-softmax attention scanning over K/V blocks.
 
@@ -218,6 +234,7 @@ def blockwise_attention(
             k_offset=k_offset + j * block_k,
             k_end=k_offset + tk if padded else None,
             block_length=block_length,
+            window=window,
         )
         return (m, l, acc), None
 
@@ -272,10 +289,82 @@ def _k_block_needed(iq, j, block_q: int, block_k: int, block_length: int):
     return j * block_k < (last_row // block_length + 1) * block_length
 
 
+def _at_least_0(x):
+    return max(x, 0) if isinstance(x, int) else jnp.maximum(x, 0)
+
+
+def _at_most(x, top: int):
+    return min(x, top) if isinstance(x, int) else jnp.minimum(x, top)
+
+
+def _k_block_span(iq, block_q: int, block_k: int, block_length: int, window: int):
+    """``(first, last)``: the k blocks that hold a key some row of q block
+    ``iq`` sees under a sliding ``window`` (queries and keys at the same
+    positions ``0..T-1``). The last is the causal bound of
+    :func:`_k_block_needed`; the first holds the earliest key of the q
+    block's first row, ``iq * block_q - window + 1``. The kernels' grids and
+    index maps run over this span and no further: Python integers in, Python
+    integers out (the grid's extent, :func:`visited_key_blocks`); a program
+    id in, traced values out."""
+    last_row = (iq + 1) * block_q - 1
+    last_key = last_row
+    if block_length != 1:
+        last_key = (last_row // block_length + 1) * block_length - 1
+    return _at_least_0(iq * block_q - window + 1) // block_k, last_key // block_k
+
+
+def _q_block_span(
+    jk, block_q: int, block_k: int, block_length: int, window: int, n_qb: int
+):
+    """The same span the other way, for the dK/dV sweep: ``(first, last)`` q
+    block with a row that sees a key of k block ``jk``. Key ``j`` is seen
+    from the first row of its own mask block up to row ``j + window - 1``."""
+    first_row = jk * block_k
+    if block_length != 1:
+        first_row = (first_row // block_length) * block_length
+    last_row = (jk + 1) * block_k - 1 + window - 1
+    return first_row // block_q, _at_most(last_row // block_q, n_qb - 1)
+
+
+def visited_key_blocks(
+    t: int, block_q: int | None = None, block_k: int | None = None,
+    block_length: int = 1, window: int | None = None,
+) -> int:
+    """(q block, k block) pairs the forward and dQ kernels compute for
+    histories of ``t`` (blocks left out: the kernels' own choice for ``t``):
+    the blocks of each q block's span under a window (the grid is built from
+    the same spans), the blocks up to the causal bound without one."""
+    block_q, block_k = _check_blocks(t, t, block_q, block_k)
+    visited = 0
+    for iq in range(t // block_q):
+        first, last = _k_block_span(iq, block_q, block_k, block_length, window or t)
+        visited += min(last, t // block_k - 1) - first + 1
+    return visited
+
+
+def _span_steps(span, n_blocks: int) -> int:
+    """The longest span over the ``n_blocks`` blocks of the grid's outer
+    axis: the extent of the sweep."""
+    return max(last - first + 1 for first, last in map(span, range(n_blocks)))
+
+
+def _k_step(iq, step, causal, block_q, block_k, block_length, window):
+    """The k block that step ``step`` of q block ``iq``'s sweep reads, and
+    whether it holds anything for the q block."""
+    if window is None:
+        needed = True if not causal else _k_block_needed(
+            iq, step, block_q, block_k, block_length
+        )
+        return step, needed
+    first, last = _k_block_span(iq, block_q, block_k, block_length, window)
+    return first + step, first + step <= last
+
+
 def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, *rest,
     block_q: int, block_k: int, scale: float, causal: bool,
     save_lse: bool = False, block_length: int = 1, mxu_dtype=None,
+    window: int | None = None,
 ):
     """One ``(batch·head, q-block, k-block)`` program.
 
@@ -283,7 +372,11 @@ def _flash_kernel(
     programs run j = 0..nk-1 in order, carrying the online-softmax state
     in VMEM scratch (``m``/``l`` use column 0 of a (block_q, LANE)
     tile); the final k step normalizes into ``o_ref``. Same update math
-    as :func:`online_block_update`.
+    as :func:`online_block_update`. Under a ``window`` the sweep is the q
+    block's span of k blocks (:func:`_k_block_span`), step ``s`` reading
+    block ``first + s``; a q block with a shorter span than the grid's
+    extent skips the steps past its last block (the index map holds the
+    block it has, so nothing is fetched for them).
     """
     from jax.experimental import pallas as pl  # deferred: TPU-only path
 
@@ -294,10 +387,10 @@ def _flash_kernel(
         m_ref, l_ref, acc_ref = rest
 
     iq = pl.program_id(1)
-    j = pl.program_id(2)
-    n_kb = pl.num_programs(2)
+    step = pl.program_id(2)
+    n_steps = pl.num_programs(2)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -305,9 +398,7 @@ def _flash_kernel(
 
     # Under causality, K blocks strictly past this q block's diagonal
     # contribute nothing; skip their compute entirely.
-    needed = True if not causal else _k_block_needed(
-        iq, j, block_q, block_k, block_length
-    )
+    j, needed = _k_step(iq, step, causal, block_q, block_k, block_length, window)
 
     @pl.when(needed)
     def _update():
@@ -323,7 +414,7 @@ def _flash_kernel(
                 jnp.int32, (block_q, block_k), 1
             )
             scores = jnp.where(
-                _visible(q_pos, k_pos, block_length), scores, NEG_INF
+                _visible(q_pos, k_pos, block_length, window), scores, NEG_INF
             )
         m = m_ref[:, 0]
         m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
@@ -335,15 +426,20 @@ def _flash_kernel(
         # alpha = exp(-inf - finite) = 0 wipes the zero-init state.
         # The softmax tail is VPU-bound; each removed elementwise pass
         # over the (block_q, block_k) tile is measurable throughput.
-        p = jnp.exp(scores - m_new[:, None])
-        alpha = jnp.exp(m - m_new)
+        # Under a window that does not hold: the first block of a span may
+        # lie wholly before the window of the q block's later rows, whose
+        # m_new is then still -inf. One select a row (not a tile) keeps
+        # exp(-inf - -inf) out: such a row adds nothing and stays at zero.
+        m_safe = m_new if window is None else jnp.where(m_new == NEG_INF, 0.0, m_new)
+        p = jnp.exp(scores - m_safe[:, None])
+        alpha = jnp.exp(m - m_safe)
         l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=-1)
         acc_ref[:] = acc_ref[:] * alpha[:, None] + _acc_dot(
             p, v_blk, ((1,), (0,)), mxu_dtype
         )
         m_ref[:, 0] = m_new
 
-    @pl.when(j == n_kb - 1)
+    @pl.when(step == n_steps - 1)
     def _finalize():
         l = l_ref[:, 0]
         o_ref[0] = (
@@ -440,6 +536,30 @@ def _check_blocks(tq: int, tk: int, block_q: int | None, block_k: int | None):
     return block_q, block_k
 
 
+def _k_sweep(tq, tk, causal, block_q, block_k, block_length, window):
+    """The k sweep of the forward and dQ kernels: its extent (the grid's
+    innermost axis) and the k block that step ``j`` of q block ``iq`` reads.
+    No window: every k block in turn, as ever. A window: the longest span
+    over the q blocks, a step past a shorter span's end holding that span's
+    last block (a block index that does not change is not fetched again)."""
+    if window is None:
+        return tk // block_k, lambda iq, j: j
+    if not causal or tq != tk:
+        raise ValueError(
+            "flash_attention: a window is a causal mask over queries and keys "
+            f"at the same positions, got causal={causal}, Tq={tq}, Tk={tk}"
+        )
+
+    def span(iq):
+        return _k_block_span(iq, block_q, block_k, block_length, window)
+
+    def k_block(iq, j):
+        first, last = span(iq)
+        return jnp.minimum(first + j, last)
+
+    return _span_steps(span, tq // block_q), k_block
+
+
 def _flash_forward(
     q: jax.Array,
     k: jax.Array,
@@ -452,6 +572,7 @@ def _flash_forward(
     pad_lanes: int = _LANE,
     block_length: int = 1,
     bf16_dots: bool = False,
+    window: int | None = None,
 ):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -473,6 +594,7 @@ def _flash_forward(
     hkv, tk = k.shape[1], k.shape[2]
     group = _head_group(h, hkv)
     block_q, block_k = _check_blocks(tq, tk, block_q, block_k)
+    k_sweep, k_block = _k_sweep(tq, tk, causal, block_q, block_k, block_length, window)
     if not (q.dtype == k.dtype == v.dtype):
         # _acc_dot's downcast rule is only safe for the kernels' own f32
         # intermediates; a mixed-dtype *input* would be silently rounded.
@@ -505,18 +627,20 @@ def _flash_forward(
             _flash_kernel,
             block_q=block_q, block_k=block_k, scale=scale, causal=causal,
             save_lse=save_lse, block_length=block_length,
-            mxu_dtype=jnp.bfloat16 if bf16_dots else None,
+            mxu_dtype=jnp.bfloat16 if bf16_dots else None, window=window,
         ),
         out_shape=out_shape,
-        grid=(b * h, tq // block_q, tk // block_k),
+        grid=(b * h, tq // block_q, k_sweep),
         in_specs=[
             pl.BlockSpec((1, block_q, dp), lambda bh, iq, j: (bh, iq, 0),
                          memory_space=pltpu.VMEM),
             # Row bh = batch * h + head of q reads row bh // group of k/v:
             # batch * hkv + head // group, the shared head.
-            pl.BlockSpec((1, block_k, dp), lambda bh, iq, j: (bh // group, j, 0),
+            pl.BlockSpec((1, block_k, dp),
+                         lambda bh, iq, j: (bh // group, k_block(iq, j), 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, dp), lambda bh, iq, j: (bh // group, j, 0),
+            pl.BlockSpec((1, block_k, dp),
+                         lambda bh, iq, j: (bh // group, k_block(iq, j), 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=out_specs,
@@ -540,7 +664,7 @@ def _flash_forward(
 
 def _attn_probs(
     q, k, lse, scale, causal, iq, jk, block_q, block_k, block_length=1,
-    mxu_dtype=None,
+    mxu_dtype=None, window=None,
 ):
     """Recompute the (block_q, block_k) probability tile from saved lse.
 
@@ -556,7 +680,7 @@ def _attn_probs(
         k_pos = jk * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1
         )
-        s = jnp.where(_visible(q_pos, k_pos, block_length), s, NEG_INF)
+        s = jnp.where(_visible(q_pos, k_pos, block_length, window), s, NEG_INF)
     # lse is finite for every row inside the kernel (each causal row
     # sees at least key 0 — see the forward's guard-removal note), and
     # masked scores are -inf -> exp(-inf - finite) = 0 with no NaN
@@ -567,9 +691,10 @@ def _attn_probs(
 def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
     *, block_q: int, block_k: int, scale: float, causal: bool,
-    block_length: int = 1, mxu_dtype=None,
+    block_length: int = 1, mxu_dtype=None, window: int | None = None,
 ):
-    """dQ: grid ``(batch·head, q-block, k-block)``, k innermost.
+    """dQ: grid ``(batch·head, q-block, k-block)``, k innermost (the forward
+    kernel's sweep, over the q block's span under a ``window``).
 
     ``ds = p · (dO Vᵀ − Δ)``, ``dq += ds K · scale`` accumulated in VMEM
     scratch over the k sweep, written once on the final k step. Δ is the
@@ -578,16 +703,14 @@ def _flash_bwd_dq_kernel(
     from jax.experimental import pallas as pl
 
     iq = pl.program_id(1)
-    j = pl.program_id(2)
-    n_kb = pl.num_programs(2)
+    step = pl.program_id(2)
+    n_steps = pl.num_programs(2)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    needed = True if not causal else _k_block_needed(
-        iq, j, block_q, block_k, block_length
-    )
+    j, needed = _k_step(iq, step, causal, block_q, block_k, block_length, window)
 
     @pl.when(needed)
     def _update():
@@ -597,13 +720,13 @@ def _flash_bwd_dq_kernel(
         do = do_ref[0]
         p = _attn_probs(
             q, k_blk, lse_ref[0, 0], scale, causal, iq, j, block_q, block_k,
-            block_length, mxu_dtype,
+            block_length, mxu_dtype, window,
         )
         dpv = _acc_dot(do, v_blk, ((1,), (1,)), mxu_dtype)
         ds = p * (dpv - delta_ref[0, 0][:, None])
         dq_acc[:] += _acc_dot(ds, k_blk, ((1,), (0,)), mxu_dtype) * scale
 
-    @pl.when(j == n_kb - 1)
+    @pl.when(step == n_steps - 1)
     def _finalize():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
@@ -613,13 +736,16 @@ def _flash_bwd_dkv_kernel(
     dk_acc, dv_acc,
     *, block_q: int, block_k: int, scale: float, causal: bool,
     block_length: int = 1, mxu_dtype=None, n_qb: int | None = None,
+    window: int | None = None, q_blocks: int | None = None,
 ):
     """dK/dV: grid ``(batch·kv-head, k-block, group·q-block)``, q innermost.
 
     ``dv += pᵀ dO``; ``dk += dsᵀ Q · scale`` — both accumulated in VMEM
     scratch over the q sweep for a fixed k block. With grouped heads the
     sweep runs over the q blocks of every query head of the group in turn
-    (``n_qb`` q blocks a head), so the group's sum never leaves VMEM.
+    (``n_qb`` q blocks a head), so the group's sum never leaves VMEM. Under a
+    ``window`` a head's ``n_qb`` steps are the k block's span of q blocks
+    (:func:`_q_block_span`, of the ``q_blocks`` there are), from its first.
     """
     from jax.experimental import pallas as pl
 
@@ -635,9 +761,14 @@ def _flash_bwd_dkv_kernel(
 
     # Under causality, q blocks strictly before this k block's start see
     # none of it; skip them.
-    needed = True if not causal else _k_block_needed(
-        i, jk, block_q, block_k, block_length
-    )
+    if window is None:
+        needed = True if not causal else _k_block_needed(
+            i, jk, block_q, block_k, block_length
+        )
+    else:
+        first, last = _q_block_span(jk, block_q, block_k, block_length, window, q_blocks)
+        i = first + i
+        needed = i <= last
 
     @pl.when(needed)
     def _update():
@@ -647,7 +778,7 @@ def _flash_bwd_dkv_kernel(
         do = do_ref[0]
         p = _attn_probs(
             q, k_blk, lse_ref[0, 0], scale, causal, i, jk, block_q, block_k,
-            block_length, mxu_dtype,
+            block_length, mxu_dtype, window,
         )
         dv_acc[:] += _acc_dot(p, do, ((0,), (0,)), mxu_dtype)
         dpv = _acc_dot(do, v_blk, ((1,), (1,)), mxu_dtype)
@@ -663,6 +794,7 @@ def _flash_bwd_dkv_kernel(
 def _flash_backward(
     q, k, v, o, lse, g, causal, block_q, block_k, interpret,
     pad_lanes: int = _LANE, block_length: int = 1, bf16_dots: bool = False,
+    window: int | None = None,
 ):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -671,9 +803,11 @@ def _flash_backward(
     hkv, tk = k.shape[1], k.shape[2]
     group = _head_group(h, hkv)
     mask = dict(
-        block_length=block_length, mxu_dtype=jnp.bfloat16 if bf16_dots else None
+        block_length=block_length, mxu_dtype=jnp.bfloat16 if bf16_dots else None,
+        window=window,
     )
     block_q, block_k = _check_blocks(tq, tk, block_q, block_k)
+    k_sweep, k_block = _k_sweep(tq, tk, causal, block_q, block_k, block_length, window)
     scale = 1.0 / math.sqrt(d)
     # The forward enforced a single q/k/v dtype; the cotangent can still
     # arrive wider (e.g. an f32 loss over a bf16 output) — align it so
@@ -693,7 +827,8 @@ def _flash_backward(
     gr = g.reshape(b * h, tq, dp)
     qspec = pl.BlockSpec((1, block_q, dp), lambda bh, x, y: (bh, x, 0),
                          memory_space=pltpu.VMEM)
-    kspec_dq = pl.BlockSpec((1, block_k, dp), lambda bh, iq, j: (bh // group, j, 0),
+    kspec_dq = pl.BlockSpec((1, block_k, dp),
+                            lambda bh, iq, j: (bh // group, k_block(iq, j), 0),
                             memory_space=pltpu.VMEM)
     rowspec = pl.BlockSpec((1, 1, block_q), lambda bh, x, y: (bh, 0, x),
                            memory_space=pltpu.VMEM)
@@ -704,7 +839,7 @@ def _flash_backward(
             **mask,
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, tq, dp), q.dtype),
-        grid=(b * h, tq // block_q, tk // block_k),
+        grid=(b * h, tq // block_q, k_sweep),
         in_specs=[qspec, kspec_dq, kspec_dq, qspec, rowspec, rowspec],
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32)],
@@ -717,18 +852,29 @@ def _flash_backward(
     # dK/dV sweep: the grid's second axis is the k block, q innermost. Row
     # bkv of k/v is read by the q rows bkv * group .. + group - 1; step i of
     # the sweep is q block i % n_qb of the group's query head i // n_qb.
-    n_qb = tq // block_q
-    if group == 1:
-        q_row = lambda bkv, i: (bkv, i)  # noqa: E731
+    # Under a window a head's steps are the k block's span of q blocks, the
+    # longest span's many; a step past a shorter span's end holds its last.
+    q_blocks = n_qb = tq // block_q
+    if window is not None:
+        def span(jk):
+            return _q_block_span(jk, block_q, block_k, block_length, window, q_blocks)
+
+        n_qb = _span_steps(span, tk // block_k)
+
+        def q_row(bkv, jk, i):
+            first, last = span(jk)
+            return bkv * group + i // n_qb, jnp.minimum(first + i % n_qb, last)
+    elif group == 1:
+        q_row = lambda bkv, jk, i: (bkv, i)  # noqa: E731
     else:
-        q_row = lambda bkv, i: (bkv * group + i // n_qb, i % n_qb)  # noqa: E731
-    qspec_kv = pl.BlockSpec((1, block_q, dp), lambda bh, jk, i: (*q_row(bh, i), 0),
+        q_row = lambda bkv, jk, i: (bkv * group + i // n_qb, i % n_qb)  # noqa: E731
+    qspec_kv = pl.BlockSpec((1, block_q, dp), lambda bh, jk, i: (*q_row(bh, jk, i), 0),
                             memory_space=pltpu.VMEM)
     kspec_kv = pl.BlockSpec((1, block_k, dp), lambda bh, jk, i: (bh, jk, 0),
                             memory_space=pltpu.VMEM)
 
     def row_kv(bh, jk, i):
-        head, block = q_row(bh, i)
+        head, block = q_row(bh, jk, i)
         return head, 0, block
 
     rowspec_kv = pl.BlockSpec((1, 1, block_q), row_kv, memory_space=pltpu.VMEM)
@@ -736,7 +882,8 @@ def _flash_backward(
         functools.partial(
             _flash_bwd_dkv_kernel,
             block_q=block_q, block_k=block_k, scale=scale, causal=causal,
-            n_qb=None if group == 1 else n_qb, **mask,
+            n_qb=None if group == 1 and window is None else n_qb,
+            q_blocks=q_blocks, **mask,
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b * hkv, tk, dp), k.dtype),
@@ -762,7 +909,7 @@ def _flash_backward(
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -774,6 +921,7 @@ def flash_attention(
     pad_lanes: int = _LANE,
     block_length: int = 1,
     bf16_dots: bool = False,
+    window: int | None = None,
 ):
     """Pallas TPU flash attention, forward *and* backward kernels.
 
@@ -797,33 +945,37 @@ def flash_attention(
     ``k``/``v`` may carry fewer, shared heads (module docstring).
     ``bf16_dots`` rounds the operands of every product to bfloat16 inside
     the kernels (see :func:`_acc_dot`); inputs, outputs and accumulators
-    keep their dtype.
+    keep their dtype. ``window`` (with ``causal``, queries and keys at the
+    same positions) narrows the mask to the ``window`` latest positions; all
+    three kernels then sweep only the blocks inside it (module docstring).
     """
     return _flash_forward(
         q, k, v, causal, block_q, block_k, interpret, pad_lanes=pad_lanes,
-        block_length=block_length, bf16_dots=bf16_dots,
+        block_length=block_length, bf16_dots=bf16_dots, window=window,
     )
 
 
 def _flash_fwd(
     q, k, v, causal, block_q, block_k, interpret, pad_lanes, block_length,
-    bf16_dots,
+    bf16_dots, window=None,
 ):
     out, lse = _flash_forward(
         q, k, v, causal, block_q, block_k, interpret, save_lse=True,
         pad_lanes=pad_lanes, block_length=block_length, bf16_dots=bf16_dots,
+        window=window,
     )
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(
     causal, block_q, block_k, interpret, pad_lanes, block_length, bf16_dots,
-    res, g,
+    window, res, g,
 ):
     q, k, v, o, lse = res
     return _flash_backward(
         q, k, v, o, lse, g, causal, block_q, block_k, interpret,
         pad_lanes=pad_lanes, block_length=block_length, bf16_dots=bf16_dots,
+        window=window,
     )
 
 
@@ -852,6 +1004,68 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * weight
 
 
+# YaRN's constants (arXiv 2309.00071, and every published ``rope_parameters``
+# group of ``rope_type`` ``yarn`` this repo runs): the ramp between a
+# frequency kept and one divided runs from the index that turns 32 times in
+# the original positions to the one that turns once.
+YARN_BETA_FAST = 32.0
+YARN_BETA_SLOW = 1.0
+
+
+def yarn_scale(factor: float) -> float:
+    """What YaRN multiplies cosine and sine by: ``0.1 ln(factor) + 1`` (a
+    published ``attention_factor`` of 1.4852 for a factor of 128 is this)."""
+    return 0.1 * math.log(factor) + 1.0 if factor > 1.0 else 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    """Rotary positions as a published ``rope_parameters`` group states them,
+    where they are more than one ``theta`` over the whole head.
+
+    ``share`` (``partial_rotary_factor``): the first ``share * d`` channels
+    of a head rotate, the rest pass. ``yarn_factor`` above 1 (``rope_type``
+    ``yarn``): each of the rotated channels' frequencies is blended between
+    itself and itself over ``yarn_factor`` by a linear ramp over the
+    frequencies' indices, from the index that turns ``YARN_BETA_FAST`` times
+    in ``yarn_positions`` positions (rounded down; below it a frequency
+    stays) to the one that turns ``YARN_BETA_SLOW`` times (rounded up; above
+    it a frequency is divided whole), and cosine and sine are multiplied by
+    :func:`yarn_scale`."""
+
+    theta: float
+    share: float = 1.0
+    yarn_factor: float = 1.0
+    yarn_positions: int = 0
+
+    @property
+    def scale(self) -> float:
+        """What cosine and sine are multiplied by."""
+        return yarn_scale(self.yarn_factor)
+
+    def rotated(self, d: int) -> int:
+        """How many of a head's ``d`` channels rotate."""
+        return int(d * self.share)
+
+    def inv_freq(self, d: int) -> jax.Array:
+        """The ``rotated(d) / 2`` inverse frequencies."""
+        r = self.rotated(d)
+        index = jnp.arange(0, r, 2, dtype=jnp.float32)
+        inv_freq = 1.0 / self.theta ** (index / r)
+        if self.yarn_factor <= 1.0:
+            return inv_freq
+
+        def turns_at(turns: float) -> float:  # the index that turns so often
+            return r * math.log(self.yarn_positions / (turns * 2 * math.pi)) / (
+                2 * math.log(self.theta)
+            )
+
+        low = max(math.floor(turns_at(YARN_BETA_FAST)), 0)
+        high = min(math.ceil(turns_at(YARN_BETA_SLOW)), r - 1)
+        ramp = jnp.clip((index / 2 - low) / max(high - low, 1e-3), 0.0, 1.0)
+        return inv_freq / self.yarn_factor * ramp + inv_freq * (1.0 - ramp)
+
+
 def _rope_angles(pos: jax.Array, d: int, theta: float) -> jax.Array:
     """Rotate-half rotary's angles ``(T, d)`` at the positions ``pos``: the
     ``d / 2`` frequencies, once for each half of a head."""
@@ -860,10 +1074,22 @@ def _rope_angles(pos: jax.Array, d: int, theta: float) -> jax.Array:
     return jnp.concatenate([angles, angles], axis=-1)
 
 
-def rotary(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
+def rotary(x: jax.Array, pos: jax.Array, theta: float | Rope) -> jax.Array:
     """Rotate-half rotary positions on ``x`` ``(B, T, heads, d)`` at the
-    global positions ``pos`` ``(T,)``."""
+    global positions ``pos`` ``(T,)``: one ``theta`` over the whole head, or
+    what a :class:`Rope` says (a part of the head, frequencies scaled by
+    band, cosine and sine scaled)."""
     d = x.shape[-1]
+    if isinstance(theta, Rope):
+        r = theta.rotated(d)
+        angles = pos.astype(jnp.float32)[:, None] * theta.inv_freq(d)[None, :]
+        angles = jnp.concatenate([angles, angles], axis=-1)[None, :, None, :]
+        turned, x1, x2 = x[..., :r], x[..., : r // 2], x[..., r // 2: r]
+        rotated = jnp.concatenate([-x2, x1], axis=-1)
+        out = theta.scale * (
+            turned * jnp.cos(angles) + rotated * jnp.sin(angles)
+        )
+        return out if r == d else jnp.concatenate([out, x[..., r:]], axis=-1)
     angles = _rope_angles(pos, d, theta)[None, :, None, :]
     x1, x2 = x[..., : d // 2], x[..., d // 2:]
     rotated = jnp.concatenate([-x2, x1], axis=-1)
@@ -1062,6 +1288,7 @@ def attention(
     block_k: int | None = None,
     block_length: int = 1,
     bf16_dots: bool = False,
+    window: int | None = None,
 ) -> jax.Array:
     """Dispatch: ``'pallas'`` kernel on TPU-compatible shapes,
     ``'xla'`` blockwise scan otherwise; ``'auto'`` picks by the process
@@ -1101,9 +1328,9 @@ def attention(
     if impl == "pallas":
         return flash_attention(
             q, k, v, causal, block_q, block_k, False, _LANE, block_length,
-            bf16_dots,
+            bf16_dots, window,
         )
     return blockwise_attention(
         q, k, v, causal, block_k=128 if block_k is None else block_k,
-        block_length=block_length,
+        block_length=block_length, window=window,
     )

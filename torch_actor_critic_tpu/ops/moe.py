@@ -162,10 +162,12 @@ def route(
     bias: jax.Array | None = None, scale: float = 1.0, impl: str = "auto",
 ):
     """``(top_e, top_w)``: each token's ``top_k`` experts of all and their
-    weights. ``scoring="softmax"``: the largest probabilities, renormalised.
-    ``"sigmoid"``: scores ``s = sigmoid(u W_r)``; the chosen are the largest of
-    ``s + bias`` (the correction bias moves the choice alone), their weights
-    ``scale * s / (sum of the chosen s + 1e-20)``. The router's product runs
+    weights. ``scoring="softmax"``: the largest probabilities, renormalised,
+    times ``scale``. ``"sigmoid"``: scores ``s = sigmoid(u W_r)``; the chosen
+    are the largest of ``s + bias`` (the correction bias moves the choice
+    alone), their weights ``scale * s / (sum of the chosen s + 1e-20)``. A
+    softmax router's ``scale`` of 1 multiplies nothing (the program of a stack
+    that states none is the one it was). The router's product runs
     at ``highest`` precision: the choice is discrete, and a near-tie must flip
     only on what came in, never on this product's own rounding. The choice
     and the chosen scores are :func:`top_scores`'s (``impl``)."""
@@ -179,7 +181,8 @@ def route(
         p = jax.nn.sigmoid(logits)
     top_e, top_p = top_scores(p, bias, top_k, impl)
     if scoring == "softmax":
-        return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        top_w = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        return top_e, top_w if scale == 1.0 else scale * top_w
     return top_e, scale * top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
 
 

@@ -258,6 +258,14 @@ class DataParallelSAC:
         # the true gradient (each rank contributes its chunk's terms;
         # verified against the unsharded path in tests/test_parallel.py).
         self._sp_active = self.sp > 1 and hasattr(sac.actor_def, "attention_fn")
+        if self._sp_active and getattr(sac, "shared_trunk", False):
+            # The ring knows the causal mask alone: a block-causal or windowed
+            # layer under it would be handed another mask than its own.
+            raise ValueError(
+                f"a shared history trunk is not sharded over sp={self.sp}: ring "
+                "attention applies no block-causal or sliding-window mask "
+                "(models/sequence.py::TrunkSpec); use sp=1"
+            )
         if self._sp_active:
             from torch_actor_critic_tpu.parallel.context import (
                 make_ring_attention_fn,

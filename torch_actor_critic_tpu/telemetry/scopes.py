@@ -43,6 +43,13 @@ COLLECT_ENV = "tac/collect/env_step"
 # tac/critic and tac/actor: the innermost name is the one a reader gets.
 TRUNK_EMBED = "tac/trunk/embed"  # observation projection, final norm
 TRUNK_ATTENTION = "tac/trunk/attention"
+# A stack that mixes attention kinds names each sublayer's kind inside it
+# (norm, projections, rotary, kernels, output projection), and the per-head
+# output gate apart; a reader of tac/trunk/attention still sums them all.
+TRUNK_ATTENTION_FULL = "tac/trunk/attention/full"
+TRUNK_ATTENTION_SLIDING = "tac/trunk/attention/sliding"
+TRUNK_ATTENTION_GATE = "tac/trunk/attention/gate"
+TRUNK_DENSE_FFN = "tac/trunk/dense_ffn"  # a block's dense gated feed-forward and its norm
 TRUNK_MOE_ROUTE = "tac/trunk/moe/route"
 TRUNK_MOE_EXPERTS = "tac/trunk/moe/experts"
 TRUNK_MOE_PRODUCTS = "tac/trunk/moe/experts/products"  # the grouped products alone
@@ -58,7 +65,8 @@ TRUNK_SSM_GATE_NORM = "tac/trunk/ssm/gate_norm"
 SCOPES = (
     PUSH, SAMPLE, DECODE, CRITIC, ACTOR, ALPHA, OPTIMIZER, POLYAK,
     ALLREDUCE, COLLECT_ACT, COLLECT_ENV, TRUNK_EMBED, TRUNK_ATTENTION,
-    TRUNK_MOE_ROUTE, TRUNK_MOE_EXPERTS, TRUNK_MOE_PRODUCTS, TRUNK_MOE_PLAN,
+    TRUNK_ATTENTION_FULL, TRUNK_ATTENTION_SLIDING, TRUNK_ATTENTION_GATE,
+    TRUNK_DENSE_FFN, TRUNK_MOE_ROUTE, TRUNK_MOE_EXPERTS, TRUNK_MOE_PRODUCTS, TRUNK_MOE_PLAN,
     TRUNK_MOE_LATENT, TRUNK_MOE_SHARED, TRUNK_SSM_PROJ, TRUNK_SSM_CONV,
     TRUNK_SSM_SCAN, TRUNK_SSM_GATE_NORM,
 )

@@ -18,6 +18,18 @@ import json
 import typing as t
 
 
+# trunk_pattern's letters, one a layer (models/sequence.py builds each).
+TRUNK_LAYER_KINDS = {
+    "S": "an SDAR block (attention, then sparse experts)",
+    "M": "a Mamba-2 mixer",
+    "*": "an attention mixer",
+    "E": "an expert mixer",
+    "F": "full attention, then sparse experts",
+    "W": "sliding-window attention, then sparse experts",
+    "f": "full attention, then a dense feed-forward",
+    "w": "sliding-window attention, then a dense feed-forward",
+}
+
 @dataclasses.dataclass
 class SACConfig:
     # --- SAC hyperparameters (ref main.py:147-160) ---
@@ -108,10 +120,13 @@ class SACConfig:
     # one trunk in the actor and one in every critic, sized by seq_*.
     # Otherwise it is a published decoder stack as ONE trunk that the critic
     # loss trains, the actor reads through stop_gradient and the polyak
-    # target covers: trunk_pattern is its layers, one letter each ("S": an
-    # SDAR block, attention then sparse experts; "M": a Mamba-2 mixer; "*":
-    # an attention mixer; "E": an expert mixer), and trunk_* are its
-    # published widths and what this chip holds of each layer. trunk_block
+    # target covers: trunk_pattern is its layers, one letter each
+    # (TRUNK_LAYER_KINDS below: "S": an SDAR block, attention then sparse
+    # experts; "M": a Mamba-2 mixer; "*": an attention mixer; "E": an expert
+    # mixer; "F" / "W": full or sliding-window attention, then sparse
+    # experts; "f" / "w": the same with a dense gated feed-forward), and
+    # trunk_* are its published widths and what this chip holds of each
+    # layer (head counts, window and rotary by attention kind). trunk_block
     # "sdar_moe" is short for trunk_layers SDAR blocks: SDAR-30B-A3B's
     # decoder (RMSNorm, rotary positions, grouped-query block-causal
     # attention with per-head q/k norm, a sparse-expert feed-forward of
@@ -147,6 +162,26 @@ class SACConfig:
     trunk_ssm_state: int = 128
     trunk_ssm_conv: int = 4
     trunk_ssm_chunk: int = 128
+    # What a stack that mixes attention kinds takes (laguna: "F", "W", "f",
+    # "w" blocks): rotary without the per-head norm (trunk_qk_norm off); one
+    # sigmoid gate a head on attention's output; the dense feed-forward's
+    # width; softmax routing times trunk_routed_scale; and by attention
+    # kind: a sliding layer's window (the keys a query sees, its own among
+    # them), its held query heads (0: trunk_q_heads) and its theta over the
+    # whole head (0: trunk_rope_theta); a full layer's rotary over the first
+    # trunk_rope_share of the head, under YaRN where the factor is above 1
+    # (frequencies blended by band between themselves and themselves over
+    # the factor, from trunk_rope_yarn_positions and YaRN's two betas;
+    # cosine and sine times YaRN's 0.1 ln(factor) + 1).
+    trunk_qk_norm: bool = True
+    trunk_head_gate: bool = False
+    trunk_dense_width: int = 0
+    trunk_window: int = 0
+    trunk_window_q_heads: int = 0
+    trunk_window_rope_theta: float = 0.0
+    trunk_rope_share: float = 1.0
+    trunk_rope_yarn_factor: float = 1.0
+    trunk_rope_yarn_positions: int = 0
     trunk_q_hidden: int = 256  # width of the Q heads' hidden layer
     trunk_remat: int = 0  # the first n blocks are recomputed in the backward pass
     # compute_dtype float32 means the TPU's default precision for a float32
@@ -497,10 +532,13 @@ class SACConfig:
                 f"trunk_block must be 'transformer' or 'sdar_moe', got "
                 f"{self.trunk_block!r}"
             )
-        if set(self.trunk_pattern) - set("SM*E"):
+        unknown = sorted(set(self.trunk_pattern) - set(TRUNK_LAYER_KINDS))
+        if unknown:
             raise ValueError(
-                f"trunk_pattern={self.trunk_pattern!r} is one letter a layer of "
-                "'S', 'M', '*' and 'E'"
+                f"trunk_pattern={self.trunk_pattern!r} has {unknown}; it is one "
+                "letter a layer of " + "; ".join(
+                    f"{letter!r}: {what}" for letter, what in TRUNK_LAYER_KINDS.items()
+                )
             )
         if self.shared_trunk:
             lo, hi = self.trunk_experts_held
@@ -509,10 +547,35 @@ class SACConfig:
                     f"trunk_experts_held={self.trunk_experts_held} must be a "
                     f"range [lo, hi) inside the {self.trunk_experts} experts"
                 )
-            if self.trunk_q_heads % self.trunk_kv_heads:
+            if self.trunk_q_heads % self.trunk_kv_heads or (
+                self.trunk_window_q_heads % self.trunk_kv_heads
+            ):
                 raise ValueError(
-                    f"trunk_q_heads={self.trunk_q_heads} must be a multiple of "
+                    f"trunk_q_heads={self.trunk_q_heads} and trunk_window_q_heads="
+                    f"{self.trunk_window_q_heads} must be multiples of "
                     f"trunk_kv_heads={self.trunk_kv_heads}"
+                )
+            if set(self.trunk_pattern) & set("Ww") and self.trunk_window < 1:
+                raise ValueError(
+                    f"trunk_pattern={self.trunk_pattern!r} has a sliding-window "
+                    f"layer: trunk_window={self.trunk_window} must be the keys a "
+                    "query sees, at least 1"
+                )
+            if set(self.trunk_pattern) & set("fw") and self.trunk_dense_width < 1:
+                raise ValueError(
+                    f"trunk_pattern={self.trunk_pattern!r} has a dense feed-forward: "
+                    f"trunk_dense_width={self.trunk_dense_width} must be its width"
+                )
+            rotated = self.trunk_head_dim * self.trunk_rope_share
+            if rotated != int(rotated) or int(rotated) % 2 or not rotated or (
+                self.trunk_rope_yarn_factor > 1.0 and self.trunk_rope_yarn_positions < 1
+            ):
+                raise ValueError(
+                    f"trunk_rope_share={self.trunk_rope_share} of trunk_head_dim="
+                    f"{self.trunk_head_dim} must be an even number of channels, and "
+                    f"YaRN (trunk_rope_yarn_factor={self.trunk_rope_yarn_factor}) "
+                    "needs trunk_rope_yarn_positions, the positions it was "
+                    "stretched from"
                 )
             if self.trunk_router not in ("softmax", "sigmoid") or (
                 self.trunk_expert_form not in ("silu_gated", "relu2")
