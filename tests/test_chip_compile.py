@@ -213,12 +213,11 @@ def _flash_window(devices):
     ]
 
 
-def _trunk_burst(devices):
+def _compile_trunk_burst(devices):
     """The shared-trunk burst over the cell's ring of histories (8,192 rows
     of 1024 x 17, the trunk itself at a cut width so that this compiles in
-    seconds): the grouped products and the kernels lower under the burst's
-    ``vmap`` over its device axis, and no gather or scatter of the ring's
-    size is among its instructions."""
+    seconds), compiled for the described v5e: ``(configuration, rows,
+    history, compiled burst)``."""
     from torch_actor_critic_tpu.sac.trainer import build_models, make_learner
 
     rows, history = 8192, 1024
@@ -246,6 +245,14 @@ def _trunk_burst(devices):
     compiled = learner._build_burst(cfg.update_every, state, ring, chunk).lower(
         state, ring, chunk
     ).compile()
+    return cfg, rows, history, compiled
+
+
+def _trunk_burst(devices):
+    """The cut burst (:func:`_compile_trunk_burst`): the grouped products and
+    the kernels lower under the burst's ``vmap`` over its device axis, and no
+    gather or scatter of the ring's size is among its instructions."""
+    cfg, rows, history, compiled = _compile_trunk_burst(devices)
     text = compiled.as_text()
     assert "ragged-dot" in text and text.count("tpu_custom_call") >= 3
     # ISSUE 39: q's one pass ahead of the flash kernels is taken under the
@@ -287,6 +294,70 @@ def _trunk_burst(devices):
     # experts: the cell's; with 16 the rounds are XLA's), and no sort or mask
     _selection_is_a_pass(text, cfg.batch_size * history, cfg.trunk_experts_per_tok, 128)
     _plan_sorts_the_held_candidates(text, cfg.batch_size * history, cfg.trunk_experts_per_tok, 4)
+
+
+def _weight_gradient_fusions(hlo_text):
+    """``[(dimensions of a convolution's result, ones left out and sorted,
+    whether an instruction under the optimizer's scope shares its fusion)]``
+    for every convolution inside a fusion of an optimized program, a nested
+    fusion counted with the fusion that calls it."""
+    bodies = {
+        m.group(1): m.group(2) for m in re.finditer(
+            r"^(?:ENTRY )?%([\w.\-]+) \(.*?\{\n(.*?)^\}", hlo_text, re.S | re.M
+        )
+    }
+
+    def whole(name, seen):
+        body = bodies.get(name, "")
+        for callee in re.findall(r"calls=%([\w.\-]+)", body):
+            if callee not in seen:
+                seen.add(callee)
+                body += whole(callee, seen)
+        return body
+
+    found = []
+    for name, body in bodies.items():
+        if "fused_computation" in name:
+            continue
+        for callee in re.findall(r" fusion\(.*?calls=%([\w.\-]+)", body):
+            inside = whole(callee, {callee})
+            for dims in re.findall(r"= \w+\[([\d,]+)\]\S* convolution\(", inside):
+                shape = tuple(sorted(int(d) for d in dims.split(",") if d != "1"))
+                found.append((shape, scopes.OPTIMIZER in inside))
+    return found
+
+
+def _weight_gradients_stand_alone(hlo_text, taken, left):
+    """ISSUE 46: no fusion holds both a convolution whose result is a taken
+    kernel's shape and an instruction under ``tac/optimizer`` (the product is
+    XLA's plain fusion between its two barriers, Adam and polyak a pass of
+    their own), while a kernel the rule leaves still has Adam fused behind
+    its gradient (what the reading looks for is there to be found)."""
+    fusions = _weight_gradient_fusions(hlo_text)
+    taken, left = ({tuple(sorted(shape)) for shape in shapes} for shapes in (taken, left))
+    assert taken <= {shape for shape, _ in fusions}, (taken, fusions)
+    assert [f for f in fusions if f[0] in taken and f[1]] == []
+    assert [f for f in fusions if f[0] in left and f[1]], fusions
+
+
+def _trunk_burst_weight_gradients(devices):
+    """The cut burst with the shape rule lowered to its widths (``q_proj`` and
+    ``o_proj``, 256 x 1024, taken; ``k_proj`` and ``v_proj``, 256 x 256,
+    left): the mechanism engages in the compiled program, at 4.5 MB more of
+    the compiler's account of the step's peak."""
+    from torch_actor_critic_tpu.models import sequence
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(sequence, "OWN_WEIGHT_GRAD_MIN_ELEMENTS", 256 * 1024)
+        cfg, _, _, compiled = _compile_trunk_burst(devices)
+    hidden, d = cfg.trunk_hidden, cfg.trunk_head_dim
+    _weight_gradients_stand_alone(
+        compiled.as_text(),
+        taken=[(hidden, cfg.trunk_q_heads * d)], left=[(hidden, cfg.trunk_kv_heads * d)],
+    )
+    # 2,202,822,144 B with the two kernels' gradients and their operands
+    # written out (PR 46), against the cut burst's 2,198,294,528 without
+    assert compiled.memory_analysis().peak_memory_in_bytes < 2.2035e9
 
 
 def _selection_is_a_pass(hlo_text, tokens, top_k, experts):
@@ -387,6 +458,15 @@ def _hybrid_trunk_burst(devices):
     lo, hi = cfg.trunk_experts_held
     _plan_sorts_the_held_candidates(
         text, cfg.batch_size * cfg.history_len, cfg.trunk_experts_per_tok, hi - lo
+    )
+    # ISSUE 46, by the rule as it stands: the shared expert's and the
+    # state-space projections' weight gradients are products of their own;
+    # ``k_proj`` / ``v_proj`` (4096 x 128) keep Adam fused behind theirs
+    inner = cfg.trunk_ssm_heads * cfg.trunk_ssm_head_dim
+    _weight_gradients_stand_alone(
+        text,
+        taken=[(cfg.trunk_hidden, cfg.trunk_shared_expert_width), (inner, cfg.trunk_hidden)],
+        left=[(cfg.trunk_hidden, cfg.trunk_kv_heads * cfg.trunk_head_dim)],
     )
 
 
@@ -852,6 +932,7 @@ CASES = [
     pytest.param(_flash_grouped, (4,), id="flash-grouped-block4"),
     pytest.param(_flash_window, (), id="flash-window-512-of-4096"),
     pytest.param(_trunk_burst, (), id="trunk-burst-ring"),
+    pytest.param(_trunk_burst_weight_gradients, (), id="trunk-burst-own-weight-gradients"),
     pytest.param(_trunk_attention_passes, (), id="trunk-attention-passes"),
     pytest.param(_hybrid_trunk_burst, (), id="hybrid-trunk-burst-at-size"),
     pytest.param(

@@ -290,11 +290,71 @@ class HeadNormRope(nn.Module):
         return qk_norm_rope(y, weight, pos, self.theta, self.eps, self.impl)
 
 
+# A projection's kernel of this many elements or more has its weight gradient
+# computed as a product of its own (:class:`OwnWeightGrad`); under it the
+# barriers cost a write and a read of the operands and the gradient and buy
+# nothing. Set by one sweep on the chip in the three trunk cells (PERF.md
+# section 6, PR 46): halfway between the largest kernel that lost by it
+# (2,097,152 elements, a 4096 x 512 ``q_proj``) and the smallest that gained
+# (3,145,728, a 3072 x 1024 ``shared_up``).
+OWN_WEIGHT_GRAD_MIN_ELEMENTS = 5 << 19
+
+
+@jax.custom_vjp
+def _project(x: jax.Array, kernel: jax.Array) -> jax.Array:
+    """``x @ kernel`` over ``x``'s last axis, as ``nn.Dense`` makes it."""
+    return jax.lax.dot_general(x, kernel, (((x.ndim - 1,), (0,)), ((), ())))
+
+
+def _project_fwd(x, kernel):
+    return _project(x, kernel), (x, kernel)
+
+
+def _project_bwd(residuals, dy):
+    """The input's cotangent as ``nn.Dense``'s transpose makes it, left to
+    XLA; the kernel's gradient the same product of the same values (tokens
+    contracted, default precision) between two barriers: XLA computes
+    neither operand inside the product's tiles (the normed input, the gated
+    pre-activation's cotangent: elementwise producers it would recompute for
+    every tile that asks) and fuses no optimizer pass (Adam's moments, the
+    parameter, the polyak target) behind it."""
+    x, kernel = residuals
+    dx = jax.lax.dot_general(dy, kernel, (((dy.ndim - 1,), (1,)), ((), ())))
+    tokens = tuple(range(x.ndim - 1))
+    x, dy = jax.lax.optimization_barrier((x, dy))
+    dkernel = jax.lax.dot_general(x, dy, ((tokens, tokens), ((), ())))
+    return dx, jax.lax.optimization_barrier(dkernel)
+
+
+_project.defvjp(_project_fwd, _project_bwd)
+
+
+class OwnWeightGrad(nn.Module):
+    """``nn.Dense``'s product (its ``dot_general_cls``), whose backward pass
+    gives a kernel of :data:`OWN_WEIGHT_GRAD_MIN_ELEMENTS` or more its
+    gradient as a product of its own (:func:`_project_bwd`); a smaller kernel
+    keeps ``lax.dot_general`` and its transpose. Forward the two are the same
+    product. Sows the kernel's element count into the ``weight_grads``
+    collection under ``"own"`` or ``"xla"``, by which backward pass it got,
+    for whoever applies the trunk with that collection mutable (the name is
+    the collection's structure, so it outlives a block's ``nn.remat``, which
+    hands values back traced)."""
+
+    def __call__(self, x, kernel, dimension_numbers, precision=None):
+        own = kernel.size >= OWN_WEIGHT_GRAD_MIN_ELEMENTS
+        if not self.is_initializing():  # ``init`` hands back what it did: the parameters
+            self.sow("weight_grads", "own" if own else "xla", kernel.size)
+        if own:
+            return _project(x, kernel)
+        return jax.lax.dot_general(x, kernel, dimension_numbers, precision=precision)
+
+
 def _linear(features: int, dtype, name: str) -> nn.Dense:
     """A projection with no bias (the published layer has none anywhere)."""
     return nn.Dense(
         features, use_bias=False, kernel_init=torch_linear_kernel_init,
         dtype=dtype, param_dtype=jnp.float32, name=name,
+        dot_general_cls=OwnWeightGrad,
     )
 
 

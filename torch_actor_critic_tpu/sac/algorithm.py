@@ -24,12 +24,14 @@ optax transforms) — it is hashable setup, never traced state.
 from __future__ import annotations
 
 import functools
+import sys
 import typing as t
 
 import jax
 import jax.numpy as jnp
 import optax
 from flax import linen as nn
+from flax import traverse_util
 
 from torch_actor_critic_tpu.buffer.replay import (
     as_observations,
@@ -79,6 +81,12 @@ def dynamic_lr_step(
     updates, inner = core.update(grads, inner, params)
     updates = jax.tree_util.tree_map(lambda u: -lr * u, updates)
     return updates, (inner, *rest)
+
+
+@functools.cache
+def _say_once(line: str) -> None:
+    """A constant of a traced program, on standard error once a process."""
+    print(line, file=sys.stderr, flush=True)
 
 
 @jax.named_scope(scopes.ALLREDUCE)
@@ -202,9 +210,14 @@ class SAC:
         """One pass of the shared trunk: the last step's features, and the
         statistics of its layers that hold experts, in the stack's order:
         ``{"sizes": (layers, held experts), "choices": (layers, tokens,
-        experts a token)}``."""
+        experts a token)}``, and ``"weight_grads"``: how many of the stack's
+        dense projections have their kernel's gradient as a product of its
+        own and what share of the projections' parameters those kernels are,
+        as the projections said when this pass was traced
+        (:class:`models.sequence.OwnWeightGrad`)."""
         h, sown = self.critic_def.apply(
-            params, obs, method=self.critic_def.features, mutable=["moe_stats"]
+            params, obs, method=self.critic_def.features,
+            mutable=["moe_stats", "weight_grads"],
         )
         layers = sown["moe_stats"]["trunk"]
         names = sorted(layers, key=lambda n: int(n.rsplit("_", 1)[1]))
@@ -214,6 +227,18 @@ class SAC:
             k: jnp.stack([layer[k][0] for layer in sown_by])
             for k in ("sizes", "choices")
         }
+        sizes = {  # float32: a large stack's elements pass 2**31
+            path: jnp.float32(size) for path, (size,) in
+            traverse_util.flatten_dict(sown["weight_grads"]).items()
+        }
+        own = [size for path, size in sizes.items() if path[-1] == "own"]
+        _say_once(
+            "trunk: weight_grad_own_products %d of %d dense projections"
+            % (len(own), len(sizes))
+        )
+        stats["weight_grads"] = jnp.stack(
+            [jnp.float32(len(own)), sum(own, jnp.float32(0)) / sum(sizes.values())]
+        )
         return h, stats
 
     def _q_apply(self, params, h, action):
@@ -444,9 +469,16 @@ class SAC:
         """Counters of the expert layers, reduced here on the device: the
         assignments that landed on held experts in the online pass (the one
         the backward pass repeats) and in the target pass, summed over
-        layers, and the online pass's largest and mean tokens a held expert."""
+        layers, and the online pass's largest and mean tokens a held expert;
+        and how many of the stack's dense projections have their weight
+        gradient as a product of its own, and what share of those projections'
+        parameters that is (constants of the program, counted when it was
+        traced)."""
         sizes = stats["sizes"].astype(jnp.float32)
+        own_products, own_share = stats["weight_grads"]
         counters = {
+            "trunk/weight_grad_own_products": own_products,
+            "trunk/weight_grad_own_share": own_share,
             "trunk/held_assignments": jnp.sum(sizes),
             "trunk/held_assignments_target": jnp.sum(
                 stats_target["sizes"].astype(jnp.float32)
