@@ -8,12 +8,17 @@
 test:
 	python -m pytest tests/ -q
 
-# Iteration default: skips the @pytest.mark.slow tests (>30s each:
-# multi-process launches, long training loops, native ASan build) and
-# the composer wall-runner construction, and stops at the first failure.
+# Iteration default: skips the @pytest.mark.slow tests (multi-process
+# launches, real-environment and long training runs, the native ASan
+# build: pyproject.toml says what the marker means) and the composer
+# wall-runner construction, and stops at the first failure.
 # The run the driver makes and counts (ROADMAP.md "Tier-1 verify") is
-# this selection on six workers, one file to a worker:
+# this selection on six workers, one file to a worker, cut at 1,470 s:
 #   JAX_PLATFORMS=cpu python -m pytest tests/ -q -m "not slow" -n 6 --dist loadfile
+# A file is the unit of work there, so time a new test file alone
+# under that load before committing it; one over 240 s is split by
+# subject or shares its compiles (a test itself fails at 600 s:
+# tests/conftest.py).
 test-fast:
 	python -m pytest tests/ -q -x -m "not slow" --ignore=tests/test_wall_runner_env.py
 

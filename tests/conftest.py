@@ -37,8 +37,46 @@ assert jax.device_count() == 8, jax.devices()
 
 
 import contextlib  # noqa: E402
+import signal  # noqa: E402
+import threading  # noqa: E402
 
 import pytest  # noqa: E402
+
+# The longest test takes 165 s under the driver's load (ROADMAP.md, "Tier-1
+# verify"). One that waits on something that never comes costs itself and
+# these ten minutes, not the run's clock and every test behind it.
+TEST_LIMIT_SECONDS = 600
+
+
+@contextlib.contextmanager
+def limited():
+    """Fail what runs inside once ``TEST_LIMIT_SECONDS`` of wall-clock have
+    passed (``SIGALRM`` from the real-time timer: a wait in Python, a sleep,
+    a child's ``wait`` or a socket is interrupted, a call that never comes
+    back from native code is not). The timer is off and the handler there
+    was is back afterwards. Signals reach the main thread alone, so off it
+    this arms nothing; pytest-xdist runs a worker's tests on its main thread."""
+    if threading.current_thread() is not threading.main_thread():
+        yield
+        return
+    seconds = TEST_LIMIT_SECONDS
+
+    def too_long(signum, frame):
+        pytest.fail(f"still running at the limit of {seconds:g} s a test", pytrace=False)
+
+    previous = signal.signal(signal.SIGALRM, too_long)
+    signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+
+
+@pytest.fixture(autouse=True)
+def within_the_limit():
+    with limited():
+        yield
 
 
 @contextlib.contextmanager

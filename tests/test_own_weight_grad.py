@@ -154,15 +154,41 @@ def _trunk(family):
     return SequenceTrunk(spec=FAMILIES[family], attention_fn=sequence.xla_attention)
 
 
+def _built(family, obs):
+    """A family's tree from key 4 and its forward pass, each one compiled call.
+    Module constants and ``_linear`` are read when a call is traced, so a test
+    that patches one builds under its patch: a function of its own a call, for
+    ``jit`` to trace anew whatever it has seen of an equal module."""
+    trunk = _trunk(family)
+    params = jax.jit(lambda key, obs: trunk.init(key, obs))(jax.random.key(4), obs)["params"]
+    return params, jax.jit(lambda p, obs: trunk.apply({"params": p}, obs))(params, obs)
+
+
+@pytest.fixture(scope="module")
+def stacks():
+    """``stacks(family)``: the histories and the family's tree by the rule as
+    it stands, built once for the tests that read them."""
+    made = {}
+
+    def of(family):
+        if family not in made:
+            obs = jax.random.normal(jax.random.key(2), OBS.shape)
+            made[family] = (obs, _built(family, obs)[0])
+        return made[family]
+
+    return of
+
+
 @pytest.mark.parametrize("family", sorted(FAMILIES))
-def test_the_tree_is_the_one_nn_dense_builds_from_the_same_key(monkeypatch, every_kernel, family):
+def test_the_tree_is_the_one_nn_dense_builds_from_the_same_key(
+    monkeypatch, every_kernel, stacks, family
+):
     """Names, shapes, dtypes and every byte: a tree the parent saved loads,
     and gives the forward pass it gave."""
-    obs = jax.random.normal(jax.random.key(2), OBS.shape)
-    ours = _trunk(family).init(jax.random.key(4), obs)["params"]
-    out = _trunk(family).apply({"params": ours}, obs)
+    obs, _ = stacks(family)
+    ours, out = _built(family, obs)
     monkeypatch.setattr(sequence, "_linear", _plain_linear)
-    parents = _trunk(family).init(jax.random.key(4), obs)["params"]
+    parents, parents_out = _built(family, obs)
     ours_flat, parents_flat = (
         traverse_util.flatten_dict(tree, sep="/") for tree in (ours, parents)
     )
@@ -170,16 +196,16 @@ def test_the_tree_is_the_one_nn_dense_builds_from_the_same_key(monkeypatch, ever
     for name, leaf in ours_flat.items():
         assert (leaf.shape, leaf.dtype) == (parents_flat[name].shape, parents_flat[name].dtype)
         assert np.asarray(leaf).tobytes() == np.asarray(parents_flat[name]).tobytes(), name
-    np.testing.assert_array_equal(_trunk(family).apply({"params": parents}, obs), out)
+    np.testing.assert_array_equal(parents_out, out)
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
-def test_a_stack_trains_as_it_did_with_every_weight_gradient_its_own_product(monkeypatch, family):
+def test_a_stack_trains_as_it_did_with_every_weight_gradient_its_own_product(
+    monkeypatch, stacks, family
+):
     """The whole stack's gradient, blocks recomputed, with every projection
     taken against none: the same products of the same values."""
-    obs = jax.random.normal(jax.random.key(2), OBS.shape)
-    trunk = _trunk(family)
-    params = trunk.init(jax.random.key(4), obs)["params"]
+    trunk, (obs, params) = _trunk(family), stacks(family)
     grad = lambda: jax.jit(jax.grad(  # noqa: E731
         lambda p: jnp.sum(trunk.apply({"params": p}, obs) ** 2)
     ))(params)

@@ -160,7 +160,7 @@ def test_trunk_one_pass_is_the_composition(kernels_here, monkeypatch, how, heads
     params = _seeded(trunk, x)
 
     def run(passes):
-        def loss(p, x):  # traced anew on every run
+        def loss(p, x):  # traced anew on every run, and compiled as one program
             return jnp.sum(trunk.apply(p, x) ** 2)
 
         names = _pallas_names(jax.make_jaxpr(jax.grad(loss))(params, x).jaxpr)
@@ -168,9 +168,9 @@ def test_trunk_one_pass_is_the_composition(kernels_here, monkeypatch, how, heads
         assert None in names
         if how == "vmap":
             stack = lambda a: jnp.stack([a, 1.5 * a])  # noqa: E731
-            fn = jax.vmap(jax.value_and_grad(loss))
+            fn = jax.jit(jax.vmap(jax.value_and_grad(loss)))
             return fn(jax.tree_util.tree_map(stack, params), stack(x))
-        return jax.value_and_grad(loss)(params, x)
+        return jax.jit(jax.value_and_grad(loss))(params, x)
 
     got = run(True)
     monkeypatch.setattr(attention_ops, "_pass_fits", lambda *a, **kw: False)

@@ -72,6 +72,38 @@ def test_children_resolve_the_same_cache_directory(tmp_path):
         assert out.stdout.strip().splitlines()[-1] == want
 
 
+# ------------------------------------------------------- a test that waits
+
+
+def test_a_test_that_waits_fails_by_itself_at_the_limit(monkeypatch):
+    """``conftest.py`` arms ``limited`` around every test: a call that
+    sleeps past the limit fails with the limit in its message, and
+    afterwards no timer is armed and ``SIGALRM`` has the handler it had."""
+    import signal
+    import time
+
+    import conftest
+
+    def mine(signum, frame):  # what this test finds in place again
+        raise AssertionError("the limit's handler is gone and its timer is not")
+
+    monkeypatch.setattr(conftest, "TEST_LIMIT_SECONDS", 0.2)
+    before = signal.signal(signal.SIGALRM, mine)  # the suite's own, armed for this test
+    try:
+        started = time.monotonic()
+        with pytest.raises(pytest.fail.Exception, match=r"limit of 0\.2 s"):
+            with conftest.limited():
+                time.sleep(30)
+        assert time.monotonic() - started < 5
+        assert signal.getitimer(signal.ITIMER_REAL) == (0.0, 0.0)
+        assert signal.getsignal(signal.SIGALRM) is mine
+        with conftest.limited():  # inside the limit: nothing fires, nothing is left
+            pass
+        assert signal.getitimer(signal.ITIMER_REAL) == (0.0, 0.0)
+    finally:
+        signal.signal(signal.SIGALRM, before)
+
+
 # ------------------------------------------------- one process for each chip
 
 
