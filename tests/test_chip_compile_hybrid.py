@@ -29,6 +29,15 @@ from torch_actor_critic_tpu.ops import moe
 from torch_actor_critic_tpu.ops.attention import flash_attention
 from torch_actor_critic_tpu.parallel import DataParallelSAC, make_mesh
 
+def _benchmark_on_path():
+    """``benchmark`` is a package of the repo's root, not of ``tests/``."""
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+
+
 def _flash_grouped(devices, block_length):
     """The three flash kernels at the SDAR trunk's shapes: 32 query heads
     over 4 shared key/value heads of 128 (read through the index maps),
@@ -51,9 +60,9 @@ def _flash_grouped(devices, block_length):
 def _flash_window(devices):
     """The three flash kernels at a sliding layer's shapes in
     ``laguna_s21_trunk_burst``: 18 query heads over 2 shared key/value heads
-    of 128, histories of 4,096, a window of 512: the grids run over the two
-    key blocks (three query blocks, for dK/dV) a row of 512-wide blocks can
-    see where the causal sweep runs over eight, and the forward kernel keeps
+    of 128, histories of 4,096, a window of 512: the grids run over the 15
+    tile pairs a head's schedule holds (its tables go in by scalar prefetch)
+    where the causal one holds 36, and the forward kernel keeps
     the kind the benchmark's flash readers find it by (inside the cell's
     burst all 25 kernels of the five layers read so: the sandbox compile at
     size, PERF.md section 4)."""
@@ -75,15 +84,36 @@ def _flash_window(devices):
     ]
 
 
+def _tiles_by_rule(devices):
+    """The tile each trunk cell's attention layers get where the caller names
+    none: 512, the largest that divides the history, under every mask
+    (``_AUTO_BLOCK_CAP`` has the chip's readings of 256 and 128 under a
+    window and under the block-causal mask: each lost)."""
+    _benchmark_on_path()
+    from benchmark.harness import registry
+    from torch_actor_critic_tpu.ops.attention import _check_blocks, visited_key_blocks
+
+    got = {}
+    for name in ("sdar30b_a3b_trunk", "nemotron3_super_trunk", "laguna_s21_trunk"):
+        model = registry.load_config(name)["model"]
+        t, b = model["history_len"], model.get("block_length", 1)
+        assert _check_blocks(t, t, None, None) == (512, 512), name
+        for window in {None, model.get("window")}:
+            got[name, window] = visited_key_blocks(t, block_length=b, window=window)
+    # the tile pairs of a head's sweep, where the rectangular grids had 4, 4, 64 and 16 steps
+    assert got == {
+        ("sdar30b_a3b_trunk", None): 3,
+        ("nemotron3_super_trunk", None): 3,
+        ("laguna_s21_trunk", None): 36,
+        ("laguna_s21_trunk", 512): 15,
+    }
+
+
 def _compile_hybrid_trunk_burst(devices):
     """The ``nemotron_h`` trunk's burst as the benchmark's cell builds it, at
     the cell's own sizes, compiled for the described v5e: ``(cell's
     configuration, abstract state, compiled burst)``."""
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if root not in sys.path:
-        sys.path.insert(0, root)
+    _benchmark_on_path()
     from benchmark.drivers import trunkburst
     from benchmark.harness import registry, spans
     from torch_actor_critic_tpu.sac.trainer import build_models, make_learner
@@ -158,6 +188,7 @@ CASES = [
     pytest.param(_flash_grouped, (1,), id="flash-grouped-causal"),
     pytest.param(_flash_grouped, (4,), id="flash-grouped-block4"),
     pytest.param(_flash_window, (), id="flash-window-512-of-4096"),
+    pytest.param(_tiles_by_rule, (), id="flash-tiles-by-rule"),
     pytest.param(_hybrid_trunk_burst, (), id="hybrid-trunk-burst-at-size"),
 ]
 

@@ -130,18 +130,14 @@ def _blocks_with_a_visible_pair(t, block_q, block_k, block_length, window):
 def test_the_kernels_visit_the_blocks_with_a_visible_pair_and_no_other(
     t, block_q, block_k, block_length, window,
 ):
-    """The spans the grids are built from, against a brute-force count over
-    the mask: every block with a visible pair is inside a span, and a span
-    holds no block without one (both edges are skipped)."""
+    """The schedules the grids are built from, against a brute-force count
+    over the mask: every block with a visible pair is a step, and no block
+    without one is (both edges are skipped)."""
     visited = ops.visited_key_blocks(t, block_q, block_k, block_length, window)
     assert visited == _blocks_with_a_visible_pair(t, block_q, block_k, block_length, window)
-    if window is not None:  # the dK/dV sweep's spans count the same pairs
-        n_qb = t // block_q
-        spans = [
-            ops._q_block_span(jk, block_q, block_k, block_length, window, n_qb)
-            for jk in range(t // block_k)
-        ]
-        assert sum(last - first + 1 for first, last in spans) == visited
+    needed = ops._pair_needed(t, t, block_q, block_k, True, block_length, window)
+    for group in (None, 1):  # the forward and dQ sweep, and the dK/dV sweep: the same pairs
+        assert len(ops._tile_schedule(needed, group)[0]) == visited
 
 
 def test_a_window_of_512_at_4096_visits_under_half_of_the_causal_blocks():

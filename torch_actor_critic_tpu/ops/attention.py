@@ -16,11 +16,13 @@ Three implementations of the same math, one contract:
   over K/V blocks via ``lax.scan``: O(block) memory, differentiable,
   runs on any backend. This is the training-path default.
 - :func:`flash_attention` — a Pallas TPU kernel of the same loop:
-  grid ``(batch·heads, q-blocks, k-blocks)`` so VMEM only ever holds
+  grid ``(batch·heads, tile pairs)`` so VMEM only ever holds
   one ``(block, head_dim)`` tile of each operand (long sequences
   stream from HBM through the BlockSpec pipeline), MXU matmuls with
-  f32 accumulators in VMEM scratch. Wrapped in a ``custom_vjp`` whose
-  backward is *also* Pallas (FlashAttention-2 style: forward saves the
+  f32 accumulators in VMEM scratch. The pairs are a schedule built from the
+  mask's geometry (:func:`_pair_needed`, :func:`_tile_schedule`): a pair
+  with nothing visible is no step of the grid and is never fetched. Wrapped
+  in a ``custom_vjp`` whose backward is *also* Pallas (FlashAttention-2 style: forward saves the
   per-row logsumexp; dQ and dK/dV kernels recompute probability tiles
   from it), so training gets the kernel in both directions. Head dims
   are zero-padded to the 128-lane width transparently. The residual of the
@@ -53,15 +55,15 @@ causal mask to *block-causal* (position ``i`` sees ``j`` iff
 ``j // b <= i // b``: causal across blocks of ``b``, full inside one;
 ``b = 1`` is the causal mask, by the same code as before); ``window`` ``w``
 narrows it to a *sliding window* (``i`` sees ``j`` only if ``j > i - w``: the
-``w`` latest positions, its own among them; ``None`` is no window, and the
-kernels then lower as they did before there was one), the kernels visiting
-only the key blocks a row of query blocks can see, at both edges
-(:func:`_k_block_span`: a block outside is neither computed nor fetched); and
+``w`` latest positions, its own among them; ``None`` is no window), the
+kernels visiting only the key blocks a row of query blocks can see, at both
+edges (the schedule again: a block outside is neither computed nor fetched); and
 grouped heads: ``k``/``v`` may carry ``heads // group`` heads, query head ``i``
 then reads key/value head ``i // group``. The XLA paths repeat ``k`` and
 ``v``; the kernels read the shared head through their index maps, so no
-repeated copy is ever written (the dK/dV kernel sweeps the group's query
-heads in its innermost grid axis and sums them in VMEM).
+repeated copy is ever written (the dK/dV kernel's schedule runs a key
+block's query blocks once for each query head of the group, and the sum stays
+in VMEM).
 """
 
 from __future__ import annotations
@@ -73,6 +75,7 @@ import typing as t
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG_INF = float("-inf")
 
@@ -250,7 +253,7 @@ def blockwise_attention(
 _LANE = 128  # TPU lane width: last tile dim, and scratch column count
 
 
-def _acc_dot(a: jax.Array, b: jax.Array, dims, mxu_dtype=None) -> jax.Array:
+def _acc_dot(a: jax.Array, b: jax.Array, dims) -> jax.Array:
     """``dot_general`` with f32 accumulation on MXU-native operands.
 
     Operands keep their storage dtype (bf16 stays bf16 — the MXU's fast
@@ -261,13 +264,12 @@ def _acc_dot(a: jax.Array, b: jax.Array, dims, mxu_dtype=None) -> jax.Array:
     TPU scheme; bf16 probabilities are inside the softmax's own error
     budget. f32-in/f32-out math is bit-identical to a plain f32 dot.
 
-    ``mxu_dtype`` (the kernels' ``bf16_dots``) rounds both operands to it
-    first: float32 tiles in HBM and VMEM, one bfloat16 pass on the MXU
-    with float32 accumulation, which is what XLA's default precision makes
-    of a float32 product outside the kernels.
+    Under the kernels' ``bf16_dots`` the operands that come from HBM reach
+    here rounded to bfloat16 (:func:`_mxu`), and the rule above rounds the
+    intermediates to match: float32 tiles in HBM and VMEM, one bfloat16 pass
+    on the MXU with float32 accumulation, which is what XLA's default
+    precision makes of a float32 product outside the kernels.
     """
-    if mxu_dtype is not None:
-        a, b = a.astype(mxu_dtype), b.astype(mxu_dtype)
     if a.dtype != b.dtype:
         if a.dtype == jnp.float32:
             a = a.astype(b.dtype)
@@ -278,52 +280,83 @@ def _acc_dot(a: jax.Array, b: jax.Array, dims, mxu_dtype=None) -> jax.Array:
     )
 
 
-def _k_block_needed(iq, j, block_q: int, block_k: int, block_length: int):
-    """Whether k block ``j`` holds a key some row of q block ``iq`` sees:
-    the block's first key lies at or before the last key visible to the q
-    block's last row. Blocks past it are skipped whole; with
-    ``block_length`` 1 this is the causal diagonal test."""
-    if block_length == 1:
-        return j * block_k <= (iq + 1) * block_q - 1
-    last_row = (iq + 1) * block_q - 1
-    return j * block_k < (last_row // block_length + 1) * block_length
+# What stands beside a step in a schedule's ``kinds``: a row's first and last.
+_FIRST, _LAST = 1, 2
 
 
-def _at_least_0(x):
-    return max(x, 0) if isinstance(x, int) else jnp.maximum(x, 0)
+def _pair_needed(
+    tq: int, tk: int, block_q: int, block_k: int, causal: bool,
+    block_length: int = 1, window: int | None = None,
+) -> list:
+    """``[q block][k block]``: whether some query of the q block sees some key
+    of the k block, queries and keys at the positions ``0..``. From the static
+    sizes alone, in plain Python at trace time: both edges of the mask are
+    monotone in the query and in the key, so a tile's corners decide it. The
+    last key a query ``r`` sees is ``(r // b + 1) * b - 1``, the first
+    ``r - window + 1``."""
+
+    def pair(iq, jk):
+        if not causal:
+            return True
+        first_row, first_key = iq * block_q, jk * block_k
+        last_row, last_key = first_row + block_q - 1, first_key + block_k - 1
+        some = first_key <= (last_row // block_length + 1) * block_length - 1
+        return some and (window is None or last_key > first_row - window)
+
+    return [[pair(iq, jk) for jk in range(tk // block_k)] for iq in range(tq // block_q)]
 
 
-def _at_most(x, top: int):
-    return min(x, top) if isinstance(x, int) else jnp.minimum(x, top)
+# The most steps a sweep's schedule may hold: its ``int32`` tables (three, and
+# a fourth in the dK/dV sweep) rest in the core's scalar memory for the whole
+# call, 1 MiB on a v5e. Asked in the sandbox for the described chip, the
+# compiler took the three kernels at 32,760 steps (512 KiB of tables in the
+# dK/dV sweep) and refused them at 66,048 (PERF.md section 6, PR 48). A causal
+# sweep of 512-wide tiles stays inside 32,768 steps up to histories of 130,560
+# (the forward and dQ sweeps) or, with eight query heads to a key head, 46,080
+# (dK/dV).
+_SCHEDULE_STEPS_MAX = 1 << 15
 
 
-def _k_block_span(iq, block_q: int, block_k: int, block_length: int, window: int):
-    """``(first, last)``: the k blocks that hold a key some row of q block
-    ``iq`` sees under a sliding ``window`` (queries and keys at the same
-    positions ``0..T-1``). The last is the causal bound of
-    :func:`_k_block_needed`; the first holds the earliest key of the q
-    block's first row, ``iq * block_q - window + 1``. The kernels' grids and
-    index maps run over this span and no further: Python integers in, Python
-    integers out (the grid's extent, :func:`visited_key_blocks`); a program
-    id in, traced values out."""
-    last_row = (iq + 1) * block_q - 1
-    last_key = last_row
-    if block_length != 1:
-        last_key = (last_row // block_length + 1) * block_length - 1
-    return _at_least_0(iq * block_q - window + 1) // block_k, last_key // block_k
+def _tile_schedule(needed, group: int | None = None) -> tuple:
+    """A sweep's steps over the pairs ``needed`` holds, rows in order, as
+    ``int32`` tables with one entry a step: ``(rows, cols, kinds)`` for the
+    forward and dQ sweeps (``group`` ``None``), which hold a q block
+    (``rows``) and run over its k blocks (``cols``); ``(rows, cols, kinds,
+    heads)`` for the dK/dV sweep, which holds a k block and runs over its q
+    blocks once for each of the group's ``group`` query heads (``heads``) in
+    turn. ``kinds`` flags a row's first and last step. A pair the mask
+    empties is no step, so it is neither computed nor fetched. A row with no
+    pair at all (a dK/dV row of keys past the last query: a query always sees
+    a key) still has to write its zeros: it is one step on its first pair,
+    which the mask hides whole, so the step adds nothing."""
+    by_row = needed if group is None else zip(*needed)
+    steps = []
+    for row, row_needed in enumerate(by_row):
+        pairs = [
+            [row, col, 0, head]
+            for head in range(group or 1)
+            for col, seen in enumerate(row_needed) if seen
+        ] or [[row, 0, 0, 0]]
+        pairs[0][2] |= _FIRST
+        pairs[-1][2] |= _LAST
+        steps += pairs
+    if len(steps) > _SCHEDULE_STEPS_MAX:
+        raise ValueError(
+            f"flash_attention: a sweep of {len(steps)} tile pairs is more than "
+            f"the {_SCHEDULE_STEPS_MAX} whose schedule the kernels hold in "
+            "scalar memory; use attention(impl='xla') or blockwise_attention "
+            "for histories this long."
+        )
+    tables = tuple(np.asarray(steps, np.int32).T)
+    return tables if group is not None else tables[:3]
 
 
-def _q_block_span(
-    jk, block_q: int, block_k: int, block_length: int, window: int, n_qb: int
-):
-    """The same span the other way, for the dK/dV sweep: ``(first, last)`` q
-    block with a row that sees a key of k block ``jk``. Key ``j`` is seen
-    from the first row of its own mask block up to row ``j + window - 1``."""
-    first_row = jk * block_k
-    if block_length != 1:
-        first_row = (first_row // block_length) * block_length
-    last_row = (jk + 1) * block_k - 1 + window - 1
-    return first_row // block_q, _at_most(last_row // block_q, n_qb - 1)
+def _check_window(tq: int, tk: int, causal: bool, window: int | None) -> None:
+    if window is not None and (not causal or tq != tk):
+        raise ValueError(
+            "flash_attention: a window is a causal mask over queries and keys "
+            f"at the same positions, got causal={causal}, Tq={tq}, Tk={tk}"
+        )
 
 
 def visited_key_blocks(
@@ -332,114 +365,83 @@ def visited_key_blocks(
 ) -> int:
     """(q block, k block) pairs the forward and dQ kernels compute for
     histories of ``t`` (blocks left out: the kernels' own choice for ``t``):
-    the blocks of each q block's span under a window (the grid is built from
-    the same spans), the blocks up to the causal bound without one."""
+    the pairs the kernels' schedule is built from."""
     block_q, block_k = _check_blocks(t, t, block_q, block_k)
-    visited = 0
-    for iq in range(t // block_q):
-        first, last = _k_block_span(iq, block_q, block_k, block_length, window or t)
-        visited += min(last, t // block_k - 1) - first + 1
-    return visited
+    return sum(map(sum, _pair_needed(t, t, block_q, block_k, True, block_length, window)))
 
 
-def _span_steps(span, n_blocks: int) -> int:
-    """The longest span over the ``n_blocks`` blocks of the grid's outer
-    axis: the extent of the sweep."""
-    return max(last - first + 1 for first, last in map(span, range(n_blocks)))
+def _mxu(x: jax.Array, mxu_dtype) -> jax.Array:
+    return x if mxu_dtype is None else x.astype(mxu_dtype)
 
 
-def _k_step(iq, step, causal, block_q, block_k, block_length, window):
-    """The k block that step ``step`` of q block ``iq``'s sweep reads, and
-    whether it holds anything for the q block."""
-    if window is None:
-        needed = True if not causal else _k_block_needed(
-            iq, step, block_q, block_k, block_length
-        )
-        return step, needed
-    first, last = _k_block_span(iq, block_q, block_k, block_length, window)
-    return first + step, first + step <= last
+def _masked(scores, iq, jk, block_q, block_k, block_length, window, keys_first=False):
+    """A tile's scores with the pairs the mask hides at ``-inf``: queries down
+    the rows and keys along the lanes, or, ``keys_first``, the tile the other
+    way round. Every tile of a causal call pays it, the ones the mask leaves
+    whole too: once compiled it is a compare and a select a score vreg, and
+    leaving it off whole tiles read level on the chip (PERF.md section 6, PR
+    48)."""
+    q_axis, k_axis = (1, 0) if keys_first else (0, 1)
+    q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, scores.shape, q_axis)
+    k_pos = jk * block_k + jax.lax.broadcasted_iota(jnp.int32, scores.shape, k_axis)
+    return jnp.where(_visible(q_pos, k_pos, block_length, window), scores, NEG_INF)
 
 
 def _flash_kernel(
-    q_ref, k_ref, v_ref, o_ref, *rest,
+    rows_ref, cols_ref, kinds_ref, q_ref, k_ref, v_ref, o_ref, *rest,
     block_q: int, block_k: int, scale: float, causal: bool,
     save_lse: bool = False, block_length: int = 1, mxu_dtype=None,
     window: int | None = None,
 ):
-    """One ``(batch·head, q-block, k-block)`` program.
+    """One ``(batch·head, step)`` program of the forward sweep.
 
-    The k-block grid dimension is innermost, so for a fixed q block the
-    programs run j = 0..nk-1 in order, carrying the online-softmax state
-    in VMEM scratch (``m``/``l`` use column 0 of a (block_q, LANE)
-    tile); the final k step normalizes into ``o_ref``. Same update math
-    as :func:`online_block_update`. Under a ``window`` the sweep is the q
-    block's span of k blocks (:func:`_k_block_span`), step ``s`` reading
-    block ``first + s``; a q block with a shorter span than the grid's
-    extent skips the steps past its last block (the index map holds the
-    block it has, so nothing is fetched for them).
+    The steps are the schedule's (:func:`_tile_schedule`): for a fixed q block
+    its non-empty k blocks in order, carrying the online-softmax state in VMEM
+    scratch (``m``/``l`` use column 0 of a (block_q, LANE) tile); the row's
+    first step resets it, the last normalizes into ``o_ref``. Same update math
+    as :func:`online_block_update`.
     """
     from jax.experimental import pallas as pl  # deferred: TPU-only path
 
     if save_lse:
-        lse_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        lse_ref = None
-        m_ref, l_ref, acc_ref = rest
+        lse_ref, *rest = rest
+    m_ref, l_ref, acc_ref = rest
+    step = pl.program_id(1)
+    kind = kinds_ref[step]
+    iq, jk = rows_ref[step], cols_ref[step]
 
-    iq = pl.program_id(1)
-    step = pl.program_id(2)
-    n_steps = pl.num_programs(2)
-
-    @pl.when(step == 0)
+    @pl.when((kind & _FIRST) != 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # Under causality, K blocks strictly past this q block's diagonal
-    # contribute nothing; skip their compute entirely.
-    j, needed = _k_step(iq, step, causal, block_q, block_k, block_length, window)
+    q = _mxu(q_ref[0], mxu_dtype)
+    k_blk = _mxu(k_ref[0], mxu_dtype)
+    v_blk = _mxu(v_ref[0], mxu_dtype)
+    scores = _acc_dot(q, k_blk, ((1,), (1,))) * scale
+    if causal:
+        scores = _masked(scores, iq, jk, block_q, block_k, block_length, window)
+    m = m_ref[:, 0]
+    m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
+    # No isneginf guards in-kernel (unlike online_block_update, whose
+    # ring-attention callers CAN see fully-masked rows): without a window a
+    # row's sweep starts at k block 0, where every row sees key 0, so m_new
+    # is finite from the first step on. Masked scores are -inf ->
+    # exp(-inf - finite) = 0, and the first step's alpha =
+    # exp(-inf - finite) = 0 wipes the zero-init state.
+    # Under a window that does not hold: the first block of a row may lie
+    # wholly before the window of the q block's later rows, whose m_new is
+    # then still -inf. One select a row (not a tile) keeps exp(-inf - -inf)
+    # out: such a row adds nothing and stays at zero.
+    m_safe = m_new if window is None else jnp.where(m_new == NEG_INF, 0.0, m_new)
+    p = jnp.exp(scores - m_safe[:, None])
+    alpha = jnp.exp(m - m_safe)
+    l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=-1)
+    acc_ref[:] = acc_ref[:] * alpha[:, None] + _acc_dot(p, v_blk, ((1,), (0,)))
+    m_ref[:, 0] = m_new
 
-    @pl.when(needed)
-    def _update():
-        q = q_ref[0]
-        k_blk = k_ref[0]
-        v_blk = v_ref[0]
-        scores = _acc_dot(q, k_blk, ((1,), (1,)), mxu_dtype) * scale
-        if causal:
-            q_pos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            scores = jnp.where(
-                _visible(q_pos, k_pos, block_length, window), scores, NEG_INF
-            )
-        m = m_ref[:, 0]
-        m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
-        # No isneginf guards in-kernel (unlike online_block_update,
-        # whose ring-attention callers CAN see fully-masked rows): the
-        # causal k-skip still runs j=0, where every row sees key 0, so
-        # m_new is finite from the first visited block on. Masked
-        # scores are -inf -> exp(-inf - finite) = 0, and the j=0
-        # alpha = exp(-inf - finite) = 0 wipes the zero-init state.
-        # The softmax tail is VPU-bound; each removed elementwise pass
-        # over the (block_q, block_k) tile is measurable throughput.
-        # Under a window that does not hold: the first block of a span may
-        # lie wholly before the window of the q block's later rows, whose
-        # m_new is then still -inf. One select a row (not a tile) keeps
-        # exp(-inf - -inf) out: such a row adds nothing and stays at zero.
-        m_safe = m_new if window is None else jnp.where(m_new == NEG_INF, 0.0, m_new)
-        p = jnp.exp(scores - m_safe[:, None])
-        alpha = jnp.exp(m - m_safe)
-        l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=-1)
-        acc_ref[:] = acc_ref[:] * alpha[:, None] + _acc_dot(
-            p, v_blk, ((1,), (0,)), mxu_dtype
-        )
-        m_ref[:, 0] = m_new
-
-    @pl.when(step == n_steps - 1)
+    @pl.when((kind & _LAST) != 0)
     def _finalize():
         l = l_ref[:, 0]
         o_ref[0] = (
@@ -502,9 +504,16 @@ def _pad_head_dim(
 
 # Auto block-size cap: auto picks the largest block in
 # {128, 256, 512} that tiles the sequence (fewer, larger tiles: less
-# grid overhead and K/V re-reading). The one reading of these kernels
-# on record is PERF.md section 5, trunk.flash_roofline, at 512-wide
-# tiles; no ledger line compares block sizes (ROADMAP C5).
+# grid overhead and K/V re-reading), whatever the mask. Read on the chip
+# inside the trunk cells' whole bursts (PERF.md section 6, PR 48; ms a
+# window of ten steps, empty pairs already out of the grid): a window of
+# 512 over histories of 4,096 at 512 / 256 / 128-wide tiles 2,789 / 2,891 /
+# 3,088 (a sliding layer's kernels 0.76-1.21 ms a call at 512, 1.07-1.92 at
+# 256, though 256 computes a quarter fewer score elements); the block-causal
+# mask of 4 over 1,024 at 512 / 256 / 128: 1,813 / 2,020 / 2,456; the causal
+# mask over 4,096 at 512 / 256: 2,789 / 3,056. A step of the grid costs more
+# than the scores a smaller tile leaves out, so the tile is no function of
+# the mask.
 _AUTO_BLOCK_CAP = 512
 
 
@@ -536,28 +545,24 @@ def _check_blocks(tq: int, tk: int, block_q: int | None, block_k: int | None):
     return block_q, block_k
 
 
-def _k_sweep(tq, tk, causal, block_q, block_k, block_length, window):
-    """The k sweep of the forward and dQ kernels: its extent (the grid's
-    innermost axis) and the k block that step ``j`` of q block ``iq`` reads.
-    No window: every k block in turn, as ever. A window: the longest span
-    over the q blocks, a step past a shorter span's end holding that span's
-    last block (a block index that does not change is not fetched again)."""
-    if window is None:
-        return tk // block_k, lambda iq, j: j
-    if not causal or tq != tk:
-        raise ValueError(
-            "flash_attention: a window is a causal mask over queries and keys "
-            f"at the same positions, got causal={causal}, Tq={tq}, Tk={tk}"
-        )
+def _vmem_spec(block_shape, index_map):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    def span(iq):
-        return _k_block_span(iq, block_q, block_k, block_length, window)
+    return pl.BlockSpec(block_shape, index_map, memory_space=pltpu.VMEM)
 
-    def k_block(iq, j):
-        first, last = span(iq)
-        return jnp.minimum(first + j, last)
 
-    return _span_steps(span, tq // block_q), k_block
+def _q_held_specs(block_q: int, block_k: int, dp: int, group: int):
+    """``(q tile, k/v tile, a q block's row statistics)``: a step's blocks in
+    the forward and dQ sweeps, read from the schedule. A block on the held
+    axis changes only when the row does, so an output there is written back
+    once a row, whole. Row bh = batch * h + head of q reads row bh // group
+    of k/v: batch * hkv + head // group, the shared head."""
+    return (
+        _vmem_spec((1, block_q, dp), lambda bh, s, rows, *_: (bh, rows[s], 0)),
+        _vmem_spec((1, block_k, dp), lambda bh, s, rows, cols, _: (bh // group, cols[s], 0)),
+        _vmem_spec((1, 1, block_q), lambda bh, s, rows, *_: (bh, 0, rows[s])),
+    )
 
 
 def _flash_forward(
@@ -593,8 +598,11 @@ def _flash_forward(
     b, h, tq, d = q.shape
     hkv, tk = k.shape[1], k.shape[2]
     group = _head_group(h, hkv)
+    _check_window(tq, tk, causal, window)
     block_q, block_k = _check_blocks(tq, tk, block_q, block_k)
-    k_sweep, k_block = _k_sweep(tq, tk, causal, block_q, block_k, block_length, window)
+    schedule = _tile_schedule(
+        _pair_needed(tq, tk, block_q, block_k, causal, block_length, window)
+    )
     if not (q.dtype == k.dtype == v.dtype):
         # _acc_dot's downcast rule is only safe for the kernels' own f32
         # intermediates; a mixed-dtype *input* would be silently rounded.
@@ -611,51 +619,41 @@ def _flash_forward(
     qr = q.reshape(b * h, tq, dp)
     kr = k.reshape(b * hkv, tk, dp)
     vr = v.reshape(b * hkv, tk, dp)
+    mxu_dtype = jnp.bfloat16 if bf16_dots else None
+    qspec, kspec, rowspec = _q_held_specs(block_q, block_k, dp, group)
     out_shape = [jax.ShapeDtypeStruct((b * h, tq, dp), q.dtype)]
-    out_specs = [
-        pl.BlockSpec((1, block_q, dp), lambda bh, iq, j: (bh, iq, 0),
-                     memory_space=pltpu.VMEM),
-    ]
+    out_specs = [qspec]
     if save_lse:
         out_shape.append(jax.ShapeDtypeStruct((b * h, 1, tq), jnp.float32))
-        out_specs.append(
-            pl.BlockSpec((1, 1, block_q), lambda bh, iq, j: (bh, 0, iq),
-                         memory_space=pltpu.VMEM)
-        )
+        out_specs.append(rowspec)
     outs = pl.pallas_call(
         functools.partial(
             _flash_kernel,
             block_q=block_q, block_k=block_k, scale=scale, causal=causal,
-            save_lse=save_lse, block_length=block_length,
-            mxu_dtype=jnp.bfloat16 if bf16_dots else None, window=window,
+            save_lse=save_lse,
+            block_length=block_length, mxu_dtype=mxu_dtype, window=window,
         ),
         out_shape=out_shape,
-        grid=(b * h, tq // block_q, k_sweep),
-        in_specs=[
-            pl.BlockSpec((1, block_q, dp), lambda bh, iq, j: (bh, iq, 0),
-                         memory_space=pltpu.VMEM),
-            # Row bh = batch * h + head of q reads row bh // group of k/v:
-            # batch * hkv + head // group, the shared head.
-            pl.BlockSpec((1, block_k, dp),
-                         lambda bh, iq, j: (bh // group, k_block(iq, j), 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, dp),
-                         lambda bh, iq, j: (bh // group, k_block(iq, j), 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANE), jnp.float32),  # m (col 0)
-            pltpu.VMEM((block_q, _LANE), jnp.float32),  # l (col 0)
-            pltpu.VMEM((block_q, dp), jnp.float32),     # acc
-        ],
-        # bh and q-block programs are independent; the k sweep carries
-        # the online-softmax scratch and must stay sequential.
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            # the tables: an index map takes them after the grid's indices, a
+            # kernel before its operands
+            num_scalar_prefetch=len(schedule),
+            grid=(b * h, len(schedule[0])),
+            in_specs=[qspec, kspec, kspec],
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((block_q, _LANE), jnp.float32),  # m (col 0)
+                pltpu.VMEM((block_q, _LANE), jnp.float32),  # l (col 0)
+                pltpu.VMEM((block_q, dp), jnp.float32),     # acc
+            ],
+        ),
+        # bh programs are independent; a row's steps carry the
+        # online-softmax scratch and must stay sequential.
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(qr, kr, vr)
+    )(*schedule, qr, kr, vr)
     out = outs[0].reshape(b, h, tq, dp)[..., :d]
     if save_lse:
         return out, outs[1]
@@ -663,129 +661,110 @@ def _flash_forward(
 
 
 def _attn_probs(
-    q, k, lse, scale, causal, iq, jk, block_q, block_k, block_length=1,
-    mxu_dtype=None, window=None,
+    q, k, lse_ref, scale, causal, iq, jk, block_q, block_k, block_length, window,
+    keys_first=False,
 ):
     """Recompute the (block_q, block_k) probability tile from saved lse.
 
     ``p[r, c] = exp(s[r, c] - lse[r])`` — exactly the forward's softmax
     weights, recovered without re-running the online max/normalizer scan.
-    Shared by both backward kernels.
+    Shared by both backward kernels. ``keys_first`` (the dK/dV kernel): the
+    tile transposed, ``(block_k, block_q)``, from ``K Qᵀ``: the products that
+    sum over the queries then contract its lanes, as the MXU takes them, and
+    ``lse`` is read as it rests, a q block's rows in the lanes.
     """
-    s = _acc_dot(q, k, ((1,), (1,)), mxu_dtype) * scale
+    if keys_first:
+        s, lse = _acc_dot(k, q, ((1,), (1,))) * scale, lse_ref[0]
+    else:
+        s, lse = _acc_dot(q, k, ((1,), (1,))) * scale, lse_ref[0, 0][:, None]
     if causal:
-        q_pos = iq * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = jk * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        s = jnp.where(_visible(q_pos, k_pos, block_length, window), s, NEG_INF)
+        s = _masked(s, iq, jk, block_q, block_k, block_length, window, keys_first)
     # lse is finite for every row inside the kernel (each causal row
     # sees at least key 0 — see the forward's guard-removal note), and
     # masked scores are -inf -> exp(-inf - finite) = 0 with no NaN
-    # path, so no isneginf passes are needed on the VPU-bound tail.
-    return jnp.exp(s - lse[:, None])
+    # path, so no isneginf passes are needed.
+    return jnp.exp(s - lse)
 
 
 def _flash_bwd_dq_kernel(
+    rows_ref, cols_ref, kinds_ref,
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
     *, block_q: int, block_k: int, scale: float, causal: bool,
     block_length: int = 1, mxu_dtype=None, window: int | None = None,
 ):
-    """dQ: grid ``(batch·head, q-block, k-block)``, k innermost (the forward
-    kernel's sweep, over the q block's span under a ``window``).
+    """dQ: grid ``(batch·head, step)``, the forward kernel's sweep (a q block
+    held, its non-empty k blocks in turn).
 
     ``ds = p · (dO Vᵀ − Δ)``, ``dq += ds K · scale`` accumulated in VMEM
-    scratch over the k sweep, written once on the final k step. Δ is the
+    scratch over the row, written once on its last step. Δ is the
     precomputed ``rowsum(dO ∘ O)`` (standard FlashAttention-2 backward).
     """
     from jax.experimental import pallas as pl
 
-    iq = pl.program_id(1)
-    step = pl.program_id(2)
-    n_steps = pl.num_programs(2)
+    step = pl.program_id(1)
+    kind = kinds_ref[step]
+    iq, jk = rows_ref[step], cols_ref[step]
 
-    @pl.when(step == 0)
+    @pl.when((kind & _FIRST) != 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    j, needed = _k_step(iq, step, causal, block_q, block_k, block_length, window)
+    q, do = _mxu(q_ref[0], mxu_dtype), _mxu(do_ref[0], mxu_dtype)
+    k_blk, v_blk = _mxu(k_ref[0], mxu_dtype), _mxu(v_ref[0], mxu_dtype)
+    p = _attn_probs(
+        q, k_blk, lse_ref, scale, causal, iq, jk, block_q, block_k,
+        block_length, window,
+    )
+    dpv = _acc_dot(do, v_blk, ((1,), (1,)))
+    ds = p * (dpv - delta_ref[0, 0][:, None])
+    dq_acc[:] += _acc_dot(ds, k_blk, ((1,), (0,))) * scale
 
-    @pl.when(needed)
-    def _update():
-        q = q_ref[0]
-        k_blk = k_ref[0]
-        v_blk = v_ref[0]
-        do = do_ref[0]
-        p = _attn_probs(
-            q, k_blk, lse_ref[0, 0], scale, causal, iq, j, block_q, block_k,
-            block_length, mxu_dtype, window,
-        )
-        dpv = _acc_dot(do, v_blk, ((1,), (1,)), mxu_dtype)
-        ds = p * (dpv - delta_ref[0, 0][:, None])
-        dq_acc[:] += _acc_dot(ds, k_blk, ((1,), (0,)), mxu_dtype) * scale
-
-    @pl.when(step == n_steps - 1)
+    @pl.when((kind & _LAST) != 0)
     def _finalize():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(
+    rows_ref, cols_ref, kinds_ref, heads_ref,
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_acc, dv_acc,
     *, block_q: int, block_k: int, scale: float, causal: bool,
-    block_length: int = 1, mxu_dtype=None, n_qb: int | None = None,
-    window: int | None = None, q_blocks: int | None = None,
+    block_length: int = 1, mxu_dtype=None, window: int | None = None,
 ):
-    """dK/dV: grid ``(batch·kv-head, k-block, group·q-block)``, q innermost.
+    """dK/dV: grid ``(batch·kv-head, step)``, a k block held and its
+    non-empty q blocks in turn.
 
     ``dv += pᵀ dO``; ``dk += dsᵀ Q · scale`` — both accumulated in VMEM
-    scratch over the q sweep for a fixed k block. With grouped heads the
-    sweep runs over the q blocks of every query head of the group in turn
-    (``n_qb`` q blocks a head), so the group's sum never leaves VMEM. Under a
-    ``window`` a head's ``n_qb`` steps are the k block's span of q blocks
-    (:func:`_q_block_span`, of the ``q_blocks`` there are), from its first.
+    scratch over the row. With grouped heads the row runs over the q blocks
+    of every query head of the group in turn (the schedule's ``heads``, which
+    only the index maps read), so the group's sum never leaves VMEM.
     """
     from jax.experimental import pallas as pl
 
-    jk = pl.program_id(1)
-    sweep = pl.program_id(2)
-    n_sweep = pl.num_programs(2)
-    i = sweep if n_qb is None else jax.lax.rem(sweep, n_qb)
+    del heads_ref
+    step = pl.program_id(1)
+    kind = kinds_ref[step]
+    jk, iq = rows_ref[step], cols_ref[step]
 
-    @pl.when(sweep == 0)
+    @pl.when((kind & _FIRST) != 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    # Under causality, q blocks strictly before this k block's start see
-    # none of it; skip them.
-    if window is None:
-        needed = True if not causal else _k_block_needed(
-            i, jk, block_q, block_k, block_length
-        )
-    else:
-        first, last = _q_block_span(jk, block_q, block_k, block_length, window, q_blocks)
-        i = first + i
-        needed = i <= last
+    q, do = _mxu(q_ref[0], mxu_dtype), _mxu(do_ref[0], mxu_dtype)
+    k_blk, v_blk = _mxu(k_ref[0], mxu_dtype), _mxu(v_ref[0], mxu_dtype)
+    # every tile keys first, ``(block_k, block_q)``: pᵀ and dsᵀ come out of
+    # the products as the sums over the queries take them
+    p = _attn_probs(
+        q, k_blk, lse_ref, scale, causal, iq, jk, block_q, block_k,
+        block_length, window, keys_first=True,
+    )
+    dv_acc[:] += _acc_dot(p, do, ((1,), (0,)))
+    dpv = _acc_dot(v_blk, do, ((1,), (1,)))
+    ds = p * (dpv - delta_ref[0])
+    dk_acc[:] += _acc_dot(ds, q, ((1,), (0,))) * scale
 
-    @pl.when(needed)
-    def _update():
-        q = q_ref[0]
-        k_blk = k_ref[0]
-        v_blk = v_ref[0]
-        do = do_ref[0]
-        p = _attn_probs(
-            q, k_blk, lse_ref[0, 0], scale, causal, i, jk, block_q, block_k,
-            block_length, mxu_dtype, window,
-        )
-        dv_acc[:] += _acc_dot(p, do, ((0,), (0,)), mxu_dtype)
-        dpv = _acc_dot(do, v_blk, ((1,), (1,)), mxu_dtype)
-        ds = p * (dpv - delta_ref[0, 0][:, None])
-        dk_acc[:] += _acc_dot(ds, q, ((0,), (0,)), mxu_dtype) * scale
-
-    @pl.when(sweep == n_sweep - 1)
+    @pl.when((kind & _LAST) != 0)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -802,12 +781,9 @@ def _flash_backward(
     b, h, tq, d = q.shape
     hkv, tk = k.shape[1], k.shape[2]
     group = _head_group(h, hkv)
-    mask = dict(
-        block_length=block_length, mxu_dtype=jnp.bfloat16 if bf16_dots else None,
-        window=window,
-    )
     block_q, block_k = _check_blocks(tq, tk, block_q, block_k)
-    k_sweep, k_block = _k_sweep(tq, tk, causal, block_q, block_k, block_length, window)
+    needed = _pair_needed(tq, tk, block_q, block_k, causal, block_length, window)
+    mxu_dtype = jnp.bfloat16 if bf16_dots else None
     scale = 1.0 / math.sqrt(d)
     # The forward enforced a single q/k/v dtype; the cotangent can still
     # arrive wider (e.g. an f32 loss over a bf16 output) — align it so
@@ -825,83 +801,64 @@ def _flash_backward(
     kr = k.reshape(b * hkv, tk, dp)
     vr = v.reshape(b * hkv, tk, dp)
     gr = g.reshape(b * h, tq, dp)
-    qspec = pl.BlockSpec((1, block_q, dp), lambda bh, x, y: (bh, x, 0),
-                         memory_space=pltpu.VMEM)
-    kspec_dq = pl.BlockSpec((1, block_k, dp),
-                            lambda bh, iq, j: (bh // group, k_block(iq, j), 0),
-                            memory_space=pltpu.VMEM)
-    rowspec = pl.BlockSpec((1, 1, block_q), lambda bh, x, y: (bh, 0, x),
-                           memory_space=pltpu.VMEM)
-    dq = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_kernel,
-            block_q=block_q, block_k=block_k, scale=scale, causal=causal,
-            **mask,
-        ),
-        out_shape=jax.ShapeDtypeStruct((b * h, tq, dp), q.dtype),
-        grid=(b * h, tq // block_q, k_sweep),
-        in_specs=[qspec, kspec_dq, kspec_dq, qspec, rowspec, rowspec],
-        out_specs=qspec,
-        scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(qr, kr, vr, gr, lse, delta)
 
-    # dK/dV sweep: the grid's second axis is the k block, q innermost. Row
-    # bkv of k/v is read by the q rows bkv * group .. + group - 1; step i of
-    # the sweep is q block i % n_qb of the group's query head i // n_qb.
-    # Under a window a head's steps are the k block's span of q blocks, the
-    # longest span's many; a step past a shorter span's end holds its last.
-    q_blocks = n_qb = tq // block_q
-    if window is not None:
-        def span(jk):
-            return _q_block_span(jk, block_q, block_k, block_length, window, q_blocks)
+    def sweep(kernel, schedule, out_shape, in_specs, out_specs, acc):
+        """One backward kernel over ``schedule``'s steps, ``acc`` the shape of
+        its float32 accumulators."""
+        return pl.pallas_call(
+            functools.partial(
+                kernel, block_q=block_q, block_k=block_k, scale=scale,
+                causal=causal, block_length=block_length,
+                mxu_dtype=mxu_dtype, window=window,
+            ),
+            out_shape=out_shape,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(schedule),
+                grid=(out_shape[0].shape[0], len(schedule[0])),
+                in_specs=in_specs,
+                out_specs=out_specs,
+                scratch_shapes=[pltpu.VMEM(acc, jnp.float32)] * len(out_shape),
+            ),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+            ),
+            interpret=interpret,
+        )(*schedule, qr, kr, vr, gr, lse, delta)
 
-        n_qb = _span_steps(span, tk // block_k)
+    # dQ: the forward's sweep, the schedule's rows q blocks and its columns k
+    # blocks.
+    qspec, kspec, rowspec = _q_held_specs(block_q, block_k, dp, group)
+    (dq,) = sweep(
+        _flash_bwd_dq_kernel, _tile_schedule(needed),
+        [jax.ShapeDtypeStruct((b * h, tq, dp), q.dtype)],
+        [qspec, kspec, kspec, qspec, rowspec, rowspec], [qspec],
+        (block_q, dp),
+    )
 
-        def q_row(bkv, jk, i):
-            first, last = span(jk)
-            return bkv * group + i // n_qb, jnp.minimum(first + i % n_qb, last)
-    elif group == 1:
-        q_row = lambda bkv, jk, i: (bkv, i)  # noqa: E731
-    else:
-        q_row = lambda bkv, jk, i: (bkv * group + i // n_qb, i % n_qb)  # noqa: E731
-    qspec_kv = pl.BlockSpec((1, block_q, dp), lambda bh, jk, i: (*q_row(bh, jk, i), 0),
-                            memory_space=pltpu.VMEM)
-    kspec_kv = pl.BlockSpec((1, block_k, dp), lambda bh, jk, i: (bh, jk, 0),
-                            memory_space=pltpu.VMEM)
+    # dK/dV: the schedule's rows are k blocks, its columns q blocks. Row bkv
+    # of k/v is read by the q rows bkv * group .. + group - 1, the step's
+    # query head ``heads[s]`` of them.
+    def q_row(bkv, s, heads):
+        return bkv * group + heads[s]
 
-    def row_kv(bh, jk, i):
-        head, block = q_row(bh, jk, i)
-        return head, 0, block
-
-    rowspec_kv = pl.BlockSpec((1, 1, block_q), row_kv, memory_space=pltpu.VMEM)
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dkv_kernel,
-            block_q=block_q, block_k=block_k, scale=scale, causal=causal,
-            n_qb=None if group == 1 and window is None else n_qb,
-            q_blocks=q_blocks, **mask,
-        ),
-        out_shape=[
+    qspec = _vmem_spec(
+        (1, block_q, dp),
+        lambda bkv, s, rows, cols, _, heads: (q_row(bkv, s, heads), cols[s], 0),
+    )
+    kspec = _vmem_spec((1, block_k, dp), lambda bkv, s, rows, *_: (bkv, rows[s], 0))
+    rowspec = _vmem_spec(
+        (1, 1, block_q),
+        lambda bkv, s, rows, cols, _, heads: (q_row(bkv, s, heads), 0, cols[s]),
+    )
+    dk, dv = sweep(
+        _flash_bwd_dkv_kernel, _tile_schedule(needed, group),
+        [
             jax.ShapeDtypeStruct((b * hkv, tk, dp), k.dtype),
             jax.ShapeDtypeStruct((b * hkv, tk, dp), v.dtype),
         ],
-        grid=(b * hkv, tk // block_k, group * n_qb),
-        in_specs=[qspec_kv, kspec_kv, kspec_kv, qspec_kv, rowspec_kv,
-                  rowspec_kv],
-        out_specs=[kspec_kv, kspec_kv],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, dp), jnp.float32),
-            pltpu.VMEM((block_k, dp), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(qr, kr, vr, gr, lse, delta)
+        [qspec, kspec, kspec, qspec, rowspec, rowspec], [kspec, kspec],
+        (block_k, dp),
+    )
 
     dq = dq.reshape(b * h, tq, dp)[..., :d].reshape(b, h, tq, d)
     dk = dk.reshape(b * hkv, tk, dp)[..., :d].reshape(b, hkv, tk, d)
@@ -948,6 +905,13 @@ def flash_attention(
     keep their dtype. ``window`` (with ``causal``, queries and keys at the
     same positions) narrows the mask to the ``window`` latest positions; all
     three kernels then sweep only the blocks inside it (module docstring).
+
+    A sweep's schedule rests in scalar memory, so a call holds at most
+    ``_SCHEDULE_STEPS_MAX`` tile pairs a head (a key head's whole group, in
+    the dK/dV sweep) and raises ``ValueError`` past them: causal histories of
+    46,080 at 512-wide tiles with eight query heads to a key head, 130,560
+    with one. A head's q blocks run in turn on one core (the grid's second
+    axis carries a row's state): only batch x heads is split across cores.
     """
     return _flash_forward(
         q, k, v, causal, block_q, block_k, interpret, pad_lanes=pad_lanes,
@@ -980,8 +944,6 @@ def _flash_bwd(
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
-
-
 
 
 # --------------------------------------------------------------------------
